@@ -10,16 +10,22 @@ machine_translation (same set the reference benchmarks).
 """
 import argparse
 import json
+import os
 import sys
 import time
 
 import numpy as np
 
-import paddle_tpu.fluid as fluid
-from models import MODELS
+_HERE = os.path.dirname(os.path.abspath(__file__))
+for _p in (_HERE, os.path.dirname(os.path.dirname(_HERE))):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import paddle_tpu.fluid as fluid  # noqa: E402
+from models import MODELS  # noqa: E402
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument('--model', default='resnet', choices=sorted(MODELS))
     p.add_argument('--batch_size', type=int, default=32)
@@ -31,11 +37,16 @@ def parse_args():
     p.add_argument('--pass_num', type=int, default=1,
                    help='repeat the timed loop this many times')
     p.add_argument('--no_random', action='store_true')
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Run the benchmark; returns the record it prints. ``--device
+    TPU`` means the TPU: with none in this process the place raises
+    here, before any model is built."""
+    args = parse_args(argv)
+    place = fluid.TPUPlace(0) if args.device == 'TPU' else fluid.CPUPlace()
+    device = place.jax_device()
     build = MODELS[args.model]
 
     main_prog, startup = fluid.Program(), fluid.Program()
@@ -47,7 +58,6 @@ def main():
                                        momentum=0.9)
         opt.minimize(loss)
 
-    place = fluid.TPUPlace(0) if args.device == 'TPU' else fluid.CPUPlace()
     exe = fluid.Executor(place)
     exe.run(startup)
 
@@ -61,15 +71,19 @@ def main():
             last, = exe.run(main_prog, feed=feed, fetch_list=[loss])
     dt = time.perf_counter() - t0
     per_sec = args.pass_num * args.iterations * args.batch_size / dt
-    print(json.dumps({
+    record = {
         'model': args.model,
         'batch_size': args.batch_size,
         'iterations': args.iterations,
         'last_loss': float(np.ravel(last)[0]),
         'throughput': round(per_sec, 2),
         'unit': unit,
-    }))
+        'device': {'platform': device.platform,
+                   'kind': device.device_kind},
+    }
+    print(json.dumps(record))
+    return record
 
 
 if __name__ == '__main__':
-    sys.exit(main())
+    main()

@@ -67,9 +67,12 @@ def se_resnext(args):
             'images/sec')
 
 
-def stacked_dynamic_lstm(args):
+def stacked_dynamic_lstm(args, use_peepholes=True):
     """Stacked LSTM sentiment net on synthetic word sequences
-    (parity: benchmark/fluid/stacked_dynamic_lstm.py)."""
+    (parity: benchmark/fluid/stacked_dynamic_lstm.py). The reference
+    script keeps dynamic_lstm's default peepholes, which the fused
+    Pallas cell does not take; ``use_peepholes=False`` is the variant
+    that reaches it (chip_smoke.py runs both)."""
     dict_size = 10000
     emb_dim = 512
     hid_dim = 512
@@ -81,11 +84,13 @@ def stacked_dynamic_lstm(args):
     label = fluid.layers.data(name='label', shape=[1], dtype='int64')
     emb = fluid.layers.embedding(input=data, size=[dict_size, emb_dim])
     fc1 = fluid.layers.fc(input=emb, size=hid_dim * 4)
-    lstm1, _ = fluid.layers.dynamic_lstm(input=fc1, size=hid_dim * 4)
+    lstm1, _ = fluid.layers.dynamic_lstm(input=fc1, size=hid_dim * 4,
+                                         use_peepholes=use_peepholes)
     inputs = [fc1, lstm1]
     for _ in range(2, stacked_num + 1):
         fc = fluid.layers.fc(input=inputs, size=hid_dim * 4)
-        lstm, _ = fluid.layers.dynamic_lstm(input=fc, size=hid_dim * 4)
+        lstm, _ = fluid.layers.dynamic_lstm(input=fc, size=hid_dim * 4,
+                                            use_peepholes=use_peepholes)
         inputs = [fc, lstm]
     fc_last = fluid.layers.sequence_pool(input=inputs[0], pool_type='max')
     lstm_last = fluid.layers.sequence_pool(input=inputs[1], pool_type='max')

@@ -14,8 +14,12 @@ Usage mirrors the reference:
     ...
     exe = fluid.Executor(fluid.TPUPlace(0))
 """
-from . import framework
-from . import ops  # registers all kernels
+from .core.compile_cache import configure_compile_cache
+
+configure_compile_cache()   # before anything below can compile
+
+from . import framework  # noqa: E402
+from . import ops  # noqa: E402  (registers all kernels)
 from .framework import (Program, Block, Variable, Operator,  # noqa
                         default_startup_program, default_main_program,
                         program_guard, switch_startup_program,

@@ -27,7 +27,8 @@ from .pass_base import Pass, PassResult, register_pass
 __all__ = ['DeadOpElimination', 'ConstantFolding', 'ElementwiseFusion',
            'ConvEpilogueFusion', 'BufferReuse', 'BatchNormFolding',
            'DEFAULT_PASSES', 'INFERENCE_PASSES', 'RNG_OPS',
-           'FUSED_ELEMENTWISE_OP', 'FUSED_CONV_OP']
+           'FUSED_ELEMENTWISE_OP', 'FUSED_CONV_OP',
+           'conv_fuse_counts']
 
 # Ops that consume the threaded PRNG key: removing one would shift the
 # RNG stream of every later stochastic op, silently changing numerics —
@@ -799,29 +800,52 @@ def _fused_conv_kernel(ctx):
     epilogue) when engaged and supported, exact replay of the captured
     sub-ops otherwise. Replay is bit-identical to the unfused program —
     the pass can absorb liberally because correctness never rides on
-    the Pallas path. Fallbacks while the Pallas path was engaged are
-    counted and journalled; the off-TPU replay is not a fallback."""
+    the Pallas path. What the kernel cannot take is refused by
+    predicate (a reason string) and counted by reason in
+    ``conv_fuse_fallbacks_total{reason=}``; lowerings that engaged
+    count in ``conv_fuse_engaged_total``; the off-TPU replay is not a
+    fallback. A kernel that raises while engaged is a failure of the
+    step, not a replay."""
     import jax
     from ..ops import pallas_kernels as pk
     ops = _materialized_sub_ops(ctx)
     mode = pk.conv_epilogue_mode()
     if mode:
-        try:
-            why = _lower_fused_conv(ctx, ops, mode)
-        except Exception as err:  # never let the fused path kill a
-            why = 'error:%s' % type(err).__name__   # compile: replay
+        why = _lower_fused_conv(ctx, ops, mode)
+        reg = _obs.default_registry()
         if why is None:
+            reg.counter(
+                'conv_fuse_engaged_total',
+                help='fused_conv lowerings that ran as one Pallas '
+                     'kernel').inc()
             return
-        _obs.default_registry().counter(
+        reg.counter(
             'conv_fuse_fallbacks_total',
             help='fused_conv lowerings that fell back to exact replay '
-                 '(Pallas engaged but shape/dtype/layout unsupported)'
-        ).inc()
+                 '(Pallas engaged but shape/dtype/layout refused), by '
+                 'reason', reason=why).inc()
         _obs.emit('conv_fuse_fallback', reason=why,
                   types=list(ctx.attr('fused_types', ())),
                   out=ctx.op.outputs['Out'][0])
     with jax.named_scope(FUSED_CONV_OP):
         ctx.runner.run_ops(ops, ctx.env)
+
+
+def conv_fuse_counts():
+    """``{'engaged': n, 'fallbacks': {reason: n}}``: how the process's
+    fused_conv lowerings went so far (counted per trace, i.e. per
+    compile)."""
+    snap = _obs.default_registry().snapshot()
+
+    def series(name):
+        return snap.get(name, {}).get('series', ())
+
+    return {
+        'engaged': int(sum(s['value'] for s in
+                           series('conv_fuse_engaged_total'))),
+        'fallbacks': {s['labels']['reason']: int(s['value'])
+                      for s in series('conv_fuse_fallbacks_total')
+                      if s['value']}}
 
 
 @register_pass

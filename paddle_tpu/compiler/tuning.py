@@ -15,8 +15,9 @@ path and persists the winner in a :class:`TuningCache` keyed by
   pre-compiling buckets, so a fresh serving process cold-starts with
   the tuned configs instead of re-searching (COMPILER.md).
 
-Cache file: ``$PADDLE_TPU_TUNING_CACHE`` or
-``~/.cache/paddle_tpu/tuning_cache.json`` (atomic tmp->rename writes).
+Cache file: ``$PADDLE_TPU_TUNING_CACHE`` or ``tuning_cache.json``
+beside the compile cache in the checkout (core/compile_cache.py;
+atomic tmp->rename writes).
 """
 import contextlib
 import hashlib
@@ -79,14 +80,10 @@ def backend():
     """Device-kind-qualified backend token for cache keys. Winners are
     per device KIND, not just platform family — a v5e schedule is not a
     v4 schedule. Collapses to the bare platform when the device kind
-    adds nothing (cpu/interpreters), so existing cpu-keyed entries and
-    tests are unchanged."""
+    adds nothing (cpu/interpreters), so cpu-keyed entries stay bare."""
     import jax
     plat = jax.default_backend()
-    try:
-        kind = str(jax.devices()[0].device_kind)
-    except Exception:
-        kind = plat
+    kind = str(jax.local_devices()[0].device_kind)
     kind = kind.strip().lower().replace(' ', '-')
     return plat if kind == plat else '%s:%s' % (plat, kind)
 
@@ -105,9 +102,9 @@ def entry_token(entry):
 
 
 def _default_path():
+    from ..core.compile_cache import cache_root
     return os.environ.get('PADDLE_TPU_TUNING_CACHE') or os.path.join(
-        os.path.expanduser('~'), '.cache', 'paddle_tpu',
-        'tuning_cache.json')
+        cache_root(), 'tuning_cache.json')
 
 
 class TuningCache(object):

@@ -1,5 +1,6 @@
 from .places import (TPUPlace, CPUPlace, CUDAPlace, CUDAPinnedPlace,  # noqa
-                     is_compiled_with_cuda, is_compiled_with_tpu)
+                     PlaceUnavailableError, is_compiled_with_cuda,
+                     is_compiled_with_tpu)
 from .registry import register_kernel, get_kernel, has_kernel  # noqa
 
 

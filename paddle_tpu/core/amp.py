@@ -8,8 +8,9 @@ request ``preferred_element_type=float32``, so XLA emits bf16 MXU ops
 with f32 accumulators. Gradients flow through the casts and arrive f32;
 optimizer state stays f32 throughout.
 
-Enabled by default on TPU backends, off on CPU (tests compare against
-f64-ish numpy references). Override with PADDLE_TPU_AMP=0/1.
+Enabled by default on the chip (``places.on_tpu()``), off elsewhere
+(CPU tests compare against f64-ish numpy references). Override with
+PADDLE_TPU_AMP=0/1.
 """
 import os
 
@@ -20,8 +21,8 @@ def amp_enabled():
     if _STATE['mode'] is None:
         env = os.environ.get('PADDLE_TPU_AMP', 'auto').lower()
         if env in ('auto', ''):
-            import jax
-            _STATE['mode'] = jax.default_backend() not in ('cpu',)
+            from .places import on_tpu
+            _STATE['mode'] = on_tpu()
         else:
             _STATE['mode'] = env not in ('0', 'off', 'false', 'no')
     return _STATE['mode']

@@ -1,0 +1,45 @@
+"""Where this program keeps what it generates and reuses across
+processes: JAX's persistent compilation cache and the tuning cache.
+
+One rule, one place. If ``JAX_COMPILATION_CACHE_DIR`` is set, the
+compile cache lives there — JAX reads that variable itself, and no code
+sets another directory. If it is not, the cache lives at one fixed,
+git-ignored path inside the checkout, resolved from the package
+location: never from the cwd, a temp name, a pid or the time, because
+the path is part of what makes a second process hit what the first one
+compiled. Nothing else in the tree configures a jax cache directory
+(``PTPU_AOT_CACHE`` is a different, opt-in store of serialized
+executables: fleet/coldstart.py).
+"""
+import os
+
+__all__ = ['CACHE_ENV', 'cache_root', 'compile_cache_dir',
+           'configure_compile_cache']
+
+CACHE_ENV = 'JAX_COMPILATION_CACHE_DIR'
+
+_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.ptpu_cache')
+
+
+def cache_root():
+    """The in-checkout directory for generated, reusable state (listed
+    in .gitignore)."""
+    return _ROOT
+
+
+def compile_cache_dir():
+    """The directory JAX's persistent compilation cache uses."""
+    return os.environ.get(CACHE_ENV) or os.path.join(_ROOT, 'jax')
+
+
+def configure_compile_cache():
+    """Point JAX's persistent compilation cache at
+    :func:`compile_cache_dir`. Called once, when the package is
+    imported — before anything can compile. Returns the directory."""
+    path = compile_cache_dir()
+    if not os.environ.get(CACHE_ENV):
+        import jax
+        jax.config.update('jax_compilation_cache_dir', path)
+    return path

@@ -1,43 +1,63 @@
 """Device places.
 
 Parity: paddle/fluid/platform/place.h (CPUPlace/CUDAPlace/CUDAPinnedPlace).
-BASELINE north star: add ``TPUPlace`` alongside. On this stack every place
-maps to a JAX backend; ``CUDAPlace`` is accepted for script compatibility and
-resolves to the best available accelerator (TPU if present).
+BASELINE north star: add ``TPUPlace`` alongside. A place names one device
+this process can address, and means what it says: ``TPUPlace(i)`` is the
+i-th local TPU or a :class:`PlaceUnavailableError` — never another
+backend, never ``i`` wrapped onto fewer devices. ``CUDAPlace`` is the
+script-compatibility alias for "the backend JAX was started on", which
+is also what ``place=None`` resolves to (:func:`default_place`).
 """
-import functools
 
 __all__ = ['TPUPlace', 'CPUPlace', 'CUDAPlace', 'CUDAPinnedPlace',
+           'PlaceUnavailableError', 'default_place', 'on_tpu',
            'is_compiled_with_cuda', 'is_compiled_with_tpu']
 
 
-@functools.lru_cache(maxsize=None)
-def _backend_devices(platform):
-    """Process-LOCAL devices: a Place names a device this process can
-    address. Under jax.distributed, jax.devices() is the global list and
-    device 0 may belong to another process — placing startup state there
-    would make every state array non-addressable (multi-process bug,
-    r4)."""
+class PlaceUnavailableError(RuntimeError):
+    """The process has no such device: the platform is not one JAX was
+    started on, or ``device_id`` is past its last local device."""
+
+
+def _local_devices(platform):
+    """Process-LOCAL devices of ``platform`` (None = the default
+    backend), () when JAX has no such backend. A Place names a device
+    this process can address: under jax.distributed, jax.devices() is
+    the global list and device 0 may belong to another process."""
     import jax
     try:
-        if platform is None:
-            return tuple(jax.local_devices())
         return tuple(jax.local_devices(backend=platform))
     except RuntimeError:
         return ()
 
 
+def on_tpu():
+    """THE predicate for "this process runs on the chip": AMP's auto
+    mode, every Pallas engagement policy and the tuning key ask this
+    one question."""
+    import jax
+    return jax.default_backend() == 'tpu'
+
+
 class Place(object):
-    platform = 'cpu'
+    platform = None     # None -> the backend JAX was started on
 
     def __init__(self, device_id=0):
         self.device_id = device_id
 
     def jax_device(self):
-        devs = _backend_devices(self.platform)
+        import jax
+        devs = _local_devices(self.platform)
         if not devs:
-            devs = _backend_devices(None)  # default backend
-        return devs[self.device_id % len(devs)]
+            raise PlaceUnavailableError(
+                '%r: this process has no %r platform (JAX default '
+                'backend: %r)' % (self, self.platform,
+                                  jax.default_backend()))
+        if not 0 <= self.device_id < len(devs):
+            raise PlaceUnavailableError(
+                '%r: device_id out of range, this process addresses %d '
+                '%s device(s)' % (self, len(devs), devs[0].platform))
+        return devs[self.device_id]
 
     def __eq__(self, other):
         return (type(self) is type(other)
@@ -53,40 +73,37 @@ class Place(object):
 class CPUPlace(Place):
     platform = 'cpu'
 
-    def __init__(self, device_id=0):
-        super(CPUPlace, self).__init__(device_id)
-
 
 class TPUPlace(Place):
     platform = 'tpu'
 
-    def jax_device(self):
-        devs = _backend_devices('tpu')
-        if not devs:
-            devs = _backend_devices(None)
-        return devs[self.device_id % len(devs)]
-
 
 class CUDAPlace(Place):
-    """Compatibility alias: scripts written for CUDAPlace run on the best
-    available accelerator (TPU > GPU > CPU)."""
-    platform = None
-
-    def jax_device(self):
-        for plat in ('tpu', 'gpu', None):
-            devs = _backend_devices(plat)
-            if devs:
-                return devs[self.device_id % len(devs)]
-        raise RuntimeError("no jax devices")
+    """Compatibility alias: scripts written for CUDAPlace run on the
+    backend JAX was started on."""
 
 
 class CUDAPinnedPlace(CPUPlace):
     pass
 
 
+def default_place():
+    """What ``place=None`` means everywhere (Executor, ModelServer,
+    Trainer): device 0 of the backend JAX was started on, under the
+    name that says which — so a CPU-only process gets ``CPUPlace(0)``
+    and says so, never a ``TPUPlace`` that quietly ran elsewhere."""
+    import jax
+    plat = jax.default_backend()
+    if plat == 'tpu':
+        return TPUPlace(0)
+    if plat == 'cpu':
+        return CPUPlace(0)
+    return CUDAPlace(0)
+
+
 def is_compiled_with_cuda():
-    return bool(_backend_devices('gpu'))
+    return bool(_local_devices('gpu'))
 
 
 def is_compiled_with_tpu():
-    return bool(_backend_devices('tpu'))
+    return bool(_local_devices('tpu'))

@@ -361,7 +361,7 @@ def _is_dynamic_program(program):
 
 class Executor(object):
     def __init__(self, place=None, partitioner=None):
-        self.place = place or _places.TPUPlace(0)
+        self.place = place or _places.default_place()
         # Placement owner (PARTITIONING.md): every Executor dispatches
         # through a Partitioner. None defers to the lazy CPU-fallback
         # partitioner for `place` (a 1-device mesh -> plain jit,
@@ -1323,6 +1323,36 @@ class Executor(object):
             tspan.end()
         return steps_out
 
+    def lowered(self, program, feed, fetch_list, scope=None):
+        """The ``jax.stages.Lowered`` of the single-device step
+        :meth:`run` compiles for these arguments — same prune, same
+        pass pipeline, same lowering, nothing executed. Its
+        ``as_text()`` is where to look for what the step really
+        contains (chip_smoke.py checks the Mosaic custom calls of the
+        Pallas kernels there)."""
+        return self._lower(program, feed, fetch_list, scope,
+                           optimize=True)
+
+    def _lower(self, program, feed, fetch_list, scope, optimize):
+        """Shared by :meth:`lowered` (the pass pipeline's program) and
+        :meth:`cost_analysis` (the pruned program as written)."""
+        scope = scope or global_scope()
+        fetch_names, feed, state_in_names, state_out_names, static_env = \
+            self._prep_lowering(program, feed, fetch_list, scope,
+                                consume_readers=False)
+        if optimize:
+            lower_prog = self._optimized_program(program, fetch_names,
+                                                 scope=scope)
+        else:
+            lower_prog = self._maybe_prune(program, fetch_names)
+        fn = lower_block(lower_prog, lower_prog.global_block(),
+                         sorted(feed.keys()), fetch_names,
+                         state_in_names, state_out_names,
+                         static_env=static_env)
+        state = {n: scope.raw(n) for n in state_in_names}
+        with jax.default_device(self.place.jax_device()):
+            return jax.jit(fn).lower(feed, state)
+
     def cost_analysis(self, program, feed, fetch_list, scope=None):
         """XLA's own ledger for the step this program compiles to:
         flops, HBM bytes accessed (per-fusion sums), and compiled
@@ -1330,17 +1360,8 @@ class Executor(object):
         reference exposes per-op timings via its profiler; here the
         whole block is ONE XLA program so the ledger is the natural
         analog)."""
-        scope = scope or global_scope()
-        fetch_names, feed, state_in_names, state_out_names, static_env = \
-            self._prep_lowering(program, feed, fetch_list, scope,
-                                consume_readers=False)
-        lower_prog = self._maybe_prune(program, fetch_names)
-        fn = lower_block(lower_prog, lower_prog.global_block(),
-                         sorted(feed.keys()), fetch_names,
-                         state_in_names, state_out_names,
-                         static_env=static_env)
-        state = {n: scope.raw(n) for n in state_in_names}
-        comp = jax.jit(fn).lower(feed, state).compile()
+        comp = self._lower(program, feed, fetch_list, scope,
+                           optimize=False).compile()
         ca = comp.cost_analysis()
         if isinstance(ca, list):
             ca = ca[0]

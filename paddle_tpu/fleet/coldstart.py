@@ -60,7 +60,8 @@ AOT_CACHE_ENV = 'PTPU_AOT_CACHE'
 # executable carries input_output_alias metadata whose jax-side
 # dispatch bookkeeping does not survive the serialize round trip, and
 # deserializing one corrupts state buffers shared across shape buckets
-_SCHEMA = 2
+# schema 3: entries record the devices they were compiled for
+_SCHEMA = 3
 _SUFFIX = '.aotx'
 
 _lock = threading.Lock()
@@ -150,10 +151,14 @@ class AotStore(object):
     """Atomic on-disk store of AOT-serialized executables.
 
     One file per compilation: ``<dir>/<sha256(program_cache_key)>.aotx``
-    holding a pickled ``{'token', 'payload', 'in_tree', 'out_tree'}``
-    record. The payload is what ``serialize_executable.serialize``
-    returns; the trees are the PyTreeDefs needed to rebuild the
-    ``Compiled``'s calling convention. Trust model: the cache dir is
+    holding a pickled ``{'token', 'payload', 'in_tree', 'out_tree',
+    'device_ids'}`` record. The payload is what
+    ``serialize_executable.serialize`` returns; the trees are the
+    PyTreeDefs needed to rebuild the ``Compiled``'s calling convention;
+    the device ids are the devices it was compiled for, in assignment
+    order — ``deserialize_and_load`` otherwise loads onto every local
+    device, and a one-device executable then demands one shard per
+    device. Trust model: the cache dir is
     operator-provided, the same trust domain as the TuningCache — do
     not point it at hostile data.
     """
@@ -215,10 +220,13 @@ class AotStore(object):
                       have=rec.get('token'), want=want)
             return None
         try:
+            import jax
             from jax.experimental.serialize_executable import \
                 deserialize_and_load
+            local = {d.id: d for d in jax.local_devices()}
             compiled = deserialize_and_load(
-                rec['payload'], rec['in_tree'], rec['out_tree'])
+                rec['payload'], rec['in_tree'], rec['out_tree'],
+                execution_devices=[local[i] for i in rec['device_ids']])
         except Exception as e:  # noqa: BLE001 — skew the token missed
             self.m_failures.inc()
             self.m_misses.inc()
@@ -244,7 +252,10 @@ class AotStore(object):
             from jax.experimental.serialize_executable import serialize
             payload, in_tree, out_tree = serialize(compiled)
             rec = {'token': token(**token_kw), 'payload': payload,
-                   'in_tree': in_tree, 'out_tree': out_tree}
+                   'in_tree': in_tree, 'out_tree': out_tree,
+                   'device_ids': [
+                       d.id for d in
+                       compiled.runtime_executable().local_devices()]}
             blob = pickle.dumps(rec, protocol=pickle.HIGHEST_PROTOCOL)
             os.makedirs(self.dirname, exist_ok=True)
             fd, tmp = tempfile.mkstemp(dir=self.dirname,

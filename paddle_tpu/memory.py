@@ -19,7 +19,7 @@ def _device(place=None):
     import jax
     if place is not None and hasattr(place, 'jax_device'):
         return place.jax_device()
-    return jax.devices()[0]
+    return jax.local_devices()[0]
 
 
 def memory_stats(place=None):
@@ -32,23 +32,12 @@ def memory_stats(place=None):
     """
     import jax
     dev = _device(place)
-    try:
-        stats = dev.memory_stats()
-    except Exception:
-        stats = None
+    stats = dev.memory_stats()
     if not stats:
-        # Backend without allocator stats (CPU, tunneled devices): count
-        # live jax.Array bytes resident on this device instead.
-        live = 0
-        try:
-            for arr in jax.live_arrays():
-                try:
-                    if dev in arr.devices():
-                        live += arr.nbytes // len(arr.devices())
-                except Exception:
-                    continue
-        except Exception:
-            pass
+        # Backend without allocator stats (CPU): count live jax.Array
+        # bytes resident on this device instead.
+        live = sum(arr.nbytes // len(arr.devices())
+                   for arr in jax.live_arrays() if dev in arr.devices())
         return {'bytes_in_use': live, 'supported': False,
                 'source': 'live_arrays'}
     out = dict(stats)
@@ -82,8 +71,8 @@ class HostArena(object):
     """
 
     def __init__(self, chunk_bytes=8 << 20):
-        from .native.loader import _load
-        self._lib = _load()
+        from .native import loader
+        self._lib = loader._load() if loader.available() else None
         self._handle = None
         self._views = {}   # id(view) -> weakref (ndarray isn't hashable)
         if self._lib is not None:

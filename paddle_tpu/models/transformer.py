@@ -28,21 +28,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f=None, **kwargs):
-    """``jax.shard_map`` across jax versions: jax < 0.5 ships it under
-    ``jax.experimental.shard_map`` and spells ``check_vma`` as
-    ``check_rep`` — normalize so the model code runs on both (this
-    image's jax lacks ``jax.shard_map``; the tier-1 seed failed here)."""
-    if hasattr(jax, 'shard_map'):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-        if 'check_vma' in kwargs:
-            kwargs['check_rep'] = kwargs.pop('check_vma')
-    if f is None:
-        return functools.partial(fn, **kwargs)
-    return fn(f, **kwargs)
-
 __all__ = ['TransformerConfig', 'init_params', 'forward', 'loss_fn',
            'make_train_step', 'param_specs', 'ring_attention',
            'stack_pipeline_params', 'unstack_pipeline_params',
@@ -368,11 +353,11 @@ def make_pipeline_fn(cfg, mesh, attn_fn, n_micro, axis_name='pp'):
 
     layers_specs = _stacked_layer_specs(cfg, S, axis_name)
     batch_axis = 'dp' if axes.get('dp', 1) > 1 else None
-    return shard_map_compat(
-        mesh=mesh,
+    return jax.shard_map(
+        run, mesh=mesh,
         in_specs=(layers_specs, P(batch_axis, None, None)),
         out_specs=P(batch_axis, None, None),
-        check_vma=False)(run)
+        check_vma=False)
 
 
 def forward_pipelined(params, tokens, cfg, pipe_fn, pos_offset=0):
@@ -473,8 +458,8 @@ def make_train_step(cfg, mesh, lr=1e-3, seq_parallel=None):
     if use_sp:
         # ring attention runs under shard_map over the sp axis only;
         # dp/tp stay with the SPMD partitioner.
-        @shard_map_compat(
-            mesh=mesh,
+        @functools.partial(
+            jax.shard_map, mesh=mesh,
             in_specs=(P(None, 'sp', None, None),) * 3,
             out_specs=P(None, 'sp', None, None),
             check_vma=False)

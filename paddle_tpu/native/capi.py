@@ -1,45 +1,40 @@
 """Build/bind helper for the inference C API (capi.cc).
 
-Parity: paddle/capi (C-linkage predictor). ``load()`` lazily builds
-libptpu_capi.so (same pattern as loader.py) and returns a ctypes
+Parity: paddle/capi (C-linkage predictor). ``load()`` builds
+libptpu_capi.so from capi.cc on first use (build.py) and returns a ctypes
 handle with argtypes set — usable both for in-process testing and as
 documentation of the C surface. C programs link the .so directly; see
 tests/test_capi.py for a compiled-C-driver example.
 """
 import ctypes
 import os
-import subprocess
 import threading
+
+from .build import ensure_built, load_library
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_HERE, 'libptpu_capi.so')
 _LIB = None
 _LOCK = threading.Lock()
-_TRIED = False
+
+
+_SOURCES = ('capi.cc',)
 
 
 def build():
-    subprocess.run(['make', '-s', '-C', _HERE, 'libptpu_capi.so'],
-                   check=True, capture_output=True)
-    return _LIB_PATH
+    return ensure_built('libptpu_capi.so', _SOURCES)
 
 
 def load():
-    global _LIB, _TRIED
+    """The bound library; raises :class:`build.NativeBuildError` (with
+    the toolchain's message) when it cannot be built or loaded."""
+    global _LIB
     if _LIB is not None:
         return _LIB
     with _LOCK:
-        if _LIB is not None or _TRIED:
+        if _LIB is not None:
             return _LIB
-        _TRIED = True
-        try:
-            src = os.path.join(_HERE, 'capi.cc')
-            if not os.path.exists(_LIB_PATH) or (
-                    os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
-                build()
-            lib = ctypes.CDLL(_LIB_PATH)
-        except Exception:
-            return None
+        lib = load_library('libptpu_capi.so', _SOURCES)
         lib.ptpu_predictor_create.restype = ctypes.c_void_p
         lib.ptpu_predictor_create.argtypes = [ctypes.c_char_p]
         lib.ptpu_predictor_num_inputs.restype = ctypes.c_int

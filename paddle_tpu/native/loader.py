@@ -1,43 +1,37 @@
 """ctypes binding for the native recordio reader/writer + prefetch
-loader (recordio.cc). Built lazily with make on first use; every entry
-point degrades to the pure-Python implementation in reader_io.py when the
-toolchain is unavailable (pybind11 is not in this image — plain ctypes).
+loader (recordio.cc). Built from source on first use (build.py); the
+readers degrade to the pure-Python implementation in reader_io.py when
+the toolchain is unavailable — and say so (pybind11 is not in this
+image — plain ctypes).
 """
 import ctypes
-import os
-import subprocess
 import threading
+import warnings
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_HERE, 'librecordio.so')
+from .build import NativeBuildError, load_library
+
 _LIB = None
 _BUILD_LOCK = threading.Lock()
-_BUILD_TRIED = False
-
-
-def _build():
-    subprocess.run(['make', '-s', '-C', _HERE], check=True,
-                   capture_output=True)
+_BUILD_ERROR = None
 
 
 def _load():
-    global _LIB, _BUILD_TRIED
+    """The bound library; raises :class:`NativeBuildError` (with the
+    toolchain's message) when it cannot be built or loaded."""
+    global _LIB, _BUILD_ERROR
     if _LIB is not None:
         return _LIB
     with _BUILD_LOCK:
-        if _LIB is not None or _BUILD_TRIED:
+        if _LIB is not None:
             return _LIB
-        _BUILD_TRIED = True
+        if _BUILD_ERROR is not None:
+            raise _BUILD_ERROR
         try:
-            srcs = [os.path.join(_HERE, f) for f in os.listdir(_HERE)
-                    if f.endswith('.cc')] + [os.path.join(_HERE, 'Makefile')]
-            if not os.path.exists(_LIB_PATH) or (
-                    os.path.getmtime(_LIB_PATH) <
-                    max(os.path.getmtime(s) for s in srcs)):
-                _build()
-            lib = ctypes.CDLL(_LIB_PATH)
-        except Exception:
-            return None
+            lib = load_library('librecordio.so',
+                               ('recordio.cc', 'arena.cc'))
+        except NativeBuildError as e:
+            _BUILD_ERROR = e      # do not re-run make on every call
+            raise
         lib.rio_open.restype = ctypes.c_void_p
         lib.rio_open.argtypes = [ctypes.c_char_p]
         lib.rio_next.restype = ctypes.POINTER(ctypes.c_uint8)
@@ -70,14 +64,21 @@ def _load():
 
 
 def available():
-    return _load() is not None
+    """True when the native library is usable. False — with a warning
+    carrying the build error — when it is not and callers should take
+    their pure-Python path."""
+    try:
+        _load()
+    except NativeBuildError as e:
+        warnings.warn('native loader unavailable, using the pure-Python '
+                      'readers: %s' % e, RuntimeWarning, stacklevel=2)
+        return False
+    return True
 
 
 def read_records(path):
     """Generator over raw record payload bytes (native crc32 checked)."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native loader not built")
     h = lib.rio_open(path.encode())
     if not h:
         raise IOError("%s is not a paddle_tpu recordio file" % path)
@@ -98,8 +99,6 @@ def read_records(path):
 def write_records(path, payloads):
     """Write payload byte strings; returns the record count."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native loader not built")
     h = lib.rio_writer_open(path.encode())
     if not h:
         raise IOError("cannot open %s for writing" % path)
@@ -129,8 +128,7 @@ class PrefetchLoader(object):
         self._h = None
 
     def __iter__(self):
-        lib = _load()
-        if lib is None:
+        if not available():
             # degraded mode: plain sequential python reads
             from ..reader_io import read_records as py_read
             for _ in range(self._passes):
@@ -138,6 +136,7 @@ class PrefetchLoader(object):
                     for payload in py_read(fn):
                         yield payload
             return
+        lib = _load()
         arr = (ctypes.c_char_p * len(self._filenames))(
             *[f.encode() for f in self._filenames])
         h = lib.loader_create(arr, len(self._filenames),

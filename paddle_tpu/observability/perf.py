@@ -49,8 +49,7 @@ from .journal import emit as _emit
 from . import metrics as _metrics
 
 __all__ = [
-    'PERF_ENV', 'PEAK_FLOPS_ENV', 'HBM_GBPS_ENV',
-    'DEFAULT_PEAK_FLOPS', 'DEFAULT_HBM_GBPS',
+    'PERF_ENV', 'UnknownDeviceKindError',
     'ProgramLedger', 'LedgerBook', 'PerfBaseline',
     'capture_enabled', 'enable_capture', 'capture_scope',
     'capture_compiled', 'seal', 'publish_step',
@@ -61,20 +60,16 @@ __all__ = [
 ]
 
 PERF_ENV = 'PTPU_PERF'              # '1' -> capture on for the process
-PEAK_FLOPS_ENV = 'PTPU_PERF_PEAK_FLOPS'   # override bf16 peak (flop/s)
-HBM_GBPS_ENV = 'PTPU_PERF_HBM_GBPS'       # override HBM bandwidth
 
-# bf16 peak flop/s by device-kind substring (first match wins) — same
-# table bench.py's MFU headlines always used; v5e is the measured chip.
+# Published per-chip peaks, keyed by PJRT ``device_kind`` substring
+# (first match wins; v5e reports 'TPU v5 lite'). bf16 flop/s and HBM
+# GB/s; v5e from the Google Cloud "TPU v5e" page. A device that is not
+# here has no roofline: asking for one is an error, never another
+# chip's numbers.
 PEAK_BF16 = (('v6', 918e12), ('v5p', 459e12), ('v5', 197e12),
              ('v4', 275e12), ('v3', 123e12), ('v2', 45e12))
-# HBM GB/s by device-kind substring; 819 is the v5e number every
-# published bandwidth-bound figure in PERF.md is computed against.
 HBM_GBPS = (('v6', 1640.0), ('v5p', 2765.0), ('v5', 819.0),
             ('v4', 1228.0), ('v3', 900.0), ('v2', 700.0))
-
-DEFAULT_PEAK_FLOPS = 197e12
-DEFAULT_HBM_GBPS = 819.0
 
 BASELINE_SCHEMA = 1
 
@@ -86,30 +81,29 @@ DETERMINISTIC_RTOL = 0.02
 _TRUTHY = ('1', 'true', 'on', 'yes')
 
 
-def peak_flops_for(device_kind, default=DEFAULT_PEAK_FLOPS):
-    """bf16 peak flop/s for a PJRT ``device_kind`` string (env override
-    ``PTPU_PERF_PEAK_FLOPS`` wins; unknown kinds -> ``default``)."""
-    ov = os.environ.get(PEAK_FLOPS_ENV)
-    if ov:
-        try:
-            return float(ov)
-        except ValueError:
-            pass
-    kind = (device_kind or '').lower()
-    return next((p for s, p in PEAK_BF16 if s in kind), default)
+class UnknownDeviceKindError(ValueError):
+    """``device_kind`` has no entry in the peak tables."""
 
 
-def hbm_gbps_for(device_kind, default=DEFAULT_HBM_GBPS):
-    """HBM bandwidth in GB/s for a device kind (env override
-    ``PTPU_PERF_HBM_GBPS`` wins; unknown kinds -> ``default``)."""
-    ov = os.environ.get(HBM_GBPS_ENV)
-    if ov:
-        try:
-            return float(ov)
-        except ValueError:
-            pass
+def _peak_lookup(table, what, device_kind):
     kind = (device_kind or '').lower()
-    return next((b for s, b in HBM_GBPS if s in kind), default)
+    for sub, value in table:
+        if sub in kind:
+            return value
+    raise UnknownDeviceKindError(
+        'no %s known for device_kind %r; add it to '
+        'paddle_tpu.observability.perf with its source' %
+        (what, device_kind))
+
+
+def peak_flops_for(device_kind):
+    """bf16 peak flop/s for a PJRT ``device_kind`` string."""
+    return _peak_lookup(PEAK_BF16, 'bf16 peak flop/s', device_kind)
+
+
+def hbm_gbps_for(device_kind):
+    """HBM bandwidth in GB/s for a PJRT ``device_kind`` string."""
+    return _peak_lookup(HBM_GBPS, 'HBM bandwidth', device_kind)
 
 
 # ---- capture gate ---------------------------------------------------------
@@ -210,6 +204,14 @@ class ProgramLedger(object):
                    + self.temp_bytes)
 
     @property
+    def _has_roofline(self):
+        """False for a ledger captured on the CPU backend: it has no
+        roofline, so its derived fields are absent — never computed
+        against another chip's peaks. Any other kind must be in the
+        tables (the lookups raise)."""
+        return self.device_kind != 'cpu'
+
+    @property
     def peak_flops(self):
         return peak_flops_for(self.device_kind)
 
@@ -228,22 +230,28 @@ class ProgramLedger(object):
     @property
     def roofline_bound(self):
         """Which roofline leg binds this program: the larger of the two
-        bound times is the constraint the measured step cannot beat."""
+        bound times is the constraint the measured step cannot beat.
+        None where the device has no roofline."""
+        if not self._has_roofline:
+            return None
         return ('compute' if self.compute_bound_s()
                 >= self.bandwidth_bound_s() else 'bandwidth')
 
     def mfu(self, measured_ms=None, peak=None):
         """XLA-counted flops over the measured step against bf16 peak;
-        None until a measured step time is known."""
+        None until a measured step time is known, and where the device
+        has no roofline."""
         ms = self.measured_ms if measured_ms is None else measured_ms
         if not ms:
             return None
-        pk = self.peak_flops if peak is None else peak
-        return self.flops / (ms / 1e3) / pk
+        if peak is None:
+            if not self._has_roofline:
+                return None
+            peak = self.peak_flops
+        return self.flops / (ms / 1e3) / peak
 
     # -- serialization ------------------------------------------------------
-    def bench_dict(self, measured_ms, hbm_gbps=DEFAULT_HBM_GBPS,
-                   peak=DEFAULT_PEAK_FLOPS):
+    def bench_dict(self, measured_ms, hbm_gbps, peak):
         """The exact BENCH-JSON ``ledger`` dict bench.py has always
         published (resnet50 r4 onward) — field names and rounding are
         byte-compatible with the retired private implementation."""
@@ -270,11 +278,12 @@ class ProgramLedger(object):
             'temp_bytes': self.temp_bytes,
             'argument_bytes': self.argument_bytes,
             'live_bytes': self.live_bytes,
-            'bandwidth_bound_ms': round(
-                self.bandwidth_bound_s() * 1e3, 3),
-            'compute_bound_ms': round(self.compute_bound_s() * 1e3, 3),
-            'roofline': self.roofline_bound,
         }
+        if self._has_roofline:
+            d['bandwidth_bound_ms'] = round(
+                self.bandwidth_bound_s() * 1e3, 3)
+            d['compute_bound_ms'] = round(self.compute_bound_s() * 1e3, 3)
+            d['roofline'] = self.roofline_bound
         if self.label:
             d['program'] = self.label
         if self.compile_wall_s is not None:
@@ -459,8 +468,9 @@ def publish_step(fingerprint, seconds_per_step):
                       'compute-bound, 0.0 = bandwidth-bound',
                       program=fingerprint))
         _GAUGES[fingerprint] = pair
-    pair[0].set(mfu or 0.0)
-    pair[1].set(1.0 if ledger.roofline_bound == 'compute' else 0.0)
+    if mfu is not None:      # a device with a roofline (not the CPU)
+        pair[0].set(mfu)
+        pair[1].set(1.0 if ledger.roofline_bound == 'compute' else 0.0)
     if fingerprint not in _PUBLISHED:
         _PUBLISHED.add(fingerprint)
         _emit('perf_ledger', fp=fingerprint, phase='measured',
@@ -472,13 +482,12 @@ def publish_step(fingerprint, seconds_per_step):
 
 # ---- shared offline helpers (the one ledger implementation) ---------------
 def program_ledger(exe, program, feed, fetch_list, scope=None,
-                   measured_ms=None, hbm_gbps=DEFAULT_HBM_GBPS,
-                   peak=DEFAULT_PEAK_FLOPS):
+                   measured_ms=None, device_kind=None):
     """The bench.py ledger dict for a fluid program, via
     ``Executor.cost_analysis`` (the allowlisted XLA caller). With
-    ``measured_ms`` this returns the full BENCH-compatible dict
-    (``bandwidth_bound_ms`` .. ``hw_flops_per_sec``); without it, just
-    the raw cost fields."""
+    ``measured_ms`` (and the ``device_kind`` it was measured on) this
+    returns the full BENCH-compatible dict (``bandwidth_bound_ms`` ..
+    ``hw_flops_per_sec``); without it, just the raw cost fields."""
     ca = exe.cost_analysis(program, feed, fetch_list, scope=scope)
     if measured_ms is None:
         return dict(ca)
@@ -488,7 +497,9 @@ def program_ledger(exe, program, feed, fetch_list, scope=None,
         output_bytes=ca.get('output_bytes', 0.0),
         temp_bytes=ca['temp_bytes'],
         argument_bytes=ca.get('argument_bytes', 0))
-    return ledger.bench_dict(measured_ms, hbm_gbps=hbm_gbps, peak=peak)
+    return ledger.bench_dict(measured_ms,
+                             hbm_gbps=hbm_gbps_for(device_kind),
+                             peak=peak_flops_for(device_kind))
 
 
 def memory_dict(comp):
@@ -510,8 +521,7 @@ def transformer_flops_per_token(n_layers, d_model, vocab, seq):
     return 6 * n_matmul + 12 * n_layers * (seq // 2) * d_model
 
 
-def mfu_from_throughput(per_sec, flops_per_unit,
-                        peak=DEFAULT_PEAK_FLOPS):
+def mfu_from_throughput(per_sec, flops_per_unit, peak):
     """round(throughput * flops-per-unit / peak, 4) — the BENCH-JSON
     MFU rounding, one place."""
     return round(per_sec * flops_per_unit / peak, 4)

@@ -23,21 +23,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is TPU-only at runtime but importable everywhere
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..core.places import on_tpu as _on_tpu
 
 _NEG_INF = -1e30
-
-
-def _on_tpu():
-    try:
-        return jax.default_backend() == 'tpu'
-    except Exception:
-        return False
 
 
 # ---- flash attention ------------------------------------------------------------
@@ -602,9 +593,9 @@ def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
     if block_k is None:
         block_k = 1024
     work = B * H * T
-    use_pallas = _HAS_PALLAS and (interpret or (
-        _on_tpu() and T >= _FLASH_MIN_T and work >= _FLASH_MIN_ROWS))
-    if force is not None and _HAS_PALLAS and (interpret or _on_tpu()):
+    use_pallas = interpret or (
+        _on_tpu() and T >= _FLASH_MIN_T and work >= _FLASH_MIN_ROWS)
+    if force is not None and (interpret or _on_tpu()):
         # benchmarking hook: measure the kernel on both sides of the
         # engagement boundary (bench.py's engagement table)
         use_pallas = force
@@ -704,12 +695,15 @@ _lstm_cell.defvjp(_lstm_cell_fwd, _lstm_cell_bwd)
 # hardcoded here (tools/lint_repo.py ``hardcoded-schedule``).
 #
 # Grid: (N, H-blocks, outchannel-blocks). 1x1 convs tile H cleanly
-# (input rows partition as [bh*stride] blocks); KxK convs take the
-# whole padded image per step — overlapping input windows cannot be
+# (input rows partition as bh-row blocks); KxK convs take the whole
+# padded image per step — overlapping input windows cannot be
 # expressed by a BlockSpec partition — with a static python loop over
-# the (kh, kw) taps. Strided taps use a reshape-and-take trick instead
-# of strided slicing (Mosaic-safe); the input is padded with slack rows
-# so every tap's reshape fits.
+# the (kh, kw) taps. Strides are taken OUTSIDE the kernel: the padded
+# input is split into its sh*sw stride phases (x[:, p::sh, q::sw]), so
+# every tap is a unit-stride window of one phase. Mosaic refuses
+# strided loads of bf16 and of blocks whose last dim is not 128, and
+# its reshape-and-take relayouts overflowed scoped VMEM (PERF.md,
+# "Bring-up on the chip").
 
 # Epilogue stage vocabulary. Math mirrors ops/math_ops.py kernels
 # one-for-one (the replay fallback runs those exact kernels; the fused
@@ -786,8 +780,8 @@ def _apply_stage(y, st, fetch_aux):
     raise ValueError('unknown epilogue stage %r' % (st,))
 
 
-def _fconv_kernel(*refs, kh, kw, sh, sw, bh, wo, depthwise, stages,
-                  aux_kinds, emit_stats):
+def _fconv_kernel(*refs, kh, kw, sh, sw, phases, bh, wo, depthwise,
+                  stages, aux_kinds, emit_stats):
     """One (n, h-block, outchannel-block) grid step: conv taps
     accumulate f32, stats partials (train BN) and epilogue stages apply
     in-register, one store."""
@@ -795,15 +789,13 @@ def _fconv_kernel(*refs, kh, kw, sh, sw, bh, wo, depthwise, stages,
     x_ref, w_ref = refs[0], refs[1]
     aux_refs = refs[2:2 + n_aux]
     out_ref = refs[2 + n_aux]
-    xb = x_ref[0]                      # [row_span, Wtot, C]
     acc = None
     for i in range(kh):
         for j in range(kw):
-            t = xb[i:i + bh * sh, j:j + wo * sw, :]
-            if sh > 1:   # reshape-and-take: rows i, i+sh, ... (no
-                t = t.reshape(bh, sh, t.shape[1], t.shape[2])[:, 0]
-            if sw > 1:   # strided slices — Mosaic-safe)
-                t = t.reshape(t.shape[0], wo, sw, t.shape[-1])[:, :, 0]
+            # tap (i, j) is a unit-stride [bh, wo, C] window of stride
+            # phase (i % sh, j % sw), loaded straight from the ref
+            t = x_ref[0, phases.index((i % sh, j % sw)),
+                      pl.ds(i // sh, bh), pl.ds(j // sw, wo), :]
             if depthwise:
                 tap = t.astype(jnp.float32) * \
                     w_ref[i, j].astype(jnp.float32)[None, None, :]
@@ -822,43 +814,51 @@ def _fconv_kernel(*refs, kh, kw, sh, sw, bh, wo, depthwise, stages,
         # its slab slot exclusively (no output revisiting)
         psum_ref = refs[2 + n_aux + 1]
         psumsq_ref = refs[2 + n_aux + 2]
-        psum_ref[0, 0] = jnp.sum(y, axis=(0, 1))
-        psumsq_ref[0, 0] = jnp.sum(y * y, axis=(0, 1))
+        psum_ref[0, 0] = jnp.sum(y, axis=(0, 1))[None, :]
+        psumsq_ref[0, 0] = jnp.sum(y * y, axis=(0, 1))[None, :]
 
     def fetch_aux(idx):
-        kind2 = aux_kinds[idx]
         o = aux_refs[idx]
-        if kind2 == 't':
+        if aux_kinds[idx] == 't':
             return o[0].astype(jnp.float32)          # [bh, wo, bc]
-        if kind2 == 's':
-            return o[0, 0].astype(jnp.float32)       # scalar
-        return o[0].astype(jnp.float32)[None, None, :]   # 'c' / 'nc'
+        # 's' arrives as a [1, 1] block, 'c' / 'nc' as [1, 1, bc]: all
+        # broadcast against the tile as vectors (no scalar VMEM loads)
+        return o[...].astype(jnp.float32).reshape((1, 1, -1))
 
     for st in stages:
         y = _apply_stage(y, st, fetch_aux)
     out_ref[0] = y.astype(out_ref.dtype)
 
 
+def _conv_phases(kh, kw, sh, sw):
+    """The stride phases (row % sh, col % sw) the conv's taps read."""
+    return tuple(sorted({(i % sh, j % sw)
+                         for i in range(kh) for j in range(kw)}))
+
+
 def _fconv_pallas(x, w, aux, meta):
-    """Raw fused-conv pallas_call on padded NHWC operands."""
+    """Raw fused-conv pallas_call on padded NHWC operands (x: [N,
+    sh*hq, sw*wq, C], see fused_conv_epilogue)."""
     (kh, kw, sh, sw, bh, nh, wo, bc, noc, depthwise, stages, aux_kinds,
      emit_stats, interpret, out_dtype) = meta
     N = x.shape[0]
     ho = nh * bh
     cout = noc * bc
-    row_span = bh * sh if kh == 1 else x.shape[1]
-    wtot = x.shape[2]
+    phases = _conv_phases(kh, kw, sh, sw)
+    x = jnp.stack([x[:, p::sh, q::sw, :] for p, q in phases], axis=1)
+    npz, hq, wq = x.shape[1], x.shape[2], x.shape[3]
+    row_span = bh if kh == 1 else hq
     if depthwise:
         in_specs = [
-            pl.BlockSpec((1, row_span, wtot, bc),
-                         lambda n, h, oc: (n, h, 0, oc)),
+            pl.BlockSpec((1, npz, row_span, wq, bc),
+                         lambda n, h, oc: (n, 0, h, 0, oc)),
             pl.BlockSpec((kh, kw, bc), lambda n, h, oc: (0, 0, oc)),
         ]
     else:
-        cin = x.shape[3]
+        cin = x.shape[4]
         in_specs = [
-            pl.BlockSpec((1, row_span, wtot, cin),
-                         lambda n, h, oc: (n, h, 0, 0)),
+            pl.BlockSpec((1, npz, row_span, wq, cin),
+                         lambda n, h, oc: (n, 0, h, 0, 0)),
             pl.BlockSpec((kh, kw, cin, bc),
                          lambda n, h, oc: (0, 0, 0, oc)),
         ]
@@ -868,24 +868,25 @@ def _fconv_pallas(x, w, aux, meta):
                 (1, bh, wo, bc), lambda n, h, oc: (n, h, 0, oc)))
         elif kind == 'nc':
             in_specs.append(pl.BlockSpec(
-                (1, bc), lambda n, h, oc: (n, oc)))
+                (1, 1, bc), lambda n, h, oc: (n, 0, oc)))
         elif kind == 's':
             in_specs.append(pl.BlockSpec(
                 (1, 1), lambda n, h, oc: (0, 0)))
         else:   # 'c'
             in_specs.append(pl.BlockSpec(
-                (1, bc), lambda n, h, oc: (0, oc)))
+                (1, 1, bc), lambda n, h, oc: (0, 0, oc)))
     out_specs = [pl.BlockSpec((1, bh, wo, bc),
                               lambda n, h, oc: (n, h, 0, oc))]
     out_shape = [jax.ShapeDtypeStruct((N, ho, wo, cout), out_dtype)]
     if emit_stats:
-        out_specs += [pl.BlockSpec((1, 1, bc),
-                                   lambda n, h, oc: (n, h, oc))] * 2
-        out_shape += [jax.ShapeDtypeStruct((N, nh, cout),
+        out_specs += [pl.BlockSpec((1, 1, 1, bc),
+                                   lambda n, h, oc: (n, h, 0, oc))] * 2
+        out_shape += [jax.ShapeDtypeStruct((N, nh, 1, cout),
                                            jnp.float32)] * 2
     got = pl.pallas_call(
         functools.partial(_fconv_kernel, kh=kh, kw=kw, sh=sh, sw=sw,
-                          bh=bh, wo=wo, depthwise=depthwise,
+                          phases=phases, bh=bh, wo=wo,
+                          depthwise=depthwise,
                           stages=stages, aux_kinds=aux_kinds,
                           emit_stats=emit_stats),
         grid=(N, nh, noc),
@@ -893,8 +894,11 @@ def _fconv_pallas(x, w, aux, meta):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=bool(interpret),
-    )(x, w, *aux)
-    return tuple(got) if emit_stats else got[0]
+    )(x, w, *[a if k in ('t', 's') else a[:, None, :]
+              for k, a in zip(aux_kinds, aux)])
+    if not emit_stats:
+        return got[0]
+    return got[0], got[1][:, :, 0, :], got[2][:, :, 0, :]
 
 
 def _fconv_reference(x, w, aux, meta):
@@ -916,8 +920,8 @@ def _fconv_reference(x, w, aux, meta):
             x, w, (sh, sw), 'VALID',
             dimension_numbers=('NHWC', 'HWIO', 'NHWC'),
             preferred_element_type=jnp.float32)
-    # the padded input carries slack rows/cols (reshape-trick fit);
-    # VALID over it yields extra positions — slice to the true output
+    # the padded input is rounded up to whole stride phases; VALID
+    # over it can yield extra positions — slice to the true output
     y = conv[:, :ho, :wo, :]
     outs = []
     if emit_stats:
@@ -988,10 +992,8 @@ def conv_epilogue_mode():
         return False
     f = _FCONV_FORCE[0]
     if f is not None:
-        if not _HAS_PALLAS:
-            return False
         return 'tpu' if f is True else f
-    return 'tpu' if (_HAS_PALLAS and _on_tpu()) else False
+    return 'tpu' if _on_tpu() else False
 
 
 def _pick_div(n, target, quantum=1):
@@ -1004,7 +1006,46 @@ def _pick_div(n, target, quantum=1):
     return best
 
 
-_FCONV_MAX_VMEM = 12 * 1024 * 1024
+# One grid step's tiled bytes must fit Mosaic's default scoped-VMEM
+# limit on v5e (16 MiB; the chip has 128 MiB and `vmem_limit_bytes`
+# could raise it — nothing here needs that yet). Checked against the
+# compiler itself over 304 ResNet-50/SE-ResNeXt-like shapes: the
+# smallest estimate it refused was 19.9 MiB (PERF.md, "Bring-up on the
+# chip").
+_FCONV_MAX_VMEM = 16 * 1024 * 1024
+
+
+def _tiled_bytes(shape, dtype):
+    """VMEM bytes of one block as Mosaic lays it out: the last dim
+    padded to 128 lanes, the second-to-last to whole sublane tiles (8
+    rows of 32 bits; 16-bit types pack 16 rows) — so a cin=3 block
+    costs 128 lanes, not 3."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // item)
+    lead = 1
+    for d in shape[:-2]:
+        lead *= int(d)
+    rows = -(-int(shape[-2]) // sub) * sub
+    lanes = -(-int(shape[-1]) // 128) * 128
+    return lead * rows * lanes * item
+
+
+def _fconv_vmem_bytes(x_blk, w_blk, out_blk, x_dtype, w_dtype, aux,
+                      emit_stats):
+    """Tiled VMEM bytes of one _fconv_kernel grid step, from its input,
+    weight and output (``[bh, wo, bc]``) block shapes: the pipelined
+    blocks double-buffered, plus the f32 accumulator, the epilogue
+    value and one tap window, which are live at once. ``aux`` is
+    ``[(kind, dtype)]``."""
+    bh, wo, bc = out_blk
+    est = 2 * (_tiled_bytes(x_blk, x_dtype) + _tiled_bytes(w_blk, w_dtype)
+               + _tiled_bytes(out_blk, x_dtype))
+    for kind, dtype in aux:
+        est += 2 * _tiled_bytes(out_blk if kind == 't' else (1, bc), dtype)
+    if emit_stats:
+        est += 4 * _tiled_bytes((1, bc), jnp.float32)
+    return est + 2 * _tiled_bytes((bh * wo, bc), jnp.float32) \
+        + _tiled_bytes((bh, wo, x_blk[-1]), jnp.float32)
 
 
 def fused_conv_epilogue(x, w, aux, aux_kinds, strides, paddings,
@@ -1044,19 +1085,29 @@ def fused_conv_epilogue(x, w, aux, aux_kinds, strides, paddings,
     bh = _pick_div(ho, int(sched['block_h'])) if kh == 1 else ho
     nh = ho // bh
     noc = cout // bc
-    # pad with the reshape-trick slack so every (kh, kw) tap fits
-    htot = ho * sh if kh == 1 else kh - 1 + ho * sh
-    wtot = kw - 1 + wo * sw
-    row_span = bh * sh if kh == 1 else htot
+    if not depthwise and not interpret and x.dtype == jnp.bfloat16 \
+            and wo % 2 and int(x.shape[3]) % 128:
+        # the dot's [bh, wo, cin] -> [bh*wo, cin] row merge: bf16 packs
+        # two rows per sublane, and Mosaic has no shape cast for an odd
+        # row count unless the lanes are whole tiles ("infer-vector-
+        # layout: unsupported shape cast")
+        return None, 'packed-row-merge'
+    # each stride phase of the padded input is [hq, wq]: deep enough
+    # for the farthest tap of that phase
+    hq = (kh - 1) // sh + ho
+    wq = (kw - 1) // sw + wo
     cin_blk = bc if depthwise else int(x.shape[3])
-    est = 4 * (row_span * wtot * cin_blk + kh * kw * cin_blk * bc
-               + 3 * bh * wo * bc)
-    for k, a in zip(aux_kinds, aux):
-        est += 4 * (bh * wo * bc if k == 't' else int(a.shape[-1]))
+    est = _fconv_vmem_bytes(
+        (len(_conv_phases(kh, kw, sh, sw)), bh if kh == 1 else hq, wq,
+         cin_blk),
+        (kh, kw, bc) if depthwise else (kh, kw, cin_blk, bc),
+        (bh, wo, bc), x.dtype, w.dtype,
+        [(k, a.dtype) for k, a in zip(aux_kinds, aux)], emit_stats)
     if est > _FCONV_MAX_VMEM:
         return None, 'vmem'
-    xp = jnp.pad(x, ((0, 0), (ph, htot - H - ph),
-                     (pw, wtot - W - pw), (0, 0)))
+    xp = jnp.pad(x, ((0, 0), (ph, max(sh * hq - H - ph, 0)),
+                     (pw, max(sw * wq - W - pw, 0)),
+                     (0, 0)))[:, :sh * hq, :sw * wq]
     meta = (kh, kw, sh, sw, bh, nh, wo, bc, noc, bool(depthwise),
             tuple(stages), tuple(aux_kinds), bool(emit_stats),
             bool(interpret), str(x.dtype))
@@ -1071,7 +1122,7 @@ def fused_lstm_cell(xg, r_prev, c_prev, w, interpret=None):
     path."""
     if interpret is None:
         interpret = False
-    use_pallas = _HAS_PALLAS and (interpret or _on_tpu())
+    use_pallas = interpret or _on_tpu()
     # Whole-array kernel: everything must fit VMEM (~16MB). The weight
     # dominates; past ~10MB of f32 operands Mosaic compilation fails.
     B, H = c_prev.shape

@@ -47,6 +47,10 @@ def get_mesh(num_devices=None, axes=None, shape=None):
     from jax.sharding import Mesh
     if _current_mesh is not None and num_devices is None and shape is None:
         return _current_mesh
+    # the GLOBAL device list, on purpose: under jax.distributed a dp
+    # mesh spans every process's devices (Partitioner.globalize feeds
+    # it); in one process it is the local list. A Place, by contrast,
+    # names a local device (core/places.py).
     devices = jax.devices()
     if shape:
         axes = tuple(shape.keys())

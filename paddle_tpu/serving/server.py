@@ -118,7 +118,7 @@ class ModelServer(object):
                  retry_backoff=0.05, retry_on=(OSError,),
                  breaker_config=None, stage_timeouts=None,
                  watchdog_poll=0.05, partitioner=None):
-        self.place = place or _places.TPUPlace(0)
+        self.place = place or _places.default_place()
         # PARTITIONING.md: a real-mesh partitioner makes this server
         # sharded end to end — loaded models distribute their params
         # across the mesh, and every bucket's program compiles as a
@@ -414,6 +414,11 @@ class ModelServer(object):
                     if feed is None:
                         break
                     if tuner is not None:
+                        # the search runs (and donates state) on
+                        # model.scope: queued warmup batches of the
+                        # same scope must land first
+                        while pending:
+                            pending.pop().result(timeout=timeout)
                         _, searched = tuner.tune_if_missing(
                             model.program, feed, model.fetch_vars,
                             scope=model.scope, name=name)

@@ -31,7 +31,7 @@ from . import optimizer as opt_module
 from . import data_feeder
 from . import unique_name
 from .core.lowering import RNG_KEY
-from .core.places import TPUPlace, CPUPlace
+from .core.places import default_place
 from .parallel import parallel_executor
 from .resilience import CheckpointConfig, AnomalyGuard  # noqa: F401 (API)
 from .resilience import anomaly as _anomaly
@@ -69,17 +69,9 @@ class EndStepEvent(object):
 
 
 def check_and_get_place(place):
-    """Default to the TPU when available (parity: trainer.py::
-    check_and_get_place prefers CUDA)."""
-    if place is None:
-        import jax
-        try:
-            if jax.devices()[0].platform not in ('cpu',):
-                return TPUPlace(0)
-        except Exception:
-            pass
-        return CPUPlace()
-    return place
+    """``None`` resolves to device 0 of the backend JAX was started on
+    (parity: trainer.py::check_and_get_place prefers CUDA)."""
+    return default_place() if place is None else place
 
 
 class Trainer(object):

@@ -23,10 +23,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 import jax  # noqa: E402
 
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_cpu_collectives_implementation', 'gloo')
-except Exception:
-    pass
+jax.config.update('jax_cpu_collectives_implementation', 'gloo')
 
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding  # noqa: E402

@@ -20,9 +20,6 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.native import capi
 
-pytestmark = pytest.mark.skipif(capi.load() is None,
-                                reason='C toolchain unavailable')
-
 
 @pytest.fixture(scope='module')
 def saved_model(tmp_path_factory):

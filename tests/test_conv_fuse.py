@@ -55,7 +55,10 @@ def _op_types(program):
 
 
 def _counter(name):
-    return obs.default_registry().counter(name)
+    """Total over every label set of a counter (fallbacks are counted
+    by reason)."""
+    fam = obs.default_registry().snapshot().get(name)
+    return sum(s['value'] for s in fam['series']) if fam else 0
 
 
 def _randomize_bn_stats(program, scope, rng):
@@ -88,22 +91,22 @@ def _parity_legs(build, feed, fetch_names, expect_fused=True):
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
     rng = np.random.RandomState(7)
-    fused_c, fall_c = (_counter('conv_fuse_ops_fused_total'),
-                       _counter('conv_fuse_fallbacks_total'))
     with fluid.scope_guard(scope):
         exe.run(startup)
         _randomize_bn_stats(main, scope, rng)
         with compiler.disabled():
             raw = exe.run(main, feed=dict(feed), fetch_list=fetch_names)
-        f0, b0 = fused_c.value, fall_c.value
+        f0, b0 = (_counter('conv_fuse_ops_fused_total'),
+                  _counter('conv_fuse_fallbacks_total'))
         with pk.force_conv_epilogue('interpret'):
             fused = exe.run(main, feed=dict(feed),
                             fetch_list=fetch_names)
+    fused_d = _counter('conv_fuse_ops_fused_total') - f0
     if expect_fused:
-        assert fused_c.value > f0, 'conv_epilogue_fuse fused nothing'
+        assert fused_d > 0, 'conv_epilogue_fuse fused nothing'
     return ([np.asarray(v) for v in raw],
             [np.asarray(v) for v in fused],
-            fused_c.value - f0, fall_c.value - b0)
+            fused_d, _counter('conv_fuse_fallbacks_total') - b0)
 
 
 # ---- covered-shape exactness ----------------------------------------------
@@ -135,6 +138,66 @@ def test_conv_bn_relu_pallas_parity():
     optimized, _ = compiler.optimize(main, fetch_names=[out.name])
     assert FUSED_CONV_OP in _op_types(optimized)
     assert 'batch_norm' not in _op_types(optimized)
+
+
+@pytest.mark.parametrize('hw,k,s,p,dw', [
+    ((8, 8), 1, 2, 0, False), ((7, 9), 1, 2, 0, False),
+    ((8, 8), 3, 2, 1, False), ((7, 9), 3, 2, 1, False),
+    ((9, 7), 3, 2, 0, False), ((12, 12), 5, 3, 2, False),
+    ((8, 8), 3, 2, 1, True)])
+def test_strided_kernel_matches_plain_conv(hw, k, s, p, dw):
+    """Strides are taken outside the kernel, as stride phases of the
+    padded input: every tap of every phase must land where a plain
+    strided conv puts it — odd extents, k > s and k < s included — for
+    the output and for the train-BN moment partials."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.RandomState(3)
+    n, cin, cout = 2, 4, (4 if dw else 8)
+    x = jnp.asarray(rng.randn(n, hw[0], hw[1], cin), jnp.float32)
+    w = jnp.asarray(rng.randn(*((k, k, cin) if dw
+                                else (k, k, cin, cout))), jnp.float32)
+    want = jax.lax.conv_general_dilated(
+        x, w[:, :, None, :] if dw else w, (s, s), [(p, p), (p, p)],
+        feature_group_count=cin if dw else 1,
+        dimension_numbers=('NHWC', 'HWIO', 'NHWC'),
+        precision=jax.lax.Precision.HIGHEST)
+    got, why = pk.fused_conv_epilogue(x, w, (), (), (s, s), (p, p), dw,
+                                      (), emit_stats=True, interpret=True)
+    assert why is None
+    y, psum, psumsq = got
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(psum.sum((0, 1)), want.sum((0, 1, 2)),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(psumsq.sum((0, 1)),
+                               (want * want).sum((0, 1, 2)),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_tiled_vmem_bytes_count_lanes_and_sublanes():
+    """The VMEM predicate counts what Mosaic allocates: 128 lanes and
+    whole sublane tiles, so narrow channel counts are not cheap."""
+    assert pk._tiled_bytes((58, 58, 3), 'float32') == 58 * 64 * 128 * 4
+    assert pk._tiled_bytes((58, 58, 3), 'bfloat16') == 58 * 64 * 128 * 2
+    assert pk._tiled_bytes((1, 256), 'float32') == 8 * 256 * 4
+
+
+def test_hardware_predicates_refuse_by_reason():
+    """What Mosaic refuses is declined up front, by name (PERF.md
+    "Bring-up on the chip"): bf16 row merges with an odd width and
+    partial lanes, and blocks past the scoped-VMEM limit."""
+    import jax.numpy as jnp
+
+    def why(x_shape, w_shape, dtype):
+        return pk.fused_conv_epilogue(
+            jnp.zeros(x_shape, dtype), jnp.zeros(w_shape, dtype), (), (),
+            (1, 1), (w_shape[0] // 2,) * 2, False, ())[1]
+
+    assert why((2, 7, 7, 64), (1, 1, 64, 128), jnp.bfloat16) == \
+        'packed-row-merge'
+    assert why((2, 7, 7, 64), (1, 1, 64, 64), jnp.float32) == \
+        'channel-align'
+    assert why((2, 112, 112, 256), (3, 3, 256, 256), jnp.float32) == 'vmem'
 
 
 def test_residual_add_parity():

@@ -241,7 +241,6 @@ def test_ring_attention_uses_flash_kernel(monkeypatch):
     Pallas kernel for its partials."""
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from paddle_tpu.models import transformer as T
 
     fired = []
@@ -261,10 +260,10 @@ def test_ring_attention_uses_flash_kernel(monkeypatch):
     rng = np.random.RandomState(1)
     B, Tt, H, D = 1, 256, 2, 64   # T_local = 128
     q = jnp.asarray(rng.randn(B, Tt, H, D) * 0.5, jnp.float32)
-    ring = shard_map(lambda q, k, v: T.ring_attention(q, k, v, 'sp'),
-                     mesh=mesh,
-                     in_specs=(P(None, 'sp'),) * 3,
-                     out_specs=P(None, 'sp'), check_rep=False)
+    ring = jax.shard_map(lambda q, k, v: T.ring_attention(q, k, v, 'sp'),
+                         mesh=mesh,
+                         in_specs=(P(None, 'sp'),) * 3,
+                         out_specs=P(None, 'sp'), check_vma=False)
     out = np.asarray(jax.jit(ring)(q, q, q))
     assert fired, "Pallas kernel did not engage inside ring attention"
     ref = np.asarray(pk.attention_reference(q, q, q, causal=True))

@@ -8,10 +8,7 @@ import pytest
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:     # jax < 0.5 ships it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.parallel import collective

@@ -22,10 +22,10 @@ def test_ring_attention_matches_dense():
                                 jnp.asarray(v))
 
     from jax.sharding import PartitionSpec as P
-    ring = jax.jit(T.shard_map_compat(
+    ring = jax.jit(jax.shard_map(
+        lambda a, b, c: T.ring_attention(a, b, c, 'sp'),
         mesh=mesh, in_specs=(P(None, 'sp'),) * 3,
-        out_specs=P(None, 'sp'), check_vma=False)(
-            lambda a, b, c: T.ring_attention(a, b, c, 'sp')))(q, k, v)
+        out_specs=P(None, 'sp'), check_vma=False))(q, k, v)
     np.testing.assert_allclose(np.asarray(dense), np.asarray(ring),
                                rtol=2e-2, atol=2e-2)
 
@@ -58,7 +58,6 @@ def test_ring_attention_gradients_match_full_attention():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from paddle_tpu.models import transformer as T
     from paddle_tpu.ops import pallas_kernels as pk
 
@@ -71,9 +70,9 @@ def test_ring_attention_gradients_match_full_attention():
     v = jnp.asarray(rng.randn(B, Tt, H, D) * 0.5, jnp.float32)
     go = jnp.asarray(rng.randn(B, Tt, H, D) * 0.1, jnp.float32)
 
-    ring = shard_map(lambda q, k, v: T.ring_attention(q, k, v, 'sp'),
-                     mesh=mesh, in_specs=(P(None, 'sp'),) * 3,
-                     out_specs=P(None, 'sp'), check_rep=False)
+    ring = jax.shard_map(lambda q, k, v: T.ring_attention(q, k, v, 'sp'),
+                         mesh=mesh, in_specs=(P(None, 'sp'),) * 3,
+                         out_specs=P(None, 'sp'), check_vma=False)
     g_ring = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(ring(q, k, v) * go),
         argnums=(0, 1, 2)))(q, k, v)

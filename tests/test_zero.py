@@ -250,7 +250,6 @@ def test_manual_bucket_collective_is_literal_reduce_scatter():
     a REAL psum_scatter: reduce-scatter in the compiled HLO, NO
     all-reduce, and each device gets exactly its owner shard of the
     summed partial gradients."""
-    from paddle_tpu.models.transformer import shard_map_compat
     from jax.sharding import PartitionSpec as P
     mesh = _mesh(2)
     rng = np.random.RandomState(0)
@@ -263,10 +262,10 @@ def test_manual_bucket_collective_is_literal_reduce_scatter():
                                           manual=True)
         return outs[0][None], outs[1][None]
 
-    f = jax.jit(shard_map_compat(body, mesh=mesh,
-                                 in_specs=(P('dp'), P('dp')),
-                                 out_specs=(P('dp'), P('dp')),
-                                 check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=(P('dp'), P('dp')),
+                              out_specs=(P('dp'), P('dp')),
+                              check_vma=False))
     o1, o2 = f(g1, g2)
     txt = f.lower(g1, g2).compile().as_text()
     assert len(re.findall('reduce-scatter', txt)) >= 1
