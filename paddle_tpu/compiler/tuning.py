@@ -20,6 +20,7 @@ beside the compile cache in the checkout (core/compile_cache.py;
 atomic tmp->rename writes).
 """
 import contextlib
+import functools
 import hashlib
 import json
 import os
@@ -279,6 +280,11 @@ def wrap_jitted(fn, entry):
         with apply_entry(entry):
             return fn(*args, **kwargs)
 
+    # for whoever lowers the callable again under the same knobs
+    # (observability.perf.scope_map); deliberately not a ``lower``
+    # attribute, which the AOT store takes for a plain jit
+    wrapped.__wrapped__ = fn
+    wrapped.knobs = functools.partial(apply_entry, entry)
     return wrapped
 
 

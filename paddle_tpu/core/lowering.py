@@ -15,6 +15,7 @@ update ops) consume them as ordinary environment values.
 """
 import contextlib
 import functools
+import re
 import time
 
 import jax
@@ -185,9 +186,10 @@ class BlockRunner(object):
             kernel = get_kernel(op.type)
             t0 = time.perf_counter() if profiling else 0.0
             try:
-                # named_scope stamps the op type into HLO metadata, so
-                # XLA traces (Perfetto/TensorBoard) carry op provenance
-                with jax.named_scope(op.type):
+                # named_scope stamps the op into HLO metadata, which is
+                # how a device operation finds its Fluid op again
+                # (observability.perf.scope_map)
+                with jax.named_scope(op_scope(op)):
                     kernel(OpCtx(op, env, self))
             except Exception as e:
                 raise type(e)(
@@ -245,6 +247,24 @@ class BlockRunner(object):
                     if name not in self.keep:
                         env.pop(name, None)
         return env
+
+
+# The scopes lower_block lowers under. With the backward marker the
+# ops before it are differentiated, so JAX names their forward
+# ``jvp(forward)`` and their backward ``transpose(jvp(forward))``.
+FORWARD_SCOPE, OPTIMIZER_SCOPE = 'forward', 'optimizer'
+_SCOPE_LEGAL = re.compile(r'^[A-Za-z0-9_.\-]+$')
+
+
+def op_scope(op):
+    """``<op.type>:<first output>`` (``conv2d:res2a_branch2a.tmp_0``),
+    so that two convs of different stages are two names; the type alone
+    where the output's name would not survive in a scope path
+    (``x@GRAD``)."""
+    outs = op.output_arg_names
+    if outs and _SCOPE_LEGAL.match(outs[0]):
+        return '%s:%s' % (op.type, outs[0])
+    return op.type
 
 
 def _is_float(val):
@@ -668,8 +688,9 @@ def lower_block(program, block, feed_names, fetch_names, state_in_names,
         env.update(state)
         env.update(feeds)
         if marker_idx < 0:
-            BlockRunner(block, dynamic=dynamic, keep=keep).run_ops(
-                ops, env)
+            with jax.named_scope(FORWARD_SCOPE):
+                BlockRunner(block, dynamic=dynamic, keep=keep).run_ops(
+                    ops, env)
         else:
             marker = ops[marker_idx]
             param_names = [p for p in marker.attrs['params']]
@@ -707,6 +728,7 @@ def lower_block(program, block, feed_names, fetch_names, state_in_names,
                 p[0] for pairs in (marker.attrs.get('sparse') or {}
                                    ).values() for p in pairs}
 
+            @jax.named_scope(FORWARD_SCOPE)
             def g(param_vals):
                 genv = dict(base_env)
                 genv.update(param_vals)
@@ -746,24 +768,28 @@ def lower_block(program, block, feed_names, fetch_names, state_in_names,
             env = env2
             env.update({p: param_vals[p] for p in diff_names})
             scale = marker.attrs.get('loss_scale', None)
-            for p, gname in zip(param_names, grad_names):
-                if p in sparse_map:
-                    items = []
-                    for ids_name, carrier in sparse_map[p]:
-                        rows = pgrads[carrier]
-                        if scale is not None and scale != 1.0:
-                            rows = rows * scale
-                        ids, _ = _rows_of(env[ids_name])
-                        items.append((rows, ids))
-                    env[gname] = SparseRows(items,
-                                            int(env[p].shape[0]))
-                    continue
-                gval = pgrads[p]
-                if scale is not None and scale != 1.0:
-                    gval = gval * scale
-                env[gname] = gval
-            BlockRunner(block, dynamic=dynamic, keep=keep).run_ops(
-                post, env)
+            # everything after the backward: un-scaling the gradients,
+            # then the ops behind the marker (clip, regularizers, the
+            # optimizer's updates)
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                for p, gname in zip(param_names, grad_names):
+                    if p in sparse_map:
+                        items = []
+                        for ids_name, carrier in sparse_map[p]:
+                            rows = pgrads[carrier]
+                            if scale is not None and scale != 1.0:
+                                rows = rows * scale
+                            ids, _ = _rows_of(env[ids_name])
+                            items.append((rows, ids))
+                        env[gname] = SparseRows(items,
+                                                int(env[p].shape[0]))
+                        continue
+                    gval = pgrads[p]
+                    if scale is not None and scale != 1.0:
+                        gval = gval * scale
+                    env[gname] = gval
+                BlockRunner(block, dynamic=dynamic, keep=keep).run_ops(
+                    post, env)
 
         fetches = [env[n] for n in fetch_names]
         new_state = {}

@@ -12,8 +12,8 @@ computation with no host round-trips.
 """
 import collections
 import contextlib
+import itertools
 import threading
-import time
 
 import numpy as np
 import jax
@@ -36,6 +36,30 @@ __all__ = ['Executor', 'CacheInfo', 'global_scope', 'scope_guard',
            'switch_scope', 'fetch_var', 'as_numpy']
 
 CacheInfo = collections.namedtuple('CacheInfo', ['hits', 'misses', 'size'])
+
+# What exe/prep hands to exe/launch and exe/commit: the compiled
+# callable with its arguments, and what the lookup found. ``compiled``
+# is True where this call will trace and compile (a miss that no AOT
+# entry served); ``checked`` where the callable is checkify-wrapped.
+def _dynamic_memoized(program):
+    """:func:`_is_dynamic_program`, once per program fingerprint."""
+    memo = program.__dict__.setdefault('_dynamic_memo', {})
+    fp = program.fingerprint()
+    if fp not in memo:
+        memo[fp] = _is_dynamic_program(program)
+    return memo[fp]
+
+
+class _Lowerable(object):
+    __slots__ = ('abstract', 'sharded', 'runs')
+
+    def __init__(self, abstract, sharded):
+        self.abstract, self.sharded, self.runs = abstract, sharded, 1
+
+
+_Step = collections.namedtuple('_Step', [
+    'jitted', 'feed', 'state', 'fp', 'fetch_names', 'sharded', 'cache',
+    'compiled', 'checked', 'ledger'])
 
 def _coldstart_store():
     """The active AOT cold-start store (SERVING.md "Self-driving
@@ -377,6 +401,12 @@ class Executor(object):
         # under jax.jit's own thread-safe cache)
         self._cache = {}
         self._cache_lock = threading.RLock()
+        # beside each jitted entry, what it was first called with (shapes
+        # and shardings, no arrays) and how often it ran: enough for
+        # observability.perf.scope_map() to lower it again on demand
+        self._lowerable = {}
+        # step_num of this Executor's exe/run and exe/chain phases
+        self._step_nums = itertools.count()
         self._cache_hits = 0
         self._cache_misses = 0
         # process-wide telemetry (OBSERVABILITY.md): every Executor
@@ -398,6 +428,7 @@ class Executor(object):
         self._m_compile = reg.histogram(
             'executor_compile_seconds',
             'lowering + first (compiling) execution wall per cache miss')
+        _perf.register_executor(self)
 
     @property
     def partitioner(self):
@@ -763,6 +794,48 @@ class Executor(object):
             _compiler.tuning.backend())
         return _compiler.tuning.wrap_jitted(jitted, entry)
 
+    def device_context(self, sharded):
+        """Where a jitted call, or an AOT lowering of one, runs: the
+        mesh scope when sharded, this Executor's device otherwise."""
+        if sharded:
+            return self.partitioner.run_context()
+        return jax.default_device(self.place.jax_device())
+
+    def lowerable_entries(self):
+        """``[(fingerprint, callable, abstract args, sharded, runs)]``
+        of the compiled-program cache: what
+        ``observability.perf.scope_map`` lowers once more, on demand,
+        under :meth:`device_context`. ``runs`` counts the entry's
+        dispatches."""
+        with self._cache_lock:
+            return [(key[0], self._cache.get(key), e.abstract, e.sharded,
+                     e.runs) for key, e in self._lowerable.items()]
+
+    def _settle(self, top, prep, launch, step, new_state, scope, **chain):
+        """What both run paths owe once the jitted call has returned:
+        the registry series, the compile and run events, and the new
+        state in the scope."""
+        self._m_run.observe(launch.dur_s)
+        h, m = self._m_hits.value, self._m_misses.value
+        self._m_hit_rate.set(h / (h + m) if h + m else 0.0)
+        if step.compiled:
+            # jax.jit compiles lazily at the first call, so the real
+            # XLA compile wall is the whole miss: exe/prep's start to
+            # the end of this first exe/launch (an AOT warm start never
+            # compiled: its wall lives in coldstart_load_seconds / the
+            # 'coldstart' journal event)
+            compile_wall = launch.t0 + launch.dur_s - prep.t0
+            self._m_compile.observe(compile_wall)
+            _obs.emit('compile_end', fp=step.fp,
+                      dur_s=round(compile_wall, 6), **chain)
+            if step.ledger is not None:
+                _perf.seal(step.ledger, compile_wall, trace=top.context)
+        if _obs.journal_active():
+            _obs.emit('exe_run', cache=step.cache, fp=step.fp,
+                      dur_s=round(launch.dur_s, 6), **chain)
+        for n, v in new_state.items():
+            scope.set_var(n, v)
+
     def run(self, program=None, feed=None, fetch_list=None,
             feed_var_name='feed', fetch_var_name='fetch', scope=None,
             return_numpy=True, use_program_cache=True,
@@ -773,25 +846,73 @@ class Executor(object):
         executes; materialize later with ``as_numpy``/``np.asarray`` or
         ``jax.block_until_ready``. Overrides ``return_numpy``. An
         installed AnomalyGuard still observes every fetch (observation
-        materializes — guard correctness beats overlap)."""
+        materializes — guard correctness beats overlap).
+
+        The call is four phases (``observability.phase``,
+        OBSERVABILITY.md "Distributed tracing"): ``exe/run`` around
+        ``exe/prep``, ``exe/launch`` and ``exe/commit``, each in the
+        profiler's trace whenever one is taken, and in the journal
+        under an active parent span (a serving batch, a trainer step):
+        bare runs stay span-free."""
         if program is None:
             program = default_main_program()
         if not isinstance(program, Program):
             raise TypeError("Executor requires Program as its Parameter. But "
                             "you passed in %s" % type(program))
-        feed = feed or {}
-        fetch_list = fetch_list or []
         scope = scope or global_scope()
+        with _obs.phase('exe/run', step_num=next(self._step_nums)) as top:
+            with _obs.phase('exe/prep', top) as prep:
+                step = self._prep_run(top, program, feed or {},
+                                      fetch_list or [], scope)
+            with _obs.phase('exe/launch', top, cache=step.cache) as launch, \
+                    self.device_context(step.sharded):
+                if step.checked:
+                    err, (fetches, new_state) = step.jitted(step.feed,
+                                                            step.state)
+                    err.throw()
+                else:
+                    # profiling path is eager; its guard checks raise
+                    # inline
+                    fetches, new_state = step.jitted(step.feed, step.state)
+            with _obs.phase('exe/commit', top):
+                self._settle(top, prep, launch, step, new_state, scope)
+                if getattr(program, '_half_inference', None):
+                    # boundary contract: fetches come back float32 even
+                    # though the net ran in half (Float16Transpiler)
+                    fetches = [_to_f32_fetch(f) for f in fetches]
+                if _anomaly.any_active():
+                    # resilience hook: an installed AnomalyGuard
+                    # inspects every fetch (NaN/Inf policy for raw
+                    # exe.run loops); no-op by default
+                    _anomaly.observe_fetches(step.fetch_names, fetches)
+                if async_fetch:
+                    # lazy device handles: dispatch returned, values
+                    # unforced
+                    top.note(dispatched=True)
+                elif return_numpy:
+                    with _obs.phase('exe/fetch', top):
+                        fetches = [as_numpy(f) for f in fetches]
+                else:
+                    # reference contract: fetches are LoDTensors; a
+                    # dense fetch still answers .lod() (with []) — wrap
+                    # bare arrays
+                    fetches = [SequenceTensor(f, None) if isinstance(
+                        f, (jax.Array, np.ndarray)) else f
+                        for f in fetches]
+                return fetches
+
+    def _prep_run(self, top, program, feed, fetch_list, scope):
+        """``exe/prep`` of :meth:`run`: everything from the raw feed to
+        the compiled callable with its arguments. On a cache miss it
+        holds ``exe/verify`` and ``exe/compile`` (passes, lowering and
+        the jit wrapper; XLA itself compiles inside the first
+        ``exe/launch``)."""
         # feed validation runs on the RAW feed: _prepare_feed casts to
         # the declared dtype, which would mask exactly the mismatches
         # the check exists to name (FeedInvalid, ANALYSIS.md)
         _analysis.check_feeds_for_executor(program, feed)
 
-        dynamic = program.__dict__.setdefault(
-            '_dynamic_memo', {}).get(program.fingerprint())
-        if dynamic is None:
-            dynamic = _is_dynamic_program(program)
-            program._dynamic_memo[program.fingerprint()] = dynamic
+        dynamic = _dynamic_memoized(program)
         fetch_names, feed, state_in_names, state_out_names, static_env = \
             self._prep_lowering(program, feed, fetch_list, scope,
                                 dynamic=dynamic)
@@ -807,14 +928,7 @@ class Executor(object):
         key = program_cache_key(program, feed, static_env, fetch_names,
                                 state_in_names, state_out_names, guard,
                                 profiling, part.cache_token(program))
-        # traced only under an active parent span (a serving batch, a
-        # trainer step): bare runs stay span-free, and the untraced
-        # cost is one thread-local read
-        _pctx = _obs.current_context()
-        tspan = _obs.start_span('exe/run', parent=_pctx,
-                                activate=False, fp=key[0]) \
-            if _pctx is not None else None
-        t_lookup = time.perf_counter()
+        top.note(fp=key[0])
         feeds_s = state_s = None
         with self._cache_lock:
             entry = self._cache.get(key)
@@ -855,74 +969,26 @@ class Executor(object):
                     # static verify BEFORE any lowering: a mis-wired
                     # program raises typed ProgramInvalid naming the
                     # offending op instead of an XLA trace error
-                    _t_verify = time.perf_counter()
-                    _analysis.verify_for_executor(
-                        program,
-                        feed_names=set(feed) | set(static_env),
-                        fetch_names=fetch_names)
-                    if tspan is not None:
-                        _obs.emit_span(
-                            'exe/verify',
-                            time.perf_counter() - _t_verify,
-                            parent=tspan)
+                    with _obs.phase('exe/verify', top):
+                        _analysis.verify_for_executor(
+                            program,
+                            feed_names=set(feed) | set(static_env),
+                            fetch_names=fetch_names)
                 _obs.emit('compile_begin', fp=key[0])
-                lower_prog = self._optimized_program(
-                    program, fetch_names, scope=scope, dynamic=dynamic)
-                fn = lower_block(lower_prog, lower_prog.global_block(),
-                                 sorted(feed.keys()), fetch_names,
-                                 state_in_names, state_out_names,
-                                 dynamic=dynamic, static_env=static_env)
-                # State donation is unsafe for compilations that get
-                # sealed to the AOT store: serialize_executable keeps
-                # the XLA-side input_output_alias but the round trip
-                # loses jax's dispatch-side donation bookkeeping, and a
-                # deserialized aliased executable scribbles over state
-                # buffers other bucket executables still hold (silent
-                # garbage, not an error). Donation-free sealing costs
-                # one state-buffer copy per dispatch on AOT-gated runs.
-                donate = () if aot_store is not None else (1,)
-                if profiling or dynamic:
-                    # Per-op profiling and dynamic (beam-decode) programs
-                    # run UN-jitted: the lowering executes op by op on the
-                    # device with concrete values and host control flow.
-                    jitted = fn
-                elif sharded:
-                    out_state_s = part.state_shardings(program,
-                                                       state_out_names)
-                    # fetches come back fully replicated: every process
-                    # must be able to materialize numpy, and leaving
-                    # them unspecified lets XLA pick a dp-sharded
-                    # layout that the donated (replicated) state
-                    # buffers cannot alias — a runtime INTERNAL error
-                    # on same-global-shape pairs (caught by the verify
-                    # drive on the sharded inference path)
-                    fetch_s = part.replicated
-                    fn = part.trace_wrap(fn)
-                    if guard:
-                        from jax.experimental import checkify
-                        jitted = part.partition(
-                            checkify.checkify(fn),
-                            in_shardings=(feeds_s, state_s),
-                            out_shardings=(None, (fetch_s,
-                                                  out_state_s)))
-                    else:
-                        jitted = part.partition(
-                            fn, in_shardings=(feeds_s, state_s),
-                            out_shardings=(fetch_s, out_state_s),
-                            donate_argnums=donate)
-                elif guard:
-                    # Debug mode: functionalize the per-op NaN/Inf checks.
-                    # No donation — on a thrown error the scope must still
-                    # hold live (pre-step) state buffers.
-                    from jax.experimental import checkify
-                    jitted = jax.jit(checkify.checkify(fn))
-                else:
-                    jitted = part.partition(fn, donate_argnums=donate)
-                jitted = self._apply_tuning(key, jitted)
+                with _obs.phase('exe/compile', top, fp=key[0]):
+                    jitted = self._lower_step(
+                        program, key, feed, fetch_names, state_in_names,
+                        state_out_names, static_env, scope,
+                        dynamic=dynamic, profiling=profiling, guard=guard,
+                        sharded=sharded, donate=aot_store is None,
+                        feeds_s=feeds_s, state_s=state_s)
                 self._cache[key] = jitted
             elif entry is not None:
                 self._cache_hits += 1
                 jitted = entry
+                known = self._lowerable.get(key)
+                if known is not None:
+                    known.runs += 1
         was_miss = entry is None
         (self._m_misses if was_miss else self._m_hits).inc()
 
@@ -937,6 +1003,13 @@ class Executor(object):
             # untouched
             state = part.reconcile_state(state, state_s)
 
+        if was_miss and not (profiling or dynamic):
+            # what observability.perf.scope_map() lowers again on
+            # demand: shapes and shardings only, nothing compiled here
+            self._lowerable[key] = _Lowerable(_perf.abstract_args(
+                feed, state, (feeds_s, state_s) if sharded else None),
+                sharded)
+
         _ledger = None
         if was_miss and not aot_hit and not (profiling or dynamic) \
                 and not (sharded and part.multiprocess) \
@@ -946,8 +1019,7 @@ class Executor(object):
             # extra AOT lower().compile() against abstract avals per
             # compile, zero steady-state cost. Runs under the same
             # device/mesh context as the dispatch and never raises.
-            with part.run_context() if sharded else \
-                    jax.default_device(self.place.jax_device()):
+            with self.device_context(sharded):
                 _ledger = _perf.capture_compiled(
                     jitted, feed, state, key[0],
                     backend=jax.default_backend(),
@@ -965,8 +1037,7 @@ class Executor(object):
             # uses the Compiled directly so the compile happens once.
             # A non-lowerable callable (tuning-wrapped) returns None
             # and stays on the lazy path.
-            with part.run_context() if sharded else \
-                    jax.default_device(self.place.jax_device()):
+            with self.device_context(sharded):
                 try:
                     if not sharded and (
                             any(map(_mesh_committed, feed.values()))
@@ -988,72 +1059,68 @@ class Executor(object):
                 with self._cache_lock:
                     self._cache[key] = compiled
 
-        t_run = time.perf_counter()
-        with part.run_context() if sharded else \
-                jax.default_device(self.place.jax_device()):
-            if guard and not (profiling or dynamic):
-                err, (fetches, new_state) = jitted(feed, state)
-                err.throw()
+        return _Step(jitted, feed, state, key[0], fetch_names, sharded,
+                     'miss' if was_miss else 'hit',
+                     was_miss and not aot_hit,
+                     guard and not (profiling or dynamic), _ledger)
+
+    def _lower_step(self, program, key, feed, fetch_names, state_in_names,
+                    state_out_names, static_env, scope, dynamic,
+                    profiling, guard, sharded, donate, feeds_s, state_s):
+        """``exe/compile`` of a single step: the pass pipeline, the
+        lowering of the block, and the jit (or partition) wrapper with
+        its tuning knobs. Nothing is traced or compiled by XLA here."""
+        part = self.partitioner
+        lower_prog = self._optimized_program(
+            program, fetch_names, scope=scope, dynamic=dynamic)
+        fn = lower_block(lower_prog, lower_prog.global_block(),
+                         sorted(feed.keys()), fetch_names,
+                         state_in_names, state_out_names,
+                         dynamic=dynamic, static_env=static_env)
+        # State donation is unsafe for compilations that get sealed to
+        # the AOT store: serialize_executable keeps the XLA-side
+        # input_output_alias but the round trip loses jax's
+        # dispatch-side donation bookkeeping, and a deserialized
+        # aliased executable scribbles over state buffers other bucket
+        # executables still hold (silent garbage, not an error).
+        # Donation-free sealing costs one state-buffer copy per
+        # dispatch on AOT-gated runs.
+        donate = (1,) if donate else ()
+        if profiling or dynamic:
+            # Per-op profiling and dynamic (beam-decode) programs run
+            # UN-jitted: the lowering executes op by op on the device
+            # with concrete values and host control flow.
+            jitted = fn
+        elif sharded:
+            out_state_s = part.state_shardings(program, state_out_names)
+            # fetches come back fully replicated: every process must be
+            # able to materialize numpy, and leaving them unspecified
+            # lets XLA pick a dp-sharded layout that the donated
+            # (replicated) state buffers cannot alias — a runtime
+            # INTERNAL error on same-global-shape pairs (caught by the
+            # verify drive on the sharded inference path)
+            fetch_s = part.replicated
+            fn = part.trace_wrap(fn)
+            if guard:
+                from jax.experimental import checkify
+                jitted = part.partition(
+                    checkify.checkify(fn),
+                    in_shardings=(feeds_s, state_s),
+                    out_shardings=(None, (fetch_s, out_state_s)))
             else:
-                # profiling path is eager; its guard checks raise inline
-                fetches, new_state = jitted(feed, state)
-        run_wall = time.perf_counter() - t_run
-        self._m_run.observe(run_wall)
-        h, m = self._m_hits.value, self._m_misses.value
-        self._m_hit_rate.set(h / (h + m) if h + m else 0.0)
-        if was_miss and not aot_hit:
-            # jax.jit compiles lazily at the first call, so the real
-            # XLA compile wall is lookup -> end of this first execution
-            # (an AOT warm start never compiled: its wall lives in
-            # coldstart_load_seconds / the 'coldstart' journal event)
-            compile_wall = time.perf_counter() - t_lookup
-            self._m_compile.observe(compile_wall)
-            _obs.emit('compile_end', fp=key[0],
-                      dur_s=round(compile_wall, 6))
-            if tspan is not None:
-                _obs.emit_span('exe/compile', compile_wall,
-                               parent=tspan, fp=key[0])
-            if _ledger is not None:
-                _perf.seal(_ledger, compile_wall,
-                           trace=tspan.context if tspan is not None
-                           else _pctx)
-        if tspan is not None:
-            _obs.emit_span('exe/dispatch', run_wall, parent=tspan,
-                           cache='miss' if was_miss else 'hit')
-        if _obs.journal_active():
-            _obs.emit('exe_run', cache='miss' if was_miss else 'hit',
-                      fp=key[0], dur_s=round(run_wall, 6))
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        if getattr(program, '_half_inference', None):
-            # boundary contract: fetches come back float32 even though
-            # the net ran in half (Float16Transpiler)
-            fetches = [_to_f32_fetch(f) for f in fetches]
-        if _anomaly.any_active():
-            # resilience hook: an installed AnomalyGuard inspects every
-            # fetch (NaN/Inf policy for raw exe.run loops); no-op by
-            # default
-            _anomaly.observe_fetches(fetch_names, fetches)
-        if async_fetch:
-            # lazy device handles: dispatch returned, values unforced
-            if tspan is not None:
-                tspan.end(dispatched=True)
-            return fetches
-        if return_numpy:
-            _t_fetch = time.perf_counter()
-            fetches = [as_numpy(f) for f in fetches]
-            if tspan is not None:
-                _obs.emit_span('exe/fetch',
-                               time.perf_counter() - _t_fetch,
-                               parent=tspan)
+                jitted = part.partition(
+                    fn, in_shardings=(feeds_s, state_s),
+                    out_shardings=(fetch_s, out_state_s),
+                    donate_argnums=donate)
+        elif guard:
+            # Debug mode: functionalize the per-op NaN/Inf checks. No
+            # donation — on a thrown error the scope must still hold
+            # live (pre-step) state buffers.
+            from jax.experimental import checkify
+            jitted = jax.jit(checkify.checkify(fn))
         else:
-            # reference contract: fetches are LoDTensors; a dense fetch
-            # still answers .lod() (with []) — wrap bare arrays
-            fetches = [SequenceTensor(f, None) if isinstance(
-                f, (jax.Array, np.ndarray)) else f for f in fetches]
-        if tspan is not None:
-            tspan.end()
-        return fetches
+            jitted = part.partition(fn, donate_argnums=donate)
+        return self._apply_tuning(key, jitted)
 
     def run_chained(self, program=None, feed_list=None, fetch_list=None,
                     scope=None, return_numpy=True, async_fetch=False):
@@ -1086,18 +1153,8 @@ class Executor(object):
         if not feed_list:
             return []
 
-        def _sequential():
-            return [self.run(program, feed=f, fetch_list=fetch_list,
-                             scope=scope, return_numpy=return_numpy,
-                             async_fetch=async_fetch)
-                    for f in feed_list]
-
         k = len(feed_list)
-        dynamic = program.__dict__.setdefault(
-            '_dynamic_memo', {}).get(program.fingerprint())
-        if dynamic is None:
-            dynamic = _is_dynamic_program(program)
-            program._dynamic_memo[program.fingerprint()] = dynamic
+        dynamic = _dynamic_memoized(program)
         from .debugging import nan_checks_enabled
         from . import profiler as _prof
         from .layers.io import ReaderVar
@@ -1105,11 +1162,67 @@ class Executor(object):
             isinstance(v, ReaderVar) and getattr(v, 'source', None)
             is not None
             for v in program.global_block().vars.values())
-        part = self.partitioner
-        if k == 1 or dynamic or nan_checks_enabled() or \
-                _prof.op_profiling_enabled() or has_reader:
-            return _sequential()
+        if not (k == 1 or dynamic or nan_checks_enabled()
+                or _prof.op_profiling_enabled() or has_reader):
+            steps_out = self._run_chain(program, feed_list, fetch_list,
+                                        scope, return_numpy, async_fetch)
+            if steps_out is not None:
+                return steps_out
+        return [self.run(program, feed=f, fetch_list=fetch_list,
+                         scope=scope, return_numpy=return_numpy,
+                         async_fetch=async_fetch)
+                for f in feed_list]
 
+    def _run_chain(self, program, feed_list, fetch_list, scope,
+                   return_numpy, async_fetch):
+        """The chunk as one dispatch: ``exe/chain`` around the same
+        ``exe/prep``, ``exe/launch`` and ``exe/commit`` as :meth:`run`.
+        None where the chunk turns out not to chain while it is
+        prepared (the trace then holds an ``exe/chain`` with its
+        ``exe/prep`` alone, and the caller's K ``exe/run`` after it)."""
+        k = len(feed_list)
+        with _obs.phase('exe/chain', step_num=next(self._step_nums),
+                        steps=k) as top:
+            with _obs.phase('exe/prep', top) as prep:
+                step = self._prep_chain(top, program, feed_list,
+                                        fetch_list, scope)
+            if step is None:
+                top.note(fallback=True)
+                return None
+            with _obs.phase('exe/launch', top, cache=step.cache) as launch, \
+                    self.device_context(step.sharded):
+                fetches, new_state = step.jitted(step.feed, step.state)
+            with _obs.phase('exe/commit', top):
+                self._settle(top, prep, launch, step, new_state, scope,
+                             chain=k)
+                if getattr(program, '_half_inference', None):
+                    fetches = [_to_f32_fetch(f) for f in fetches]
+                anomaly_on = _anomaly.any_active()
+                to_host = return_numpy and not async_fetch
+                with _obs.phase('exe/fetch', top) if to_host \
+                        else contextlib.nullcontext():
+                    steps_out = []
+                    for i in range(k):
+                        row = [jax.tree_util.tree_map(lambda x: x[i], f)
+                               for f in fetches]
+                        if anomaly_on:
+                            _anomaly.observe_fetches(step.fetch_names, row)
+                        if to_host:
+                            row = [as_numpy(f) for f in row]
+                        elif not async_fetch:
+                            row = [SequenceTensor(f, None) if isinstance(
+                                f, (jax.Array, np.ndarray)) else f
+                                for f in row]
+                        steps_out.append(row)
+                return steps_out
+
+    def _prep_chain(self, top, program, feed_list, fetch_list, scope):
+        """``exe/prep`` of a chained chunk: the K feeds prepared and
+        stacked, the K-step program looked up or lowered
+        (``exe/verify``, ``exe/compile``), the state gathered and
+        committed. None where the chunk cannot chain."""
+        k = len(feed_list)
+        part = self.partitioner
         fetch_names = [f.name if isinstance(f, Variable) else f
                        for f in fetch_list]
         prepped, static_envs = [], []
@@ -1126,7 +1239,7 @@ class Executor(object):
                          for n, v in se.items())) == env0
             for se in static_envs[1:])
         if any(s != specs[0] for s in specs[1:]) or not static_same:
-            return _sequential()      # ragged tail / shape-feed churn
+            return None      # ragged tail / shape-feed churn
 
         state_in_names, state_out_names = self._state_names(program,
                                                             scope)
@@ -1139,22 +1252,18 @@ class Executor(object):
             # the scan carry must be treedef-stable step to step; a
             # program writing persistables absent from the scope would
             # grow it mid-chain
-            return _sequential()
+            return None
 
         try:
             stacked = jax.tree_util.tree_map(_stack_steps, *prepped)
         except (ValueError, TypeError):
-            return _sequential()      # heterogeneous feed structure
+            return None      # heterogeneous feed structure
 
         key = program_cache_key(program, prepped[0], static_envs[0],
                                 fetch_names, state_in_names,
                                 state_out_names, False, 'chain',
                                 part.cache_token(program))
-        _pctx = _obs.current_context()
-        tspan = _obs.start_span('exe/chain', parent=_pctx,
-                                activate=False, fp=key[0], steps=k) \
-            if _pctx is not None else None
-        t_lookup = time.perf_counter()
+        top.note(fp=key[0])
         state_s = stacked_s = None
         with self._cache_lock:
             entry = self._cache.get(key)
@@ -1166,45 +1275,45 @@ class Executor(object):
                 stacked_s = part.stacked_feed_shardings(prepped[0])
             if entry is None:
                 self._cache_misses += 1
-                _t_verify = time.perf_counter()
-                _analysis.verify_for_executor(
-                    program,
-                    feed_names=set(prepped[0]) | set(static_envs[0]),
-                    fetch_names=fetch_names)
-                if tspan is not None:
-                    _obs.emit_span('exe/verify',
-                                   time.perf_counter() - _t_verify,
-                                   parent=tspan)
+                with _obs.phase('exe/verify', top):
+                    _analysis.verify_for_executor(
+                        program,
+                        feed_names=set(prepped[0]) | set(static_envs[0]),
+                        fetch_names=fetch_names)
                 _obs.emit('compile_begin', fp=key[0], chain=k)
-                lower_prog = self._optimized_program(program,
-                                                     fetch_names,
-                                                     scope=scope)
-                fn = lowering.lower_block_chained(
-                    lower_prog, lower_prog.global_block(),
-                    sorted(prepped[0].keys()), fetch_names,
-                    state_in_names, state_out_names,
-                    static_env=static_envs[0])
-                if part.active:
-                    # K-step chain over the mesh: stacked feeds shard
-                    # their per-step batch dim, the scan carry keeps
-                    # each state var's own sharding
-                    out_state_s = part.state_shardings(
-                        program, state_out_names)
-                    jitted = part.partition(
-                        part.trace_wrap(fn),
-                        in_shardings=(stacked_s, state_s),
-                        # stacked fetches replicated (prefix-broadcast
-                        # over the fetch list) for the same donation-
-                        # aliasing reason as the single-step path
-                        out_shardings=(part.replicated, out_state_s),
-                        donate_argnums=(1,))
-                else:
-                    jitted = part.partition(fn, donate_argnums=(1,))
-                jitted = self._apply_tuning(key, jitted)
+                with _obs.phase('exe/compile', top, fp=key[0]):
+                    lower_prog = self._optimized_program(
+                        program, fetch_names, scope=scope)
+                    fn = lowering.lower_block_chained(
+                        lower_prog, lower_prog.global_block(),
+                        sorted(prepped[0].keys()), fetch_names,
+                        state_in_names, state_out_names,
+                        static_env=static_envs[0])
+                    if part.active:
+                        # K-step chain over the mesh: stacked feeds
+                        # shard their per-step batch dim, the scan
+                        # carry keeps each state var's own sharding
+                        out_state_s = part.state_shardings(
+                            program, state_out_names)
+                        jitted = part.partition(
+                            part.trace_wrap(fn),
+                            in_shardings=(stacked_s, state_s),
+                            # stacked fetches replicated (prefix-
+                            # broadcast over the fetch list) for the
+                            # same donation-aliasing reason as the
+                            # single-step path
+                            out_shardings=(part.replicated, out_state_s),
+                            donate_argnums=(1,))
+                    else:
+                        jitted = part.partition(fn, donate_argnums=(1,))
+                    jitted = self._apply_tuning(key, jitted)
                 self._cache[key] = jitted
             else:
                 self._cache_hits += 1
                 jitted = entry
+                known = self._lowerable.get(key)
+                if known is not None:
+                    known.runs += 1
         was_miss = entry is None
         (self._m_misses if was_miss else self._m_hits).inc()
 
@@ -1227,18 +1336,21 @@ class Executor(object):
                     'run_chained: multi-process globalize of the '
                     '%d-step chunk failed (%r); falling back to %d '
                     'sequential run() dispatches' % (k, e, k),
-                    RuntimeWarning, stacklevel=2)
+                    RuntimeWarning, stacklevel=4)
                 _obs.emit('multihost', action='chain_fallback',
                           steps=k, error=repr(e))
-                if tspan is not None:
-                    tspan.end(fallback='globalize')
-                return _sequential()
+                return None
+        if was_miss:
+            self._lowerable[key] = _Lowerable(_perf.abstract_args(
+                stacked, state,
+                (stacked_s, state_s) if part.active else None),
+                part.active)
         _ledger = None
-        if was_miss and not multiproc and _perf.capture_enabled():
-            # chained programs ledger separately (K steps fused into
-            # one XLA program — flops/bytes are per-CHUNK, chain=k)
-            with part.run_context() if part.active else \
-                    jax.default_device(self.place.jax_device()):
+        with self.device_context(part.active):
+            if was_miss and not multiproc and _perf.capture_enabled():
+                # chained programs ledger separately (K steps fused
+                # into one XLA program — flops/bytes are per-CHUNK,
+                # chain=k)
                 _ledger = _perf.capture_compiled(
                     jitted, stacked, state,
                     key[0], backend=jax.default_backend(),
@@ -1248,9 +1360,6 @@ class Executor(object):
                         part.describe() if part.active else None),
                     devices=part.device_count if part.active else 1,
                     chain=k)
-        t_run = time.perf_counter()
-        with part.run_context() if part.active else \
-                jax.default_device(self.place.jax_device()):
             if not multiproc:
                 # commit the state to its run placement BEFORE the
                 # first call: prefetch-staged feeds arrive committed,
@@ -1272,56 +1381,9 @@ class Executor(object):
                     # propagated; re-commit any that drifted from the
                     # declared in_shardings
                     stacked = part.reconcile(stacked, stacked_s)
-            fetches, new_state = jitted(stacked, state)
-        run_wall = time.perf_counter() - t_run
-        self._m_run.observe(run_wall)
-        h, m = self._m_hits.value, self._m_misses.value
-        self._m_hit_rate.set(h / (h + m) if h + m else 0.0)
-        if was_miss:
-            compile_wall = time.perf_counter() - t_lookup
-            self._m_compile.observe(compile_wall)
-            _obs.emit('compile_end', fp=key[0], chain=k,
-                      dur_s=round(compile_wall, 6))
-            if tspan is not None:
-                _obs.emit_span('exe/compile', compile_wall,
-                               parent=tspan, fp=key[0])
-            if _ledger is not None:
-                _perf.seal(_ledger, compile_wall,
-                           trace=tspan.context if tspan is not None
-                           else _pctx)
-        if tspan is not None:
-            _obs.emit_span('exe/dispatch', run_wall, parent=tspan,
-                           cache='miss' if was_miss else 'hit')
-        if _obs.journal_active():
-            _obs.emit('exe_run', cache='miss' if was_miss else 'hit',
-                      fp=key[0], chain=k, dur_s=round(run_wall, 6))
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        if getattr(program, '_half_inference', None):
-            fetches = [_to_f32_fetch(f) for f in fetches]
-        anomaly_on = _anomaly.any_active()
-        _t_fetch = time.perf_counter()
-        steps_out = []
-        for i in range(k):
-            row = [jax.tree_util.tree_map(lambda x: x[i], f)
-                   for f in fetches]
-            if anomaly_on:
-                _anomaly.observe_fetches(fetch_names, row)
-            if async_fetch:
-                pass
-            elif return_numpy:
-                row = [as_numpy(f) for f in row]
-            else:
-                row = [SequenceTensor(f, None) if isinstance(
-                    f, (jax.Array, np.ndarray)) else f for f in row]
-            steps_out.append(row)
-        if tspan is not None:
-            if not async_fetch and return_numpy:
-                _obs.emit_span('exe/fetch',
-                               time.perf_counter() - _t_fetch,
-                               parent=tspan)
-            tspan.end()
-        return steps_out
+        return _Step(jitted, stacked, state, key[0], fetch_names,
+                     part.active, 'miss' if was_miss else 'hit', was_miss,
+                     False, _ledger)
 
     def lowered(self, program, feed, fetch_list, scope=None):
         """The ``jax.stages.Lowered`` of the single-device step
@@ -1377,5 +1439,6 @@ class Executor(object):
     def close(self):
         with self._cache_lock:
             self._cache.clear()
+            self._lowerable.clear()
             self._cache_hits = 0
             self._cache_misses = 0

@@ -51,8 +51,9 @@ from .journal import (SCHEMA_VERSION, JOURNAL_ENV, RunJournal,  # noqa
                       install_env_journal)
 from .tracing import (TraceContext, Span, NULL_SPAN,  # noqa: F401
                       start_span, span, current_span, current_context,
-                      link, emit_span, sample_rate, parent_from_env,
-                      TRACE_PARENT_ENV, TRACE_SAMPLE_ENV)
+                      link, emit_span, phase, sample_rate,
+                      parent_from_env, TRACE_PARENT_ENV,
+                      TRACE_SAMPLE_ENV)
 from . import perf  # noqa: F401
 from .perf import (ProgramLedger, LedgerBook, PerfBaseline,  # noqa
                    PERF_ENV)
@@ -76,7 +77,7 @@ __all__ = [
     'journal', 'journal_active', 'emit', 'read_journal',
     'install_env_journal',
     'TraceContext', 'Span', 'NULL_SPAN', 'start_span', 'span',
-    'current_span', 'current_context', 'link', 'emit_span',
+    'current_span', 'current_context', 'link', 'emit_span', 'phase',
     'sample_rate', 'parent_from_env', 'TRACE_PARENT_ENV',
     'TRACE_SAMPLE_ENV',
     'perf', 'ProgramLedger', 'LedgerBook', 'PerfBaseline', 'PERF_ENV',

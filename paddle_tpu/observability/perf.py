@@ -41,7 +41,9 @@ import contextlib
 import hashlib
 import json
 import os
+import re
 import threading
+import weakref
 
 # NB: the package __init__ rebinds the name ``journal`` to the
 # contextmanager, so import the emit hook directly (not the submodule)
@@ -57,6 +59,8 @@ __all__ = [
     'peak_flops_for', 'hbm_gbps_for', 'mesh_signature',
     'shape_signature', 'transformer_flops_per_token',
     'mfu_from_throughput', 'program_ledger', 'memory_dict',
+    'abstract_args', 'register_executor', 'scope_map', 'parse_scopes',
+    'split_scope', 'PHASES',
 ]
 
 PERF_ENV = 'PTPU_PERF'              # '1' -> capture on for the process
@@ -393,11 +397,7 @@ def capture_compiled(jitted, feed, state, fingerprint, backend='',
     if not capture_enabled():
         return None
     try:
-        import jax
-        abstract = jax.tree_util.tree_map(
-            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype),
-            (feed, state))
-        comp = jitted.lower(*abstract).compile()
+        comp = jitted.lower(*abstract_args(feed, state)).compile()
         ca = comp.cost_analysis()
         if isinstance(ca, list):
             ca = ca[0]
@@ -478,6 +478,217 @@ def publish_step(fingerprint, seconds_per_step):
                       mfu=round(mfu, 4) if mfu is not None else None,
                       roofline=ledger.roofline_bound)
     return mfu
+
+
+# ---- from device operations back to Fluid scopes --------------------------
+# core/lowering.py lowers a block under ``forward`` (differentiated where
+# the program has a backward marker, so JAX names it ``jvp(forward)`` and
+# its backward ``transpose(jvp(forward))``) and ``optimizer``, and every
+# op under ``<op.type>[:<first output>]``. XLA keeps that path in each
+# instruction's ``metadata op_name``; a device trace names operations by
+# instruction only, so the compiled module's text is the join.
+PHASES = ('forward', 'backward', 'optimizer')
+_EXECUTORS = weakref.WeakSet()
+_COMPUTATION = re.compile(r'^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$')
+_INSTRUCTION = re.compile(r'^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s')
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r'\bcalls=%?([\w.\-]+)')
+_HEAVY = re.compile(r'\s(convolution|dot)\(')     # a TPU matmul is one too
+_MODULE = re.compile(r'^HloModule\s+([^\s,]+)')
+_WRAPPERS = ('checkpoint', 'rematted_computation', 'while', 'body',
+             'cond', 'branch')
+
+
+def register_executor(exe):
+    """Executors announce themselves (weakly) so that
+    :func:`scope_map` finds their compiled programs on demand."""
+    _EXECUTORS.add(exe)
+
+
+def abstract_args(feed, state, shardings=None):
+    """``(feed, state)`` as shapes and dtypes alone, each carrying its
+    name's sharding of ``shardings`` (``(feed shardings, state
+    shardings)`` of a Partitioner) where given: what an AOT ``lower()``
+    of the step takes in place of arrays."""
+    import jax
+
+    def avals(tree, named):
+        return {n: jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(
+                v.shape, v.dtype, sharding=(named or {}).get(n)), t)
+            for n, t in tree.items()}
+
+    feeds_s, state_s = shardings or (None, None)
+    return avals(feed, feeds_s), avals(state, state_s)
+
+
+def _fluid_at(parts):
+    """Index of the Fluid op's component in a split ``op_name``: the
+    one after the last phase scope (loop and remat wrappers skipped),
+    None where the path ends at its primitive there."""
+    at = [i for i, c in enumerate(parts)
+          if c in ('forward', 'optimizer') or '(forward)' in c]
+    rest = [i for i in range(at[-1] + 1, len(parts))
+            if parts[i] not in _WRAPPERS] if at else []
+    return rest[0] if len(rest) > 1 else None
+
+
+def split_scope(op_name):
+    """``(phase, Fluid op)`` of a ``metadata op_name`` path: ``backward``
+    if the path holds ``transpose(``, ``optimizer`` if it holds the
+    ``optimizer`` scope, else ``forward``; the Fluid op is the component
+    after the phase's scope (``conv2d:res2a_branch2a.tmp_0``; two
+    joined by ``+`` where :func:`parse_scopes` found a fusion doing
+    both), None where the path ends there."""
+    parts = op_name.split('/')
+    if 'transpose(' in op_name:
+        phase = 'backward'
+    elif 'optimizer' in parts:
+        phase = 'optimizer'
+    else:
+        phase = 'forward'
+    at = _fluid_at(parts)
+    return phase, (parts[at] if at is not None else None)
+
+
+def parse_scopes(hlo_text):
+    """``(module name, {instruction name: op_name})`` of a compiled
+    module's text. One fusion is one operation to a trace, so it gets
+    one scope. Without a convolution or dot inside, that is the latest
+    phase found inside, since it runs when the last of what it holds
+    can run (forward ops inside a backward fusion are recomputed there;
+    the gradient's last casts ride inside Adam's update): its own
+    ``op_name`` if that is of this phase, else the most frequent one of
+    this phase inside. With one, it is the convolution's or dot's (a
+    TPU matmul is a convolution), and where the fusion goes on into a
+    later phase — a weight gradient's matmul with that weight's update
+    as its epilogue — the later Fluid op is joined to the matmul's
+    (``mul:fc_5.tmp_0+adam:fc_5.w_0``), so that a table shows both. An
+    instruction that has no metadata, in itself or inside, is left
+    out."""
+    module, comps, cur = '', {}, None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            m = _MODULE.match(line)
+            if m:
+                module = m.group(1)
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = comps.setdefault(m.group(1), [])
+            continue
+        if line.startswith('}'):
+            cur = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            name = _OP_NAME.search(line)
+            calls = _CALLS.search(line)
+            cur.append((m.group(2), name.group(1) if name else None,
+                        calls.group(1) if calls else None,
+                        bool(_HEAVY.search(line))))
+
+    def rank(op_name):
+        return PHASES.index(split_scope(op_name)[0])
+
+    def of_fusion(own, comp):
+        inside = [n for _, n, _, _ in comps.get(comp, ()) if n]
+        if own:
+            inside.append(own)
+        if not inside:
+            return None
+        latest = max(map(rank, inside))
+        last = [n for n in inside if rank(n) == latest]
+        tail = own if own and rank(own) == latest \
+            else max(set(last), key=last.count)
+        heavy = [n for _, n, _, h in comps.get(comp, ()) if n and h]
+        if not heavy:
+            return tail
+        parts = heavy[0].split('/')
+        at, then = _fluid_at(parts), split_scope(tail)[1]
+        if rank(heavy[0]) < latest and at is not None and then:
+            parts[at] += '+' + then
+        return '/'.join(parts)
+
+    scopes = {}
+    for rows in comps.values():
+        for inst, name, calls, _ in rows:
+            if calls:
+                name = of_fusion(name, calls)
+            if name:
+                scopes[inst] = name
+    return module, scopes
+
+
+def _scoped(text):
+    return any(('/%s/' % s) in text or ('(%s)' % s) in text
+               for s in ('forward', 'optimizer'))
+
+
+def _compiled_text(jitted, abstract):
+    """The optimized HLO text of a cached entry, compiled once more
+    from the persistent cache. JAX leaves metadata out of that cache's
+    key, so the cache may hand back an executable that another version
+    of the program compiled, with that version's scopes: where the text
+    lacks this version's, the entry is lowered and compiled afresh
+    (metadata in the key, nothing written back)."""
+    import jax
+    if not hasattr(jitted, 'trace'):          # an AOT-loaded Compiled
+        return jitted.as_text()
+    traced = jitted.trace(*abstract)
+    text = traced.lower().compile().as_text()
+    if _scoped(text):
+        return text
+    cfg = jax.config
+    keep = (cfg.jax_compilation_cache_include_metadata_in_key,
+            cfg.jax_persistent_cache_min_compile_time_secs)
+    cfg.update('jax_compilation_cache_include_metadata_in_key', True)
+    cfg.update('jax_persistent_cache_min_compile_time_secs', 1e9)
+    try:
+        # JAX also remembers, in memory and by module, the executable
+        # the compile above was handed; dropping that memo costs a
+        # later re-lowering one more load, and no running program
+        from jax._src.interpreters import pxla
+        pxla._cached_compilation.cache_clear()
+        return traced.lower(
+            lowering_platforms=(jax.default_backend(),)
+        ).compile().as_text()
+    finally:
+        cfg.update('jax_compilation_cache_include_metadata_in_key',
+                   keep[0])
+        cfg.update('jax_persistent_cache_min_compile_time_secs', keep[1])
+
+
+def scope_map(min_runs=1, executors=None):
+    """``{module: {instruction name: op_name}}`` for the compiled
+    programs of ``executors`` (every live Executor by default) that ran
+    at least ``min_runs`` times (a training loop's step, not its
+    startup program). On demand only — a trace reader or a tool calls
+    this, ``Executor.run`` never does: each entry is lowered and
+    compiled once more from the abstract arguments of its miss, which
+    costs seconds (the persistent compile cache serves the compile). A
+    module is keyed ``<hlo module>|<fingerprint>|<n>``; an entry that
+    cannot be read maps to ``{'error': <why>}`` under the same key."""
+    out = {}
+    for exe in list(_EXECUTORS) if executors is None else executors:
+        for fp, jitted, abstract, sharded, runs in exe.lowerable_entries():
+            if runs < min_runs or jitted is None:
+                continue
+            key = '|%s|%d' % (fp, len(out))
+            try:
+                # a tuning-wrapped entry is lowered as it was traced:
+                # its jit, under its knobs (compiler/tuning.py)
+                knobs = getattr(jitted, 'knobs', None)
+                if knobs is not None:
+                    jitted = jitted.__wrapped__
+                with exe.device_context(sharded), \
+                        knobs() if knobs else contextlib.nullcontext():
+                    text = _compiled_text(jitted, abstract)
+                module, scopes = parse_scopes(text)
+                out[module + key] = scopes
+            except Exception as e:  # noqa: BLE001 — diagnostic: the map
+                # says which entry it could not read, and why
+                out['?' + key] = {'error': '%s: %s' % (type(e).__name__, e)}
+    return out
 
 
 # ---- shared offline helpers (the one ledger implementation) ---------------

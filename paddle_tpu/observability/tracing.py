@@ -22,9 +22,11 @@ Span records are plain journal events (OBSERVABILITY.md):
                 request spans it serves (N↔1, not parent-child)
 =============  =========================================================
 
-Overhead contract: with no journal installed every API here returns the
-shared :data:`NULL_SPAN` after one module-global ``None`` check — no
-allocation, no ids, no clock read. With a journal installed, sampling
+Overhead contract: with no journal installed every span API here
+returns the shared :data:`NULL_SPAN` after one module-global ``None``
+check — no allocation, no ids, no clock read. :class:`phase` (the
+Executor's phases) besides enters a profiler ``TraceAnnotation``, which
+is inert while no profiler session runs. With a journal installed, sampling
 is decided once per root from ``PTPU_TRACE_SAMPLE`` (default 1.0) by
 hashing the trace id, so a rate of 0.25 keeps whole trees, never
 orphan fragments; unsampled trees still propagate one shared inert
@@ -42,7 +44,7 @@ from .metrics import default_registry
 
 __all__ = ['TraceContext', 'Span', 'NULL_SPAN', 'start_span', 'span',
            'current_span', 'current_context', 'link', 'emit_span',
-           'sample_rate', 'parent_from_env', 'TRACE_PARENT_ENV',
+           'phase', 'sample_rate', 'parent_from_env', 'TRACE_PARENT_ENV',
            'TRACE_SAMPLE_ENV']
 
 TRACE_SAMPLE_ENV = 'PTPU_TRACE_SAMPLE'
@@ -320,6 +322,86 @@ def emit_span(name, dur_s, parent=None, **fields):
           span=ctx.span_id, parent=ctx.parent_id,
           dur_s=round(dur_s, 6), **fields)
     return ctx
+
+
+_ANNOTATIONS = None
+
+
+def _annotations():
+    # jax is imported at first use, not at import: observability/ stays
+    # import-cycle-free and stdlib-only for whoever never runs a phase
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        from jax import profiler
+        _ANNOTATIONS = (profiler.TraceAnnotation,
+                        profiler.StepTraceAnnotation)
+    return _ANNOTATIONS
+
+
+class phase(object):
+    """One named phase of a call, on two sinks and one clock.
+
+    Entering always enters a ``jax.profiler.TraceAnnotation`` of the
+    phase's name, so the phase lies in the profiler's trace on the
+    clock the device's operations are on; with no profiler session
+    running that is an inert object (well under a microsecond).
+    The journal gets the same name from the same call site under the
+    journal's own rule — installed, and a parent span active:
+
+    - a root (no ``parent``) opens a real span (``span_begin`` /
+      ``span_end``) under the thread's current span, not activated;
+      with ``step_num`` it is a ``StepTraceAnnotation``, which fills
+      the device plane's ``Steps`` line;
+    - a child (``parent`` is the root ``phase``) writes one
+      ``span_end`` through :func:`emit_span` when it exits.
+
+    ``t0`` and ``dur_s`` (``time.perf_counter``) stay readable after
+    the block, for the series that were fed from hand-held timers;
+    ``note()`` adds fields to the ``span_end`` record."""
+
+    __slots__ = ('name', 'span', 't0', 'dur_s', '_ann', '_parent',
+                 '_fields', '_end')
+
+    def __init__(self, name, parent=None, step_num=None, **fields):
+        plain, step = _annotations()
+        self.name = name
+        self.span = None
+        self.t0 = self.dur_s = 0.0
+        self._ann = plain(name) if step_num is None \
+            else step(name, step_num=step_num)
+        self._parent, self._fields, self._end = parent, fields, None
+
+    @property
+    def context(self):
+        """The journal span's :class:`TraceContext`, None untraced."""
+        return self.span.context if self.span is not None else None
+
+    def note(self, **fields):
+        self._end = dict(self._end or (), **fields)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._parent is None:
+            pctx = current_context()
+            if pctx is not None:
+                self.span = start_span(self.name, parent=pctx,
+                                       activate=False, **self._fields)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.dur_s = time.perf_counter() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
+        end = self._end or {}
+        if exc_type is not None:
+            end['error'] = exc_type.__name__
+        if self.span is not None:
+            self.span.end(**end)
+        elif self._parent is not None and self._parent.span is not None:
+            end.update(self._fields)
+            emit_span(self.name, self.dur_s, parent=self._parent.span,
+                      **end)
+        return False
 
 
 def parent_from_env(environ=None):

@@ -200,6 +200,7 @@ def _flash_pallas_call(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, D), jnp.float32),     # unnormalised acc
         ],
+        name='_flash_kernel',
         interpret=interpret,
     )(q, k, v)
     return on, lse
@@ -413,6 +414,7 @@ def _flash_bwd_merged(q, k, v, do, lse, delta, causal, block_q, block_k,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
+        name='_flash_dkvdq_kernel',
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     dq = jnp.sum(dqp.astype(jnp.float32), axis=1).astype(q.dtype)
@@ -456,6 +458,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        name='_flash_dq_kernel',
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     qi_map = _qi_clamp(causal, block_q, block_k)
@@ -482,6 +485,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q, block_k,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
+        name='_flash_dkv_kernel',
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -656,6 +660,7 @@ def _lstm_cell_pallas(xg, r_prev, c_prev, w, interpret):
         _lstm_cell_kernel,
         out_shape=(jax.ShapeDtypeStruct((B, H), r_prev.dtype),
                    jax.ShapeDtypeStruct((B, H), c_prev.dtype)),
+        name='_lstm_cell_kernel',
         interpret=interpret,
     )(xg, r_prev, c_prev, w)
 
@@ -893,6 +898,7 @@ def _fconv_pallas(x, w, aux, meta):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name='_fconv_kernel',
         interpret=bool(interpret),
     )(x, w, *[a if k in ('t', 's') else a[:, None, :]
               for k, a in zip(aux_kinds, aux)])
