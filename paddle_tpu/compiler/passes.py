@@ -28,7 +28,7 @@ __all__ = ['DeadOpElimination', 'ConstantFolding', 'ElementwiseFusion',
            'ConvEpilogueFusion', 'BufferReuse', 'BatchNormFolding',
            'DEFAULT_PASSES', 'INFERENCE_PASSES', 'RNG_OPS',
            'FUSED_ELEMENTWISE_OP', 'FUSED_CONV_OP',
-           'conv_fuse_counts']
+           'conv_fuse_counts', 'flash_counts']
 
 # Ops that consume the threaded PRNG key: removing one would shift the
 # RNG stream of every later stochastic op, silently changing numerics —
@@ -831,21 +831,32 @@ def _fused_conv_kernel(ctx):
         ctx.runner.run_ops(ops, ctx.env)
 
 
+def _series(name):
+    """The live series of one metric of the default registry."""
+    return _obs.default_registry().snapshot().get(name, {}).get(
+        'series', ())
+
+
 def conv_fuse_counts():
     """``{'engaged': n, 'fallbacks': {reason: n}}``: how the process's
     fused_conv lowerings went so far (counted per trace, i.e. per
     compile)."""
-    snap = _obs.default_registry().snapshot()
-
-    def series(name):
-        return snap.get(name, {}).get('series', ())
-
     return {
         'engaged': int(sum(s['value'] for s in
-                           series('conv_fuse_engaged_total'))),
+                           _series('conv_fuse_engaged_total'))),
         'fallbacks': {s['labels']['reason']: int(s['value'])
-                      for s in series('conv_fuse_fallbacks_total')
+                      for s in _series('conv_fuse_fallbacks_total')
                       if s['value']}}
+
+
+def flash_counts():
+    """``{(route, dtype): n}``: how the process's flash_attention op
+    lowerings went so far (ops/misc_ops.py; counted per trace, as the
+    conv-fuse counts are). route is 'pallas' or 'xla', dtype the
+    operand dtype the attention ran in ('bf16', 'f32')."""
+    return {(s['labels']['route'], s['labels']['dtype']): int(s['value'])
+            for s in _series('flash_attention_lowerings_total')
+            if s['value']}
 
 
 @register_pass

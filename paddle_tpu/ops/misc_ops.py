@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import observability as _obs
 from ..core.registry import register_kernel
 from .common import unwrap
 
@@ -446,25 +447,45 @@ def _flash_attention_op(ctx):
     flash kernel (ops/pallas_kernels.py) — engaged on TPU at long seq
     lens, identical-math XLA reference elsewhere. Inputs Q/K/V:
     [B, T, D]; attr num_heads splits D. This is the op behind
-    layers.flash_attention, the fluid route to the flagship transformer
-    path (bench.py's headline)."""
-    from .pallas_kernels import flash_attention
-    q = unwrap(ctx.input('Q'))
-    k = unwrap(ctx.input('K'))
-    v = unwrap(ctx.input('V'))
+    layers.flash_attention, the fluid route to the kernels (the OPT
+    cell of benchmark/chip builds its attention from it).
+
+    QK^T and PV are matmuls, so under AMP the op is on the MXU path
+    like mul/matmul/conv2d (core/amp.py::mxu_compute): f32 q, k, v are
+    cast to bf16 on either route, the dots accumulate f32 and the
+    softmax state stays f32 inside the kernels, and the output flows
+    bf16 under act_bf16(). Each lowering counts once in
+    ``flash_attention_lowerings_total{route=, dtype=}``
+    (compiler/passes.py::flash_counts)."""
+    from .pallas_kernels import flash_attention, flash_plan
+    from ..core.amp import mxu_compute
     heads = int(ctx.attr('num_heads', 1))
     causal = bool(ctx.attr('causal', True))
-    B, T, D = q.shape
-    dh = D // heads
-    qh = q.reshape(B, T, heads, dh)
-    kh = k.reshape(B, T, heads, dh)
-    vh = v.reshape(B, T, heads, dh)
     # autotuned tile sizes, when the compiler's tuning cache holds an
     # entry for this (program, shape, backend); (None, None) otherwise
     # keeps the kernel's dtype-aware defaults
     from ..compiler import tuning as _ctuning
     bq, bk = _ctuning.flash_blocks()
-    # NB: flash_attention applies the 1/sqrt(dh) logit scale itself
-    out = flash_attention(qh, kh, vh, causal=causal,
-                          block_q=bq, block_k=bk)
-    ctx.set_output('Out', out.reshape(B, T, D))
+
+    def attend(q, k, v):
+        B, T, D = q.shape
+        dh = D // heads
+        qh = q.reshape(B, T, heads, dh)
+        kh = k.reshape(B, T, heads, dh)
+        vh = v.reshape(B, T, heads, dh)
+        _obs.default_registry().counter(
+            'flash_attention_lowerings_total',
+            help='flash_attention op lowerings, by the route taken '
+                 '(pallas kernels / xla reference) and the operand '
+                 'dtype the attention ran in',
+            route='xla' if flash_plan(qh, bq, bk) is None else 'pallas',
+            dtype={'bfloat16': 'bf16', 'float32': 'f32'}.get(
+                qh.dtype.name, qh.dtype.name)).inc()
+        # NB: flash_attention applies the 1/sqrt(dh) logit scale itself
+        out = flash_attention(qh, kh, vh, causal=causal,
+                              block_q=bq, block_k=bk)
+        return out.reshape(B, T, D)
+
+    ctx.set_output('Out', mxu_compute(
+        attend, unwrap(ctx.input('Q')), unwrap(ctx.input('K')),
+        unwrap(ctx.input('V'))))

@@ -576,22 +576,16 @@ def flash_attention(q, k, v, causal=True, block_q=None, block_k=None,
                                     interpret, force)[0]
 
 
-def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
-                             block_k=None, interpret=None, force=None):
-    """flash_attention that also returns per-row logsumexp [B, H, T].
-
-    This is the ring-attention building block: each device computes its
-    local (out, lse) partials per KV block and merges them exactly via
-    logsumexp weighting — gradients flow through BOTH outputs (the lse
-    cotangent folds into the Pallas backward's delta term). Engagement
-    policy identical to flash_attention; falls back to the XLA
-    reference (with lse) elsewhere."""
+def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None):
+    """THE engagement decision for q [B, T, H, D]: the (block_q, block_k)
+    the Pallas kernels run with, or None where the XLA reference runs
+    instead. flash_attention_with_lse routes by it; the flash_attention
+    op (ops/misc_ops.py) labels its lowering counter with it."""
     B, T, H = q.shape[0], q.shape[1], q.shape[2]
-    if interpret is None:
-        interpret = False
     # dtype-aware default blocks (r5 full-backward sweep, PERF.md):
-    # bf16 halves VMEM per block, so 1024x1024 fits and wins ~5%;
-    # f32 1024x1024 exceeds the VMEM scoped limit -> 512/1024
+    # bf16 1024x1024 won the swept set by ~5%. f32 keeps 512/1024 as
+    # swept in r4, when f32 1024x1024 overflowed scoped VMEM; this
+    # Mosaic compiles it (PERF.md, PR 21) and nobody has timed it.
     if block_q is None:
         block_q = 1024 if q.dtype == jnp.bfloat16 else 512
     if block_k is None:
@@ -605,10 +599,27 @@ def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
         use_pallas = force
     bq = _pick_block(T, block_q)
     bk = _pick_block(T, block_k)
-    if bq is None or bk is None:
-        use_pallas = False
-    if not use_pallas:
+    if not use_pallas or bq is None or bk is None:
+        return None
+    return bq, bk
+
+
+def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
+                             block_k=None, interpret=None, force=None):
+    """flash_attention that also returns per-row logsumexp [B, H, T].
+
+    This is the ring-attention building block: each device computes its
+    local (out, lse) partials per KV block and merges them exactly via
+    logsumexp weighting — gradients flow through BOTH outputs (the lse
+    cotangent folds into the Pallas backward's delta term). Engagement
+    policy identical to flash_attention (flash_plan); falls back to the
+    XLA reference (with lse) elsewhere."""
+    if interpret is None:
+        interpret = False
+    plan = flash_plan(q, block_q, block_k, interpret, force)
+    if plan is None:
         return attention_reference_with_lse(q, k, v, causal)
+    bq, bk = plan
     return _flash_lse(q, k, v, causal, bq, bk, interpret)
 
 

@@ -351,3 +351,189 @@ def test_merged_backward_matches_two_pass(causal):
     for a, b in zip(g_merged, g_two):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-7)
+
+
+# ---- the flash_attention op on AMP's MXU path -------------------------------
+@pytest.fixture
+def amp():
+    """core.amp with its state handed back as it was found, so no other
+    test sees AMP on."""
+    from paddle_tpu.core import amp as _amp
+    saved = dict(_amp._STATE)
+    yield _amp
+    _amp._STATE.clear()
+    _amp._STATE.update(saved)
+
+
+@pytest.fixture
+def engage(monkeypatch):
+    """The op's engaged route on the CPU: the engagement rule sees a TPU
+    and no row floor, and the kernels run in the Pallas interpreter."""
+    orig = pk._flash_lse
+    monkeypatch.setattr(pk, '_on_tpu', lambda: True)
+    monkeypatch.setattr(pk, '_FLASH_MIN_ROWS', 0)
+    monkeypatch.setattr(
+        pk, '_flash_lse',
+        lambda q, k, v, causal, bq, bk, interpret:
+            orig(q, k, v, causal, bq, bk, True))
+
+
+_FLASH_OP_B, _FLASH_OP_H, _FLASH_OP_DH = 2, 4, 64
+
+
+def _flash_op_feed(T, seed=11):
+    rng = np.random.RandomState(seed)
+    shape = (_FLASH_OP_B, T, _FLASH_OP_H * _FLASH_OP_DH)
+    return {n: rng.randn(*shape).astype('float32') * s
+            for n, s in (('q', 1.0), ('k', 1.0), ('v', 1.0), ('w', 0.1))}
+
+
+def _flash_op_program(T, depth=1, grads=True):
+    """``depth`` flash_attention ops in a row on fed q, k, v (B2 H4
+    D64) and, with ``grads``, the gradients of sum(out * w) in q, k, v
+    (fluid.gradients replays the op path under jax.vjp: a second trace
+    of each op). Returns (main, startup, [out, dq, dk, dv])."""
+    import paddle_tpu.fluid as fluid
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q, k, v, w = [
+            fluid.layers.data(name=n, shape=[T, _FLASH_OP_H * _FLASH_OP_DH],
+                              dtype='float32') for n in 'qkvw']
+        for x in (q, k, v):
+            x.stop_gradient = False
+        out = q
+        for _ in range(depth):
+            out = fluid.layers.flash_attention(out, k, v,
+                                               num_heads=_FLASH_OP_H)
+        fetch = [out]
+        if grads:
+            loss = fluid.layers.reduce_sum(
+                fluid.layers.elementwise_mul(out, w))
+            fetch += fluid.gradients(loss, [q, k, v])
+    return main, startup, fetch
+
+
+def _flash_op_run(T, blocks=None, lower_only=False, grads=True):
+    """Run (or only lower) the one-op program: [out, dq, dk, dv] as
+    numpy, or the lowered step's text."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.compiler import tuning
+    main, startup, fetch = _flash_op_program(T, grads=grads)
+    exe = fluid.Executor(fluid.CPUPlace())
+    entry = {'flash_block_q': blocks, 'flash_block_k': blocks} \
+        if blocks else None
+    with fluid.scope_guard(fluid.Scope()), tuning.apply_entry(entry):
+        exe.run(startup)
+        if lower_only:
+            return exe.lowered(main, feed=_flash_op_feed(T),
+                               fetch_list=fetch).as_text()
+        return exe.run(main, feed=_flash_op_feed(T), fetch_list=fetch)
+
+
+def _flash_op_direct(T, attend):
+    """[out, dq, dk, dv] of ``attend`` (attention_reference, or
+    flash_attention as the op's body called it before it joined AMP)
+    called directly on the same float32 feed, heads split as the op
+    splits them."""
+    feed = {n: jnp.asarray(x) for n, x in _flash_op_feed(T).items()}
+    heads = (_FLASH_OP_B, T, _FLASH_OP_H, _FLASH_OP_DH)
+
+    def loss(q, k, v):
+        o = attend(q.reshape(heads), k.reshape(heads), v.reshape(heads),
+                   causal=True).reshape(q.shape)
+        return jnp.sum(o * feed['w']), o
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(
+        feed['q'], feed['k'], feed['v'])
+    return [np.asarray(a) for a in (out,) + grads]
+
+
+def _dot_types(text):
+    """(lhs dtype, rhs dtype, result dtype) of every dot in a lowered
+    step's text."""
+    import re
+    return re.findall(
+        r'stablehlo\.dot_general.*: \(tensor<[0-9x]*x(\w+)>, '
+        r'tensor<[0-9x]*x(\w+)>\) -> tensor<[0-9x]*x(\w+)>', text)
+
+
+@pytest.mark.parametrize('route,T,grads', [('pallas', 512, True),
+                                           ('xla', 256, False)])
+def test_flash_op_amp_lowers_bf16_operands(route, T, grads, amp, engage):
+    """Under AMP the op is on the MXU path like mul/matmul: float32 q, k,
+    v reach the kernels (engaged route: forward and merged backward) and
+    the XLA reference (T < 512: its forward; jax's transpose of a dot
+    that accumulates float32 takes the float32 cotangent as it comes)
+    as bf16, and every dot takes bf16 operands and accumulates float32;
+    with AMP off the same program lowers float32 dots only."""
+    amp.set_amp(True)
+    dots = _dot_types(_flash_op_run(T, lower_only=True, grads=grads))
+    assert dots and set(dots) == {('bf16', 'bf16', 'f32')}, set(dots)
+    amp.set_amp(False)
+    dots = _dot_types(_flash_op_run(T, lower_only=True, grads=grads))
+    assert dots and set(dots) == {('f32', 'f32', 'f32')}, set(dots)
+
+
+@pytest.mark.parametrize('T', [512, 256], ids=['pallas', 'xla'])
+def test_flash_op_amp_output_dtype(T, amp, engage):
+    """bf16 out where activations flow bf16 (act_bf16), float32 where
+    they do not (set_amp_act(False)); the gradients of float32 inputs
+    come back float32 either way."""
+    amp.set_amp(True)
+    out = _flash_op_run(T, blocks=128)
+    assert out[0].dtype == jnp.bfloat16
+    assert [g.dtype for g in out[1:]] == [np.float32] * 3
+    amp.set_amp_act(False)
+    out = _flash_op_run(T, blocks=128)
+    assert [a.dtype for a in out] == [np.float32] * 4
+
+
+@pytest.mark.parametrize('T', [512, 256], ids=['pallas', 'xla'])
+def test_flash_op_amp_off_is_bit_identical(T, amp, engage):
+    """With AMP off the op hands q, k, v on as they arrive: output and
+    gradients are, bit for bit, what flash_attention gives called
+    directly on the same float32 inputs (the parent's op body)."""
+    amp.set_amp(False)
+    for a, b in zip(_flash_op_run(T),
+                    _flash_op_direct(T, pk.flash_attention)):
+        assert a.dtype == np.float32
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize('route,T,blocks', [
+    ('pallas', 512, 128), ('pallas', 512, None), ('xla', 256, None)])
+def test_flash_op_amp_matches_f32_reference(route, T, blocks, amp, engage):
+    """Forward and the gradients of q, k, v through the op under AMP at
+    D = 64 against the float32 attention_reference, each within 3e-2 of
+    the reference's largest magnitude (chip_smoke.py's kernel rule)."""
+    amp.set_amp(True)
+    got = _flash_op_run(T, blocks=blocks)
+    for name, a, b in zip(('out', 'dq', 'dk', 'dv'), got,
+                          _flash_op_direct(T, pk.attention_reference)):
+        a = np.asarray(a, np.float32)
+        assert np.isfinite(a).all(), name
+        err = np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-6)
+        assert err < 3e-2, '%s (%s): %.3g' % (name, route, err)
+
+
+@pytest.mark.parametrize('amp_on,route,T', [
+    (True, 'pallas', 512), (True, 'xla', 256),
+    (False, 'pallas', 512), (False, 'xla', 256)])
+def test_flash_counts_one_per_op_lowering(amp_on, route, T, amp, engage):
+    """One lowering of a two-layer program counts two flash_attention
+    lowerings under the route taken and the dtype the attention ran
+    in, and none under any other label."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.compiler.passes import flash_counts
+    amp.set_amp(amp_on)
+    main, startup, fetch = _flash_op_program(T, depth=2, grads=False)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        before = flash_counts()
+        exe.lowered(main, feed=_flash_op_feed(T), fetch_list=fetch)
+        after = flash_counts()
+    moved = {key: n - before.get(key, 0) for key, n in after.items()
+             if n != before.get(key, 0)}
+    assert moved == {(route, 'bf16' if amp_on else 'f32'): 2}
