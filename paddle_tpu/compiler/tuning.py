@@ -371,7 +371,10 @@ class Autotuner(object):
                 pruned += len(_CONV_SCHEDULE_SPACE) - len(space)
                 cands.extend(dict(c) for c in space)
         if 'flash_attention' in types:
-            for bq, bk in ((512, 512), (512, 1024), (1024, 1024)):
+            # the set swept on the chip for the [B, T, H*dh] kernels
+            # (PERF.md, PR 27)
+            for bq, bk in ((1024, 1024), (512, 1024), (512, 512),
+                           (256, 512)):
                 cands.append({'flash_block_q': bq, 'flash_block_k': bk})
         # dedupe, keep order
         seen, out = set(), []

@@ -448,7 +448,13 @@ def _flash_attention_op(ctx):
     lens, identical-math XLA reference elsewhere. Inputs Q/K/V:
     [B, T, D]; attr num_heads splits D. This is the op behind
     layers.flash_attention, the fluid route to the kernels (the OPT
-    cell of benchmark/chip builds its attention from it).
+    cell of benchmark/chip builds its attention from it). The engaged
+    kernels take Q, K, V and write Out in this very layout (a program
+    addresses its heads as a 128-lane block of D), so the head split
+    below is a reshape on both routes and nothing is transposed or
+    copied between a projection and a kernel; a head shape the lane
+    blocks cannot take (odd num_heads at head size 64, a head size
+    other than 64 or a multiple of 128) counts as route=xla.
 
     QK^T and PV are matmuls, so under AMP the op is on the MXU path
     like mul/matmul/conv2d (core/amp.py::mxu_compute): f32 q, k, v are

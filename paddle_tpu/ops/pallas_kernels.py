@@ -8,7 +8,9 @@ equivalents are written in Pallas:
 
 - ``flash_attention``: blockwise online-softmax attention that never
   materialises the [T, T] score matrix; q/k/v blocks stream HBM->VMEM and
-  the inner matmuls hit the MXU. Grid = (batch*heads, q-blocks).
+  the inner matmuls hit the MXU. The kernels read [B, T, H*dh] as the
+  projections write it; grid = (batch, head groups of 128 lanes,
+  q-blocks, k-blocks).
 - ``fused_lstm_cell``: one kernel for the recurrent matmul + all four gate
   nonlinearities + state update, so per-step HBM traffic is just the
   carried state (XLA would otherwise split matmul and VPU work).
@@ -73,18 +75,60 @@ _LOG2E = 1.4426950408889634
 _USE_EXP2 = [True]
 
 
+def _lane_heads(H, dh):
+    """Heads to a program, ``hp``, such that a block of ``hp * dh`` lanes
+    of a [B, T, H*dh] array is whole 128-lane tiles: 2 at head size 64,
+    1 at a multiple of 128. None for a head shape the addressing cannot
+    take (odd H at head size 64, any other head size): flash_plan sends
+    those to the XLA reference."""
+    if dh % 128 == 0:
+        return 1
+    if dh == 64 and H % 2 == 0:
+        return 2
+    return None
+
+
+def _only_head(x, i, dh):
+    """``x`` [rows, hp*dh] with every lane outside head ``i`` of the
+    program's lane block zeroed, so a dot that contracts all the lanes
+    sees head ``i`` alone and one that keeps them leaves the other
+    heads' lanes 0. No lane is sliced: Mosaic gets a full-width
+    select."""
+    if x.shape[-1] == dh:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane // dh == i, x, jnp.zeros_like(x))
+
+
+def _by_head(xs, shape, dh):
+    """[rows, hp*dh] that takes head i's lanes from ``xs[i]`` (each
+    [rows, hp*dh], or [rows, 1] to spread a per-row statistic over its
+    head's lanes)."""
+    out = jnp.broadcast_to(xs[-1], shape)
+    if len(xs) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        for i in range(len(xs) - 2, -1, -1):
+            out = jnp.where(lane // dh == i, xs[i], out)
+    return out
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, block_q, block_k, causal, n_kb, exp2):
-    """One (batch*head, q-block, k-block) grid step.
+                  acc_scr, *, block_q, block_k, causal, n_kb, exp2, dh):
+    """One (batch, head group, q-block, k-block) grid step on blocks
+    [block, hp*dh] of [B, T, H*dh] arrays: the ``hp`` heads whose lanes
+    fill the block (two at head size 64) are attended one after the
+    other, each by zeroing the other's lanes of q and contracting all
+    the lanes (_only_head), and share one lane-selected accumulator.
 
     The k-block index is the innermost grid dim, so Mosaic streams k/v
     blocks HBM->VMEM with automatic double-buffering while the online
-    softmax state (m, l, acc) persists in VMEM scratch across steps.
-    No dynamic_slice on values anywhere — Mosaic can't lower it; all
-    block movement is done by the BlockSpec index maps.
+    softmax state (m and l per head, acc) persists in VMEM scratch
+    across steps. No dynamic_slice on values anywhere — Mosaic can't
+    lower it; all block movement is done by the BlockSpec index maps.
     """
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
+    hp = m_scr.shape[0]
 
     @pl.when(kb == 0)
     def _init():
@@ -102,34 +146,41 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         # MXU) and accumulate f32 via preferred_element_type; the
         # online-softmax state stays f32 (r4 perf: the f32 upcast
         # halved MXU throughput on the AMP path)
-        q = q_ref[0]                              # [block_q, D]
-        k = k_ref[0]                              # [block_k, D]
+        q = q_ref[0]                              # [block_q, hp*dh]
+        k = k_ref[0]                              # [block_k, hp*dh]
         v = v_ref[0]
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(dh)
         _exp = jnp.exp2 if exp2 else jnp.exp
         if exp2:
             scale = scale * _LOG2E  # scores live in log2 units
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bk]
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]                     # [bq, 1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = _exp(m_prev - m_new)
-        p = _exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            keep = q_pos >= k_pos
+        alphas, pvs = [], []
+        for i in range(hp):
+            s = jax.lax.dot_general(
+                _only_head(q, i, dh), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [bq, bk]
+            if causal:
+                s = jnp.where(keep, s, _NEG_INF)
+            m_prev = m_scr[i, :, :1]                  # [bq, 1]
+            l_prev = l_scr[i, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = _exp(m_prev - m_new)
+            p = _exp(s - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # head i in its own lanes, p @ (the other heads' v) in theirs
+            pvs.append(jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            alphas.append(alpha)
+            m_scr[i] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[i] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+        acc_scr[:] = acc_scr[:] * _by_head(alphas, acc_scr.shape, dh) \
+            + _by_head(pvs, acc_scr.shape, dh)
 
     if causal:
         last_kb = jnp.minimum(n_kb - 1, ((qi + 1) * block_q - 1) // block_k)
@@ -138,83 +189,126 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(kb == last_kb)
     def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        ls = [jnp.maximum(l_scr[i, :, :1], 1e-30) for i in range(hp)]
+        o_ref[0] = (acc_scr[:] / _by_head(ls, acc_scr.shape, dh)) \
+            .astype(o_ref.dtype)
         # logsumexp row stats (NATURAL log even in exp2 mode), saved
         # for the blockwise backward and the ring-attention merge
-        m_nat = m_scr[:, :1] / _LOG2E if exp2 else m_scr[:, :1]
-        lse_ref[0] = m_nat + jnp.log(l)
+        for i in range(hp):
+            m = m_scr[i, :, :1]
+            lse_ref[0, i] = (m / _LOG2E if exp2 else m) + jnp.log(ls[i])
 
 
-def _kb_clamp(causal, block_q, block_k, n_kb):
-    """k-block index map for causal kernels: dead (fully-masked) grid
-    steps re-reference the last live block, so Pallas skips their HBM
-    DMA entirely (an index map that repeats the previous indices is a
-    no-op fetch)."""
+def _live_kb(causal, block_q, block_k, n_kb):
+    """(q-block i, step j) -> the k block that step reads. Causal: dead
+    (fully-masked) steps re-reference the last live block, so Pallas
+    skips their HBM DMA entirely (an index map that repeats the
+    previous indices is a no-op fetch)."""
     if not causal:
-        return lambda b, i, j: (b, j, 0)
-
-    def imap(b, i, j):
-        last = jnp.minimum(n_kb - 1, ((i + 1) * block_q - 1) // block_k)
-        return (b, jnp.minimum(j, last), 0)
-    return imap
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(
+        j, jnp.minimum(n_kb - 1, ((i + 1) * block_q - 1) // block_k))
 
 
-def _qi_clamp(causal, block_q, block_k):
-    """q-block index map for the dk/dv pass: steps before the diagonal
-    re-reference the first live q block (no-op DMA)."""
+def _live_qi(causal, block_q, block_k):
+    """(k-block j, step i) -> the q block that step of a kv-major sweep
+    reads: steps before the diagonal re-reference the first live q
+    block (no-op DMA)."""
     if not causal:
-        return lambda b, j, i: (b, i, 0)
-
-    def imap(b, j, i):
-        first = (j * block_k) // block_q
-        return (b, jnp.maximum(i, first), 0)
-    return imap
+        return lambda j, i: i
+    return lambda j, i: jnp.maximum(i, (j * block_k) // block_q)
 
 
-def _flash_pallas_call(q, k, v, causal, block_q, block_k, interpret):
-    """Raw Pallas forward on [BH, T, D] -> (out, lse [BH, T, 1])."""
-    BH, T, D = q.shape
+def _outer(x, y):
+    """Row-block map of the operand that stays put while the inner grid
+    dimension sweeps: the outer index."""
+    return x
+
+
+def _wide(rows, lanes, at):
+    """BlockSpec of a [rows, lanes] block of a [B, T, H*dh] array under
+    a (batch, head group, outer, inner) grid: ``at(outer, inner)`` is
+    the row block, the head group is the lane block. This is where the
+    heads are addressed — no copy splits them off."""
+    return pl.BlockSpec((1, rows, lanes),
+                        lambda b, g, x, y: (b, at(x, y), g))
+
+
+def _cols(hp, rows, at):
+    """BlockSpec of lse [B, H, T, 1], the layout the forward can write
+    without two programs sharing a block: the ``hp`` columns of a
+    program's heads, ``rows`` rows each."""
+    return pl.BlockSpec((1, hp, rows, 1),
+                        lambda b, g, x, y: (b, g, at(x, y), 0))
+
+
+# Two heads a program at 1024x1024 blocks need 16.5 MB of scoped VMEM
+# in the merged backward (compiled for v5e inside the OPT step), half a
+# megabyte over Mosaic's default limit of 16 MB; the chip has 128 MB.
+_FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=32 * 1024 * 1024)
+
+
+# The raw calls are jitted with everything but the arrays static (the
+# two trace-time flags, _USE_EXP2 and _MERGED_BWD, among them: their
+# callers read them): a model's layers share one shape, so the step
+# traces and lowers each kernel once, not once a layer. Two heads a
+# program double a kernel's trace, and the step is traced two or three
+# times before its first timed run.
+_RAW_STATICS = ('H', 'causal', 'block_q', 'block_k', 'interpret', 'exp2')
+
+
+@functools.partial(jax.jit, static_argnames=_RAW_STATICS)
+def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret,
+                       exp2):
+    """Raw Pallas forward on [B, T, H*dh] -> (out [B, T, H*dh],
+    lse [B, H, T, 1])."""
+    B, T, HD = q.shape
+    dh = HD // H
+    hp = _lane_heads(H, dh)
+    lanes = hp * dh
     n_kb = T // block_k
-    kb_map = _kb_clamp(causal, block_q, block_k, n_kb)
-    on, lse = pl.pallas_call(
+    kb_at = _live_kb(causal, block_q, block_k, n_kb)
+    return pl.pallas_call(
         functools.partial(_flash_kernel, block_q=block_q,
                           block_k=block_k, causal=causal, n_kb=n_kb,
-                          exp2=_USE_EXP2[0]),
-        grid=(BH, T // block_q, n_kb),
+                          exp2=exp2, dh=dh),
+        grid=(B, H // hp, T // block_q, n_kb),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), kb_map),
-            pl.BlockSpec((1, block_k, D), kb_map),
+            _wide(block_q, lanes, _outer),
+            _wide(block_k, lanes, kb_at),
+            _wide(block_k, lanes, kb_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            _wide(block_q, lanes, _outer),
+            _cols(hp, block_q, _outer),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, T, HD), q.dtype),
+            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max m
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum l
-            pltpu.VMEM((block_q, D), jnp.float32),     # unnormalised acc
+            pltpu.VMEM((hp, block_q, 128), jnp.float32),  # running max m
+            pltpu.VMEM((hp, block_q, 128), jnp.float32),  # running sum l
+            pltpu.VMEM((block_q, lanes), jnp.float32),    # unnormalised acc
         ],
         name='_flash_kernel',
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v)
-    return on, lse
 
 
 def _bwd_p_ds(q, k, v, do, lse, delta, qi, kb, block_q, block_k, causal,
-              exp2):
-    """Shared backward recompute: normalised probs ``p`` and the score
-    cotangent ``ds = p * (dp - delta)`` for one (q-block, k-block) tile,
-    plus the softmax ``scale``. The ONE copy of the score/mask/prob
-    math used by all three backward kernels (two-pass dq, two-pass
-    dk/dv, merged) — they are selected at runtime, so their tile math
-    must never diverge."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+              exp2, dh):
+    """Shared backward recompute for ONE head: normalised probs ``p``
+    and the score cotangent ``ds = p * (dp - delta)`` for one (q-block,
+    k-block) tile, plus the softmax ``scale``. ``q`` and ``do`` arrive
+    with the other heads' lanes zeroed (_only_head), so the dots over
+    all the lanes of the whole k and v blocks are this head's. The ONE
+    copy of the score/mask/prob math used by all three backward kernels
+    (two-pass dq, two-pass dk/dv, merged) — they are selected at
+    runtime, so their tile math must never diverge."""
+    scale = 1.0 / math.sqrt(dh)
     _exp = jnp.exp2 if exp2 else jnp.exp
     sscale = scale * _LOG2E if exp2 else scale
     s = jax.lax.dot_general(
@@ -234,12 +328,60 @@ def _bwd_p_ds(q, k, v, do, lse, delta, qi, kb, block_q, block_k, causal,
     return p, ds, scale
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dq_scr, *, block_q, block_k, causal, n_kb,
-                     exp2):
-    """dq pass: one (bh, q-block, k-block) step; dq accumulates in VMEM."""
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
+def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, causal, exp2, dh,
+              dk_scr=None, dv_scr=None, want_dq=True):
+    """One (q-block, k-block) tile of the backward for the ``hp`` heads
+    of head group ``g``, one head after the other: dk and dv of the
+    tile are added to ``dk_scr``/``dv_scr`` where given, and the tile's
+    dq contribution [block_q, hp*dh] is returned where wanted. dv and
+    dk contract the zeroed q and dO, so each head's product is 0 in the
+    other heads' lanes and the heads add; dq's product with the whole k
+    block is lane-selected. delta = rowsum(dO * O) - g_lse is made here
+    from the dO and O blocks the tile holds anyway: a [block_q, hp*dh]
+    product where a score tile is [block_q, block_k]."""
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, glse_ref = in_refs
+    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+    hp = lse_ref.shape[1]
+    do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    g_lse = glse_ref[0]                           # [block_q, H]
+    head = jax.lax.broadcasted_iota(jnp.int32, g_lse.shape, 1)
+    dqs = []
+    for i in range(hp):
+        q_i = _only_head(q, i, dh)
+        do_i = _only_head(do, i, dh)
+        # this head's column of g_lse by a select and a lane sum: a
+        # lane cannot be sliced at an offset the grid decides
+        delta = jnp.sum(_only_head(do_o, i, dh), axis=-1, keepdims=True) \
+            - jnp.sum(jnp.where(head == g * hp + i, g_lse, 0.0),
+                      axis=-1, keepdims=True)
+        p, ds, scale = _bwd_p_ds(q_i, k, v, do_i, lse_ref[0, i], delta,
+                                 qi, kb, block_q, block_k, causal, exp2,
+                                 dh)
+        ds_lp = ds.astype(q.dtype)
+        if dv_scr is not None:
+            # p^T @ do and ds^T @ q via dim-0 contractions (no
+            # transposes)
+            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+                p.astype(do.dtype), do_i, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+                ds_lp, q_i, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+        if want_dq:
+            dqs.append(jax.lax.dot_general(
+                ds_lp, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale)
+    return _by_head(dqs, q.shape, dh) if want_dq else None
+
+
+def _flash_dq_kernel(*refs, block_q, block_k, causal, n_kb, exp2, dh):
+    """dq pass of the two-pass fallback: one (batch, head group,
+    q-block, k-block) step; dq accumulates in VMEM. ``refs``: the seven
+    inputs of _bwd_tile, dq_ref, dq_scr."""
+    in_refs, (dq_ref, dq_scr) = refs[:7], refs[7:]
+    g = pl.program_id(1)
+    qi = pl.program_id(2)
+    kb = pl.program_id(3)
 
     @pl.when(kb == 0)
     def _init():
@@ -249,27 +391,35 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _compute():
-        k = k_ref[0]
-        _, ds, scale = _bwd_p_ds(q_ref[0], k, v_ref[0], do_ref[0],
-                                 lse_ref[0], delta_ref[0], qi, kb,
-                                 block_q, block_k, causal, exp2)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dq_scr[:] = dq_scr[:] + _bwd_tile(
+            in_refs, g, qi, kb, block_q, block_k, causal, exp2, dh)
 
     @pl.when(kb == n_kb - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_scr, dv_scr, *,
-                      block_q, block_k, causal, n_qb, exp2):
-    """dk/dv pass: one (bh, k-block, q-block) step; q blocks stream
-    innermost, dk/dv accumulate in VMEM. All math stays q-major so no
-    in-kernel transposes are needed (dot_general contracts dim 0)."""
-    kb = pl.program_id(1)
-    qi = pl.program_id(2)
+def _flash_dkvdq_kernel(*refs, block_q, block_k, causal, n_qb, exp2, dh):
+    """One (batch, head group, k-block, q-block) step of a kv-major
+    sweep: q blocks stream innermost, dk/dv accumulate in VMEM. All
+    math stays q-major so no in-kernel transposes are needed
+    (dot_general contracts dim 0). ``refs``: the seven inputs of
+    _bwd_tile, dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr.
+
+    Merged backward: the ONE sweep also writes the dq contribution of
+    this k block to a per-(kb) partial slab [n_kb, B, T, H*dh] that XLA
+    sums afterwards. Saves the dq pass's full score/prob recomputation
+    — one of the two exp sweeps and two of the seven backward T^2 dots
+    — at the cost of the slab (bf16 for bf16 inputs, f32 otherwise —
+    see _slab_dtype), so the caller only routes here while the slab is
+    affordable. Race-free by construction: every grid step owns its dqp
+    block exclusively (no output revisiting, which Pallas leaves
+    undefined across non-consecutive steps). With ``dqp_ref`` None it
+    is the dk/dv pass of the two-pass fallback (_flash_dkv_kernel)."""
+    in_refs, (dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr) = refs[:7], refs[7:]
+    g = pl.program_id(1)
+    kb = pl.program_id(2)
+    qi = pl.program_id(3)
 
     @pl.when(qi == 0)
     def _init():
@@ -278,53 +428,12 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     live = ((qi + 1) * block_q - 1 >= kb * block_k) if causal else (qi >= 0)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
-        do = do_ref[0]
-        p, ds, scale = _bwd_p_ds(q, k_ref[0], v_ref[0], do, lse_ref[0],
-                                 delta_ref[0], qi, kb, block_q, block_k,
-                                 causal, exp2)
-        # p^T @ do and ds^T @ q via dim-0 contractions (no transposes)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    @pl.when(qi == n_qb - 1)
-    def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _flash_dkvdq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr, *,
-                        block_q, block_k, causal, n_qb, exp2):
-    """Merged backward: ONE kv-major sweep computes dk/dv (VMEM
-    accumulators, as in _flash_dkv_kernel) AND the dq contribution of
-    this k block, written to a per-(kb) partial slab that XLA sums
-    afterwards. Saves the dq pass's full score/prob recomputation — one
-    of the two exp sweeps and two of the seven backward T^2 dots — at
-    the cost of a [n_kb, T, D] partial slab (bf16 for bf16 inputs, f32
-    otherwise — see _slab_dtype), so the caller only routes here while
-    the slab is affordable. Race-free by construction: every grid
-    step owns its dqp block exclusively (no output revisiting, which
-    Pallas leaves undefined across non-consecutive steps)."""
-    kb = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    live = ((qi + 1) * block_q - 1 >= kb * block_k) if causal else (qi >= 0)
-
-    # dead diagonal blocks still own a dqp slab slot — zero it so the
-    # XLA sum sees defined content (writes cast to the slab dtype)
-    dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
+    if dqp_ref is not None:
+        # dead diagonal blocks still own a dqp slab slot — zero it so
+        # the XLA sum sees defined content
+        @pl.when(jnp.logical_not(live))
+        def _dead():
+            dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
 
     # NB: a diagonal-only masking variant (skip iota/where on blocks
     # strictly below the diagonal) measured 0.99-1.00x at T=2048-8192 —
@@ -332,30 +441,24 @@ def _flash_dkvdq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # always-mask path stays (PERF.md r5b)
     @pl.when(live)
     def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        do = do_ref[0]
-        p, ds, scale = _bwd_p_ds(q, k, v_ref[0], do, lse_ref[0],
-                                 delta_ref[0], qi, kb, block_q, block_k,
-                                 causal, exp2)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds_lp = ds.astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds_lp, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        # this k block's dq contribution (the dq pass's third dot,
-        # without re-deriving s/p)
-        dqp_ref[0, 0] = (jax.lax.dot_general(
-            ds_lp, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale) \
-            .astype(dqp_ref.dtype)
+        dq = _bwd_tile(in_refs, g, qi, kb, block_q, block_k, causal,
+                       exp2, dh, dk_scr, dv_scr,
+                       want_dq=dqp_ref is not None)
+        if dqp_ref is not None:
+            # this k block's dq contribution (the dq pass's third dot,
+            # without re-deriving s/p)
+            dqp_ref[0, 0] = dq.astype(dqp_ref.dtype)
 
     @pl.when(qi == n_qb - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _flash_dkv_kernel(*refs, **kw):
+    """dk/dv pass of the two-pass fallback: the kv-major sweep without
+    the dq slab."""
+    _flash_dkvdq_kernel(*refs[:-2], None, *refs[-2:], **kw)
 
 
 # merged-backward routing: ON, but only while the dq-partials slab
@@ -377,132 +480,92 @@ def _slab_dtype(q_dtype):
     return jnp.bfloat16 if q_dtype == jnp.bfloat16 else jnp.float32
 
 
-def _flash_bwd_merged(q, k, v, do, lse, delta, causal, block_q, block_k,
-                      interpret):
-    """One-sweep dk/dv/dq-partials call; returns (dq, dk, dv).
+@functools.partial(jax.jit, static_argnames=_RAW_STATICS + ('merged',))
+def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
+                      block_k, interpret, exp2, merged):
+    """Blockwise backward on [B, T, H*dh] operands (lse [B, H, T, 1] as
+    the forward wrote it): O(T) memory, never materialises the [T, T]
+    score matrix (ADVICE r1: the old backward recomputed full attention
+    through XLA). Returns (dq, dk, dv) in the operands' layout.
 
-    Slab dtype from _slab_dtype (bf16 inputs -> bf16 slab, 1.05x
-    measured; otherwise exact f32)."""
-    BH, T, D = q.shape
+    g_lse [B, H, T]: cotangent of the logsumexp output. The chain rule
+    folds it straight into the delta term — ds = p*(dp - delta + g_lse)
+    — because dlse/ds_ij = p_ij; dv is unaffected. It goes in as
+    [B, T, H], B*H*T float32 being the one array here that changes its
+    order: a program takes all H columns of its rows and picks its
+    own. ``merged``: the one-sweep backward, while its slab is
+    affordable."""
+    B, T, HD = q.shape
+    dh = HD // H
+    hp = _lane_heads(H, dh)
+    lanes = hp * dh
     n_qb = T // block_q
     n_kb = T // block_k
+    operands = (q, k, v, do, o, lse,
+                g_lse.astype(jnp.float32).transpose(0, 2, 1))
+    kernel_args = dict(block_q=block_q, block_k=block_k, causal=causal,
+                       exp2=exp2, dh=dh)
+
+    def in_specs(q_at, k_at):
+        heads = pl.BlockSpec((1, block_q, H),
+                             lambda b, g, x, y: (b, q_at(x, y), 0))
+        return [_wide(block_q, lanes, q_at), _wide(block_k, lanes, k_at),
+                _wide(block_k, lanes, k_at), _wide(block_q, lanes, q_at),
+                _wide(block_q, lanes, q_at), _cols(hp, block_q, q_at),
+                heads]
+
+    kv_major = dict(
+        grid=(B, H // hp, n_kb, n_qb),
+        in_specs=in_specs(_live_qi(causal, block_q, block_k), _outer),
+        scratch_shapes=[pltpu.VMEM((block_k, lanes), jnp.float32),
+                        pltpu.VMEM((block_k, lanes), jnp.float32)],
+        compiler_params=_FLASH_COMPILER_PARAMS,
+        interpret=interpret)
+    dkv_specs = [_wide(block_k, lanes, _outer),
+                 _wide(block_k, lanes, _outer)]
+    dkv_shapes = [jax.ShapeDtypeStruct((B, T, HD), k.dtype),
+                  jax.ShapeDtypeStruct((B, T, HD), v.dtype)]
     slab_dtype = _slab_dtype(q.dtype)
-    qi_map = _qi_clamp(causal, block_q, block_k)
-    dk, dv, dqp = pl.pallas_call(
-        functools.partial(_flash_dkvdq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, n_qb=n_qb,
-                          exp2=_USE_EXP2[0]),
-        grid=(BH, n_kb, n_qb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), qi_map),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D), qi_map),
-            pl.BlockSpec((1, block_q, 1), qi_map),
-            pl.BlockSpec((1, block_q, 1), qi_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, j, i: (b, j, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, T, D), v.dtype),
-            jax.ShapeDtypeStruct((BH, n_kb, T, D), slab_dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        name='_flash_dkvdq_kernel',
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    dq = jnp.sum(dqp.astype(jnp.float32), axis=1).astype(q.dtype)
-    return dq, dk, dv
-
-
-def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q, block_k,
-                      interpret, g_lse=None):
-    """Blockwise backward on [BH, T, D] operands: O(T) memory, never
-    materialises the [T, T] score matrix (ADVICE r1: the old backward
-    recomputed full attention through XLA).
-
-    g_lse (optional [BH, T, 1]): cotangent of the logsumexp output. The
-    chain rule folds it straight into the delta term — ds = p*(dp -
-    delta + g_lse) — because dlse/ds_ij = p_ij; dv is unaffected."""
-    BH, T, D = q.shape
-    n_qb = T // block_q
-    n_kb = T // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)       # [BH, T, 1]
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32)
-    slab_bytes = BH * n_kb * T * D * jnp.dtype(_slab_dtype(q.dtype)).itemsize
-    if _MERGED_BWD[0] and slab_bytes <= _MERGED_BWD_MAX_SLAB_BYTES:
-        return _flash_bwd_merged(q, k, v, do, lse, delta, causal,
-                                 block_q, block_k, interpret)
-    kb_map = _kb_clamp(causal, block_q, block_k, n_kb)
+    slab_bytes = n_kb * B * T * HD * jnp.dtype(slab_dtype).itemsize
+    if merged and slab_bytes <= _MERGED_BWD_MAX_SLAB_BYTES:
+        dk, dv, dqp = pl.pallas_call(
+            functools.partial(_flash_dkvdq_kernel, n_qb=n_qb,
+                              **kernel_args),
+            out_specs=dkv_specs + [pl.BlockSpec(
+                (1, 1, block_q, lanes),
+                lambda b, g, j, i: (j, b, i, g))],
+            out_shape=dkv_shapes + [
+                jax.ShapeDtypeStruct((n_kb, B, T, HD), slab_dtype)],
+            name='_flash_dkvdq_kernel', **kv_major,
+        )(*operands)
+        dq = jnp.sum(dqp.astype(jnp.float32), axis=0).astype(q.dtype)
+        return dq, dk, dv
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, n_kb=n_kb,
-                          exp2=_USE_EXP2[0]),
-        grid=(BH, n_qb, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), kb_map),
-            pl.BlockSpec((1, block_k, D), kb_map),
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        functools.partial(_flash_dq_kernel, n_kb=n_kb, **kernel_args),
+        grid=(B, H // hp, n_qb, n_kb),
+        in_specs=in_specs(_outer, _live_kb(causal, block_q, block_k, n_kb)),
+        out_specs=_wide(block_q, lanes, _outer),
+        out_shape=jax.ShapeDtypeStruct((B, T, HD), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, lanes), jnp.float32)],
         name='_flash_dq_kernel',
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    qi_map = _qi_clamp(causal, block_q, block_k)
+    )(*operands)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, n_qb=n_qb,
-                          exp2=_USE_EXP2[0]),
-        grid=(BH, n_kb, n_qb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), qi_map),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D), qi_map),
-            pl.BlockSpec((1, block_q, 1), qi_map),
-            pl.BlockSpec((1, block_q, 1), qi_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, T, D), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        name='_flash_dkv_kernel',
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+        functools.partial(_flash_dkv_kernel, n_qb=n_qb, **kernel_args),
+        out_specs=dkv_specs, out_shape=dkv_shapes,
+        name='_flash_dkv_kernel', **kv_major,
+    )(*operands)
     return dq, dk, dv
-
-
-def _to_bh(x):
-    B, T, H, D = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
-
-
-def _from_bh(x, B, H):
-    BH, T, D = x.shape
-    return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
+    """The engaged path on q, k, v [B, T, H, D] -> (out [B, T, H, D],
+    lse [B, H, T]). The kernels read and write [B, T, H*D] — what a
+    reshape of the projections' output is, and of the output
+    projection's input — so nothing is transposed or copied on the way
+    in or out, and the residuals are q, k, v and out themselves."""
     (out, lse), _ = _flash_lse_fwd(q, k, v, causal, block_q, block_k,
                                    interpret)
     return out, lse
@@ -510,12 +573,13 @@ def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
 
 def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
     B, T, H, D = q.shape
-    qn, kn, vn = _to_bh(q), _to_bh(k), _to_bh(v)
-    on, lse = _flash_pallas_call(qn, kn, vn, causal, block_q, block_k,
-                                 interpret)
-    lse_bht = lse[..., 0].reshape(B, H, T)
-    return ((_from_bh(on, B, H), lse_bht),
-            (qn, kn, vn, on, lse, B, H))
+    flat = (B, T, H * D)
+    out, lse = _flash_pallas_call(
+        q.reshape(flat), k.reshape(flat), v.reshape(flat), H=H,
+        causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, exp2=_USE_EXP2[0])
+    out = out.reshape(q.shape)
+    return (out, lse[..., 0]), (q, k, v, out, lse)
 
 
 def _flash_lse_bwd(causal, block_q, block_k, interpret, res, g):
@@ -524,15 +588,15 @@ def _flash_lse_bwd(causal, block_q, block_k, interpret, res, g):
     # cotangent (nonzero when ring attention merges partial blocks)
     # folds into the delta term.
     g_out, g_lse = g
-    qn, kn, vn, on, lse, B, H = res
-    BH, T, _ = qn.shape
-    g_lse_n = None
-    if g_lse is not None:
-        g_lse_n = jnp.asarray(g_lse).reshape(BH, T, 1)
-    dq, dk, dv = _flash_bwd_pallas(qn, kn, vn, on, lse, _to_bh(g_out),
-                                   causal, block_q, block_k, interpret,
-                                   g_lse=g_lse_n)
-    return (_from_bh(dq, B, H), _from_bh(dk, B, H), _from_bh(dv, B, H))
+    q, k, v, out, lse = res
+    B, T, H, D = q.shape
+    flat = (B, T, H * D)
+    grads = _flash_bwd_pallas(
+        q.reshape(flat), k.reshape(flat), v.reshape(flat),
+        out.reshape(flat), lse, g_out.reshape(flat), g_lse, H=H,
+        causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, exp2=_USE_EXP2[0], merged=_MERGED_BWD[0])
+    return tuple(x.reshape(q.shape) for x in grads)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -553,10 +617,11 @@ def _pick_block(T, target):
 # not T alone (VERDICT r4 weak #4: B=8/T=512 measured 1.10x but the old
 # T>=768 rule skipped it, while engaging thin B=1 long-T shapes the
 # sweep never covered). r4/r5 sweep on v5e (fwd+bwd, D=64, forced
-# engagement): B*H*T = 32Ki -> 1.00x (B4 H16 T512, dead even);
-# 64Ki -> 1.10x (B8 T512) / 1.19x (B4 T1024); 128Ki -> 1.62x;
-# 256Ki -> 2.49x. Engage strictly above the measured break-even:
-# B*H*T >= 64Ki, with T >= 512 so blocks stay MXU-sized.
+# engagement, the one-head-a-program kernels of that round): B*H*T =
+# 32Ki -> 1.00x (B4 H16 T512, dead even); 64Ki -> 1.10x (B8 T512) /
+# 1.19x (B4 T1024); 128Ki -> 1.62x; 256Ki -> 2.49x. Engage strictly
+# above the measured break-even: B*H*T >= 64Ki, with T >= 512 so blocks
+# stay MXU-sized.
 _FLASH_MIN_T = 512
 _FLASH_MIN_ROWS = 64 * 1024  # B*H*T break-even (measured, v5e)
 
@@ -566,11 +631,15 @@ def flash_attention(q, k, v, causal=True, block_q=None, block_k=None,
     """Blockwise attention. q,k,v: [B, T, H, D] -> [B, T, H, D].
 
     Forward and backward both run as Pallas kernels on TPU (or under
-    ``interpret=True``): the forward saves per-row logsumexp and the
-    backward streams k/v (dq pass) and q (dk/dv pass) blocks, so memory
-    stays O(T) end to end. Off-TPU, for short sequences where XLA wins,
-    or for non-128-aligned shapes, the identical-math XLA reference runs
-    instead.
+    ``interpret=True``) on the arrays as they are, read as [B, T, H*D]:
+    a program takes the 128 lanes of two heads at D = 64 (one head at a
+    multiple of 128), so no head is split off by a transpose. The
+    forward saves per-row logsumexp and the backward streams q blocks
+    past each k/v block in one merged sweep (dk, dv and the dq
+    partials), so memory stays O(T) end to end. Off-TPU, for short
+    sequences where XLA wins, for non-128-aligned T or a head shape the
+    lane addressing cannot take (odd H at D = 64, any other D), the
+    identical-math XLA reference runs instead.
     """
     return flash_attention_with_lse(q, k, v, causal, block_q, block_k,
                                     interpret, force)[0]
@@ -581,11 +650,14 @@ def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None):
     the Pallas kernels run with, or None where the XLA reference runs
     instead. flash_attention_with_lse routes by it; the flash_attention
     op (ops/misc_ops.py) labels its lowering counter with it."""
-    B, T, H = q.shape[0], q.shape[1], q.shape[2]
-    # dtype-aware default blocks (r5 full-backward sweep, PERF.md):
-    # bf16 1024x1024 won the swept set by ~5%. f32 keeps 512/1024 as
-    # swept in r4, when f32 1024x1024 overflowed scoped VMEM; this
-    # Mosaic compiles it (PERF.md, PR 21) and nobody has timed it.
+    B, T, H, D = q.shape
+    # dtype-aware default blocks. bf16: 1024x1024 won the sweep of the
+    # [B, T, H*dh] kernels on v5e at B2 H32 T2048 dh64, causal, forward
+    # and merged backward (PERF.md, PR 27: 2.40 ms against 2.55 at
+    # 512x1024, 2.96 at 512x512, 3.46 at 256x512), as it had at dh128
+    # in r5. f32 keeps 512/1024 as swept in r4; f32 1024x1024 compiles
+    # under the VMEM limit above and nobody has timed it (no cell runs
+    # f32).
     if block_q is None:
         block_q = 1024 if q.dtype == jnp.bfloat16 else 512
     if block_k is None:
@@ -599,7 +671,8 @@ def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None):
         use_pallas = force
     bq = _pick_block(T, block_q)
     bk = _pick_block(T, block_k)
-    if not use_pallas or bq is None or bk is None:
+    if not use_pallas or bq is None or bk is None \
+            or _lane_heads(H, D) is None:
         return None
     return bq, bk
 

@@ -10,23 +10,33 @@ import jax.numpy as jnp
 from paddle_tpu.ops import pallas_kernels as pk
 
 
+# (H, D, block_q, block_k): two heads to a 128-lane block at D = 64 (one
+# and two head groups), one head a block at D = 128 and 256, and
+# block_q != block_k either way round
+_HEAD_CASES = [(2, 64, 128, 128), (4, 64, 128, 256), (4, 64, 256, 128),
+               (1, 128, 128, 256), (2, 128, 256, 128), (1, 256, 128, 128)]
+_HEAD_IDS = ['h%d-d%d-%dx%d' % c for c in _HEAD_CASES]
+
+
+@pytest.mark.parametrize('H,D,bq,bk', _HEAD_CASES, ids=_HEAD_IDS)
 @pytest.mark.parametrize('causal', [True, False])
-def test_flash_attention_matches_reference(causal):
+def test_flash_attention_matches_reference(causal, H, D, bq, bk):
     rng = np.random.RandomState(0)
-    B, T, H, D = 2, 256, 2, 64
+    B, T = 2, 256
     q = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
     k = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
     v = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
+    assert pk.flash_plan(q, bq, bk, interpret=True) == (bq, bk)
     ref = pk.attention_reference(q, k, v, causal=causal)
-    out = pk.flash_attention(q, k, v, causal=causal, block_q=128,
-                             block_k=128, interpret=True)
+    out = pk.flash_attention(q, k, v, causal=causal, block_q=bq,
+                             block_k=bk, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
 
 
 def test_flash_attention_causality():
     rng = np.random.RandomState(1)
-    B, T, H, D = 1, 256, 1, 64
+    B, T, H, D = 1, 256, 2, 64
     q = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
     k = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
     v = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
@@ -125,6 +135,13 @@ def test_pallas_path_engages_for_transformer_shapes(monkeypatch):
     q2 = jnp.asarray(rng.randn(B, 100, H, D).astype('float32'))
     pk.flash_attention(q2, q2, q2, causal=True, interpret=True)
     assert not fired
+    # so does a head shape whose lanes do not fill 128-lane blocks: an
+    # odd number of heads at D = 64, any D but 64 and multiples of 128
+    for shape in ((B, T, 3, 64), (B, T, 8, 32), (B, T, 2, 192)):
+        q3 = jnp.asarray(rng.randn(*shape).astype('float32'))
+        assert pk.flash_plan(q3, interpret=True) is None
+        pk.flash_attention(q3, q3, q3, causal=True, interpret=True)
+        assert not fired
 
 
 def test_flash_attention_bf16_grads_finite():
@@ -194,46 +211,45 @@ def test_fused_lstm_engages_in_scan_with_grads(monkeypatch):
     np.testing.assert_allclose(fused, baseline, rtol=1e-4, atol=1e-5)
 
 
-def test_flash_with_lse_matches_reference_including_lse_grads():
+@pytest.mark.parametrize('H,D,bq,bk', _HEAD_CASES, ids=_HEAD_IDS)
+@pytest.mark.parametrize('causal', [True, False])
+def test_flash_with_lse_matches_reference_including_lse_grads(
+        causal, H, D, bq, bk):
     """flash_attention_with_lse: out AND lse match, and gradients flow
     correctly through BOTH outputs (the lse cotangent folds into the
     backward's delta term — the ring-attention merge depends on it)."""
     import jax
     rng = np.random.RandomState(7)
-    B, T, H, D = 2, 256, 2, 64
+    B, T = 2, 256
     q = jnp.asarray(rng.randn(B, T, H, D) * 0.5, jnp.float32)
     k = jnp.asarray(rng.randn(B, T, H, D) * 0.5, jnp.float32)
     v = jnp.asarray(rng.randn(B, T, H, D) * 0.5, jnp.float32)
     go = jnp.asarray(rng.randn(B, T, H, D) * 0.1, jnp.float32)
     gl = jnp.asarray(rng.randn(B, H, T) * 0.1, jnp.float32)
 
-    for causal in (True, False):
-        op, lp = pk.flash_attention_with_lse(
-            q, k, v, causal=causal, block_q=128, block_k=128,
+    op, lp = pk.flash_attention_with_lse(
+        q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True)
+    orf, lrf = pk.attention_reference_with_lse(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(op), np.asarray(orf),
+                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lp), np.asarray(lrf),
+                               rtol=2e-4, atol=2e-5)
+
+    def loss_p(q, k, v):
+        o, l = pk.flash_attention_with_lse(
+            q, k, v, causal=causal, block_q=bq, block_k=bk,
             interpret=True)
-        orf, lrf = pk.attention_reference_with_lse(q, k, v,
-                                                   causal=causal)
-        np.testing.assert_allclose(np.asarray(op), np.asarray(orf),
-                                   rtol=2e-4, atol=2e-5)
-        np.testing.assert_allclose(np.asarray(lp), np.asarray(lrf),
-                                   rtol=2e-4, atol=2e-5)
+        return jnp.sum(o * go) + jnp.sum(l * gl)
 
-        def loss_p(q, k, v):
-            o, l = pk.flash_attention_with_lse(
-                q, k, v, causal=causal, block_q=128, block_k=128,
-                interpret=True)
-            return jnp.sum(o * go) + jnp.sum(l * gl)
+    def loss_r(q, k, v):
+        o, l = pk.attention_reference_with_lse(q, k, v, causal=causal)
+        return jnp.sum(o * go) + jnp.sum(l * gl)
 
-        def loss_r(q, k, v):
-            o, l = pk.attention_reference_with_lse(q, k, v,
-                                                   causal=causal)
-            return jnp.sum(o * go) + jnp.sum(l * gl)
-
-        gp = jax.grad(loss_p, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gp, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
+    gp = jax.grad(loss_p, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
 
 
 def test_ring_attention_uses_flash_kernel(monkeypatch):
@@ -319,16 +335,17 @@ def test_flash_attention_layer_scaling():
                                atol=2e-5)
 
 
+@pytest.mark.parametrize('H,D', [(2, 64), (4, 64), (1, 128)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_merged_backward_matches_two_pass(causal):
+def test_merged_backward_matches_two_pass(causal, H, D):
     """The merged dkv+dq-partials backward must produce the same grads
     as the two-pass path (it is the default under the slab cap)."""
     import jax
     import jax.numpy as jnp
     rng = np.random.RandomState(7)
-    q = jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32) * 0.1
-    k = jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32) * 0.1
-    v = jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32) * 0.1
+    q = jnp.asarray(rng.randn(1, 256, H, D), jnp.float32) * 0.1
+    k = jnp.asarray(rng.randn(1, 256, H, D), jnp.float32) * 0.1
+    v = jnp.asarray(rng.randn(1, 256, H, D), jnp.float32) * 0.1
 
     def grads(merged):
         old = pk._MERGED_BWD[0]
@@ -339,7 +356,7 @@ def test_merged_backward_matches_two_pass(causal):
             def loss(q, k, v):
                 o = pk.flash_attention(q, k, v, causal=causal,
                                        force=True, block_q=128,
-                                       block_k=128, interpret=True)
+                                       block_k=256, interpret=True)
                 return jnp.sum(o * 1e-2)
 
             return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
@@ -381,30 +398,31 @@ def engage(monkeypatch):
 _FLASH_OP_B, _FLASH_OP_H, _FLASH_OP_DH = 2, 4, 64
 
 
-def _flash_op_feed(T, seed=11):
+def _flash_op_feed(T, seed=11, heads=_FLASH_OP_H):
     rng = np.random.RandomState(seed)
-    shape = (_FLASH_OP_B, T, _FLASH_OP_H * _FLASH_OP_DH)
+    shape = (_FLASH_OP_B, T, heads * _FLASH_OP_DH)
     return {n: rng.randn(*shape).astype('float32') * s
             for n, s in (('q', 1.0), ('k', 1.0), ('v', 1.0), ('w', 0.1))}
 
 
-def _flash_op_program(T, depth=1, grads=True):
-    """``depth`` flash_attention ops in a row on fed q, k, v (B2 H4
-    D64) and, with ``grads``, the gradients of sum(out * w) in q, k, v
-    (fluid.gradients replays the op path under jax.vjp: a second trace
-    of each op). Returns (main, startup, [out, dq, dk, dv])."""
+def _flash_op_program(T, depth=1, grads=True, heads=_FLASH_OP_H):
+    """``depth`` flash_attention ops in a row on fed q, k, v (B2 D64,
+    H4 unless ``heads`` says otherwise) and, with ``grads``, the
+    gradients of sum(out * w) in q, k, v (fluid.gradients replays the
+    op path under jax.vjp: a second trace of each op). Returns (main,
+    startup, [out, dq, dk, dv])."""
     import paddle_tpu.fluid as fluid
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         q, k, v, w = [
-            fluid.layers.data(name=n, shape=[T, _FLASH_OP_H * _FLASH_OP_DH],
+            fluid.layers.data(name=n, shape=[T, heads * _FLASH_OP_DH],
                               dtype='float32') for n in 'qkvw']
         for x in (q, k, v):
             x.stop_gradient = False
         out = q
         for _ in range(depth):
             out = fluid.layers.flash_attention(out, k, v,
-                                               num_heads=_FLASH_OP_H)
+                                               num_heads=heads)
         fetch = [out]
         if grads:
             loss = fluid.layers.reduce_sum(
@@ -517,23 +535,71 @@ def test_flash_op_amp_matches_f32_reference(route, T, blocks, amp, engage):
         assert err < 3e-2, '%s (%s): %.3g' % (name, route, err)
 
 
-@pytest.mark.parametrize('amp_on,route,T', [
-    (True, 'pallas', 512), (True, 'xla', 256),
-    (False, 'pallas', 512), (False, 'xla', 256)])
-def test_flash_counts_one_per_op_lowering(amp_on, route, T, amp, engage):
+@pytest.mark.parametrize('amp_on,route,T,heads', [
+    (True, 'pallas', 512, 4), (True, 'xla', 256, 4),
+    (False, 'pallas', 512, 4), (False, 'xla', 256, 4),
+    (True, 'xla', 512, 3), (False, 'xla', 512, 3)])
+def test_flash_counts_one_per_op_lowering(amp_on, route, T, heads, amp,
+                                          engage):
     """One lowering of a two-layer program counts two flash_attention
     lowerings under the route taken and the dtype the attention ran
-    in, and none under any other label."""
+    in, and none under any other label. An odd number of heads at
+    D = 64 cannot pair up into 128-lane blocks and takes the XLA route
+    at any length."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.compiler.passes import flash_counts
     amp.set_amp(amp_on)
-    main, startup, fetch = _flash_op_program(T, depth=2, grads=False)
+    main, startup, fetch = _flash_op_program(T, depth=2, grads=False,
+                                             heads=heads)
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
         before = flash_counts()
-        exe.lowered(main, feed=_flash_op_feed(T), fetch_list=fetch)
+        exe.lowered(main, feed=_flash_op_feed(T, heads=heads),
+                    fetch_list=fetch)
         after = flash_counts()
     moved = {key: n - before.get(key, 0) for key, n in after.items()
              if n != before.get(key, 0)}
     assert moved == {(route, 'bf16' if amp_on else 'f32'): 2}
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (pjit, custom_vjp_call, cond branches), the Pallas kernels' bodies
+    left out: what XLA is asked to do around the kernels."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == 'pallas_call':
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize('H,D', [(4, 64), (2, 128)])
+def test_flash_engaged_path_has_no_transpose(H, D):
+    """The engaged path reads q, k, v [B, T, H*D] as the projections
+    wrote them and writes out, dq, dk, dv the same way: heads are
+    addressed by the BlockSpecs. Forward: no transpose and no copy
+    anywhere. Backward: the one array that changes its order is the lse
+    cotangent, B*H*T float32; nothing of q's size does."""
+    B, T = 2, 256
+    x = jnp.zeros((B, T, H * D), jnp.float32)
+
+    def attend(q, k, v):
+        heads = (B, T, H, D)
+        return pk.flash_attention(
+            q.reshape(heads), k.reshape(heads), v.reshape(heads),
+            block_q=128, block_k=128, interpret=True).reshape(q.shape)
+
+    def moved(fn, *args):
+        eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+        names = [e.primitive.name for e in eqns]
+        return names, [e.invars[0].aval.shape for e in eqns
+                       if e.primitive.name in ('transpose', 'copy')]
+
+    names, shapes = moved(attend, x, x, x)
+    assert names.count('pallas_call') == 1 and shapes == []
+    names, shapes = moved(
+        lambda q, k, v, g: jax.vjp(attend, q, k, v)[1](g), x, x, x, x)
+    assert names.count('pallas_call') == 2      # forward, merged backward
+    assert shapes == [(B, H, T)], shapes
