@@ -849,14 +849,21 @@ def conv_fuse_counts():
                       if s['value']}}
 
 
-def flash_counts():
+def flash_counts(by=('route', 'dtype')):
     """``{(route, dtype): n}``: how the process's flash_attention op
     lowerings went so far (ops/misc_ops.py; counted per trace, as the
     conv-fuse counts are). route is 'pallas' or 'xla', dtype the
-    operand dtype the attention ran in ('bf16', 'f32')."""
-    return {(s['labels']['route'], s['labels']['dtype']): int(s['value'])
-            for s in _series('flash_attention_lowerings_total')
-            if s['value']}
+    operand dtype the attention ran in ('bf16', 'f32'). ``by`` names
+    the labels of the key, the counter summed over the others: 'diag'
+    is the body the kernels give a tile on the causal diagonal
+    (pallas_kernels.flash_diag: 'chunked<r>', 'whole', or 'none' for
+    the xla route or no mask)."""
+    counts = {}
+    for s in _series('flash_attention_lowerings_total'):
+        if s['value']:
+            key = tuple(s['labels'][name] for name in by)
+            counts[key] = counts.get(key, 0) + int(s['value'])
+    return counts
 
 
 @register_pass

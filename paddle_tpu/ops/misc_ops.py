@@ -461,9 +461,9 @@ def _flash_attention_op(ctx):
     cast to bf16 on either route, the dots accumulate f32 and the
     softmax state stays f32 inside the kernels, and the output flows
     bf16 under act_bf16(). Each lowering counts once in
-    ``flash_attention_lowerings_total{route=, dtype=}``
+    ``flash_attention_lowerings_total{route=, dtype=, diag=}``
     (compiler/passes.py::flash_counts)."""
-    from .pallas_kernels import flash_attention, flash_plan
+    from .pallas_kernels import flash_attention, flash_diag, flash_plan
     from ..core.amp import mxu_compute
     heads = int(ctx.attr('num_heads', 1))
     causal = bool(ctx.attr('causal', True))
@@ -479,14 +479,17 @@ def _flash_attention_op(ctx):
         qh = q.reshape(B, T, heads, dh)
         kh = k.reshape(B, T, heads, dh)
         vh = v.reshape(B, T, heads, dh)
+        plan = flash_plan(qh, bq, bk, causal=causal)
         _obs.default_registry().counter(
             'flash_attention_lowerings_total',
             help='flash_attention op lowerings, by the route taken '
-                 '(pallas kernels / xla reference) and the operand '
-                 'dtype the attention ran in',
-            route='xla' if flash_plan(qh, bq, bk) is None else 'pallas',
+                 '(pallas kernels / xla reference), the operand dtype '
+                 'the attention ran in and the body the kernels give '
+                 'a tile on the diagonal (chunked<r> / whole / none)',
+            route='xla' if plan is None else 'pallas',
             dtype={'bfloat16': 'bf16', 'float32': 'f32'}.get(
-                qh.dtype.name, qh.dtype.name)).inc()
+                qh.dtype.name, qh.dtype.name),
+            diag=flash_diag(plan, causal)).inc()
         # NB: flash_attention applies the 1/sqrt(dh) logit scale itself
         out = flash_attention(qh, kh, vh, causal=causal,
                               block_q=bq, block_k=bk)

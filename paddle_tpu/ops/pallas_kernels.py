@@ -88,32 +88,130 @@ def _lane_heads(H, dh):
     return None
 
 
-def _only_head(x, i, dh):
+def _head_lanes(shape, dh):
+    """For a [rows, hp*dh] block, which lanes are head i's, a mask a
+    head, made once a tile part and shared by every select of it; None
+    where the block is one head."""
+    if shape[-1] == dh:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return [(lane >= i * dh) & (lane < (i + 1) * dh)
+            for i in range(shape[-1] // dh)]
+
+
+def _only_head(x, heads, i):
     """``x`` [rows, hp*dh] with every lane outside head ``i`` of the
     program's lane block zeroed, so a dot that contracts all the lanes
     sees head ``i`` alone and one that keeps them leaves the other
     heads' lanes 0. No lane is sliced: Mosaic gets a full-width
     select."""
-    if x.shape[-1] == dh:
+    if heads is None:
         return x
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    return jnp.where(lane // dh == i, x, jnp.zeros_like(x))
+    return jax.lax.select(heads[i], x, jnp.zeros_like(x))
 
 
-def _by_head(xs, shape, dh):
+def _by_head(xs, heads, shape):
     """[rows, hp*dh] that takes head i's lanes from ``xs[i]`` (each
     [rows, hp*dh], or [rows, 1] to spread a per-row statistic over its
     head's lanes)."""
     out = jnp.broadcast_to(xs[-1], shape)
-    if len(xs) > 1:
-        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        for i in range(len(xs) - 2, -1, -1):
-            out = jnp.where(lane // dh == i, xs[i], out)
+    for i in range(len(xs) - 2, -1, -1):
+        out = jax.lax.select(heads[i], jnp.broadcast_to(xs[i], shape), out)
     return out
 
 
+def _row_chunks(block_q, block_k):
+    """Row chunks ``r`` a causal tile of square blocks that straddles
+    the diagonal (at offset 0 there, a static shape) is cut into: of
+    512 rows each, or of the largest 128-row multiple below that which
+    divides the block. Chunk j reads only the (j + 1) * block_q / r
+    columns at or left of its own diagonal, so the tile issues
+    (r + 1) / 2r of a whole tile's dots, exp2s, casts and sums for one
+    update of each row's state, as a whole tile makes; and no score
+    value is larger than [512, block_k], which is what lets a 2048-row
+    block into VMEM. Measured on v5e, bf16 B2 H32 T2048 dh64, ms a
+    layer, forward / merged backward (PERF.md section 6, PR 28):
+    1024x1024 whole 0.90 / 1.50, r 2 0.90 / 1.26, r 4 0.92 / 1.17, r 8
+    0.88 / 1.21; 2048x2048 r 4 0.61 / 1.17, r 8 0.63 / 1.07, r 16
+    0.74 / 1.07. The forward wants the larger chunk (a state update of
+    every row a chunk); the backward read 9 % faster at r 8, which is
+    not taken: every chunk is a body of its own that each process
+    traces and lowers before its first step, and the backward's eight
+    bodies put warm set-up 9-11 % over the parent's (section 6). 1 is
+    the whole tile under a mask over all of it: a block that is one
+    such chunk, and block_q != block_k, whose diagonal offset is a
+    grid value."""
+    if block_q != block_k:
+        return 1
+    return block_q // math.gcd(block_q, 512)
+
+
+def _tile_parts(qi, kb, block_q, block_k, T, causal, part):
+    """Run ``part(rows, cols, diag)`` over what is live of tile (q
+    block qi, k block kb): the q rows ``rows`` (a pl.ds) against the
+    first ``cols`` columns of the k block, under the causal mask of
+    rows that start ``diag`` positions after the columns do
+    (_causal_keep; None: no mask). THE one place that tells the three
+    kinds of tile apart, for the forward and every backward kernel: a
+    tile wholly above the diagonal runs nothing (its DMA is skipped by
+    the index maps); one wholly at or below it runs whole and unmasked,
+    as every tile does without ``causal``; one that straddles it runs
+    each row chunk against the columns left of that chunk's end, a
+    shape of its own each (all the columns, masked, where the tile is
+    one chunk: _row_chunks). Where the ``T`` positions hold no tile
+    below the diagonal (one block of them, the OPT cell's) that body
+    is left out of the kernel: Mosaic unrolls what a kernel holds, run
+    or not, and every set-up lowers it."""
+    def _full():
+        part(pl.ds(0, block_q), block_k, None)
+
+    if not causal:
+        _full()
+        return
+    live = kb * block_k <= (qi + 1) * block_q - 1
+    full = (kb + 1) * block_k - 1 <= qi * block_q
+    if T - block_q >= block_k:      # a q block starts past a k block's end
+        pl.when(full)(_full)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
+    def _diagonal():
+        r = _row_chunks(block_q, block_k)
+        c = block_q // r
+        if r == 1:
+            part(pl.ds(0, block_q), block_k, qi * block_q - kb * block_k)
+            return
+        for j in range(r):
+            part(pl.ds(j * c, c), (j + 1) * c, j * c)
+
+
+def _causal_keep(shape, diag):
+    """Which scores [rows, cols] of q rows that start ``diag``
+    positions after the k columns do the causal mask keeps: row i
+    keeps column j <= i + diag. None: all of them (``diag`` None)."""
+    if diag is None:
+        return None
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return row + diag >= col
+
+
+def _masked(s, keep):
+    """Scores ``s`` with those outside ``keep`` (_causal_keep) at
+    -inf."""
+    if keep is None:
+        return s
+    return jax.lax.select(keep, s, jnp.full_like(s, _NEG_INF))
+
+
+def _dot(x, y, dims):
+    """x @ y contracting ``dims`` = (dim of x, dim of y), at the INPUT
+    precision (bf16 inputs -> full-rate MXU), accumulated in float32."""
+    return jax.lax.dot_general(x, y, ((dims[:1], dims[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, block_q, block_k, causal, n_kb, exp2, dh):
+                  acc_scr, *, block_q, block_k, T, causal, exp2, dh):
     """One (batch, head group, q-block, k-block) grid step on blocks
     [block, hp*dh] of [B, T, H*dh] arrays: the ``hp`` heads whose lanes
     fill the block (two at head size 64) are attended one after the
@@ -124,11 +222,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     blocks HBM->VMEM with automatic double-buffering while the online
     softmax state (m and l per head, acc) persists in VMEM scratch
     across steps. No dynamic_slice on values anywhere — Mosaic can't
-    lower it; all block movement is done by the BlockSpec index maps.
+    lower it; all block movement is done by the BlockSpec index maps,
+    and a diagonal tile's row chunks are static slices of the refs.
     """
     qi = pl.program_id(2)
     kb = pl.program_id(3)
     hp = m_scr.shape[0]
+    n_kb = T // block_k
 
     @pl.when(kb == 0)
     def _init():
@@ -136,51 +236,40 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal: k blocks strictly above the diagonal contribute nothing.
-    live = (kb * block_k <= (qi + 1) * block_q - 1) if causal else \
-        (kb >= 0)
-
-    @pl.when(live)
-    def _compute():
-        # dots run at the INPUT precision (bf16 inputs -> full-rate
-        # MXU) and accumulate f32 via preferred_element_type; the
+    def _part(rows, cols, diag):
+        # the dots take their operands as they come (_dot); the
         # online-softmax state stays f32 (r4 perf: the f32 upcast
         # halved MXU throughput on the AMP path)
-        q = q_ref[0]                              # [block_q, hp*dh]
-        k = k_ref[0]                              # [block_k, hp*dh]
-        v = v_ref[0]
+        q = q_ref[0, rows, :]                     # [rows, hp*dh]
+        k = k_ref[0, pl.ds(0, cols), :]           # [cols, hp*dh]
+        v = v_ref[0, pl.ds(0, cols), :]
         scale = 1.0 / math.sqrt(dh)
         _exp = jnp.exp2 if exp2 else jnp.exp
         if exp2:
             scale = scale * _LOG2E  # scores live in log2 units
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            keep = q_pos >= k_pos
+        heads = _head_lanes(q.shape, dh)
+        keep = _causal_keep((rows.size, cols), diag)
+        stat = (rows.size,) + m_scr.shape[2:]
         alphas, pvs = [], []
         for i in range(hp):
-            s = jax.lax.dot_general(
-                _only_head(q, i, dh), k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [bq, bk]
-            if causal:
-                s = jnp.where(keep, s, _NEG_INF)
-            m_prev = m_scr[i, :, :1]                  # [bq, 1]
-            l_prev = l_scr[i, :, :1]
+            s = _masked(_dot(_only_head(q, heads, i), k, (1, 1)) * scale,
+                        keep)                         # [rows, cols]
+            m_prev = m_scr[i, rows, :1]               # [rows, 1]
+            l_prev = l_scr[i, rows, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = _exp(m_prev - m_new)
             p = _exp(s - m_new)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
             # head i in its own lanes, p @ (the other heads' v) in theirs
-            pvs.append(jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            pvs.append(_dot(p.astype(v.dtype), v, (1, 0)))
             alphas.append(alpha)
-            m_scr[i] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[i] = jnp.broadcast_to(l_new, l_scr.shape[1:])
-        acc_scr[:] = acc_scr[:] * _by_head(alphas, acc_scr.shape, dh) \
-            + _by_head(pvs, acc_scr.shape, dh)
+            m_scr[i, rows, :] = jnp.broadcast_to(m_new, stat)
+            l_scr[i, rows, :] = jnp.broadcast_to(l_new, stat)
+        acc_scr[rows, :] = (
+            acc_scr[rows, :] * _by_head(alphas, heads, q.shape)
+            + _by_head(pvs, heads, q.shape))
+
+    _tile_parts(qi, kb, block_q, block_k, T, causal, _part)
 
     if causal:
         last_kb = jnp.minimum(n_kb - 1, ((qi + 1) * block_q - 1) // block_k)
@@ -190,7 +279,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     @pl.when(kb == last_kb)
     def _finalize():
         ls = [jnp.maximum(l_scr[i, :, :1], 1e-30) for i in range(hp)]
-        o_ref[0] = (acc_scr[:] / _by_head(ls, acc_scr.shape, dh)) \
+        heads = _head_lanes(acc_scr.shape, dh)
+        o_ref[0] = (acc_scr[:] / _by_head(ls, heads, acc_scr.shape)) \
             .astype(o_ref.dtype)
         # logsumexp row stats (NATURAL log even in exp2 mode), saved
         # for the blockwise backward and the ring-attention merge
@@ -242,9 +332,11 @@ def _cols(hp, rows, at):
                         lambda b, g, x, y: (b, g, at(x, y), 0))
 
 
-# Two heads a program at 1024x1024 blocks need 16.5 MB of scoped VMEM
-# in the merged backward (compiled for v5e inside the OPT step), half a
-# megabyte over Mosaic's default limit of 16 MB; the chip has 128 MB.
+# Two heads a program with a whole 1024x1024 tile at once needed 16.5 MB
+# of scoped VMEM in the merged backward (compiled for v5e inside the
+# OPT step), half a megabyte over Mosaic's default limit of 16 MB; the
+# chip has 128 MB. 2048x2048 blocks in row chunks (_row_chunks) compile
+# under the same 32 MB, a whole 2048x2048 tile would not (37 MB).
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=32 * 1024 * 1024)
 
@@ -271,8 +363,8 @@ def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret,
     kb_at = _live_kb(causal, block_q, block_k, n_kb)
     return pl.pallas_call(
         functools.partial(_flash_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, n_kb=n_kb,
-                          exp2=exp2, dh=dh),
+                          block_k=block_k, T=T, causal=causal, exp2=exp2,
+                          dh=dh),
         grid=(B, H // hp, T // block_q, n_kb),
         in_specs=[
             _wide(block_q, lanes, _outer),
@@ -298,83 +390,78 @@ def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret,
     )(q, k, v)
 
 
-def _bwd_p_ds(q, k, v, do, lse, delta, qi, kb, block_q, block_k, causal,
-              exp2, dh):
+def _bwd_p_ds(q, k, v, do, lse, delta, keep, exp2, dh):
     """Shared backward recompute for ONE head: normalised probs ``p``
-    and the score cotangent ``ds = p * (dp - delta)`` for one (q-block,
-    k-block) tile, plus the softmax ``scale``. ``q`` and ``do`` arrive
-    with the other heads' lanes zeroed (_only_head), so the dots over
-    all the lanes of the whole k and v blocks are this head's. The ONE
-    copy of the score/mask/prob math used by all three backward kernels
-    (two-pass dq, two-pass dk/dv, merged) — they are selected at
-    runtime, so their tile math must never diverge."""
+    and the score cotangent ``ds = p * (dp - delta)`` for one part of a
+    (q-block, k-block) tile (the rows of ``q`` against the columns of
+    ``k``, masked to ``keep``), plus the softmax ``scale``. ``q`` and
+    ``do`` arrive with the other heads' lanes zeroed (_only_head), so
+    the dots over all the lanes of the k and v rows are this head's.
+    The ONE copy of the score/mask/prob math used by all three backward
+    kernels (two-pass dq, two-pass dk/dv, merged) — they are selected
+    at runtime, so their tile math must never diverge."""
     scale = 1.0 / math.sqrt(dh)
     _exp = jnp.exp2 if exp2 else jnp.exp
     sscale = scale * _LOG2E if exp2 else scale
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sscale
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    p = _exp(s - (lse * _LOG2E if exp2 else lse))   # normalised probs
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [bq, bk]
+    s = _masked(_dot(q, k, (1, 1)) * sscale, keep)
+    # normalised probs
+    p = _exp(s - (lse * _LOG2E if exp2 else lse))
+    dp = _dot(do, v, (1, 1))                        # [rows, cols]
     ds = p * (dp - delta)
     return p, ds, scale
 
 
-def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, causal, exp2, dh,
-              dk_scr=None, dv_scr=None, want_dq=True):
+def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
+              put_dq=None, dk_scr=None, dv_scr=None):
     """One (q-block, k-block) tile of the backward for the ``hp`` heads
-    of head group ``g``, one head after the other: dk and dv of the
-    tile are added to ``dk_scr``/``dv_scr`` where given, and the tile's
-    dq contribution [block_q, hp*dh] is returned where wanted. dv and
-    dk contract the zeroed q and dO, so each head's product is 0 in the
-    other heads' lanes and the heads add; dq's product with the whole k
-    block is lane-selected. delta = rowsum(dO * O) - g_lse is made here
-    from the dO and O blocks the tile holds anyway: a [block_q, hp*dh]
-    product where a score tile is [block_q, block_k]."""
+    of head group ``g``, one head after the other, over the tile's live
+    parts (_tile_parts): dk and dv of a part are added to its columns'
+    rows of ``dk_scr``/``dv_scr`` where given, and its dq contribution
+    [rows, hp*dh] goes to ``put_dq(rows, dq)`` where given. dv and dk
+    contract the zeroed q and dO, so each head's product is 0 in the
+    other heads' lanes and the heads add; dq's product with the k rows
+    is lane-selected. delta = rowsum(dO * O) - g_lse is made here from
+    the dO and O rows the part holds anyway: a [rows, hp*dh] product
+    where a score tile is [rows, cols]."""
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, glse_ref = in_refs
-    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
     hp = lse_ref.shape[1]
-    do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-    g_lse = glse_ref[0]                           # [block_q, H]
-    head = jax.lax.broadcasted_iota(jnp.int32, g_lse.shape, 1)
-    dqs = []
-    for i in range(hp):
-        q_i = _only_head(q, i, dh)
-        do_i = _only_head(do, i, dh)
-        # this head's column of g_lse by a select and a lane sum: a
-        # lane cannot be sliced at an offset the grid decides
-        delta = jnp.sum(_only_head(do_o, i, dh), axis=-1, keepdims=True) \
-            - jnp.sum(jnp.where(head == g * hp + i, g_lse, 0.0),
-                      axis=-1, keepdims=True)
-        p, ds, scale = _bwd_p_ds(q_i, k, v, do_i, lse_ref[0, i], delta,
-                                 qi, kb, block_q, block_k, causal, exp2,
-                                 dh)
-        ds_lp = ds.astype(q.dtype)
-        if dv_scr is not None:
-            # p^T @ do and ds^T @ q via dim-0 contractions (no
-            # transposes)
-            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                p.astype(do.dtype), do_i, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-                ds_lp, q_i, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-        if want_dq:
-            dqs.append(jax.lax.dot_general(
-                ds_lp, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale)
-    return _by_head(dqs, q.shape, dh) if want_dq else None
+
+    def _part(rows, cols, diag):
+        kcols = pl.ds(0, cols)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        k, v = k_ref[0, kcols, :], v_ref[0, kcols, :]
+        do_o = do.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32)
+        g_lse = glse_ref[0, rows, :]                  # [rows, H]
+        head = jax.lax.broadcasted_iota(jnp.int32, g_lse.shape, 1)
+        heads = _head_lanes(q.shape, dh)
+        keep = _causal_keep((rows.size, cols), diag)
+        dqs = []
+        for i in range(hp):
+            q_i = _only_head(q, heads, i)
+            do_i = _only_head(do, heads, i)
+            # this head's column of g_lse by a select and a lane sum: a
+            # lane cannot be sliced at an offset the grid decides
+            delta = jnp.sum(_only_head(do_o, heads, i), axis=-1,
+                            keepdims=True) \
+                - jnp.sum(jnp.where(head == g * hp + i, g_lse, 0.0),
+                          axis=-1, keepdims=True)
+            p, ds, scale = _bwd_p_ds(q_i, k, v, do_i, lse_ref[0, i, rows, :],
+                                     delta, keep, exp2, dh)
+            ds_lp = ds.astype(q.dtype)
+            if dv_scr is not None:
+                # p^T @ do and ds^T @ q via dim-0 contractions (no
+                # transposes)
+                dv_scr[kcols, :] += _dot(p.astype(do.dtype), do_i, (0, 0))
+                dk_scr[kcols, :] += _dot(ds_lp, q_i, (0, 0)) * scale
+            if put_dq is not None:
+                dqs.append(_dot(ds_lp, k, (1, 0)) * scale)
+        if put_dq is not None:
+            put_dq(rows, _by_head(dqs, heads, q.shape))
+
+    _tile_parts(qi, kb, block_q, block_k, T, causal, _part)
 
 
-def _flash_dq_kernel(*refs, block_q, block_k, causal, n_kb, exp2, dh):
+def _flash_dq_kernel(*refs, block_q, block_k, T, causal, exp2, dh):
     """dq pass of the two-pass fallback: one (batch, head group,
     q-block, k-block) step; dq accumulates in VMEM. ``refs``: the seven
     inputs of _bwd_tile, dq_ref, dq_scr."""
@@ -387,19 +474,18 @@ def _flash_dq_kernel(*refs, block_q, block_k, causal, n_kb, exp2, dh):
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    live = (kb * block_k <= (qi + 1) * block_q - 1) if causal else (kb >= 0)
+    def _add_dq(rows, dq):
+        dq_scr[rows, :] += dq
 
-    @pl.when(live)
-    def _compute():
-        dq_scr[:] = dq_scr[:] + _bwd_tile(
-            in_refs, g, qi, kb, block_q, block_k, causal, exp2, dh)
+    _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
+              put_dq=_add_dq)
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(kb == T // block_k - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _flash_dkvdq_kernel(*refs, block_q, block_k, causal, n_qb, exp2, dh):
+def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, exp2, dh):
     """One (batch, head group, k-block, q-block) step of a kv-major
     sweep: q blocks stream innermost, dk/dv accumulate in VMEM. All
     math stays q-major so no in-kernel transposes are needed
@@ -426,30 +512,24 @@ def _flash_dkvdq_kernel(*refs, block_q, block_k, causal, n_qb, exp2, dh):
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    live = ((qi + 1) * block_q - 1 >= kb * block_k) if causal else (qi >= 0)
-
+    put_dq = None
     if dqp_ref is not None:
-        # dead diagonal blocks still own a dqp slab slot — zero it so
-        # the XLA sum sees defined content
-        @pl.when(jnp.logical_not(live))
-        def _dead():
-            dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
+        if causal:
+            # dead tiles still own a dqp slab slot — zero it so the XLA
+            # sum sees defined content
+            @pl.when((qi + 1) * block_q - 1 < kb * block_k)
+            def _dead():
+                dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
 
-    # NB: a diagonal-only masking variant (skip iota/where on blocks
-    # strictly below the diagonal) measured 0.99-1.00x at T=2048-8192 —
-    # the exp sweep dominates the VPU tile time, so the simple
-    # always-mask path stays (PERF.md r5b)
-    @pl.when(live)
-    def _compute():
-        dq = _bwd_tile(in_refs, g, qi, kb, block_q, block_k, causal,
-                       exp2, dh, dk_scr, dv_scr,
-                       want_dq=dqp_ref is not None)
-        if dqp_ref is not None:
+        def put_dq(rows, dq):
             # this k block's dq contribution (the dq pass's third dot,
-            # without re-deriving s/p)
-            dqp_ref[0, 0] = dq.astype(dqp_ref.dtype)
+            # without re-deriving s/p), each part to its own rows
+            dqp_ref[0, 0, rows, :] = dq.astype(dqp_ref.dtype)
 
-    @pl.when(qi == n_qb - 1)
+    _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
+              put_dq, dk_scr, dv_scr)
+
+    @pl.when(qi == T // block_q - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -503,8 +583,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
     n_kb = T // block_k
     operands = (q, k, v, do, o, lse,
                 g_lse.astype(jnp.float32).transpose(0, 2, 1))
-    kernel_args = dict(block_q=block_q, block_k=block_k, causal=causal,
-                       exp2=exp2, dh=dh)
+    kernel_args = dict(block_q=block_q, block_k=block_k, T=T,
+                       causal=causal, exp2=exp2, dh=dh)
 
     def in_specs(q_at, k_at):
         heads = pl.BlockSpec((1, block_q, H),
@@ -529,8 +609,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
     slab_bytes = n_kb * B * T * HD * jnp.dtype(slab_dtype).itemsize
     if merged and slab_bytes <= _MERGED_BWD_MAX_SLAB_BYTES:
         dk, dv, dqp = pl.pallas_call(
-            functools.partial(_flash_dkvdq_kernel, n_qb=n_qb,
-                              **kernel_args),
+            functools.partial(_flash_dkvdq_kernel, **kernel_args),
             out_specs=dkv_specs + [pl.BlockSpec(
                 (1, 1, block_q, lanes),
                 lambda b, g, j, i: (j, b, i, g))],
@@ -541,7 +620,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
         dq = jnp.sum(dqp.astype(jnp.float32), axis=0).astype(q.dtype)
         return dq, dk, dv
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, n_kb=n_kb, **kernel_args),
+        functools.partial(_flash_dq_kernel, **kernel_args),
         grid=(B, H // hp, n_qb, n_kb),
         in_specs=in_specs(_outer, _live_kb(causal, block_q, block_k, n_kb)),
         out_specs=_wide(block_q, lanes, _outer),
@@ -552,7 +631,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
         interpret=interpret,
     )(*operands)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, n_qb=n_qb, **kernel_args),
+        functools.partial(_flash_dkv_kernel, **kernel_args),
         out_specs=dkv_specs, out_shape=dkv_shapes,
         name='_flash_dkv_kernel', **kv_major,
     )(*operands)
@@ -645,23 +724,35 @@ def flash_attention(q, k, v, causal=True, block_q=None, block_k=None,
                                     interpret, force)[0]
 
 
-def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None):
+def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None,
+               causal=True):
     """THE engagement decision for q [B, T, H, D]: the (block_q, block_k)
     the Pallas kernels run with, or None where the XLA reference runs
     instead. flash_attention_with_lse routes by it; the flash_attention
-    op (ops/misc_ops.py) labels its lowering counter with it."""
+    op (ops/misc_ops.py) labels its lowering counter with it and with
+    the body its blocks give the diagonal tiles (flash_diag)."""
     B, T, H, D = q.shape
-    # dtype-aware default blocks. bf16: 1024x1024 won the sweep of the
-    # [B, T, H*dh] kernels on v5e at B2 H32 T2048 dh64, causal, forward
-    # and merged backward (PERF.md, PR 27: 2.40 ms against 2.55 at
-    # 512x1024, 2.96 at 512x512, 3.46 at 256x512), as it had at dh128
-    # in r5. f32 keeps 512/1024 as swept in r4; f32 1024x1024 compiles
-    # under the VMEM limit above and nobody has timed it (no cell runs
-    # f32).
+    # dtype-aware default blocks. bf16: 1024x1024 as swept in PR 27,
+    # and one tile a program where a causal sequence of at most 2048
+    # positions is one: it has no tile below the diagonal, and a
+    # diagonal tile goes in row chunks (_row_chunks) whose score values
+    # fit VMEM where a whole 2048x2048 tile would not. Measured on v5e,
+    # causal, forward + merged backward, ms a layer (PERF.md section 6,
+    # PR 28): at B2 H32 T2048 dh64 one 2048x2048 tile reads 1.78
+    # against 2.15 at 1024x1024 in the same 512-row chunks and 2.91 at
+    # 512x512 (2.40 / 2.96 whole, PR 27); 3.52 against 4.80 at B16 H8
+    # dh128. What a k step costs beside its scores is a sweep of
+    # per-row state work (two lane reductions a row group a head, m and
+    # l, the rescale of acc) that scales with block_q and not with the
+    # tile, so fewer, larger tiles win for as long as their dead half
+    # is not computed. f32 keeps 512/1024 as swept in r4 (no cell runs
+    # f32, nobody has timed larger).
+    bf16 = q.dtype == jnp.bfloat16
+    one_tile = causal and T <= 2048
     if block_q is None:
-        block_q = 1024 if q.dtype == jnp.bfloat16 else 512
+        block_q = (2048 if one_tile else 1024) if bf16 else 512
     if block_k is None:
-        block_k = 1024
+        block_k = (2048 if one_tile else 1024) if bf16 else 1024
     work = B * H * T
     use_pallas = interpret or (
         _on_tpu() and T >= _FLASH_MIN_T and work >= _FLASH_MIN_ROWS)
@@ -677,6 +768,18 @@ def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None):
     return bq, bk
 
 
+def flash_diag(plan, causal=True):
+    """How the kernels of a flash_plan compute the tiles that straddle
+    the diagonal: 'chunked<r>' (r row chunks, each against its live
+    columns), 'whole' (the whole tile under the mask), 'none' where no
+    tile is masked (not causal, or no plan). Follows from the blocks
+    alone, as the kernels decide it (_row_chunks)."""
+    if plan is None or not causal:
+        return 'none'
+    r = _row_chunks(*plan)
+    return 'whole' if r == 1 else 'chunked%d' % r
+
+
 def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
                              block_k=None, interpret=None, force=None):
     """flash_attention that also returns per-row logsumexp [B, H, T].
@@ -689,7 +792,7 @@ def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
     XLA reference (with lse) elsewhere."""
     if interpret is None:
         interpret = False
-    plan = flash_plan(q, block_q, block_k, interpret, force)
+    plan = flash_plan(q, block_q, block_k, interpret, force, causal)
     if plan is None:
         return attention_reference_with_lse(q, k, v, causal)
     bq, bk = plan

@@ -227,8 +227,8 @@ def test_rule_hardcoded_schedule(tmp_path):
         real, os.path.join('paddle_tpu', 'ops', 'pallas_kernels.py'))
     hits = {x.detail for x in v if x.rule == 'hardcoded-schedule'}
     assert hits == {
-        'block_q = 1024 if q.dtype == jnp.bfloat16 else 512',
-        'block_k = 1024'}
+        'block_q = (2048 if one_tile else 1024) if bf16 else 512',
+        'block_k = (2048 if one_tile else 1024) if bf16 else 1024'}
     assert all(('hardcoded-schedule:paddle_tpu/ops/pallas_kernels.py:'
                 + d) in lint_repo.ALLOWLIST for d in hits)
 

@@ -10,19 +10,30 @@ import jax.numpy as jnp
 from paddle_tpu.ops import pallas_kernels as pk
 
 
-# (H, D, block_q, block_k): two heads to a 128-lane block at D = 64 (one
-# and two head groups), one head a block at D = 128 and 256, and
-# block_q != block_k either way round
-_HEAD_CASES = [(2, 64, 128, 128), (4, 64, 128, 256), (4, 64, 256, 128),
-               (1, 128, 128, 256), (2, 128, 256, 128), (1, 256, 128, 128)]
-_HEAD_IDS = ['h%d-d%d-%dx%d' % c for c in _HEAD_CASES]
+# (H, D, block_q, block_k, T): two heads to a 128-lane block at D = 64
+# (one and two head groups), one head a block at D = 128 and 256, and
+# block_q != block_k either way round (a tile on the diagonal goes
+# whole under the mask, as a square one of 256 or 512 rows does); then
+# square blocks of 384 and 768 rows, whose tiles go in 3 row chunks of
+# 128 and 256 rows (pk._row_chunks), at T = 1x, 2x and 4x the block:
+# diagonal tiles only; diagonal and full ones; dead ones too; and one
+# of 1024 rows, in 2 chunks of 512
+_HEAD_CASES = [(2, 64, 128, 128, 256), (4, 64, 128, 256, 256),
+               (4, 64, 256, 128, 256), (1, 128, 128, 256, 256),
+               (2, 128, 256, 128, 256), (1, 256, 128, 128, 256),
+               (4, 64, 256, 256, 512), (4, 64, 384, 384, 384),
+               (4, 64, 384, 384, 768), (2, 64, 384, 384, 1536),
+               (1, 128, 384, 384, 768), (1, 128, 384, 384, 1536),
+               (2, 64, 512, 512, 1024), (1, 128, 768, 768, 1536),
+               (2, 64, 1024, 1024, 1024)]
+_HEAD_IDS = ['h%d-d%d-%dx%d-t%d' % c for c in _HEAD_CASES]
 
 
-@pytest.mark.parametrize('H,D,bq,bk', _HEAD_CASES, ids=_HEAD_IDS)
+@pytest.mark.parametrize('H,D,bq,bk,T', _HEAD_CASES, ids=_HEAD_IDS)
 @pytest.mark.parametrize('causal', [True, False])
-def test_flash_attention_matches_reference(causal, H, D, bq, bk):
+def test_flash_attention_matches_reference(causal, H, D, bq, bk, T):
     rng = np.random.RandomState(0)
-    B, T = 2, 256
+    B = 2
     q = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
     k = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
     v = jnp.asarray(rng.randn(B, T, H, D).astype('float32'))
@@ -144,22 +155,53 @@ def test_pallas_path_engages_for_transformer_shapes(monkeypatch):
         assert not fired
 
 
-def test_flash_attention_bf16_grads_finite():
-    """bf16 end-to-end through the Pallas backward (the AMP path)."""
-    import jax
+@pytest.fixture
+def two_pass(request):
+    """The backward route a test asks for by ``request.param`` (True:
+    the two-pass fallback), the merged default handed back after."""
+    old = pk._MERGED_BWD[0]
+    pk._MERGED_BWD[0] = not request.param
+    yield request.param
+    pk._MERGED_BWD[0] = old
+
+
+@pytest.mark.parametrize('two_pass', [False, True], indirect=True,
+                         ids=['merged', 'two-pass'])
+@pytest.mark.parametrize('H,D,block,T', [
+    (2, 64, 128, 256), (4, 64, 384, 384), (4, 64, 384, 768),
+    (2, 64, 384, 1536), (1, 128, 384, 768), (1, 128, 512, 1024),
+    (2, 64, 1024, 2048)])
+def test_flash_attention_bf16_grads(H, D, block, T, two_pass):
+    """bf16 end-to-end through the Pallas forward and backward (the AMP
+    path), whole and chunked diagonal tiles, two heads a program and
+    one: output, lse and gradients (the lse cotangent among them)
+    within 3e-2 of the float32 reference's largest magnitude
+    (chip_smoke.py's kernel rule)."""
     rng = np.random.RandomState(6)
-    B, T, H, D = 1, 256, 2, 64
-    q = jnp.asarray(rng.randn(B, T, H, D), jnp.bfloat16)
+    q, k, v, go = (jnp.asarray(rng.randn(1, T, H, D) * 0.5, jnp.bfloat16)
+                   for _ in range(4))
+    gl = jnp.asarray(rng.randn(1, H, T) * 0.1, jnp.float32)
 
-    def loss(q, k, v):
-        o = pk.flash_attention(q, k, v, causal=True, block_q=128,
-                               block_k=128, interpret=True)
-        return jnp.sum(o.astype(jnp.float32) ** 2)
+    def run(attend, *args):
+        def loss(q, k, v):
+            o, lse = attend(q, k, v)
+            both = jnp.sum(o.astype(jnp.float32) * go.astype(jnp.float32)) \
+                + jnp.sum(lse * gl)
+            return both, (o, lse)
+        (_, outs), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(*args)
+        return outs + grads
 
-    g = jax.grad(loss, argnums=(0, 1, 2))(q, q, q)
-    for arr in g:
-        assert arr.dtype == jnp.bfloat16
-        assert bool(jnp.isfinite(arr.astype(jnp.float32)).all())
+    got = run(lambda q, k, v: pk.flash_attention_with_lse(
+        q, k, v, block_q=block, block_k=block, interpret=True), q, k, v)
+    want = run(pk.attention_reference_with_lse,
+               *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, a, b in zip(('out', 'lse', 'dq', 'dk', 'dv'), got, want):
+        assert a.dtype == (jnp.float32 if name == 'lse' else jnp.bfloat16)
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        assert np.isfinite(a).all(), name
+        err = np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-6)
+        assert err < 3e-2, '%s: %.3g' % (name, err)
 
 
 def test_fused_lstm_engages_in_scan_with_grads(monkeypatch):
@@ -211,16 +253,16 @@ def test_fused_lstm_engages_in_scan_with_grads(monkeypatch):
     np.testing.assert_allclose(fused, baseline, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize('H,D,bq,bk', _HEAD_CASES, ids=_HEAD_IDS)
+@pytest.mark.parametrize('H,D,bq,bk,T', _HEAD_CASES, ids=_HEAD_IDS)
 @pytest.mark.parametrize('causal', [True, False])
 def test_flash_with_lse_matches_reference_including_lse_grads(
-        causal, H, D, bq, bk):
+        causal, H, D, bq, bk, T):
     """flash_attention_with_lse: out AND lse match, and gradients flow
     correctly through BOTH outputs (the lse cotangent folds into the
     backward's delta term — the ring-attention merge depends on it)."""
     import jax
     rng = np.random.RandomState(7)
-    B, T = 2, 256
+    B = 2
     q = jnp.asarray(rng.randn(B, T, H, D) * 0.5, jnp.float32)
     k = jnp.asarray(rng.randn(B, T, H, D) * 0.5, jnp.float32)
     v = jnp.asarray(rng.randn(B, T, H, D) * 0.5, jnp.float32)
@@ -335,17 +377,18 @@ def test_flash_attention_layer_scaling():
                                atol=2e-5)
 
 
-@pytest.mark.parametrize('H,D', [(2, 64), (4, 64), (1, 128)])
+@pytest.mark.parametrize('H,D,bq,bk,T', [
+    (2, 64, 128, 256, 256), (4, 64, 128, 256, 256), (1, 128, 128, 256, 256),
+    (4, 64, 384, 384, 768), (1, 128, 384, 384, 1536)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_merged_backward_matches_two_pass(causal, H, D):
+def test_merged_backward_matches_two_pass(causal, H, D, bq, bk, T):
     """The merged dkv+dq-partials backward must produce the same grads
-    as the two-pass path (it is the default under the slab cap)."""
-    import jax
-    import jax.numpy as jnp
+    as the two-pass path (it is the default under the slab cap), whole
+    and chunked diagonal tiles alike: they share one tile body."""
     rng = np.random.RandomState(7)
-    q = jnp.asarray(rng.randn(1, 256, H, D), jnp.float32) * 0.1
-    k = jnp.asarray(rng.randn(1, 256, H, D), jnp.float32) * 0.1
-    v = jnp.asarray(rng.randn(1, 256, H, D), jnp.float32) * 0.1
+    q = jnp.asarray(rng.randn(1, T, H, D), jnp.float32) * 0.1
+    k = jnp.asarray(rng.randn(1, T, H, D), jnp.float32) * 0.1
+    v = jnp.asarray(rng.randn(1, T, H, D), jnp.float32) * 0.1
 
     def grads(merged):
         old = pk._MERGED_BWD[0]
@@ -355,8 +398,8 @@ def test_merged_backward_matches_two_pass(causal, H, D):
 
             def loss(q, k, v):
                 o = pk.flash_attention(q, k, v, causal=causal,
-                                       force=True, block_q=128,
-                                       block_k=256, interpret=True)
+                                       force=True, block_q=bq,
+                                       block_k=bk, interpret=True)
                 return jnp.sum(o * 1e-2)
 
             return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
@@ -368,6 +411,60 @@ def test_merged_backward_matches_two_pass(causal, H, D):
     for a, b in zip(g_merged, g_two):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize('H,D', [(2, 64), (1, 128)])
+@pytest.mark.parametrize('causal', [True, False])
+def test_flash_lse_grads_float32(causal, H, D):
+    """Float32 forward, lse and the gradients of both, merged and
+    two-pass, against the reference at 384-row blocks and T = 4 blocks:
+    dead, full and (under ``causal``) chunked diagonal tiles in one
+    sweep."""
+    rng = np.random.RandomState(8)
+    T = 1536
+    q, k, v, go = (jnp.asarray(rng.randn(1, T, H, D) * 0.5, jnp.float32)
+                   for _ in range(4))
+    gl = jnp.asarray(rng.randn(1, H, T) * 0.1, jnp.float32)
+
+    def grads(attend):
+        def loss(q, k, v):
+            o, lse = attend(q, k, v, causal=causal)
+            return jnp.sum(o * go) + jnp.sum(lse * gl), (o, lse)
+        (_, outs), g = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return outs + g
+
+    want = grads(pk.attention_reference_with_lse)
+    old = pk._MERGED_BWD[0]
+    try:
+        for merged in (True, False):
+            pk._MERGED_BWD[0] = merged
+            got = grads(lambda q, k, v, causal: pk.flash_attention_with_lse(
+                q, k, v, causal=causal, block_q=384, block_k=384,
+                interpret=True))
+            for a, b in zip(got, want):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=2e-4, atol=2e-4)
+    finally:
+        pk._MERGED_BWD[0] = old
+
+
+@pytest.mark.parametrize('dtype,T,causal,plan,diag', [
+    ('bfloat16', 2048, True, (2048, 2048), 'chunked4'),
+    ('bfloat16', 1536, True, (1536, 1536), 'chunked3'),
+    ('bfloat16', 4096, True, (1024, 1024), 'chunked2'),
+    ('bfloat16', 2048, False, (1024, 1024), 'none'),
+    ('float32', 2048, True, (512, 1024), 'whole')])
+def test_flash_plan_default_blocks(dtype, T, causal, plan, diag):
+    """flash_plan's own blocks: bf16 takes one tile a program where a
+    causal sequence of at most 2048 positions is one (no tile lies
+    below the diagonal, and a diagonal tile's row chunks fit VMEM),
+    else 1024 x 1024, whose full tiles run whole; float32 keeps 512 x
+    1024, a grid-valued diagonal offset, so the whole tile under the
+    mask."""
+    q = jnp.zeros((1, T, 4, 64), dtype)
+    assert pk.flash_plan(q, interpret=True, causal=causal) == plan
+    assert pk.flash_diag(plan, causal) == diag
 
 
 # ---- the flash_attention op on AMP's MXU path -------------------------------
@@ -405,7 +502,8 @@ def _flash_op_feed(T, seed=11, heads=_FLASH_OP_H):
             for n, s in (('q', 1.0), ('k', 1.0), ('v', 1.0), ('w', 0.1))}
 
 
-def _flash_op_program(T, depth=1, grads=True, heads=_FLASH_OP_H):
+def _flash_op_program(T, depth=1, grads=True, heads=_FLASH_OP_H,
+                      causal=True):
     """``depth`` flash_attention ops in a row on fed q, k, v (B2 D64,
     H4 unless ``heads`` says otherwise) and, with ``grads``, the
     gradients of sum(out * w) in q, k, v (fluid.gradients replays the
@@ -422,7 +520,8 @@ def _flash_op_program(T, depth=1, grads=True, heads=_FLASH_OP_H):
         out = q
         for _ in range(depth):
             out = fluid.layers.flash_attention(out, k, v,
-                                               num_heads=heads)
+                                               num_heads=heads,
+                                               causal=causal)
         fetch = [out]
         if grads:
             loss = fluid.layers.reduce_sum(
@@ -561,6 +660,47 @@ def test_flash_counts_one_per_op_lowering(amp_on, route, T, heads, amp,
     moved = {key: n - before.get(key, 0) for key, n in after.items()
              if n != before.get(key, 0)}
     assert moved == {(route, 'bf16' if amp_on else 'f32'): 2}
+
+
+@pytest.mark.parametrize('T,blocks,causal,route,diag', [
+    (768, (384, 384), True, 'pallas', 'chunked3'),
+    (1536, (768, 768), True, 'pallas', 'chunked3'),
+    (2048, (1024, 1024), True, 'pallas', 'chunked2'),
+    (512, None, True, 'pallas', 'whole'),        # f32 default: 512 x 512
+    (512, (256, 256), True, 'pallas', 'whole'),
+    (512, (256, 512), True, 'pallas', 'whole'),
+    (512, (512, 256), True, 'pallas', 'whole'),
+    (768, (384, 384), False, 'pallas', 'none'),
+    (256, None, True, 'xla', 'none')])
+def test_flash_counts_name_the_diagonal_body(T, blocks, causal, route,
+                                             diag, amp, engage):
+    """The lowering counter says which body the plan's blocks give the
+    tiles on the diagonal: row chunks where block_q == block_k holds
+    more than one chunk (of 512 rows, or of the largest 128-row multiple
+    below that which divides the block), the whole tile under the mask
+    otherwise, none where nothing is masked or the kernels do not run.
+    flash_counts() keeps its (route, dtype) keys and sums over that
+    label; flash_counts(by=('diag',)) reads it."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.compiler import tuning
+    from paddle_tpu.compiler.passes import flash_counts
+    amp.set_amp(False)
+    x = jnp.zeros((_FLASH_OP_B, T, _FLASH_OP_H, _FLASH_OP_DH))
+    plan = pk.flash_plan(x, *(blocks or (None, None)), causal=causal)
+    assert (plan is not None) == (route == 'pallas')
+    assert pk.flash_diag(plan, causal) == diag
+    main, startup, fetch = _flash_op_program(T, grads=False, causal=causal)
+    exe = fluid.Executor(fluid.CPUPlace())
+    entry = dict(zip(('flash_block_q', 'flash_block_k'), blocks)) \
+        if blocks else None
+    with fluid.scope_guard(fluid.Scope()), tuning.apply_entry(entry):
+        exe.run(startup)
+        before = flash_counts(), flash_counts(by=('diag',))
+        exe.lowered(main, feed=_flash_op_feed(T), fetch_list=fetch)
+        after = flash_counts(), flash_counts(by=('diag',))
+    moved = [{key: n - was.get(key, 0) for key, n in now.items()
+              if n != was.get(key, 0)} for was, now in zip(before, after)]
+    assert moved == [{(route, 'f32'): 1}, {(diag,): 1}]
 
 
 def _eqns(jaxpr):

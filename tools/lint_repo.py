@@ -140,9 +140,9 @@ ALLOWLIST = frozenset({
     # the literals are reachable-but-tunable. New kernels resolve
     # schedules through compiler.tuning (conv_schedule()) instead.
     'hardcoded-schedule:paddle_tpu/ops/pallas_kernels.py:'
-    'block_q = 1024 if q.dtype == jnp.bfloat16 else 512',
+    'block_q = (2048 if one_tile else 1024) if bf16 else 512',
     'hardcoded-schedule:paddle_tpu/ops/pallas_kernels.py:'
-    'block_k = 1024',
+    'block_k = (2048 if one_tile else 1024) if bf16 else 1024',
 })
 
 
