@@ -135,7 +135,7 @@ def _cache_events():
 
 def _build(fluid, name, **kwargs):
     """benchmark/fluid/models.py::<name> -> Momentum.minimize, as
-    bench.py and fluid_benchmark.py build it. Built under a fresh name
+    fluid_benchmark.py builds it. Built under a fresh name
     scope, so every build in every process is the same program."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
@@ -292,13 +292,16 @@ def leg_flash(cfg):
         q[:rows], k[:rows], v[:rows], g[:rows])
     errs = {'fwd': _kernel_check('flash forward',
                                  jax.jit(kernel)(q, k, v)[:rows], want)}
-    for label, merged in (('merged', True), ('two-pass', False)):
-        pk._MERGED_BWD[0] = merged
+    # the two passes are reached as a long sequence reaches them: by a
+    # dq slab over the cap
+    cap = pk._MERGED_BWD_MAX_SLAB_BYTES
+    for label, slab_cap in (('merged', cap), ('two-pass', 0)):
+        pk._MERGED_BWD_MAX_SLAB_BYTES = slab_cap
         try:
             got_g = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(
                 q, k, v, g)
         finally:
-            pk._MERGED_BWD[0] = True
+            pk._MERGED_BWD_MAX_SLAB_BYTES = cap
         for nm, a, b in zip(('dq', 'dk', 'dv'), got_g, want_g):
             errs['%s %s' % (label, nm)] = _kernel_check(
                 'flash %s backward %s' % (label, nm), a[:rows], b)

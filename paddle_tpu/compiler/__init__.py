@@ -12,13 +12,11 @@ integration:
 - ``inference_pipeline()`` — adds BN/scale folding into conv/fc
   weights (needs the scope; <= 1e-5 drift) at the head. Reached via
   ``optimize_inference`` / the legacy ``InferenceTranspiler`` facade.
-- ``tuning`` — the per-shape autotuner + on-disk tuning cache the
-  executor consults at compile time and serving warmup preloads.
 
-The executor folds :func:`cache_token` into every program-cache key, so
-toggling the pipeline (``set_enabled``/``set_default_passes``) or
-landing a new tuning entry invalidates exactly the affected compiled
-programs — never serving a program compiled under a different config.
+The executor folds :func:`pipeline_signature` into every program-cache
+key, so toggling the pipeline (``set_enabled``/``set_default_passes``)
+invalidates exactly the affected compiled programs — never serving a
+program compiled under a different config.
 """
 import contextlib
 
@@ -26,7 +24,6 @@ from .pass_base import (Pass, PassContext, PassResult, PassRegistry,  # noqa
                         PassPipeline, register_pass, get_pass,
                         registered_passes)
 from . import passes  # noqa  (registers canonical passes + fused kernel)
-from . import tuning  # noqa
 from . import zero  # noqa  (registers the ZeRO-2 grad-tail pass)
 from .passes import DEFAULT_PASSES, INFERENCE_PASSES  # noqa
 
@@ -34,8 +31,8 @@ __all__ = ['Pass', 'PassContext', 'PassResult', 'PassRegistry',
            'PassPipeline', 'register_pass', 'get_pass',
            'registered_passes', 'enabled', 'set_enabled', 'disabled',
            'default_pipeline', 'inference_pipeline',
-           'set_default_passes', 'pipeline_signature', 'cache_token',
-           'optimize', 'optimize_inference', 'tuning', 'zero']
+           'set_default_passes', 'pipeline_signature',
+           'optimize', 'optimize_inference', 'zero']
 
 _STATE = {'enabled': True, 'pass_names': tuple(DEFAULT_PASSES),
           'pipeline': None}
@@ -47,7 +44,7 @@ def enabled():
 
 def set_enabled(on):
     """Master switch for the executor-integrated pipeline. Flipping it
-    changes :func:`cache_token`, forcing a recompile (never a stale
+    changes :func:`pipeline_signature`, forcing a recompile (never a stale
     program)."""
     _STATE['enabled'] = bool(on)
 
@@ -91,17 +88,6 @@ def pipeline_signature():
     if not _STATE['enabled']:
         return ('off',)
     return _STATE['pass_names']
-
-
-def cache_token(program_fp, feed_sig):
-    """The compiler's contribution to the executor's program-cache key:
-    pipeline config + the tuning-cache entry token for this
-    (program, shape, backend). Cheap — one dict lookup per run."""
-    if not _STATE['enabled']:
-        return ('off',)
-    return _STATE['pass_names'] + (tuning.default_cache().token(
-        program_fp, tuning.shape_signature(feed_sig),
-        tuning.backend()),)
 
 
 def optimize(program, fetch_names=(), scope=None, clone=True):

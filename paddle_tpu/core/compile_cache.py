@@ -1,5 +1,5 @@
 """Where this program keeps what it generates and reuses across
-processes: JAX's persistent compilation cache and the tuning cache.
+processes: JAX's persistent compilation cache.
 
 One rule, one place. If ``JAX_COMPILATION_CACHE_DIR`` is set, the
 compile cache lives there — JAX reads that variable itself, and no code
@@ -13,20 +13,13 @@ executables: fleet/coldstart.py).
 """
 import os
 
-__all__ = ['CACHE_ENV', 'cache_root', 'compile_cache_dir',
-           'configure_compile_cache']
+__all__ = ['CACHE_ENV', 'compile_cache_dir', 'configure_compile_cache']
 
 CACHE_ENV = 'JAX_COMPILATION_CACHE_DIR'
 
 _ROOT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), '.ptpu_cache')
-
-
-def cache_root():
-    """The in-checkout directory for generated, reusable state (listed
-    in .gitignore)."""
-    return _ROOT
 
 
 def compile_cache_dir():

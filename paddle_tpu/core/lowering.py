@@ -499,106 +499,6 @@ def _run_remat_segments(block, ops, env, grad_mode, keep=None):
     return env
 
 
-# Graph pass: merge same-input mul (fc) ops into one wide matmul.
-# OFF by default: measured on v5e (r4, fluid transformer d1024 H16 L6
-# S2048) the pass is neutral at B=2 (96.1k vs 96.9k tok/s) and ~2.5%
-# SLOWER at B=8 (98.6k vs 101.1k) — the per-step weight concat costs
-# more than the wider matmul saves; XLA already schedules shared-LHS
-# matmuls well. Kept as an opt-in for narrow-batch inference graphs.
-MERGE_SHARED_MULS = [False]
-
-
-def _merge_shared_muls(block, ops):
-    """Rewrite groups of ``mul`` ops sharing the same X (e.g. the
-    q/k/v projections of an attention layer) into
-    concat(weights) -> one mul -> split. One [M, d]x[d, 3d] matmul
-    uses the MXU better than three [M, d]x[d, d] at small batch and
-    reads X from HBM once instead of three times (VERDICT r3 #6; the
-    reference fuses the same way inside its fused attention op,
-    operators/fused/*). Gradients of the separate weight params flow
-    through the concat automatically.
-
-    Conservative scope: 2-D persistable weights, y_num_col_dims == 1,
-    matching x_num_col_dims — anything else stays untouched.
-    """
-    from ..framework import Operator
-    groups = {}
-    for i, op in enumerate(ops):
-        if op.type != 'mul' or op.attrs.get('y_num_col_dims', 1) != 1:
-            continue
-        y_name = op.inputs['Y'][0]
-        var = block._find_var_recursive(y_name)
-        if var is None or not getattr(var, 'persistable', False):
-            continue
-        shape = getattr(var, 'shape', None)
-        if not shape or len(shape) != 2 or any(int(d) <= 0
-                                               for d in shape):
-            continue
-        x_var = block._find_var_recursive(op.inputs['X'][0])
-        # sequence (LoD) inputs: mul rewraps to SequenceTensor but
-        # split would drop the LoD — leave those untouched
-        if x_var is None or getattr(x_var, 'lod_level', 0):
-            continue
-        key = (op.inputs['X'][0], op.attrs.get('x_num_col_dims', 1))
-        groups.setdefault(key, []).append(i)
-
-    merged_at, drop = {}, set()
-    for (x_name, xd), idxs in groups.items():
-        if len(idxs) < 2:
-            continue
-        # def-use safety: hoisting later members to the first position
-        # is only sound while no intervening op REWRITES X, a group
-        # weight, or a member's Out name, AND no intervening op READS a
-        # member's Out name (a reader of a same-named var defined before
-        # the group would otherwise see the hoisted write — WAR hazard).
-        # Truncate the group at the first violation.
-        w_names = {ops[i].inputs['Y'][0] for i in idxs}
-        out_names = {ops[i].outputs['Out'][0] for i in idxs}
-        hazard = {x_name} | w_names | out_names
-        safe = [idxs[0]]
-        member = set(idxs)
-        for j in range(idxs[0] + 1, idxs[-1] + 1):
-            if j in member:
-                safe.append(j)
-                continue
-            if hazard & set(_op_writes(ops[j])):
-                break
-            if out_names & set(_op_reads(ops[j])):
-                break
-        idxs = safe
-        if len(idxs) < 2:
-            continue
-        widths = [int(block._find_var_recursive(
-            ops[i].inputs['Y'][0]).shape[1]) for i in idxs]
-        first = idxs[0]
-        base = '%s@mulfuse%d' % (x_name, first)
-        cat_w, cat_out = base + '@w', base + '@out'
-        new_ops = [
-            Operator(block, 'concat',
-                     inputs={'X': [ops[i].inputs['Y'][0] for i in idxs]},
-                     outputs={'Out': [cat_w]}, attrs={'axis': 1}),
-            Operator(block, 'mul', inputs={'X': [x_name], 'Y': [cat_w]},
-                     outputs={'Out': [cat_out]},
-                     attrs=dict(ops[first].attrs)),
-            Operator(block, 'split', inputs={'X': [cat_out]},
-                     outputs={'Out': [ops[i].outputs['Out'][0]
-                                      for i in idxs]},
-                     attrs={'axis': -1, 'sections': widths}),
-        ]
-        merged_at[first] = new_ops
-        drop.update(idxs[1:])
-
-    if not merged_at:
-        return ops
-    out = []
-    for i, op in enumerate(ops):
-        if i in merged_at:
-            out.extend(merged_at[i])
-        elif i not in drop:
-            out.append(op)
-    return out
-
-
 # op input slots whose VALUES define shapes: feeds consumed only through
 # these are bound statically at trace time (part of the jit cache key) —
 # the TPU analog of the reference's runtime shape tensors
@@ -668,13 +568,6 @@ def lower_block(program, block, feed_names, fetch_names, state_in_names,
     """
     ops = list(block.ops)
     marker_idx = _find_marker(ops)
-    if MERGE_SHARED_MULS[0] and not dynamic:
-        if marker_idx < 0:
-            ops = _merge_shared_muls(block, ops)
-        else:
-            pre = _merge_shared_muls(block, ops[:marker_idx])
-            ops = pre + ops[marker_idx:]
-            marker_idx = len(pre)
 
     # names the compiler's release annotations must never drop from the
     # environment: the epilogue below still reads them

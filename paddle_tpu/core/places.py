@@ -33,8 +33,7 @@ def _local_devices(platform):
 
 def on_tpu():
     """THE predicate for "this process runs on the chip": AMP's auto
-    mode, every Pallas engagement policy and the tuning key ask this
-    one question."""
+    mode and every Pallas engagement policy ask this one question."""
     import jax
     return jax.default_backend() == 'tpu'
 

@@ -301,9 +301,8 @@ def program_cache_key(program, feed, static_env, fetch_names, state_in,
     — ONE builder so a new invalidation dimension can never be added to
     one executor and missed in the other (static shape-feed VALUES are
     part of the key: a new shape value must retrace). The compiler's
-    token (pass-pipeline config + per-shape tuning-cache entry) rides
-    in here too, so toggling optimization or landing a new tuning
-    result can never serve a stale compiled program. Callers append
+    pass-pipeline signature rides in here too, so toggling optimization
+    can never serve a stale compiled program. Callers append
     the Partitioner's cache token via ``*extra`` — (mesh shape, device
     ids, resolved sharding signature) — so one Executor can serve the
     same program on different meshes/shardings with exactly one
@@ -315,8 +314,7 @@ def program_cache_key(program, feed, static_env, fetch_names, state_in,
             tuple(sorted((n, v.dtype.str, v.shape, v.tobytes())
                          for n, v in static_env.items())),
             tuple(fetch_names), tuple(state_in), tuple(state_out),
-            guard, lowering.MERGE_SHARED_MULS[0],
-            _compiler.cache_token(fp, feed_sig)) + tuple(extra)
+            guard, _compiler.pipeline_signature()) + tuple(extra)
 
 
 def _stack_steps(*xs):
@@ -780,20 +778,6 @@ class Executor(object):
             static_env[n] = np.asarray(as_numpy(feed.pop(n)))
         return static_env
 
-    def _apply_tuning(self, key, jitted):
-        """Compile-time tuning-cache consultation (COMPILER.md): when a
-        persisted entry exists for this (program, shape, backend), the
-        compiled callable runs under its knobs — the first (tracing)
-        call bakes them in, and the entry's token is already part of
-        ``key`` via program_cache_key."""
-        from . import compiler as _compiler
-        if not _compiler.enabled():
-            return jitted
-        entry = _compiler.tuning.default_cache().lookup(
-            key[0], _compiler.tuning.shape_signature(key[1]),
-            _compiler.tuning.backend())
-        return _compiler.tuning.wrap_jitted(jitted, entry)
-
     def device_context(self, sharded):
         """Where a jitted call, or an AOT lowering of one, runs: the
         mesh scope when sharded, this Executor's device otherwise."""
@@ -977,7 +961,7 @@ class Executor(object):
                 _obs.emit('compile_begin', fp=key[0])
                 with _obs.phase('exe/compile', top, fp=key[0]):
                     jitted = self._lower_step(
-                        program, key, feed, fetch_names, state_in_names,
+                        program, feed, fetch_names, state_in_names,
                         state_out_names, static_env, scope,
                         dynamic=dynamic, profiling=profiling, guard=guard,
                         sharded=sharded, donate=aot_store is None,
@@ -1035,8 +1019,6 @@ class Executor(object):
             # compiled lazily on the dispatch below anyway),
             # serialized for the next replica's warmup; the dispatch
             # uses the Compiled directly so the compile happens once.
-            # A non-lowerable callable (tuning-wrapped) returns None
-            # and stays on the lazy path.
             with self.device_context(sharded):
                 try:
                     if not sharded and (
@@ -1064,12 +1046,12 @@ class Executor(object):
                      was_miss and not aot_hit,
                      guard and not (profiling or dynamic), _ledger)
 
-    def _lower_step(self, program, key, feed, fetch_names, state_in_names,
+    def _lower_step(self, program, feed, fetch_names, state_in_names,
                     state_out_names, static_env, scope, dynamic,
                     profiling, guard, sharded, donate, feeds_s, state_s):
         """``exe/compile`` of a single step: the pass pipeline, the
-        lowering of the block, and the jit (or partition) wrapper with
-        its tuning knobs. Nothing is traced or compiled by XLA here."""
+        lowering of the block, and the jit (or partition) wrapper.
+        Nothing is traced or compiled by XLA here."""
         part = self.partitioner
         lower_prog = self._optimized_program(
             program, fetch_names, scope=scope, dynamic=dynamic)
@@ -1120,7 +1102,7 @@ class Executor(object):
             jitted = jax.jit(checkify.checkify(fn))
         else:
             jitted = part.partition(fn, donate_argnums=donate)
-        return self._apply_tuning(key, jitted)
+        return jitted
 
     def run_chained(self, program=None, feed_list=None, fetch_list=None,
                     scope=None, return_numpy=True, async_fetch=False):
@@ -1306,7 +1288,6 @@ class Executor(object):
                             donate_argnums=(1,))
                     else:
                         jitted = part.partition(fn, donate_argnums=(1,))
-                    jitted = self._apply_tuning(key, jitted)
                 self._cache[key] = jitted
             else:
                 self._cache_hits += 1

@@ -288,9 +288,9 @@ class AotStore(object):
     def aot_compile(jitted, feed, state, shardings=None):
         """The one AOT ``lower().compile()`` allowed on the warmup path
         (lint-pinned): turn a lazily-compiling ``jax.jit`` object into
-        the concrete ``Compiled`` this store persists. Returns None
-        when the callable cannot be AOT-lowered (a tuning-wrapped or
-        eager callable).
+        the concrete ``Compiled`` this store persists. The Executor
+        seals only what it jitted (never a profiled, dynamic or guarded
+        step), so ``jitted`` always lowers.
 
         ``shardings``, when given, is a ``(feed_shardings,
         state_shardings)`` pair of name->Sharding dicts from the
@@ -298,8 +298,6 @@ class AotStore(object):
         even when the live dispatch is mesh-committed, and XLA refuses
         the sharding mismatch at call time — so on the sharded path
         the avals must carry the same shardings the dispatch will use."""
-        if not hasattr(jitted, 'lower'):
-            return None
         import jax
 
         def aval(v, s=None):

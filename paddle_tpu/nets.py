@@ -4,9 +4,9 @@
 reference decode graphs (book test_machine_translation.py decode_main)
 drive beam search through a host-interpreted While over shrinking
 packed-LoD beams; this composite builds the same search on dense
-[B*K] rows so the While lowers to ONE lax.while_loop — measured 100x+
-faster per sentence in bench.py. The unchanged-script eager path is
-untouched; this is the fluid-facing opt-in."""
+[B*K] rows so the While lowers to ONE lax.while_loop. The
+unchanged-script eager path is untouched; this is the fluid-facing
+opt-in."""
 from . import layers
 
 __all__ = ['simple_img_conv_pool', 'sequence_conv_pool', 'glu',

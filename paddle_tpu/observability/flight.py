@@ -14,10 +14,8 @@ summaries into one atomic JSON bundle that ``tools/postmortem.py``
 renders after the fact.
 
 Overhead contract: the ring append is one list-index check + a deque
-append of an already-built tuple; ``bench.py bench_telemetry_overhead``
-pins the enabled steady-state cost (ring + live telemetry endpoint) at
-<=1% of the serving hot path. :func:`set_ring_enabled` exists so that
-bench can measure the on/off delta; production leaves it on.
+append of an already-built tuple. :func:`set_ring_enabled` exists so
+that a measurement can take the on/off delta; production leaves it on.
 
 Dump gating: bundles are only written when a directory is configured —
 ``PTPU_FLIGHT_DIR`` in the environment or :func:`configure` — so unit

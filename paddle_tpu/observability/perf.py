@@ -2,9 +2,8 @@
 and HBM attribution, and the on-disk perf-regression baseline.
 
 Every perf claim in PERF.md ultimately reduces to one artifact — the
-flops/bytes "ledger" XLA computes for a compiled program — which used
-to live as private offline code in ``bench.py``. This module promotes
-it to a first-class runtime surface:
+flops/bytes "ledger" XLA computes for a compiled program. This module
+makes it a first-class runtime surface:
 
 - :class:`ProgramLedger` — captured on the Executor's compile-cache
   MISS path (one extra AOT ``lower().compile()`` against abstract
@@ -21,7 +20,7 @@ it to a first-class runtime surface:
 - ``perf_ledger`` journal events carry the tracing trace id, so a
   regressed program resolves to a renderable span tree
   (``tools/trace_report.py``).
-- :class:`PerfBaseline` — TuningCache-style on-disk JSON keyed
+- :class:`PerfBaseline` — on-disk JSON keyed
   ``fingerprint|shape-sig|backend|mesh``; ``tools/perf_report.py``
   diffs a run against it and exits nonzero on regressions.
 
@@ -29,8 +28,7 @@ Overhead contract (mirrors tracing/journal): capture is OFF by default
 — ``capture_enabled()`` is one list read (+ an env probe on the
 compile-miss path only). Enable with :func:`enable_capture`, the
 :func:`capture_scope` context manager, or ``PTPU_PERF=1`` in the
-environment. ``bench.py bench_perf_obs_overhead`` pins the enabled
-steady-state cost at <=1% of the training hot loop.
+environment.
 
 Lint contract: this file is the ONLY place allowed to call XLA's
 ``cost_analysis()`` directly (``tools/lint_repo.py`` rule
@@ -57,8 +55,7 @@ __all__ = [
     'capture_compiled', 'seal', 'publish_step',
     'book', 'get_ledger', 'ledgers', 'clear',
     'peak_flops_for', 'hbm_gbps_for', 'mesh_signature',
-    'shape_signature', 'transformer_flops_per_token',
-    'mfu_from_throughput', 'program_ledger', 'memory_dict',
+    'shape_signature', 'memory_dict',
     'abstract_args', 'register_executor', 'scope_map', 'parse_scopes',
     'split_scope', 'PHASES',
 ]
@@ -145,9 +142,7 @@ def capture_scope(on=True):
 # ---- signatures -----------------------------------------------------------
 def shape_signature(feed, state):
     """Stable short token of the (feed, state) leaf shapes/dtypes —
-    the shape axis of the baseline key. Mirrors the spirit of
-    ``compiler.tuning.shape_signature`` without importing the executor
-    (cycle avoidance)."""
+    the shape axis of the baseline key."""
     import jax
     leaves = jax.tree_util.tree_leaves((feed, state))
     items = [(tuple(getattr(v, 'shape', ()) or ()),
@@ -255,22 +250,6 @@ class ProgramLedger(object):
         return self.flops / (ms / 1e3) / peak
 
     # -- serialization ------------------------------------------------------
-    def bench_dict(self, measured_ms, hbm_gbps, peak):
-        """The exact BENCH-JSON ``ledger`` dict bench.py has always
-        published (resnet50 r4 onward) — field names and rounding are
-        byte-compatible with the retired private implementation."""
-        return {
-            'flops': self.flops,
-            'bytes_accessed': self.bytes_accessed,
-            'temp_bytes': self.temp_bytes,
-            'bandwidth_bound_ms': round(
-                self.bytes_accessed / (hbm_gbps * 1e9) * 1e3, 1),
-            'compute_bound_ms': round(self.flops / peak * 1e3, 1),
-            'measured_ms_per_step': round(measured_ms, 1),
-            'hw_flops_per_sec': round(
-                self.flops / (measured_ms / 1e3), 0),
-        }
-
     def as_dict(self):
         d = {
             'fp': self.fingerprint, 'shape_sig': self.shape_sig,
@@ -675,13 +654,7 @@ def scope_map(min_runs=1, executors=None):
                 continue
             key = '|%s|%d' % (fp, len(out))
             try:
-                # a tuning-wrapped entry is lowered as it was traced:
-                # its jit, under its knobs (compiler/tuning.py)
-                knobs = getattr(jitted, 'knobs', None)
-                if knobs is not None:
-                    jitted = jitted.__wrapped__
-                with exe.device_context(sharded), \
-                        knobs() if knobs else contextlib.nullcontext():
+                with exe.device_context(sharded):
                     text = _compiled_text(jitted, abstract)
                 module, scopes = parse_scopes(text)
                 out[module + key] = scopes
@@ -691,56 +664,20 @@ def scope_map(min_runs=1, executors=None):
     return out
 
 
-# ---- shared offline helpers (the one ledger implementation) ---------------
-def program_ledger(exe, program, feed, fetch_list, scope=None,
-                   measured_ms=None, device_kind=None):
-    """The bench.py ledger dict for a fluid program, via
-    ``Executor.cost_analysis`` (the allowlisted XLA caller). With
-    ``measured_ms`` (and the ``device_kind`` it was measured on) this
-    returns the full BENCH-compatible dict (``bandwidth_bound_ms`` ..
-    ``hw_flops_per_sec``); without it, just the raw cost fields."""
-    ca = exe.cost_analysis(program, feed, fetch_list, scope=scope)
-    if measured_ms is None:
-        return dict(ca)
-    ledger = ProgramLedger(
-        fingerprint=program.fingerprint(),
-        flops=ca['flops'], bytes_accessed=ca['bytes_accessed'],
-        output_bytes=ca.get('output_bytes', 0.0),
-        temp_bytes=ca['temp_bytes'],
-        argument_bytes=ca.get('argument_bytes', 0))
-    return ledger.bench_dict(measured_ms,
-                             hbm_gbps=hbm_gbps_for(device_kind),
-                             peak=peak_flops_for(device_kind))
-
-
+# ---- shared offline helpers ----------------------------------------------
 def memory_dict(comp):
     """Per-device byte accounting of an AOT-compiled executable —
     the shared ``memory_analysis()`` reader (ParallelExecutor
-    ``compile_stats``, bench memory leg)."""
+    ``compile_stats``)."""
     ma = comp.memory_analysis()
     return {'argument_bytes': int(ma.argument_size_in_bytes),
             'output_bytes': int(ma.output_size_in_bytes),
             'temp_bytes': int(ma.temp_size_in_bytes)}
 
 
-def transformer_flops_per_token(n_layers, d_model, vocab, seq):
-    """Matmul-only flops/token for the bench transformer (projections
-    + FFN + unembed at 6 flops per weight, attention dots at
-    12 * layers * (S/2) * d for the causal average) — the exact
-    arithmetic behind every published transformer MFU number."""
-    n_matmul = n_layers * 12 * d_model * d_model + vocab * d_model
-    return 6 * n_matmul + 12 * n_layers * (seq // 2) * d_model
-
-
-def mfu_from_throughput(per_sec, flops_per_unit, peak):
-    """round(throughput * flops-per-unit / peak, 4) — the BENCH-JSON
-    MFU rounding, one place."""
-    return round(per_sec * flops_per_unit / peak, 4)
-
-
 # ---- regression baseline --------------------------------------------------
 class PerfBaseline(object):
-    """On-disk perf baseline, TuningCache-style: schema'd JSON of
+    """On-disk perf baseline: schema'd JSON of
     entries keyed ``fingerprint|shape-sig|backend|mesh``. Deterministic
     fields (flops, bytes) must MATCH within ``DETERMINISTIC_RTOL``;
     timing fields (``step_ms``, ``mfu``), when present on both sides,

@@ -467,11 +467,6 @@ def _flash_attention_op(ctx):
     from ..core.amp import mxu_compute
     heads = int(ctx.attr('num_heads', 1))
     causal = bool(ctx.attr('causal', True))
-    # autotuned tile sizes, when the compiler's tuning cache holds an
-    # entry for this (program, shape, backend); (None, None) otherwise
-    # keeps the kernel's dtype-aware defaults
-    from ..compiler import tuning as _ctuning
-    bq, bk = _ctuning.flash_blocks()
 
     def attend(q, k, v):
         B, T, D = q.shape
@@ -479,7 +474,7 @@ def _flash_attention_op(ctx):
         qh = q.reshape(B, T, heads, dh)
         kh = k.reshape(B, T, heads, dh)
         vh = v.reshape(B, T, heads, dh)
-        plan = flash_plan(qh, bq, bk, causal=causal)
+        plan = flash_plan(qh, causal=causal)
         _obs.default_registry().counter(
             'flash_attention_lowerings_total',
             help='flash_attention op lowerings, by the route taken '
@@ -491,8 +486,7 @@ def _flash_attention_op(ctx):
                 qh.dtype.name, qh.dtype.name),
             diag=flash_diag(plan, causal)).inc()
         # NB: flash_attention applies the 1/sqrt(dh) logit scale itself
-        out = flash_attention(qh, kh, vh, causal=causal,
-                              block_q=bq, block_k=bk)
+        out = flash_attention(qh, kh, vh, causal=causal)
         return out.reshape(B, T, D)
 
     ctx.set_output('Out', mxu_compute(

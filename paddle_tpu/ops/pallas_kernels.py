@@ -67,12 +67,8 @@ def attention_reference(q, k, v, causal=True, q_off=0, k_off=0):
 # exp2-based softmax (VERDICT r4 #4): fold log2(e) into the score
 # scale so the VPU evaluates exp2 directly instead of exp's extra
 # multiply per element. Saved lse stays NATURAL-log so the
-# backward/ring-merge contract is unchanged. NOTE: the flag is read at
-# TRACE time — flipping it after a caller has jit-compiled reuses the
-# cached executable; A/B measurement must jax.clear_caches() between
-# legs (bench.py does). Default from the on-chip A/B in PERF.md.
+# backward/ring-merge contract is unchanged.
 _LOG2E = 1.4426950408889634
-_USE_EXP2 = [True]
 
 
 def _lane_heads(H, dh):
@@ -211,7 +207,7 @@ def _dot(x, y, dims):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, block_q, block_k, T, causal, exp2, dh):
+                  acc_scr, *, block_q, block_k, T, causal, dh):
     """One (batch, head group, q-block, k-block) grid step on blocks
     [block, hp*dh] of [B, T, H*dh] arrays: the ``hp`` heads whose lanes
     fill the block (two at head size 64) are attended one after the
@@ -243,10 +239,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         q = q_ref[0, rows, :]                     # [rows, hp*dh]
         k = k_ref[0, pl.ds(0, cols), :]           # [cols, hp*dh]
         v = v_ref[0, pl.ds(0, cols), :]
-        scale = 1.0 / math.sqrt(dh)
-        _exp = jnp.exp2 if exp2 else jnp.exp
-        if exp2:
-            scale = scale * _LOG2E  # scores live in log2 units
+        scale = 1.0 / math.sqrt(dh) * _LOG2E  # scores live in log2 units
         heads = _head_lanes(q.shape, dh)
         keep = _causal_keep((rows.size, cols), diag)
         stat = (rows.size,) + m_scr.shape[2:]
@@ -257,8 +250,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             m_prev = m_scr[i, rows, :1]               # [rows, 1]
             l_prev = l_scr[i, rows, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = _exp(m_prev - m_new)
-            p = _exp(s - m_new)
+            alpha = jnp.exp2(m_prev - m_new)
+            p = jnp.exp2(s - m_new)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
             # head i in its own lanes, p @ (the other heads' v) in theirs
             pvs.append(_dot(p.astype(v.dtype), v, (1, 0)))
@@ -282,11 +275,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         heads = _head_lanes(acc_scr.shape, dh)
         o_ref[0] = (acc_scr[:] / _by_head(ls, heads, acc_scr.shape)) \
             .astype(o_ref.dtype)
-        # logsumexp row stats (NATURAL log even in exp2 mode), saved
+        # logsumexp row stats (NATURAL log: m is in log2 units), saved
         # for the blockwise backward and the ring-attention merge
         for i in range(hp):
-            m = m_scr[i, :, :1]
-            lse_ref[0, i] = (m / _LOG2E if exp2 else m) + jnp.log(ls[i])
+            lse_ref[0, i] = m_scr[i, :, :1] / _LOG2E + jnp.log(ls[i])
 
 
 def _live_kb(causal, block_q, block_k, n_kb):
@@ -341,18 +333,16 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=32 * 1024 * 1024)
 
 
-# The raw calls are jitted with everything but the arrays static (the
-# two trace-time flags, _USE_EXP2 and _MERGED_BWD, among them: their
-# callers read them): a model's layers share one shape, so the step
-# traces and lowers each kernel once, not once a layer. Two heads a
-# program double a kernel's trace, and the step is traced two or three
-# times before its first timed run.
-_RAW_STATICS = ('H', 'causal', 'block_q', 'block_k', 'interpret', 'exp2')
+# The raw calls are jitted with everything but the arrays static: a
+# model's layers share one shape, so the step traces and lowers each
+# kernel once, not once a layer. Two heads a program double a kernel's
+# trace, and the step is traced two or three times before its first
+# timed run.
+_RAW_STATICS = ('H', 'causal', 'block_q', 'block_k', 'interpret')
 
 
 @functools.partial(jax.jit, static_argnames=_RAW_STATICS)
-def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret,
-                       exp2):
+def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret):
     """Raw Pallas forward on [B, T, H*dh] -> (out [B, T, H*dh],
     lse [B, H, T, 1])."""
     B, T, HD = q.shape
@@ -363,8 +353,7 @@ def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret,
     kb_at = _live_kb(causal, block_q, block_k, n_kb)
     return pl.pallas_call(
         functools.partial(_flash_kernel, block_q=block_q,
-                          block_k=block_k, T=T, causal=causal, exp2=exp2,
-                          dh=dh),
+                          block_k=block_k, T=T, causal=causal, dh=dh),
         grid=(B, H // hp, T // block_q, n_kb),
         in_specs=[
             _wide(block_q, lanes, _outer),
@@ -390,7 +379,7 @@ def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret,
     )(q, k, v)
 
 
-def _bwd_p_ds(q, k, v, do, lse, delta, keep, exp2, dh):
+def _bwd_p_ds(q, k, v, do, lse, delta, keep, dh):
     """Shared backward recompute for ONE head: normalised probs ``p``
     and the score cotangent ``ds = p * (dp - delta)`` for one part of a
     (q-block, k-block) tile (the rows of ``q`` against the columns of
@@ -401,17 +390,15 @@ def _bwd_p_ds(q, k, v, do, lse, delta, keep, exp2, dh):
     kernels (two-pass dq, two-pass dk/dv, merged) — they are selected
     at runtime, so their tile math must never diverge."""
     scale = 1.0 / math.sqrt(dh)
-    _exp = jnp.exp2 if exp2 else jnp.exp
-    sscale = scale * _LOG2E if exp2 else scale
-    s = _masked(_dot(q, k, (1, 1)) * sscale, keep)
+    s = _masked(_dot(q, k, (1, 1)) * (scale * _LOG2E), keep)
     # normalised probs
-    p = _exp(s - (lse * _LOG2E if exp2 else lse))
+    p = jnp.exp2(s - lse * _LOG2E)
     dp = _dot(do, v, (1, 1))                        # [rows, cols]
     ds = p * (dp - delta)
     return p, ds, scale
 
 
-def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
+def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
               put_dq=None, dk_scr=None, dv_scr=None):
     """One (q-block, k-block) tile of the backward for the ``hp`` heads
     of head group ``g``, one head after the other, over the tile's live
@@ -446,7 +433,7 @@ def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
                 - jnp.sum(jnp.where(head == g * hp + i, g_lse, 0.0),
                           axis=-1, keepdims=True)
             p, ds, scale = _bwd_p_ds(q_i, k, v, do_i, lse_ref[0, i, rows, :],
-                                     delta, keep, exp2, dh)
+                                     delta, keep, dh)
             ds_lp = ds.astype(q.dtype)
             if dv_scr is not None:
                 # p^T @ do and ds^T @ q via dim-0 contractions (no
@@ -461,7 +448,7 @@ def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
     _tile_parts(qi, kb, block_q, block_k, T, causal, _part)
 
 
-def _flash_dq_kernel(*refs, block_q, block_k, T, causal, exp2, dh):
+def _flash_dq_kernel(*refs, block_q, block_k, T, causal, dh):
     """dq pass of the two-pass fallback: one (batch, head group,
     q-block, k-block) step; dq accumulates in VMEM. ``refs``: the seven
     inputs of _bwd_tile, dq_ref, dq_scr."""
@@ -477,7 +464,7 @@ def _flash_dq_kernel(*refs, block_q, block_k, T, causal, exp2, dh):
     def _add_dq(rows, dq):
         dq_scr[rows, :] += dq
 
-    _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
+    _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
               put_dq=_add_dq)
 
     @pl.when(kb == T // block_k - 1)
@@ -485,7 +472,7 @@ def _flash_dq_kernel(*refs, block_q, block_k, T, causal, exp2, dh):
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, exp2, dh):
+def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, dh):
     """One (batch, head group, k-block, q-block) step of a kv-major
     sweep: q blocks stream innermost, dk/dv accumulate in VMEM. All
     math stays q-major so no in-kernel transposes are needed
@@ -526,7 +513,7 @@ def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, exp2, dh):
             # without re-deriving s/p), each part to its own rows
             dqp_ref[0, 0, rows, :] = dq.astype(dqp_ref.dtype)
 
-    _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, exp2, dh,
+    _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
               put_dq, dk_scr, dv_scr)
 
     @pl.when(qi == T // block_q - 1)
@@ -541,13 +528,12 @@ def _flash_dkv_kernel(*refs, **kw):
     _flash_dkvdq_kernel(*refs[:-2], None, *refs[-2:], **kw)
 
 
-# merged-backward routing: ON, but only while the dq-partials slab
-# (dtype per _slab_dtype) stays affordable (it scales with n_kb; the
-# two-pass path has no such cost). Measured on v5e: 1.11x at n_kb=2
+# merged-backward routing (_flash_lse_bwd): only while the dq-partials
+# slab (dtype per _slab_dtype) stays affordable (it scales with n_kb;
+# the two-pass path has no such cost). Measured on v5e: 1.11x at n_kb=2
 # (flagship), 1.07x at n_kb=8; the win shrinks as partial traffic
 # grows, and very long T would need gigabytes of slab — cap the slab
 # bytes, not n_kb.
-_MERGED_BWD = [True]
 _MERGED_BWD_MAX_SLAB_BYTES = 512 * 1024 * 1024
 
 
@@ -562,7 +548,7 @@ def _slab_dtype(q_dtype):
 
 @functools.partial(jax.jit, static_argnames=_RAW_STATICS + ('merged',))
 def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
-                      block_k, interpret, exp2, merged):
+                      block_k, interpret, merged):
     """Blockwise backward on [B, T, H*dh] operands (lse [B, H, T, 1] as
     the forward wrote it): O(T) memory, never materialises the [T, T]
     score matrix (ADVICE r1: the old backward recomputed full attention
@@ -573,8 +559,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
     — because dlse/ds_ij = p_ij; dv is unaffected. It goes in as
     [B, T, H], B*H*T float32 being the one array here that changes its
     order: a program takes all H columns of its rows and picks its
-    own. ``merged``: the one-sweep backward, while its slab is
-    affordable."""
+    own. ``merged``: the one-sweep backward with its dq slab, else the
+    two passes (_flash_lse_bwd decides)."""
     B, T, HD = q.shape
     dh = HD // H
     hp = _lane_heads(H, dh)
@@ -584,7 +570,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
     operands = (q, k, v, do, o, lse,
                 g_lse.astype(jnp.float32).transpose(0, 2, 1))
     kernel_args = dict(block_q=block_q, block_k=block_k, T=T,
-                       causal=causal, exp2=exp2, dh=dh)
+                       causal=causal, dh=dh)
 
     def in_specs(q_at, k_at):
         heads = pl.BlockSpec((1, block_q, H),
@@ -605,16 +591,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
                  _wide(block_k, lanes, _outer)]
     dkv_shapes = [jax.ShapeDtypeStruct((B, T, HD), k.dtype),
                   jax.ShapeDtypeStruct((B, T, HD), v.dtype)]
-    slab_dtype = _slab_dtype(q.dtype)
-    slab_bytes = n_kb * B * T * HD * jnp.dtype(slab_dtype).itemsize
-    if merged and slab_bytes <= _MERGED_BWD_MAX_SLAB_BYTES:
+    if merged:
         dk, dv, dqp = pl.pallas_call(
             functools.partial(_flash_dkvdq_kernel, **kernel_args),
             out_specs=dkv_specs + [pl.BlockSpec(
                 (1, 1, block_q, lanes),
                 lambda b, g, j, i: (j, b, i, g))],
             out_shape=dkv_shapes + [
-                jax.ShapeDtypeStruct((n_kb, B, T, HD), slab_dtype)],
+                jax.ShapeDtypeStruct((n_kb, B, T, HD),
+                                     _slab_dtype(q.dtype))],
             name='_flash_dkvdq_kernel', **kv_major,
         )(*operands)
         dq = jnp.sum(dqp.astype(jnp.float32), axis=0).astype(q.dtype)
@@ -656,7 +641,7 @@ def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
     out, lse = _flash_pallas_call(
         q.reshape(flat), k.reshape(flat), v.reshape(flat), H=H,
         causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, exp2=_USE_EXP2[0])
+        interpret=interpret)
     out = out.reshape(q.shape)
     return (out, lse[..., 0]), (q, k, v, out, lse)
 
@@ -670,11 +655,14 @@ def _flash_lse_bwd(causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     B, T, H, D = q.shape
     flat = (B, T, H * D)
+    slab_bytes = (T // block_k) * B * T * H * D \
+        * jnp.dtype(_slab_dtype(q.dtype)).itemsize
     grads = _flash_bwd_pallas(
         q.reshape(flat), k.reshape(flat), v.reshape(flat),
         out.reshape(flat), lse, g_out.reshape(flat), g_lse, H=H,
         causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, exp2=_USE_EXP2[0], merged=_MERGED_BWD[0])
+        interpret=interpret,
+        merged=slab_bytes <= _MERGED_BWD_MAX_SLAB_BYTES)
     return tuple(x.reshape(q.shape) for x in grads)
 
 
@@ -706,7 +694,7 @@ _FLASH_MIN_ROWS = 64 * 1024  # B*H*T break-even (measured, v5e)
 
 
 def flash_attention(q, k, v, causal=True, block_q=None, block_k=None,
-                    interpret=None, force=None):
+                    interpret=None):
     """Blockwise attention. q,k,v: [B, T, H, D] -> [B, T, H, D].
 
     Forward and backward both run as Pallas kernels on TPU (or under
@@ -721,11 +709,10 @@ def flash_attention(q, k, v, causal=True, block_q=None, block_k=None,
     identical-math XLA reference runs instead.
     """
     return flash_attention_with_lse(q, k, v, causal, block_q, block_k,
-                                    interpret, force)[0]
+                                    interpret)[0]
 
 
-def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None,
-               causal=True):
+def flash_plan(q, block_q=None, block_k=None, interpret=None, causal=True):
     """THE engagement decision for q [B, T, H, D]: the (block_q, block_k)
     the Pallas kernels run with, or None where the XLA reference runs
     instead. flash_attention_with_lse routes by it; the flash_attention
@@ -756,10 +743,6 @@ def flash_plan(q, block_q=None, block_k=None, interpret=None, force=None,
     work = B * H * T
     use_pallas = interpret or (
         _on_tpu() and T >= _FLASH_MIN_T and work >= _FLASH_MIN_ROWS)
-    if force is not None and (interpret or _on_tpu()):
-        # benchmarking hook: measure the kernel on both sides of the
-        # engagement boundary (bench.py's engagement table)
-        use_pallas = force
     bq = _pick_block(T, block_q)
     bk = _pick_block(T, block_k)
     if not use_pallas or bq is None or bk is None \
@@ -781,7 +764,7 @@ def flash_diag(plan, causal=True):
 
 
 def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
-                             block_k=None, interpret=None, force=None):
+                             block_k=None, interpret=None):
     """flash_attention that also returns per-row logsumexp [B, H, T].
 
     This is the ring-attention building block: each device computes its
@@ -792,7 +775,7 @@ def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
     XLA reference (with lse) elsewhere."""
     if interpret is None:
         interpret = False
-    plan = flash_plan(q, block_q, block_k, interpret, force, causal)
+    plan = flash_plan(q, block_q, block_k, interpret, causal)
     if plan is None:
         return attention_reference_with_lse(q, k, v, causal)
     bq, bk = plan
@@ -882,9 +865,8 @@ _lstm_cell.defvjp(_lstm_cell_fwd, _lstm_cell_bwd)
 # vs 14.6 ms compute-bound) those extra round trips are the bill.
 #
 # Layout: NHWC internally (channels on the TPU lanes); the fused_conv
-# op kernel (compiler/passes.py) transposes at the boundary. Block/tile
-# sizes resolve through compiler/tuning.py::conv_schedule() — never
-# hardcoded here (tools/lint_repo.py ``hardcoded-schedule``).
+# op kernel (compiler/passes.py) transposes at the boundary. Block
+# sizes are the _FCONV_BLOCK_* constants below.
 #
 # Grid: (N, H-blocks, outchannel-blocks). 1x1 convs tile H cleanly
 # (input rows partition as bh-row blocks); KxK convs take the whole
@@ -1178,11 +1160,7 @@ def force_conv_epilogue(mode='interpret'):
 
 def conv_epilogue_mode():
     """The live engagement decision: False (exact replay), 'tpu', or
-    'interpret'. The tuned schedule's ``epilogue: off`` wins over
-    everything — it IS the measured decision."""
-    from ..compiler import tuning as _ctuning
-    if _ctuning.conv_schedule().get('epilogue') == 'off':
-        return False
+    'interpret'."""
     f = _FCONV_FORCE[0]
     if f is not None:
         return 'tpu' if f is True else f
@@ -1206,6 +1184,14 @@ def _pick_div(n, target, quantum=1):
 # smallest estimate it refused was 19.9 MiB (PERF.md, "Bring-up on the
 # chip").
 _FCONV_MAX_VMEM = 16 * 1024 * 1024
+
+# Block targets: output rows a grid step of a 1x1 conv, output channels
+# a step, and the lane quantum the channel block must be a multiple of
+# on the chip. Never swept on the chip: the only conv cell engages this
+# kernel 0 times (PERF.md section 3, conv_fuse_engaged).
+_FCONV_BLOCK_H = 8
+_FCONV_BLOCK_C = 256
+_FCONV_VECTOR_WIDTH = 128
 
 
 def _tiled_bytes(shape, dtype):
@@ -1256,7 +1242,6 @@ def fused_conv_epilogue(x, w, aux, aux_kinds, strides, paddings,
     the result is ``(y, psum [N, NH, Cout], psumsq)`` — f32 partial
     moments of the conv output for train-mode BN.
     """
-    from ..compiler import tuning as _ctuning
     if x.ndim != 4:
         return None, 'rank'
     if x.dtype not in (jnp.float32, jnp.bfloat16):
@@ -1270,12 +1255,11 @@ def fused_conv_epilogue(x, w, aux, aux_kinds, strides, paddings,
     wo = (W + 2 * pw - kw) // sw + 1
     if ho <= 0 or wo <= 0:
         return None, 'degenerate'
-    sched = _ctuning.conv_schedule()
-    quantum = int(sched['vector_width']) if not interpret else 1
-    bc = _pick_div(cout, int(sched['block_c']), quantum)
+    bc = _pick_div(cout, _FCONV_BLOCK_C,
+                   1 if interpret else _FCONV_VECTOR_WIDTH)
     if bc is None:
         return None, 'channel-align'
-    bh = _pick_div(ho, int(sched['block_h'])) if kh == 1 else ho
+    bh = _pick_div(ho, _FCONV_BLOCK_H) if kh == 1 else ho
     nh = ho // bh
     noc = cout // bc
     if not depthwise and not interpret and x.dtype == jnp.bfloat16 \

@@ -48,10 +48,6 @@ SITE_SERVING_PAD = 'serving/pad'          # bucket padding stage
 SITE_REMOTE_SEND = 'remote/send'          # client frame send
 SITE_REMOTE_RECV = 'remote/recv'          # client reader pull
 SITE_REMOTE_SPAWN = 'remote/spawn'        # spawn_cell provisioning
-# autotuner site (COMPILER.md "Schedule search"): fires per candidate
-# measurement, so a crashing/OOMing candidate is deterministically
-# testable — the sweep must poison the entry and continue
-SITE_TUNING_MEASURE = 'tuning/measure'    # per-candidate measurement
 
 
 class FaultInjected(IOError):

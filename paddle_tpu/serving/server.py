@@ -367,31 +367,13 @@ class ModelServer(object):
         return out, n
 
     # ---- warmup ----------------------------------------------------------
-    def warmup(self, model_name=None, upto=None, timeout=300.0,
-               autotune=False):
+    def warmup(self, model_name=None, upto=None, timeout=300.0):
         """Pre-compile every shape bucket (one synthetic request per
         bucket through the public path) so live traffic never pays a
         compile. Returns ``{model: [bucket sizes warmed]}``; models
-        whose feed shapes are dynamic (unsynthesizable) are skipped.
-
-        Before the first bucket compiles, the on-disk tuning cache
-        (COMPILER.md) is preloaded, so every warmup compile — and every
-        later live compile — runs under the autotuned per-shape configs
-        instead of re-deriving defaults: fast cold-start is the whole
-        point of paying the tuning search offline.
-
-        ``autotune=True`` additionally runs the measured schedule
-        search (:class:`~..compiler.tuning.Autotuner.tune_if_missing`)
-        for every model × bucket *before* that bucket's warmup compile
-        — only buckets with no cached entry for this device kind pay a
-        search, so the second warmup of a process (or any process that
-        preloaded a populated on-disk cache) does zero searches."""
-        from ..compiler import tuning as _ctuning
+        whose feed shapes are dynamic (unsynthesizable) are skipped."""
         from ..observability import perf as _perf
         t0 = time.monotonic()
-        tuned = _ctuning.default_cache().preload()
-        tuner = _ctuning.Autotuner() if autotune else None
-        searches = 0
         names = [model_name] if model_name is not None else self.models()
         warmed = {}
         # perf observatory: when this process is already observing
@@ -413,16 +395,6 @@ class ModelServer(object):
                     feed = model.synthetic_feed(bucket)
                     if feed is None:
                         break
-                    if tuner is not None:
-                        # the search runs (and donates state) on
-                        # model.scope: queued warmup batches of the
-                        # same scope must land first
-                        while pending:
-                            pending.pop().result(timeout=timeout)
-                        _, searched = tuner.tune_if_missing(
-                            model.program, feed, model.fetch_vars,
-                            scope=model.scope, name=name)
-                        searches += int(searched)
                     pending.append(
                         self.submit(name, feed, _warmup=True))
                     warmed[name].append(bucket)
@@ -432,8 +404,6 @@ class ModelServer(object):
         _obs.emit('serving_warmup',
                   models=len(warmed),
                   buckets=sum(len(v) for v in warmed.values()),
-                  tuning_entries=tuned,
-                  autotune_searches=searches,
                   perf_ledgers=len(_perf.book()) - _n_ledgers0,
                   dur_s=round(time.monotonic() - t0, 6))
         return warmed

@@ -471,7 +471,7 @@ class Trainer(object):
             # A 1-step chunk IS the step: no wrapper span — exe/run and
             # train/step hang off train/run directly, keeping the
             # default steps_per_dispatch=1 path inside the tracing
-            # overhead budget (bench.py bench_tracing_overhead)
+            # overhead budget
             cspan = _obs.start_span('train/chunk', steps=len(chunk),
                                     global_step=gs0) \
                 if len(chunk) > 1 else None

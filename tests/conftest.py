@@ -1,6 +1,6 @@
 """Test config: the CPU backend with 8 virtual devices, so multi-chip
 sharding paths are exercised without TPU hardware (SURVEY.md §4). The
-chip is reached only through chip_smoke.py and bench.py.
+chip is reached only through chip_smoke.py and benchmark/chip/run.py.
 """
 import os
 import re
@@ -52,8 +52,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         'markers',
         'compiler: tests of the paddle_tpu.compiler pass pipeline — '
-        'semantic equivalence, pass idempotence, cache keying, tuning '
-        'cache (tier-1; filter with -m "not compiler")')
+        'semantic equivalence, pass idempotence, cache keying '
+        '(tier-1; filter with -m "not compiler")')
     config.addinivalue_line(
         'markers',
         'partition: tests of the paddle_tpu.partition subsystem — '

@@ -111,7 +111,6 @@ def test_cache_dir_is_one_fixed_path_in_the_checkout(tmp_path):
 # ---- chip-only entry points -----------------------------------------------
 @pytest.mark.parametrize('argv', [
     ['chip_smoke.py'],
-    ['bench.py'],
     [os.path.join('benchmark', 'fluid', 'fluid_benchmark.py'),
      '--model', 'resnet', '--batch_size', '128', '--device', 'TPU'],
 ])

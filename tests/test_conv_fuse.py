@@ -13,12 +13,7 @@ fusion", PERF.md "Conv bandwidth"):
 - unsupported shapes (grouped non-depthwise convs) fall back COUNTED
   (``conv_fuse_fallbacks_total`` + a ``conv_fuse_fallback`` journal
   event naming the reason) and stay bit-exact — never silent, never
-  wrong;
-- the schedule autotuner poisons a crashed candidate and keeps
-  sweeping (seeded via faultinject ``SITE_TUNING_MEASURE``);
-- winners persist per device-kind and a second search is a cache hit
-  (``tune_if_missing``; ``ModelServer.warmup(autotune=True)`` does
-  zero searches the second time).
+  wrong.
 """
 import numpy as np
 import pytest
@@ -26,10 +21,8 @@ import pytest
 import paddle_tpu.fluid as fluid
 import paddle_tpu.compiler as compiler
 from paddle_tpu import observability as obs
-from paddle_tpu.compiler import tuning as ctuning
 from paddle_tpu.compiler.passes import FUSED_CONV_OP
 from paddle_tpu.ops import pallas_kernels as pk
-from paddle_tpu.resilience import faultinject as fi
 
 pytestmark = pytest.mark.compiler
 
@@ -38,16 +31,12 @@ TOL = 1e-5
 
 @pytest.fixture(autouse=True)
 def _compiler_defaults():
-    """Default config + throwaway tuning cache (never the developer's
-    ~/.cache file), same contract as test_compiler."""
-    prev_cache = ctuning.set_default_cache(
-        ctuning.TuningCache(path='/nonexistent/paddle-tpu-test-tuning'))
+    """Default pass configuration, same contract as test_compiler."""
     compiler.set_enabled(True)
     compiler.set_default_passes(None)
     yield
     compiler.set_enabled(True)
     compiler.set_default_passes(None)
-    ctuning.set_default_cache(prev_cache)
 
 
 def _op_types(program):
@@ -358,124 +347,3 @@ def test_grouped_conv_falls_back_counted_and_exact(tmp_path):
     assert len(events) == 1
     assert events[0]['reason'] == 'groups'
     assert 'conv2d' in events[0]['types']
-
-
-# ---- autotuner robustness -------------------------------------------------
-
-def _tiny_conv_program():
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 23
-    with fluid.program_guard(main, startup):
-        with fluid.unique_name.guard():
-            x = fluid.layers.data(name='x', shape=[2, 4, 4],
-                                  dtype='float32')
-            c = fluid.layers.conv2d(input=x, num_filters=2, filter_size=3,
-                                    padding=1, bias_attr=False)
-            out = fluid.layers.relu(c)
-    feed = {'x': np.random.RandomState(6).randn(
-        1, 2, 4, 4).astype('float32')}
-    return main, startup, out, feed
-
-
-@pytest.mark.faultinject
-def test_autotuner_poisons_crashed_candidate_and_continues(tmp_path):
-    main, startup, out, feed = _tiny_conv_program()
-    cache = ctuning.TuningCache(path=str(tmp_path / 't.json'))
-    tuner = ctuning.Autotuner(cache=cache, warmup=0, steps=1)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        fluid.Executor(fluid.CPUPlace()).run(startup)
-        journal = str(tmp_path / 'tune.jsonl')
-        with obs.journal(journal):
-            with fi.fault_plan() as plan:
-                plan.inject(fi.SITE_TUNING_MEASURE, at=[1])
-                best, report = tuner.tune(main, feed, [out.name],
-                                          scope=scope)
-    poisoned = [tok for tok, v in report.items()
-                if isinstance(v, str) and v.startswith('poisoned')]
-    assert len(poisoned) == 1, report
-    assert 'FaultInjected' in report[poisoned[0]]
-    # the sweep continued: every other candidate has a real timing,
-    # a winner was still picked and cached
-    assert all(isinstance(v, (int, float)) for tok, v in report.items()
-               if tok not in poisoned)
-    assert best and len(cache) == 1
-    # journalled: begin + one candidate_poisoned + end
-    records, _ = obs.read_journal(journal)
-    phases = [r.get('phase') for r in records if r['ev'] == 'autotune']
-    assert 'begin' in phases and 'end' in phases
-    assert phases.count('candidate_poisoned') == 1
-    ends = [r for r in records if r['ev'] == 'autotune'
-            and r.get('phase') == 'end']
-    assert ends[0]['poisoned'] == 1
-    assert ends[0]['candidates'] == len(report)
-
-
-# ---- persistence & warmup -------------------------------------------------
-
-def test_winner_persists_per_device_kind(tmp_path):
-    path = str(tmp_path / 'tuning.json')
-    cache = ctuning.TuningCache(path=path)
-    cache.put('fp', 'sig', ctuning.backend(),
-              {'conv_block_h': 16}, measured_ms=1.0)
-    # a fresh process (new cache object, same disk file) sees the
-    # winner — but only under the device kind that measured it
-    fresh = ctuning.TuningCache(path=path)
-    fresh.preload()
-    assert fresh.lookup('fp', 'sig', ctuning.backend()) == \
-        {'conv_block_h': 16}
-    assert fresh.lookup('fp', 'sig', 'tpu-v5e') is None
-
-
-def test_tune_if_missing_searches_once(tmp_path):
-    main, startup, out, feed = _tiny_conv_program()
-    cache = ctuning.TuningCache(path=str(tmp_path / 't.json'))
-    tuner = ctuning.Autotuner(cache=cache, warmup=0, steps=1)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        fluid.Executor(fluid.CPUPlace()).run(startup)
-        e1, searched1 = tuner.tune_if_missing(main, feed, [out.name],
-                                              scope=scope)
-        e2, searched2 = tuner.tune_if_missing(main, feed, [out.name],
-                                              scope=scope)
-    assert searched1 is True
-    assert searched2 is False       # second search is a cache hit
-    assert e2 == e1
-
-
-@pytest.mark.serving
-def test_warmup_autotune_second_pass_zero_searches(tmp_path):
-    """The acceptance pin: ``warmup(autotune=True)`` searches every
-    model x bucket once, persists the winners, and a second warmup —
-    same process or one that preloaded the on-disk cache — does ZERO
-    searches."""
-    prev = ctuning.set_default_cache(
-        ctuning.TuningCache(path=str(tmp_path / 'tuning.json')))
-    try:
-        main, startup, out, feed = _tiny_conv_program()
-        scope = fluid.Scope()
-        with fluid.scope_guard(scope):
-            fluid.Executor(fluid.CPUPlace()).run(startup)
-        journal = str(tmp_path / 'warm.jsonl')
-        with obs.journal(journal):
-            srv = fluid.ModelServer(max_batch_size=2)
-            try:
-                srv.register_model('m', main, ['x'], [out], scope)
-                warmed = srv.warmup(autotune=True)
-                assert warmed['m']
-                warmed2 = srv.warmup(autotune=True)
-                assert warmed2['m']
-            finally:
-                srv.close()
-        records, _ = obs.read_journal(journal)
-        warms = [r for r in records if r['ev'] == 'serving_warmup']
-        assert len(warms) == 2
-        assert warms[0]['autotune_searches'] == len(warmed['m'])
-        assert warms[1]['autotune_searches'] == 0
-        # and the searches really ran through the Autotuner (journal
-        # carries the completed sweeps -> obs_report's autotune gate)
-        ends = [r for r in records if r['ev'] == 'autotune'
-                and r.get('phase') == 'end']
-        assert len(ends) == len(warmed['m'])
-    finally:
-        ctuning.set_default_cache(prev)

@@ -192,47 +192,6 @@ def test_rule_blocking_socket_recv(tmp_path):
     assert not [x for x in v if x.rule == 'blocking-socket-recv']
 
 
-def test_rule_hardcoded_schedule(tmp_path):
-    """ISSUE 20 satellite: a literal block/tile assignment in
-    paddle_tpu/ops/ is a schedule the autotuner can never move; kernel
-    block sizes resolve through compiler.tuning (conv_schedule() /
-    apply_entry) or arrive as parameters. The two flash dtype-default
-    sites are allowlist-pinned, not invisible."""
-    src = ('block_h = 8\n'
-           'tile_n = 256 if fast else 128\n'
-           'block_c = 2 * 64\n')
-    p = tmp_path / 'mod.py'
-    p.write_text(src)
-    for rel, expect in [
-            (os.path.join('paddle_tpu', 'ops', 'pallas_kernels.py'), 3),
-            (os.path.join('paddle_tpu', 'ops', 'nn_ops.py'), 3),
-            (os.path.join('paddle_tpu', 'compiler', 'tuning.py'), 0),
-            ('tools/bench.py', 0)]:
-        v, _ = lint_repo.lint_file(str(p), rel)
-        hits = [x for x in v if x.rule == 'hardcoded-schedule']
-        assert len(hits) == expect, (rel, hits)
-    # tuned lookups, call results, parameter defaults, and non-schedule
-    # names are all clean
-    p.write_text("block_h = sched['block_h']\n"
-                 'block_c = _pick_div(c, target)\n'
-                 'block_q = block_q or 512\n'
-                 'batch = 8\n'
-                 'def f(block_q=512):\n    return block_q\n')
-    v, _ = lint_repo.lint_file(
-        str(p), os.path.join('paddle_tpu', 'ops', 'pallas_kernels.py'))
-    assert not [x for x in v if x.rule == 'hardcoded-schedule']
-    # the real tree's flash defaults are caught (then allowlisted)
-    real = os.path.join(REPO, 'paddle_tpu', 'ops', 'pallas_kernels.py')
-    v, _ = lint_repo.lint_file(
-        real, os.path.join('paddle_tpu', 'ops', 'pallas_kernels.py'))
-    hits = {x.detail for x in v if x.rule == 'hardcoded-schedule'}
-    assert hits == {
-        'block_q = (2048 if one_tile else 1024) if bf16 else 512',
-        'block_k = (2048 if one_tile else 1024) if bf16 else 1024'}
-    assert all(('hardcoded-schedule:paddle_tpu/ops/pallas_kernels.py:'
-                + d) in lint_repo.ALLOWLIST for d in hits)
-
-
 def test_rule_kv_alloc_outside_pool(tmp_path):
     """ISSUE 17 satellite: raw numpy KV buffers in serving/ or fleet/
     dodge the PagePool's kv_bytes accounting; only the kvcache package

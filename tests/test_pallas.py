@@ -1,6 +1,8 @@
 """Pallas kernels vs their XLA-fallback math (SURVEY.md §2.2: fused LSTM
 cell + flash attention). On CPU the Pallas path runs with interpret=True,
 so the kernel bodies themselves are exercised."""
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -155,14 +157,19 @@ def test_pallas_path_engages_for_transformer_shapes(monkeypatch):
         assert not fired
 
 
+def _take_two_pass(monkeypatch):
+    """Send every flash backward from here on down the two-pass route
+    the way a long sequence is sent: by a dq slab over the cap."""
+    monkeypatch.setattr(pk, '_MERGED_BWD_MAX_SLAB_BYTES', 0)
+
+
 @pytest.fixture
-def two_pass(request):
+def two_pass(request, monkeypatch):
     """The backward route a test asks for by ``request.param`` (True:
-    the two-pass fallback), the merged default handed back after."""
-    old = pk._MERGED_BWD[0]
-    pk._MERGED_BWD[0] = not request.param
-    yield request.param
-    pk._MERGED_BWD[0] = old
+    the two-pass fallback; False: merged, as under the cap)."""
+    if request.param:
+        _take_two_pass(monkeypatch)
+    return request.param
 
 
 @pytest.mark.parametrize('two_pass', [False, True], indirect=True,
@@ -332,7 +339,7 @@ def test_ring_attention_uses_flash_kernel(monkeypatch):
                     reason='Mosaic engagement is TPU-only')
 def test_flash_attention_engages_mosaic_at_bench_shapes():
     """VERDICT r2 #3: prove the Pallas path actually engages (no silent
-    XLA fallback) at the shapes bench.py measures."""
+    XLA fallback) at long sequences."""
     import numpy as np
     from paddle_tpu.ops import pallas_kernels as P
     # engagement starts at _FLASH_MIN_T=768 (r4: strictly above the
@@ -381,7 +388,8 @@ def test_flash_attention_layer_scaling():
     (2, 64, 128, 256, 256), (4, 64, 128, 256, 256), (1, 128, 128, 256, 256),
     (4, 64, 384, 384, 768), (1, 128, 384, 384, 1536)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_merged_backward_matches_two_pass(causal, H, D, bq, bk, T):
+def test_merged_backward_matches_two_pass(causal, H, D, bq, bk, T,
+                                          monkeypatch):
     """The merged dkv+dq-partials backward must produce the same grads
     as the two-pass path (it is the default under the slab cap), whole
     and chunked diagonal tiles alike: they share one tile body."""
@@ -390,24 +398,17 @@ def test_merged_backward_matches_two_pass(causal, H, D, bq, bk, T):
     k = jnp.asarray(rng.randn(1, T, H, D), jnp.float32) * 0.1
     v = jnp.asarray(rng.randn(1, T, H, D), jnp.float32) * 0.1
 
-    def grads(merged):
-        old = pk._MERGED_BWD[0]
-        pk._MERGED_BWD[0] = merged
-        try:
-            jax.clear_caches()
+    def grads():
+        def loss(q, k, v):
+            o = pk.flash_attention(q, k, v, causal=causal, block_q=bq,
+                                   block_k=bk, interpret=True)
+            return jnp.sum(o * 1e-2)
 
-            def loss(q, k, v):
-                o = pk.flash_attention(q, k, v, causal=causal,
-                                       force=True, block_q=bq,
-                                       block_k=bk, interpret=True)
-                return jnp.sum(o * 1e-2)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        finally:
-            pk._MERGED_BWD[0] = old
-
-    g_merged = grads(True)
-    g_two = grads(False)
+    g_merged = grads()
+    _take_two_pass(monkeypatch)
+    g_two = grads()
     for a, b in zip(g_merged, g_two):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-7)
@@ -415,7 +416,7 @@ def test_merged_backward_matches_two_pass(causal, H, D, bq, bk, T):
 
 @pytest.mark.parametrize('H,D', [(2, 64), (1, 128)])
 @pytest.mark.parametrize('causal', [True, False])
-def test_flash_lse_grads_float32(causal, H, D):
+def test_flash_lse_grads_float32(causal, H, D, monkeypatch):
     """Float32 forward, lse and the gradients of both, merged and
     two-pass, against the reference at 384-row blocks and T = 4 blocks:
     dead, full and (under ``causal``) chunked diagonal tiles in one
@@ -435,18 +436,15 @@ def test_flash_lse_grads_float32(causal, H, D):
         return outs + g
 
     want = grads(pk.attention_reference_with_lse)
-    old = pk._MERGED_BWD[0]
-    try:
-        for merged in (True, False):
-            pk._MERGED_BWD[0] = merged
-            got = grads(lambda q, k, v, causal: pk.flash_attention_with_lse(
-                q, k, v, causal=causal, block_q=384, block_k=384,
-                interpret=True))
-            for a, b in zip(got, want):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           rtol=2e-4, atol=2e-4)
-    finally:
-        pk._MERGED_BWD[0] = old
+    for two_pass in (False, True):
+        if two_pass:
+            _take_two_pass(monkeypatch)
+        got = grads(lambda q, k, v, causal: pk.flash_attention_with_lse(
+            q, k, v, causal=causal, block_q=384, block_k=384,
+            interpret=True))
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize('dtype,T,causal,plan,diag', [
@@ -495,6 +493,22 @@ def engage(monkeypatch):
 _FLASH_OP_B, _FLASH_OP_H, _FLASH_OP_DH = 2, 4, 64
 
 
+@contextlib.contextmanager
+def _plan_blocks(blocks):
+    """flash_plan under ``blocks`` = (block_q, block_k) where its caller
+    names none (None: its own): how a test gives the op, which names
+    none, more than one tile at a small T."""
+    with pytest.MonkeyPatch.context() as patch:
+        if blocks:
+            orig = pk.flash_plan
+            patch.setattr(
+                pk, 'flash_plan',
+                lambda q, block_q=None, block_k=None, interpret=None,
+                causal=True: orig(q, block_q or blocks[0],
+                                  block_k or blocks[1], interpret, causal))
+        yield
+
+
 def _flash_op_feed(T, seed=11, heads=_FLASH_OP_H):
     rng = np.random.RandomState(seed)
     shape = (_FLASH_OP_B, T, heads * _FLASH_OP_DH)
@@ -534,12 +548,10 @@ def _flash_op_run(T, blocks=None, lower_only=False, grads=True):
     """Run (or only lower) the one-op program: [out, dq, dk, dv] as
     numpy, or the lowered step's text."""
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.compiler import tuning
     main, startup, fetch = _flash_op_program(T, grads=grads)
     exe = fluid.Executor(fluid.CPUPlace())
-    entry = {'flash_block_q': blocks, 'flash_block_k': blocks} \
-        if blocks else None
-    with fluid.scope_guard(fluid.Scope()), tuning.apply_entry(entry):
+    with fluid.scope_guard(fluid.Scope()), \
+            _plan_blocks(blocks and (blocks, blocks)):
         exe.run(startup)
         if lower_only:
             return exe.lowered(main, feed=_flash_op_feed(T),
@@ -682,7 +694,6 @@ def test_flash_counts_name_the_diagonal_body(T, blocks, causal, route,
     flash_counts() keeps its (route, dtype) keys and sums over that
     label; flash_counts(by=('diag',)) reads it."""
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.compiler import tuning
     from paddle_tpu.compiler.passes import flash_counts
     amp.set_amp(False)
     x = jnp.zeros((_FLASH_OP_B, T, _FLASH_OP_H, _FLASH_OP_DH))
@@ -691,9 +702,7 @@ def test_flash_counts_name_the_diagonal_body(T, blocks, causal, route,
     assert pk.flash_diag(plan, causal) == diag
     main, startup, fetch = _flash_op_program(T, grads=False, causal=causal)
     exe = fluid.Executor(fluid.CPUPlace())
-    entry = dict(zip(('flash_block_q', 'flash_block_k'), blocks)) \
-        if blocks else None
-    with fluid.scope_guard(fluid.Scope()), tuning.apply_entry(entry):
+    with fluid.scope_guard(fluid.Scope()), _plan_blocks(blocks):
         exe.run(startup)
         before = flash_counts(), flash_counts(by=('diag',))
         exe.lowered(main, feed=_flash_op_feed(T), fetch_list=fetch)
@@ -701,6 +710,61 @@ def test_flash_counts_name_the_diagonal_body(T, blocks, causal, route,
     moved = [{key: n - was.get(key, 0) for key, n in now.items()
               if n != was.get(key, 0)} for was, now in zip(before, after)]
     assert moved == [{(route, 'f32'): 1}, {(diag,): 1}]
+
+
+@pytest.mark.parametrize('amp_on,T,causal,blocks', [
+    (True, 2048, True, (2048, 2048)), (True, 4096, True, (1024, 1024)),
+    (False, 2048, True, (512, 1024))],
+    ids=['bf16-T2048', 'bf16-T4096', 'f32'])
+def test_flash_op_lowers_with_flash_plans_blocks(amp_on, T, causal, blocks,
+                                                 amp, monkeypatch):
+    """The op names no blocks: what reaches the kernels is exactly
+    flash_plan's choice for the operands the attention runs in (bf16
+    under AMP), and nothing between the op and the kernels holds a
+    second one."""
+    import paddle_tpu.fluid as fluid
+    got = []
+
+    def kernels(q, k, v, causal, bq, bk, interpret):
+        got.append((q.dtype.name, bq, bk))
+        return pk.attention_reference_with_lse(q, k, v, causal)
+
+    monkeypatch.setattr(pk, '_on_tpu', lambda: True)
+    monkeypatch.setattr(pk, '_FLASH_MIN_ROWS', 0)
+    monkeypatch.setattr(pk, '_flash_lse', kernels)
+    amp.set_amp(amp_on)
+    main, startup, fetch = _flash_op_program(T, grads=False, causal=causal)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        exe.lowered(main, feed=_flash_op_feed(T), fetch_list=fetch)
+    dtype = 'bfloat16' if amp_on else 'float32'
+    q = jnp.zeros((_FLASH_OP_B, T, _FLASH_OP_H, _FLASH_OP_DH), dtype)
+    assert pk.flash_plan(q, causal=causal) == blocks
+    assert got == [(dtype,) + blocks]
+
+
+@pytest.mark.parametrize('T,kernels', [
+    (8192, ['_flash_dkvdq_kernel']),                    # 256 MiB of slab
+    (16384, ['_flash_dkv_kernel', '_flash_dq_kernel'])],  # 1 GiB
+    ids=['under-the-cap', 'over-the-cap'])
+def test_flash_backward_route_follows_the_slab(T, kernels, monkeypatch):
+    """The backward's route is decided from the dq slab's bytes and
+    nothing else: merged while n_kb * B * T * H * dh of the slab's
+    dtype is within _MERGED_BWD_MAX_SLAB_BYTES, the two passes beyond.
+    Read off the kernel names in the step lowered for the TPU at
+    H * dh = 2048 (nothing is compiled or run)."""
+    import re
+    monkeypatch.setattr(pk, '_on_tpu', lambda: True)
+    x = jax.ShapeDtypeStruct((1, T, 32, 64), jnp.bfloat16)
+
+    def backward(q, k, v, g):
+        return jax.vjp(pk.flash_attention, q, k, v)[1](g)
+
+    text = jax.jit(backward).trace(x, x, x, x).lower(
+        lowering_platforms=('tpu',)).as_text()
+    names = set(re.findall(r'kernel_name = "(\w+)"', text))
+    assert sorted(names - {'_flash_kernel'}) == kernels
 
 
 def _eqns(jaxpr):

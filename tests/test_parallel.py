@@ -339,7 +339,7 @@ def test_zero_slicing_byte_accounting_at_scale():
     expect = acc_bytes * (1 - 1.0 / 8)
     # XLA may pad buffers; require at least 90% of the expected saving
     assert saved > 0.9 * expect, (stats, expect)
-    # record the artifact for MULTICHIP/BENCH consumers
+    # record the artifact (ARCHITECTURE.md "ZeRO at scale")
     import json, os
     path = os.path.join(os.path.dirname(__file__), os.pardir,
                         'ZERO_BYTES.json')

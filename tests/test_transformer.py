@@ -142,61 +142,3 @@ def test_pipeline_stack_roundtrip():
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_allclose(a, b),
             params[k], back[k])
-
-
-def test_merge_shared_muls_pass():
-    """VERDICT r3 #6: same-input fc (mul) ops fuse into one wide
-    matmul at lowering — numerics identical, q/k/v become
-    concat -> mul -> split."""
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.core.lowering import (_merge_shared_muls,
-                                          MERGE_SHARED_MULS)
-
-    def build():
-        main, startup = fluid.Program(), fluid.Program()
-        main.random_seed = startup.random_seed = 9
-        with fluid.program_guard(main, startup):
-            x = fluid.layers.data(name='x', shape=[8, 16],
-                                  dtype='float32')
-            q = fluid.layers.fc(x, size=12, num_flatten_dims=2,
-                                bias_attr=False)
-            k = fluid.layers.fc(x, size=12, num_flatten_dims=2,
-                                bias_attr=False)
-            v = fluid.layers.fc(x, size=20, num_flatten_dims=2,
-                                bias_attr=False)
-            out = fluid.layers.concat([q, k, v], axis=2)
-            loss = fluid.layers.mean(out * out)
-            fluid.optimizer.SGD(learning_rate=0.01).minimize(loss)
-        return main, startup, loss
-
-    main, _, _ = build()
-    blk = main.global_block()
-    fwd = [op for op in blk.ops if op.type != 'backward_marker']
-    muls = [op for op in blk.ops if op.type == 'mul']
-    assert len(muls) == 3
-    merged = _merge_shared_muls(blk, list(blk.ops))
-    types = [op.type for op in merged]
-    assert types.count('mul') == 1
-    assert 'split' in types and 'concat' in types
-    split = [op for op in merged if op.type == 'split'][0]
-    assert split.attrs['sections'] == [12, 12, 20]
-
-    # numerics: identical losses with the pass on and off
-    feed = {'x': np.random.RandomState(3).randn(2, 8, 16)
-            .astype('float32')}
-
-    def run(enabled):
-        prev = MERGE_SHARED_MULS[0]
-        MERGE_SHARED_MULS[0] = enabled
-        try:
-            main, startup, loss = build()
-            exe = fluid.Executor(fluid.CPUPlace())
-            with fluid.scope_guard(fluid.Scope()):
-                exe.run(startup)
-                return [float(np.asarray(exe.run(
-                    main, feed=feed, fetch_list=[loss])[0]).mean())
-                    for _ in range(3)]
-        finally:
-            MERGE_SHARED_MULS[0] = prev
-
-    np.testing.assert_allclose(run(True), run(False), rtol=1e-5)

@@ -37,8 +37,8 @@ Rules (each encodes a convention the codebase actually relies on):
   ONE place (the perf observatory, OBSERVABILITY.md "Performance
   observatory") so key-spelling quirks (``'bytes accessed'``,
   list-wrapped results) and roofline constants never fork. New callers
-  go through ``observability.perf`` (``capture_compiled`` /
-  ``program_ledger``); ``Executor.cost_analysis`` is the one pinned
+  go through ``observability.perf`` (``capture_compiled``);
+  ``Executor.cost_analysis`` is the one pinned
   legacy entry point.
 - ``jit-on-warmup-path``: a direct ``jax.jit()``/``pjit()`` call in
   ``paddle_tpu/serving/`` or ``paddle_tpu/fleet/`` outside
@@ -62,15 +62,6 @@ Rules (each encodes a convention the codebase actually relies on):
   anywhere else can hang a fleet thread forever on a silent peer.
   Zero-argument ``.recv()`` (pipes/queues) is out of scope by
   construction.
-- ``hardcoded-schedule``: a Pallas block/tile size assigned from a
-  bare literal (``block_h = 8``, ``tile_n = 256 if ... else 128``)
-  inside ``paddle_tpu/ops/`` — kernel schedules are the autotuner's
-  search space (COMPILER.md "Schedule search"), so block/tile numbers
-  must resolve through ``compiler.tuning`` lookups
-  (``conv_schedule()`` / ``apply_entry`` overrides) or arrive as
-  function parameters; a literal baked into the kernel body is a
-  schedule the tuner can never move. The two flash-attention
-  dtype-default sites predate the tuner and are allowlist-pinned.
 - ``kv-alloc-outside-pool``: a raw numpy buffer allocation
   (``np.zeros``/``empty``/``full``/``ones``) bound to a KV-named
   target in ``paddle_tpu/serving/`` or ``paddle_tpu/fleet/`` — KV
@@ -120,11 +111,6 @@ TELEMETRY_SANCTIONED = os.path.join('paddle_tpu', 'observability',
 # accounting — a raw sized recv anywhere else is a thread that can
 # block forever on a partitioned peer
 RECV_SANCTIONED = os.path.join('paddle_tpu', 'multihost', 'remote.py')
-# the package whose block/tile assignments must come from the tuner:
-# a literal schedule constant in a kernel body is a knob the
-# autotuner (compiler/tuning.py) can never move
-SCHEDULE_PACKAGE = os.path.join('paddle_tpu', 'ops') + os.sep
-SCHEDULE_NAME_PREFIXES = ('block_', 'tile_')
 HTTP_SERVER_CLASSES = ('HTTPServer', 'ThreadingHTTPServer',
                        'BaseHTTPRequestHandler')
 
@@ -135,14 +121,6 @@ ALLOWLIST = frozenset({
     # body is the single pinned direct reader outside perf.py
     'direct-cost-analysis:paddle_tpu/executor.py:'
     'comp.cost_analysis()',
-    # flash-attention dtype defaults predate the schedule tuner; the
-    # tuner overrides them via apply_entry (flash_block_q/k knobs), so
-    # the literals are reachable-but-tunable. New kernels resolve
-    # schedules through compiler.tuning (conv_schedule()) instead.
-    'hardcoded-schedule:paddle_tpu/ops/pallas_kernels.py:'
-    'block_q = (2048 if one_tile else 1024) if bf16 else 512',
-    'hardcoded-schedule:paddle_tpu/ops/pallas_kernels.py:'
-    'block_k = (2048 if one_tile else 1024) if bf16 else 1024',
 })
 
 
@@ -286,28 +264,6 @@ def _span_name_consumed(scope, name, defining_call):
     return False
 
 
-def _literal_schedule_value(node):
-    """Is this value expression a bare schedule literal — an int
-    constant, possibly wrapped in arithmetic or a dtype-style ternary
-    (``1024 if q.dtype == bf16 else 512``)? Name lookups, dict reads
-    (``sched['block_h']``), and calls (``_pick_div(...)``) are how a
-    TUNED schedule arrives, so any of those makes the value clean."""
-    if isinstance(node, ast.Constant):
-        return isinstance(node.value, int) \
-            and not isinstance(node.value, bool)
-    if isinstance(node, ast.UnaryOp):
-        return _literal_schedule_value(node.operand)
-    if isinstance(node, ast.BinOp):
-        return _literal_schedule_value(node.left) \
-            and _literal_schedule_value(node.right)
-    if isinstance(node, ast.IfExp):
-        # the test may read anything (dtype checks); what matters is
-        # that every value the name can take is a baked-in literal
-        return _literal_schedule_value(node.body) \
-            and _literal_schedule_value(node.orelse)
-    return False
-
-
 def lint_file(path, relpath):
     with open(path) as f:
         source = f.read()
@@ -402,16 +358,6 @@ def lint_file(path, relpath):
                     'path must go through Executor.run so the '
                     'PTPU_AOT_CACHE store (fleet/coldstart.py) can '
                     'serve it' % _src(func)))
-        if isinstance(node, ast.Assign) \
-                and relpath.startswith(SCHEDULE_PACKAGE) \
-                and len(node.targets) == 1 \
-                and isinstance(node.targets[0], ast.Name) \
-                and node.targets[0].id.startswith(
-                    SCHEDULE_NAME_PREFIXES) \
-                and _literal_schedule_value(node.value):
-            out.append(Violation(
-                'hardcoded-schedule', relpath, node.lineno,
-                '%s = %s' % (node.targets[0].id, _src(node.value))))
         if isinstance(node, ast.Assign) \
                 and isinstance(node.value, ast.Call) \
                 and isinstance(node.value.func, ast.Attribute) \
@@ -508,7 +454,6 @@ def main(argv=None):
               'span-not-ended, direct-cost-analysis, '
               'jit-on-warmup-path, kv-alloc-outside-pool, '
               'http-outside-telemetry, blocking-socket-recv, '
-              'hardcoded-schedule (in paddle_tpu/ops/), '
               'dup-metric-name (across %s)'
               % '/'.join(METRIC_PACKAGES))
         return 0

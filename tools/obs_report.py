@@ -48,12 +48,10 @@ remote_elastic`` for a cross-host elastic run — ``fleet`` records
 must cover the whole remote replica lifecycle: a ``spawn_remote``,
 a ``host_lost`` detected inside its heartbeat window, an in-flight
 ``requeue`` and a scale-in ``retire`` (RESILIENCE.md "Cross-host
-elasticity"); ``--require autotune`` for a schedule-search run —
-``autotune`` records must include a completed (``phase='end'``)
-measured sweep (COMPILER.md "Schedule search"); ``--require any`` for
-presence only). Run ``--list-requires`` for the full machine-derived
-catalog — the argparse choices come straight from ``REQUIRED_EV``, so
-the list above can lag but the tool cannot.
+elasticity"); ``--require any`` for presence only). Run
+``--list-requires`` for the full machine-derived catalog — the argparse
+choices come straight from ``REQUIRED_EV``, so the list above can lag
+but the tool cannot.
 ``tools/serve_bench.py --smoke`` runs this gate over the journal its
 load run writes.
 """
@@ -122,11 +120,6 @@ REQUIRED_EV = {'step': 'step_end', 'serving': 'serving_batch',
                # host loss inside its window, the in-flight requeue,
                # and the scale-in retire back to the floor
                'remote_elastic': 'fleet',
-               # a schedule-search run must show completed autotune
-               # sweeps (COMPILER.md "Schedule search"); the gate
-               # further insists at least one search finished
-               # (phase='end') and measured a real candidate
-               'autotune': 'autotune',
                'any': None}
 
 # one-line purpose per family, keyed like REQUIRED_EV — rendered by
@@ -151,7 +144,6 @@ REQUIRE_DOC = {
     'telemetry': 'telemetry records incl. an aggregator scrape',
     'remote_elastic': 'fleet spawn_remote + in-window host_lost + '
                       'requeue + retire',
-    'autotune': 'autotune records incl. a completed measured search',
     'any': 'presence only (any well-formed journal passes)',
 }
 
@@ -202,8 +194,7 @@ def _pipeline_summary(steps, duration):
 
 def _compiler_summary(by_ev):
     """Compiler SLI (COMPILER.md): per-pass wall + rewrite counts from
-    ``compile_pass`` events, tuning-cache behavior from
-    ``tuning_lookup``/``tuning_preload``/``tuning_put``."""
+    ``compile_pass`` events."""
     passes = {}
     for r in by_ev.get('compile_pass', ()):
         p = passes.setdefault(r.get('pass', '?'), {
@@ -214,24 +205,11 @@ def _compiler_summary(by_ev):
         p['removed'] += r.get('removed', 0)
         p['fused'] += r.get('fused', 0)
         p['released'] += r.get('released', 0)
-    lookups = by_ev.get('tuning_lookup', ())
-    hits = sum(1 for r in lookups if r.get('hit'))
     return {
         'passes': passes,
         'pass_wall_s': sum(p['total_s'] for p in passes.values()),
         'ops_eliminated': sum(p['removed'] for p in passes.values()),
         'ops_fused': sum(p['fused'] for p in passes.values()),
-        'tuning': {
-            'lookups': len(lookups),
-            'hits': hits,
-            'misses': len(lookups) - hits,
-            'hit_rate': hits / len(lookups) if lookups else 0.0,
-            'preloads': len(by_ev.get('tuning_preload', ())),
-            'entries_preloaded': sum(
-                r.get('entries', 0)
-                for r in by_ev.get('tuning_preload', ())),
-            'puts': len(by_ev.get('tuning_put', ())),
-        },
     }
 
 
@@ -506,44 +484,16 @@ def _perf_summary(by_ev):
     }
 
 
-def _autotune_summary(by_ev):
-    """Schedule-search SLI (COMPILER.md "Schedule search"): completed
-    autotune sweeps per program (candidates measured, ledger-pruned,
-    poisoned, winner + best ms, search wall), plus the fused-conv
-    fallback ledger — every op the compiler fused but the lowering
-    replayed unfused, with the rejection reason."""
-    events = by_ev.get('autotune', ())
-    ends = [r for r in events if r.get('phase') == 'end']
-    searches = {}
-    for r in ends:
-        s = searches.setdefault(r.get('program', '?'), {
-            'searches': 0, 'candidates': 0, 'poisoned': 0,
-            'pruned': 0, 'seconds': 0.0, 'winner': None,
-            'best_ms': None})
-        s['searches'] += 1
-        s['candidates'] += r.get('candidates', 0)
-        s['poisoned'] += r.get('poisoned', 0)
-        s['pruned'] += r.get('pruned', 0)
-        s['seconds'] += r.get('seconds', 0.0)
-        s['winner'] = r.get('winner') or s['winner']
-        if r.get('best_ms') is not None:
-            s['best_ms'] = r['best_ms']
+def _conv_fuse_summary(by_ev):
+    """The fused-conv fallback ledger (COMPILER.md "Conv epilogue
+    fusion"): every op the compiler fused but the lowering replayed
+    unfused, with the rejection reason."""
     fallbacks = by_ev.get('conv_fuse_fallback', ())
     reasons = {}
     for r in fallbacks:
         reasons[r.get('reason', '?')] = \
             reasons.get(r.get('reason', '?'), 0) + 1
-    return {
-        'events': len(events),
-        'searches': len(ends),
-        'candidates': sum(r.get('candidates', 0) for r in ends),
-        'poisoned': sum(r.get('poisoned', 0) for r in ends),
-        'pruned': sum(r.get('pruned', 0) for r in ends),
-        'search_wall_s': sum(r.get('seconds', 0.0) for r in ends),
-        'by_program': searches,
-        'conv_fuse_fallbacks': len(fallbacks),
-        'conv_fuse_fallback_reasons': reasons,
-    }
+    return {'fallbacks': len(fallbacks), 'fallback_reasons': reasons}
 
 
 def summarize(records, malformed=0):
@@ -623,7 +573,7 @@ def summarize(records, malformed=0):
         'analysis': _analysis_summary(by_ev),
         'tracing': _tracing_summary(by_ev),
         'perf': _perf_summary(by_ev),
-        'autotune': _autotune_summary(by_ev),
+        'conv_fuse': _conv_fuse_summary(by_ev),
         'slowest_spans': [
             {'ev': r['ev'], 't': r.get('t'), 'dur_s': r['dur_s'],
              'detail': {k: v for k, v in r.items()
@@ -679,14 +629,6 @@ def render(summary, top=10):
                 'released=%d' % (name, p['runs'], p['total_s'] * 1e3,
                                  p['removed'], p['fused'],
                                  p['released']))
-        tu = co['tuning']
-        if tu['lookups'] or tu['preloads'] or tu['puts']:
-            lines.append(
-                'tuning:   %d lookups (%d hits, %.0f%% hit rate) | '
-                '%d preloads (%d entries), %d puts'
-                % (tu['lookups'], tu['hits'], 100.0 * tu['hit_rate'],
-                   tu['preloads'], tu['entries_preloaded'],
-                   tu['puts']))
     pa = s.get('partition') or {}
     if pa.get('events'):
         lines.append(
@@ -840,28 +782,13 @@ def render(summary, top=10):
                    (d['bytes_accessed'] or 0) / 1e6,
                    '%.4f' % mfu if mfu is not None else '-',
                    d.get('roofline') or '-'))
-    at = s.get('autotune') or {}
-    if at.get('searches') or at.get('conv_fuse_fallbacks'):
-        if at.get('searches'):
-            lines.append(
-                'autotune: %d search(es), %.3fs wall | %d candidates '
-                'measured (%d poisoned), %d ledger-pruned'
-                % (at['searches'], at['search_wall_s'],
-                   at['candidates'], at['poisoned'], at['pruned']))
-            for name, a in sorted(at['by_program'].items()):
-                win = ', '.join('%s=%s' % kv for kv in sorted(
-                    (a['winner'] or {}).items())) or 'baseline'
-                lines.append(
-                    '  %-20s %d search(es)  best %sms  winner: %s'
-                    % (name[:20], a['searches'],
-                       a['best_ms'] if a['best_ms'] is not None
-                       else '-', win))
-        if at.get('conv_fuse_fallbacks'):
-            lines.append(
-                'conv fallbacks: %d fused op(s) replayed unfused (%s)'
-                % (at['conv_fuse_fallbacks'],
-                   ', '.join('%s=%d' % kv for kv in sorted(
-                       at['conv_fuse_fallback_reasons'].items()))))
+    cf = s.get('conv_fuse') or {}
+    if cf.get('fallbacks'):
+        lines.append(
+            'conv fallbacks: %d fused op(s) replayed unfused (%s)'
+            % (cf['fallbacks'],
+               ', '.join('%s=%d' % kv for kv in sorted(
+                   cf['fallback_reasons'].items()))))
     if s['anomalies']:
         lines.append('anomaly:  %d guard trips' % s['anomalies'])
     lines.append('events:   %s' % ', '.join(
@@ -986,18 +913,6 @@ def check_journal(path, require='step'):
                     'detection leaned on an RPC failure, not the '
                     'monitor' % (r.get('host'), float(r['detect_s']),
                                  float(r['window_s'])))
-    if require == 'autotune':
-        ends = [r for r in records if r['ev'] == 'autotune'
-                and r.get('phase') == 'end']
-        if not ends:
-            problems.append(
-                'autotune journal shows no completed search '
-                '(phase=end) — a sweep began but never finished, or '
-                'only cache hits were journalled')
-        elif not any(r.get('candidates', 0) > 0 for r in ends):
-            problems.append(
-                'autotune journal shows completed searches but zero '
-                'measured candidates — the schedule space was empty')
     if require == 'multihost':
         # a host loss the monitor only noticed after its own heartbeat
         # window means detection is broken even if recovery worked

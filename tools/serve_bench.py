@@ -12,7 +12,8 @@ compile-cache behavior as JSON.
 (``tools/serve_baseline.json``), exiting nonzero on regression. The
 gate is deliberately wall-clock-light — CI boxes vary wildly — and
 anchors on the invariants instead: compiles bounded by the bucket
-count, zero shed/expired/failed under capacity, exact outputs, plus a
+count, zero shed/expired/failed under capacity, outputs equal to a
+serial run's within a float32 rounding step, plus a
 very conservative throughput floor.
 
     python tools/serve_bench.py                 # full load run
@@ -71,8 +72,12 @@ def _build_artifacts(workdir, n_models, seed0=7):
 
 
 def _reference_runners(dirs):
-    """Serial exact-output oracles, one per model, shared-lock
-    serialized (the oracle must stay literally serial)."""
+    """Serial output oracles, one per model, shared-lock serialized
+    (the oracle must stay literally serial). An oracle runs a request
+    at its own batch size, the server in its padded, coalesced bucket:
+    two executables, whose float32 dots XLA may sum in another order
+    (one ulp was observed, 1.8e-7 relative), so run_load compares them
+    within rtol 1e-6."""
     import paddle_tpu.fluid as fluid
     lock = threading.Lock()
     runners = {}
@@ -130,9 +135,10 @@ def run_load(n_models=1, n_threads=8, requests_per_thread=25,
                         if out.shape != (n, OUT_DIM):
                             raise AssertionError('bad shape %r'
                                                  % (out.shape,))
-                        if oracles is not None and not np.array_equal(
+                        if oracles is not None and not np.allclose(
                                 np.asarray(out),
-                                np.asarray(oracles[name](x))):
+                                np.asarray(oracles[name](x)),
+                                rtol=1e-6, atol=1e-7):
                             raise AssertionError(
                                 'output mismatch vs serial run')
                 except Exception as e:   # noqa: BLE001 — reported below
