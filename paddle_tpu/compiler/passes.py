@@ -28,7 +28,7 @@ __all__ = ['DeadOpElimination', 'ConstantFolding', 'ElementwiseFusion',
            'ConvEpilogueFusion', 'BufferReuse', 'BatchNormFolding',
            'DEFAULT_PASSES', 'INFERENCE_PASSES', 'RNG_OPS',
            'FUSED_ELEMENTWISE_OP', 'FUSED_CONV_OP',
-           'conv_fuse_counts', 'flash_counts']
+           'conv_fuse_counts', 'flash_counts', 'amp_elementwise_counts']
 
 # Ops that consume the threaded PRNG key: removing one would shift the
 # RNG stream of every later stochastic op, silently changing numerics —
@@ -849,6 +849,17 @@ def conv_fuse_counts():
                       if s['value']}}
 
 
+def _label_counts(name, by):
+    """``{labels named by ``by``: n}`` of one counter, summed over its
+    other labels; series still at 0 are left out."""
+    counts = {}
+    for s in _series(name):
+        if s['value']:
+            key = tuple(s['labels'][label] for label in by)
+            counts[key] = counts.get(key, 0) + int(s['value'])
+    return counts
+
+
 def flash_counts(by=('route', 'dtype')):
     """``{(route, dtype): n}``: how the process's flash_attention op
     lowerings went so far (ops/misc_ops.py; counted per trace, as the
@@ -858,12 +869,18 @@ def flash_counts(by=('route', 'dtype')):
     is the body the kernels give a tile on the causal diagonal
     (pallas_kernels.flash_diag: 'chunked<r>', 'whole', or 'none' for
     the xla route or no mask)."""
-    counts = {}
-    for s in _series('flash_attention_lowerings_total'):
-        if s['value']:
-            key = tuple(s['labels'][name] for name in by)
-            counts[key] = counts.get(key, 0) + int(s['value'])
-    return counts
+    return _label_counts('flash_attention_lowerings_total', by)
+
+
+def amp_elementwise_counts(by=('result',)):
+    """``{(result,): n}``: the process's binary elementwise lowerings
+    that met one bf16 and one f32 operand under bf16 activation flow
+    (ops/math_ops.py::_amp_flow; counted per trace, as the other two
+    are). result is 'kept_bf16' (a bf16 X against a broadcast f32 Y
+    returned to bf16) or 'widened_f32' (the f32 stream stayed f32);
+    lowerings with no such pair count nothing. ``by`` may name 'op'
+    (the elementwise_* type) as well."""
+    return _label_counts('amp_elementwise_lowerings_total', by)
 
 
 @register_pass

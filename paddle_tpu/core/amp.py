@@ -66,7 +66,25 @@ def act_bf16():
     activation bytes is the single biggest lever (measured r3: 69 ->
     ~50 ms/step). f32 master weights, f32 BN/moving stats, f32 losses
     and optimizer state are unchanged. PADDLE_TPU_AMP_ACT=f32 restores
-    the r2 behavior."""
+    the r2 behavior.
+
+    The rule, stated here once. Ops that RETURN TO THE INPUT DTYPE, so a
+    bf16 activation stays bf16 through them whatever float32 math they
+    do inside: ``mxu_compute`` (mul, matmul, conv2d: bf16 out),
+    ``batch_norm`` and ``layer_norm`` (f32 statistics and affine, ops/
+    nn_ops.py), ``flash_attention`` (through ``mxu_compute``), and the
+    binary ``elementwise_*`` ops where a bf16 X meets an f32 Y that
+    broadcasts (not of X's own shape: an fc's bias, a per-channel
+    scale; ops/math_ops.py::_amp_flow). Unary activations keep their
+    input's dtype by themselves. What KEEPS A STREAM FLOAT32 is an f32
+    operand of the stream's own shape: an f32 X stays f32 whatever Y
+    is, and a bf16 X against an f32 Y shaped like itself widens, which
+    is the residual stream (x + branch(x), x float32 from the
+    embedding). The rule reads the two operands' dtypes and shapes and
+    nothing else (not their element counts: a batch of one, [1, H]
+    against a bias [H], must not widen where a batch of two does not);
+    ``compiler.passes.amp_elementwise_counts()`` says how often each
+    side of it was taken."""
     mode = _STATE.get('act')
     if mode is None:
         env = os.environ.get('PADDLE_TPU_AMP_ACT', 'bf16').lower()

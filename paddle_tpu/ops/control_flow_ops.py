@@ -96,7 +96,11 @@ def _write_to_array(ctx):
                    arr['buf'].shape[0])
         pad = [(0, grow)] + [(0, 0)] * (arr['buf'].ndim - 1)
         arr = make_array(jnp.pad(arr['buf'], pad), arr['len'])
-    buf = jax.lax.dynamic_update_index_in_dim(arr['buf'], x, i, 0)
+    # an array holds one dtype, its first write's: a later element that
+    # arrives narrower (a bf16 activation under AMP into a float32 array)
+    # is stored in the array's
+    buf = jax.lax.dynamic_update_index_in_dim(
+        arr['buf'], x.astype(arr['buf'].dtype), i, 0)
     ctx.env[name] = make_array(buf, jnp.maximum(arr['len'], i + 1))
 
 
@@ -258,6 +262,20 @@ def _written_names(block):
     return names
 
 
+def _carried(new, entered):
+    """A loop-carried value in the float dtype it entered the loop with:
+    lax loops want one type per carry, and under AMP an update may arrive
+    narrower than the state it replaces (a bf16 fc output assigned to a
+    float32 memory)."""
+    def keep(a, b):
+        da, db = jnp.result_type(a), jnp.result_type(b)
+        if da != db and jnp.issubdtype(da, jnp.floating) \
+                and jnp.issubdtype(db, jnp.floating):
+            return jnp.asarray(a).astype(db)
+        return a
+    return jax.tree_util.tree_map(keep, new, entered)
+
+
 def _run_sub_block(block, env, grad_mode, dynamic=False):
     runner = BlockRunner(block, grad_mode=grad_mode, dynamic=dynamic)
     runner.run_ops(list(block.ops), env)
@@ -309,7 +327,7 @@ def _while(ctx):
         benv = dict(base_env)
         benv.update(carry)
         _run_sub_block(block, benv, grad_mode)
-        return {n: benv[n] for n in carry_names}
+        return {n: _carried(benv[n], init[n]) for n in carry_names}
 
     init = {n: env[n] for n in carry_names}
     final = jax.lax.while_loop(cond_fn, body_fn, init)
@@ -391,7 +409,8 @@ def _static_rnn(ctx):
         for n, x in zip(step_in_names, x_t):
             benv[n] = x
         _run_sub_block(block, benv, grad_mode)
-        new_carry = {p: benv[m] for p, m in zip(pre_mems, mems)}
+        new_carry = {p: _carried(benv[m], carry0[p])
+                     for p, m in zip(pre_mems, mems)}
         if has_rng:
             new_carry[RNG_KEY] = benv[RNG_KEY]
         ys = [benv[o] for o in step_out_names]

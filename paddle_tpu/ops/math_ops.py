@@ -8,11 +8,40 @@ scheduling).
 import jax
 import jax.numpy as jnp
 
+from .. import observability as _obs
+from ..core import amp
 from ..core.registry import register_kernel
 from .common import unwrap, rewrap, seq_of, bcast_y
 
 
 # ---- elementwise binary ---------------------------------------------------------
+def _amp_flow(op, x, y, out, y_broadcasts):
+    """AMP's activation rule (core/amp.py::act_bf16) where type promotion
+    widened a bf16/f32 pair of operands to f32: a bf16 X whose f32 Y
+    broadcasts (a bias, a per-channel scale: not of X's own shape) was
+    computed in f32 and returns to X's dtype, as batch_norm and
+    layer_norm do, so the cotangent that comes back is bf16 too; an
+    f32 X, or an f32 Y of X's own shape (the residual stream), keeps the
+    f32 result. Each such lowering counts once per trace in
+    ``amp_elementwise_lowerings_total{op=, result=}``
+    (compiler/passes.py::amp_elementwise_counts)."""
+    if (out.dtype != jnp.float32
+            or {x.dtype, y.dtype} != {jnp.dtype(jnp.bfloat16),
+                                      jnp.dtype(jnp.float32)}
+            or not amp.act_bf16()):
+        return out
+    keep = x.dtype == jnp.bfloat16 and y_broadcasts
+    _obs.default_registry().counter(
+        'amp_elementwise_lowerings_total',
+        help='binary elementwise lowerings under bf16 activation flow '
+             'that met one bf16 and one f32 operand, by op and result: '
+             'kept_bf16 (bf16 X, broadcast f32 Y: computed in f32, '
+             'returned to bf16) / widened_f32 (an f32 X, or an f32 Y '
+             'shaped like X: the f32 stream stays f32)',
+        op=op, result='kept_bf16' if keep else 'widened_f32').inc()
+    return out.astype(x.dtype) if keep else out
+
+
 def _elementwise(name, fn):
     @register_kernel(name)
     def _k(ctx, fn=fn):
@@ -39,10 +68,13 @@ def _elementwise(name, fn):
             yd = jnp.asarray(yd).reshape(
                 (xd.shape[0], xd.shape[1]) + (1,) * (xd.ndim - 2))
             axis = -1
+        xd = jnp.asarray(xd)
+        y_broadcasts = jnp.shape(yd) != xd.shape
         yd = bcast_y(xd, yd, axis)
-        out = fn(jnp.asarray(xd), yd)
+        out = fn(xd, yd)
         if ctx.attr('scale', None) not in (None, 1.0):
             out = out * ctx.attr('scale')
+        out = _amp_flow(name, xd, yd, out, y_broadcasts)
         ctx.set_output('Out', rewrap(tmpl, out) if tmpl is not None else out)
 
 

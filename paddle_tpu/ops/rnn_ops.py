@@ -34,6 +34,15 @@ _ACT = {
 }
 
 
+def _projected(st, w):
+    """The pre-projected input [B, T, nH] in the dtype the recurrence
+    computes in, its weights': a bf16 projection (an fc under AMP's bf16
+    activation flow against f32 master weights) widens once here, so the
+    scan's carry and its update have one dtype."""
+    x = jnp.asarray(st.data)
+    return x.astype(jnp.result_type(x.dtype, w.dtype))
+
+
 def _mask_t(lengths, T, dtype):
     """[T, B, 1] time-major step mask."""
     return (jnp.arange(T)[:, None] <
@@ -94,8 +103,8 @@ def _dynamic_lstm(ctx):
     st = ctx.input('Input')
     if not isinstance(st, SequenceTensor):
         raise TypeError("dynamic_lstm needs a SequenceTensor input")
-    x = jnp.asarray(st.data)                      # [B, T, 4H]
     w = jnp.asarray(unwrap(ctx.input('Weight')))  # [H, 4H]
+    x = _projected(st, w)                         # [B, T, 4H]
     b = jnp.asarray(unwrap(ctx.input('Bias')))    # [1, 4H] or [1, 7H]
     H = w.shape[0]
     use_peep = bool(ctx.attr('use_peepholes', True)) and b.shape[-1] == 7 * H
@@ -127,8 +136,8 @@ def _dynamic_lstm(ctx):
 @register_kernel('dynamic_lstmp')
 def _dynamic_lstmp(ctx):
     st = ctx.input('Input')
-    x = jnp.asarray(st.data)                          # [B, T, 4H]
     w = jnp.asarray(unwrap(ctx.input('Weight')))      # [P, 4H]
+    x = _projected(st, w)                             # [B, T, 4H]
     wp = jnp.asarray(unwrap(ctx.input('ProjWeight')))  # [H, P]
     b = jnp.asarray(unwrap(ctx.input('Bias')))
     H, P = wp.shape
@@ -158,8 +167,8 @@ def _dynamic_lstmp(ctx):
 @register_kernel('dynamic_gru')
 def _dynamic_gru(ctx):
     st = ctx.input('Input')
-    x = jnp.asarray(st.data)                      # [B, T, 3H]
     w = jnp.asarray(unwrap(ctx.input('Weight')))  # [H, 3H]
+    x = _projected(st, w)                         # [B, T, 3H]
     b = jnp.asarray(unwrap(ctx.input('Bias'))) if ctx.has_input('Bias') \
         else 0.0
     H = w.shape[0]

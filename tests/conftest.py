@@ -5,6 +5,8 @@ chip is reached only through chip_smoke.py and benchmark/chip/run.py.
 import os
 import re
 
+import pytest
+
 os.environ['JAX_PLATFORMS'] = 'cpu'
 # Tests compile what they test: a persistent-cache hit from an earlier
 # run would hide a program that no longer compiles. Set in the
@@ -22,6 +24,17 @@ import jax  # noqa: E402
 
 if _m is None:
     jax.config.update('jax_num_cpu_devices', _n)
+
+
+@pytest.fixture
+def amp():
+    """core.amp with its state handed back as it was found, so no other
+    test sees AMP on."""
+    from paddle_tpu.core import amp as _amp
+    saved = dict(_amp._STATE)
+    yield _amp
+    _amp._STATE.clear()
+    _amp._STATE.update(saved)
 
 
 def pytest_configure(config):

@@ -467,17 +467,6 @@ def test_flash_plan_default_blocks(dtype, T, causal, plan, diag):
 
 # ---- the flash_attention op on AMP's MXU path -------------------------------
 @pytest.fixture
-def amp():
-    """core.amp with its state handed back as it was found, so no other
-    test sees AMP on."""
-    from paddle_tpu.core import amp as _amp
-    saved = dict(_amp._STATE)
-    yield _amp
-    _amp._STATE.clear()
-    _amp._STATE.update(saved)
-
-
-@pytest.fixture
 def engage(monkeypatch):
     """The op's engaged route on the CPU: the engagement rule sees a TPU
     and no row floor, and the kernels run in the Pallas interpreter."""
