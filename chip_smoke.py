@@ -18,7 +18,10 @@ process at a time; this parent never touches JAX.
    step each of the transformer, the stacked LSTM (as benchmarked, and
    without peepholes so that the fused cell engages) and SE-ResNeXt-50
    through the Executor, with the Mosaic custom calls counted in the
-   lowered step; and, when four devices are visible, ResNet-50 through
+   lowered step; six Adam steps of the tiny hybrid stack (Mamba-2
+   mixer, routed experts, grouped-query attention) under AMP, its
+   scan state and router scores float32 in the lowered step; and,
+   when four devices are visible, ResNet-50 through
    ``ParallelExecutor``.
 2. ``--phase cache`` — a second process compiles the same ResNet-50
    step and must get it from the persistent compile cache.
@@ -459,6 +462,60 @@ def leg_model_step(rehearse, name, batch, kwargs, engages):
     return {'loss': value, 'mosaic_calls': mosaic, 'conv_fuse': fuse}
 
 
+# ---- leg 5b: the tiny hybrid (Mamba-2 / experts / GQA) under AMP ----------
+def leg_hybrid(rehearse, steps=6):
+    """A few Adam steps of the tiny hybrid stack the benchmark's tests
+    use (benchmark/chip/tests/tiny_nemotron: pattern ME*E, 2 groups, 4
+    query heads on 2 KV heads, 8 of 16 experts held) through the
+    Executor under the backend's own AMP: the loss falls, and in the
+    lowered step the scan's carried state and the router's scores are
+    float32 whatever the stream's dtype."""
+    import importlib.util
+    import re
+    import jax
+    import numpy as np
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.core import amp
+    chip = os.path.join(_HERE, 'benchmark', 'chip')
+    spec = importlib.util.spec_from_file_location(
+        'chip_models_nemotron_h',
+        os.path.join(chip, 'models', 'nemotron_h.py'))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+    with open(os.path.join(chip, 'tests', 'tiny_nemotron', 'configs',
+                           'nemotron3-super-120b-a12b.json')) as f:
+        cfg = json.load(f)
+    cfg['optimizer'] = dict(cfg['optimizer'], learning_rate=3e-3)
+    traffic = {'batch': 2, 'seq_len': 200}
+    say('[hybrid/executor] pattern %s, %d x %d tokens, AMP %s'
+        % (cfg['hybrid_override_pattern'], traffic['batch'],
+           traffic['seq_len'], 'on' if amp.amp_enabled() else 'off'))
+    built = model.build(cfg, traffic)
+    feed = {k: np.asarray(v) for k, v in model.draw_batch(
+        cfg, traffic, jax.random.PRNGKey(0)).items()}
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(_place(fluid, rehearse))
+        exe.run(built['startup'])
+        text = exe.lowered(built['main'], feed, [built['loss']]).as_text()
+        losses = [float(np.ravel(exe.run(
+            built['main'], feed=feed, fetch_list=[built['loss']])[0])[0])
+            for _ in range(steps)]
+    say('  losses: ' + ' '.join('%.4f' % v for v in losses))
+    check(_all_finite(losses) and losses[-1] < losses[0],
+          'losses finite and falling over %d steps' % steps)
+    # [.., P, N] = [.., 8, 16]: the state a scan's loop carries
+    states = re.findall(r'tensor<[0-9x]*x8x16x(\w+)>', ' '.join(
+        re.findall(r'stablehlo\.while.*', text)))
+    check(states and set(states) == {'f32'},
+          'the scan\'s carried state is float32 (%d carries)' % len(states))
+    scores = re.findall(r'chlo\.top_k.*: tensor<[0-9x]*x(\w+)>', text)
+    check(scores and set(scores) == {'f32'},
+          'the router chooses over float32 scores')
+    mosaic = text.count('tpu_custom_call')
+    say('  %d Mosaic custom call(s) in the lowered step' % mosaic)
+    return {'losses': losses, 'mosaic_calls': mosaic}
+
+
 # ---- leg 6: four chips, one process --------------------------------------
 def leg_four_chips(cfg, one_chip_losses):
     import jax
@@ -583,6 +640,7 @@ def phase_main(rehearse):
     # checks the kernel itself)
     result['se_resnext'] = leg_model_step(
         rehearse, 'se_resnext', cfg['resnet_batch'], {}, None)
+    result['hybrid'] = leg_hybrid(rehearse)
     if rehearse:
         say('[resnet50/parallel_executor] not rehearsed')
     else:

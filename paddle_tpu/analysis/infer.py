@@ -139,6 +139,9 @@ _IDENTITY_SLOTS = {
     'dropout': ('X', ('Out', 'Mask')),
     'batch_norm': ('X', ('Y',)),
     'layer_norm': ('X', ('Y',)),
+    'rms_norm': ('X', ('Y',)),
+    'causal_conv1d': ('X', ('Out',)),
+    'ssd_scan': ('X', ('Out',)),
     'assign': ('X', ('Out',)),
     'relu_grad': ('X', ('Out',)),
     'softmax_with_cross_entropy': ('Logits', ('Softmax',)),
@@ -172,6 +175,7 @@ def _cast(op, env, emit):
 
 
 @register_shape('softmax', 'dropout', 'batch_norm', 'layer_norm',
+                'rms_norm', 'causal_conv1d', 'ssd_scan',
                 'assign', 'zero_reduce_scatter',
                 'softmax_with_cross_entropy')
 def _identity(op, env, emit):
@@ -505,6 +509,78 @@ def _lookup_table(op, env, emit):
     base = ids.shape[:-1] if (len(ids.shape) and
                               ids.shape[-1] == 1) else ids.shape
     return {out: VarInfo(tuple(base) + (w.shape[1],), w.dtype)}
+
+
+def _last_dim_is(op, slot, info, want, what, emit):
+    """Error where ``slot``'s last dim is known and is not ``want``."""
+    if info is None or info.shape is None or not len(info.shape) \
+            or info.shape[-1] is None or want is None:
+        return
+    if int(info.shape[-1]) != int(want):
+        emit('rank-mismatch', ERROR,
+             "%s %s's last dim is %s but %s is %d"
+             % (op.type, slot, info.shape[-1], what, want),
+             [_first(op, slot)])
+
+
+@register_shape('flash_attention')
+def _flash_attention(op, env, emit):
+    """Out mirrors Q; K and V hold num_kv_heads heads of Q's head
+    size."""
+    q = env(_first(op, 'Q'))
+    out = _out(op)
+    if out is None or q is None:
+        return {}
+    heads = int(op.attrs.get('num_heads', 1))
+    kv_heads = int(op.attrs.get('num_kv_heads', 0) or heads)
+    dh = int(op.attrs.get('head_dim', 0) or 0)
+    if not dh and q.shape is not None and len(q.shape) \
+            and q.shape[-1] is not None:
+        dh = int(q.shape[-1]) // heads
+    if dh:
+        _last_dim_is(op, 'Q', q, heads * dh, 'num_heads * head size',
+                     emit)
+        for slot in ('K', 'V'):
+            _last_dim_is(op, slot, env(_first(op, slot)), kv_heads * dh,
+                         'num_kv_heads * head size', emit)
+    return {out: VarInfo(q.shape, q.dtype)}
+
+
+@register_shape('router_scores')
+def _router_scores(op, env, emit):
+    x, w = env(_first(op, 'X')), env(_first(op, 'W'))
+    out = _out(op)
+    if out is None or x is None or w is None \
+            or x.shape is None or w.shape is None or len(w.shape) != 2:
+        return {}
+    _last_dim_is(op, 'X', x, w.shape[0], "W's rows", emit)
+    return {out: VarInfo(tuple(x.shape[:-1]) + (w.shape[1],), 'float32')}
+
+
+@register_shape('routed_experts')
+def _routed_experts(op, env, emit):
+    """Out mirrors X; Scores is num_experts wide; W1 [held, D, F] and
+    W2 [held, F, D] agree with X's width and with each other."""
+    x = env(_first(op, 'X'))
+    out = _out(op)
+    if out is None or x is None:
+        return {}
+    held = int(op.attrs.get('held', 0))
+    _last_dim_is(op, 'Scores', env(_first(op, 'Scores')),
+                 int(op.attrs.get('num_experts', 0)) or None,
+                 'num_experts', emit)
+    w1, w2 = env(_first(op, 'W1')), env(_first(op, 'W2'))
+    if w1 is not None and w2 is not None and w1.shape is not None \
+            and w2.shape is not None and len(w1.shape) == 3 \
+            and len(w2.shape) == 3:
+        _last_dim_is(op, 'X', x, w1.shape[1], "W1's rows", emit)
+        _last_dim_is(op, 'W1', w1, w2.shape[1], "W2's rows", emit)
+        _last_dim_is(op, 'W2', w2, w1.shape[1], "W1's rows", emit)
+    updates = {out: VarInfo(x.shape, x.dtype)}
+    tokens = _out(op, 'TokensPerExpert')
+    if tokens is not None:
+        updates[tokens] = VarInfo((held,), 'int32')
+    return updates
 
 
 @register_shape('cross_entropy')

@@ -28,7 +28,8 @@ __all__ = ['DeadOpElimination', 'ConstantFolding', 'ElementwiseFusion',
            'ConvEpilogueFusion', 'BufferReuse', 'BatchNormFolding',
            'DEFAULT_PASSES', 'INFERENCE_PASSES', 'RNG_OPS',
            'FUSED_ELEMENTWISE_OP', 'FUSED_CONV_OP',
-           'conv_fuse_counts', 'flash_counts', 'amp_elementwise_counts']
+           'conv_fuse_counts', 'flash_counts', 'amp_elementwise_counts',
+           'moe_counts', 'ssd_counts']
 
 # Ops that consume the threaded PRNG key: removing one would shift the
 # RNG stream of every later stochastic op, silently changing numerics —
@@ -868,8 +869,25 @@ def flash_counts(by=('route', 'dtype')):
     the labels of the key, the counter summed over the others: 'diag'
     is the body the kernels give a tile on the causal diagonal
     (pallas_kernels.flash_diag: 'chunked<r>', 'whole', or 'none' for
-    the xla route or no mask)."""
+    the xla route or no mask), 'kv_heads' the KV heads the query heads
+    share (num_heads where every query head has its own)."""
     return _label_counts('flash_attention_lowerings_total', by)
+
+
+def moe_counts(by=('experts', 'held', 'top_k', 'route')):
+    """``{(experts, held, top_k, route): n}``: the process's
+    routed_experts op lowerings so far (ops/hybrid_ops.py; counted per
+    trace, as the others are): the experts routed over, the experts
+    held here, the experts a token takes (strings, as labels are) and
+    the grouped product ('ragged_dot')."""
+    return _label_counts('moe_lowerings_total', by)
+
+
+def ssd_counts(by=('route', 'chunk')):
+    """``{(route, chunk): n}``: the process's ssd_scan op lowerings so
+    far (ops/hybrid_ops.py): route 'xla' (the chunked form in
+    jax.numpy; no Pallas scan yet) and the chunk length."""
+    return _label_counts('ssd_lowerings_total', by)
 
 
 def amp_elementwise_counts(by=('result',)):

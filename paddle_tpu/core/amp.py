@@ -84,7 +84,18 @@ def act_bf16():
     nothing else (not their element counts: a batch of one, [1, H]
     against a bias [H], must not widen where a batch of two does not);
     ``compiler.passes.amp_elementwise_counts()`` says how often each
-    side of it was taken."""
+    side of it was taken.
+
+    What STAYS FLOAT32 inside the hybrid blocks' kernels (ops/
+    hybrid_ops.py), whatever the stream's dtype: ``rms_norm``'s
+    statistics (output in the input's dtype, as ``layer_norm``);
+    ``router_scores``' logits and scores (``mxu_operand`` inputs, f32
+    accumulation AND result: a top-k over scores rounded to bf16 would
+    be decided by ties); ``ssd_scan``'s ``dt``, decays, cumulative
+    sums and the state carried between chunks (only the operands of
+    its four products go through ``mxu_operand``); ``routed_experts``'
+    routing weights, its activation and the sum over experts (the two
+    grouped products take ``mxu_operand`` inputs)."""
     mode = _STATE.get('act')
     if mode is None:
         env = os.environ.get('PADDLE_TPU_AMP_ACT', 'bf16').lower()
@@ -94,6 +105,17 @@ def act_bf16():
 
 def set_amp_act(on):
     _STATE['act'] = on
+
+
+def mxu_operand(x):
+    """``x`` as an operand of an MXU product with f32 accumulation
+    inside a kernel that keeps its other state float32: f32 -> bf16
+    under AMP, unchanged otherwise. The product itself asks for
+    ``preferred_element_type=float32``."""
+    import jax.numpy as jnp
+    if amp_enabled() and x.dtype == jnp.float32:
+        return x.astype(jnp.bfloat16)
+    return x
 
 
 def mxu_compute(fn, *operands):

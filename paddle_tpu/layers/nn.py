@@ -32,6 +32,8 @@ __all__ = [
     'squared_l2_norm', 'l1_norm',
     'flash_attention',
     'sequence_concat',
+    'rms_norm', 'causal_conv1d', 'ssd_scan', 'mamba2_mixer',
+    'router_scores', 'routed_experts',
 ]
 
 
@@ -1395,21 +1397,189 @@ def spp(x, pyramid_height, pool_type='max', name=None):
     return out
 
 
-def flash_attention(q, k, v, num_heads=1, causal=True, name=None):
+def flash_attention(q, k, v, num_heads=1, causal=True, num_kv_heads=None,
+                    head_dim=None, name=None):
     """Multi-head scaled-dot-product attention on the Pallas flash
     kernel (paddle_tpu-native addition; the reference's composite is
-    nets.scaled_dot_product_attention). q/k/v: [B, T, D] variables; D
-    is split into ``num_heads``. Engages the blockwise Mosaic kernel on
-    TPU at long sequence lengths and the identical-math XLA reference
-    elsewhere (ops/pallas_kernels.py engagement policy)."""
+    nets.scaled_dot_product_attention). q: [B, T, num_heads * dh], k and
+    v: [B, T, num_kv_heads * dh]; ``dh`` is ``head_dim``, or q's width
+    over ``num_heads`` without it; ``num_kv_heads`` (default
+    ``num_heads``) query heads share a KV head in runs of num_heads /
+    num_kv_heads. Engages the blockwise Mosaic kernel on TPU at long
+    sequence lengths and the identical-math XLA reference elsewhere
+    (ops/pallas_kernels.py engagement policy)."""
     helper = LayerHelper('flash_attention', **locals())
+    kv_heads = int(num_kv_heads or num_heads)
+    if num_heads % kv_heads:
+        raise ValueError('flash_attention: num_heads %d is not a multiple '
+                         'of num_kv_heads %d' % (num_heads, kv_heads))
     out = helper.create_tmp_variable(dtype=q.dtype, shape=q.shape)
     helper.append_op(
         type='flash_attention',
         inputs={'Q': q, 'K': k, 'V': v},
         outputs={'Out': out},
-        attrs={'num_heads': num_heads, 'causal': causal})
+        attrs={'num_heads': num_heads, 'causal': causal,
+               'num_kv_heads': kv_heads, 'head_dim': int(head_dim or 0)})
     return out
+
+
+def rms_norm(input, epsilon=1e-05, begin_norm_axis=1, group_size=None,
+             param_attr=None, name=None):
+    """x / sqrt(mean(x^2) + epsilon) * w over the dims from
+    ``begin_norm_axis`` (paddle_tpu-native addition). ``group_size``
+    norms each run of that many channels apart (a gated norm whose
+    groups lie on different tensor-parallel chips). Statistics in
+    float32; the output keeps the input's dtype, as layer_norm."""
+    helper = LayerHelper('rms_norm', param_attr=param_attr, name=name)
+    width = _prod(input.shape[begin_norm_axis:])
+    if group_size and width % int(group_size):
+        raise ValueError('rms_norm: group_size %d does not divide %d'
+                         % (group_size, width))
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[width], dtype=input.dtype,
+        default_initializer=Constant(1.0))
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op(type='rms_norm', inputs={'X': input, 'Scale': scale},
+                     outputs={'Y': out},
+                     attrs={'epsilon': epsilon,
+                            'begin_norm_axis': begin_norm_axis,
+                            'group_size': int(group_size or 0)})
+    return out
+
+
+def causal_conv1d(input, filter_size, act=None, param_attr=None,
+                  bias_attr=None, name=None):
+    """Causal depthwise conv over time on [B, T, C]: channel c at step t
+    reads its own last ``filter_size`` steps (zeros before the start).
+    Filter [C, filter_size], bias [C] unless ``bias_attr`` is False;
+    ``act`` 'silu' or None."""
+    helper = LayerHelper('causal_conv1d', param_attr=param_attr,
+                         bias_attr=bias_attr, name=name)
+    C = int(input.shape[-1])
+    inputs = {'X': input, 'Filter': helper.create_parameter(
+        attr=helper.param_attr, shape=[C, int(filter_size)],
+        dtype=input.dtype)}
+    if bias_attr is not False:
+        inputs['Bias'] = helper.create_parameter(
+            attr=helper.bias_attr, shape=[C], dtype=input.dtype,
+            is_bias=True)
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op(type='causal_conv1d', inputs=inputs,
+                     outputs={'Out': out}, attrs={'act': act or ''})
+    return out
+
+
+def ssd_scan(x, dt, b, c, num_heads, head_dim, state_size, n_groups=1,
+             chunk_size=128, name=None):
+    """The selective scan of a Mamba-2 mixer, in chunks (state-space
+    duality): dt = softplus(dt + dt_bias), S_t = exp(dt_t A) S_{t-1} +
+    dt_t x_t (x) B_t with A = -exp(A_log) a head, y_t = S_t C_t + D x_t.
+    x [B, T, num_heads * head_dim], dt [B, T, num_heads], b and c
+    [B, T, n_groups * state_size]; head h reads group h // (num_heads /
+    n_groups). Creates A_log, D, dt_bias [num_heads]. The decays, dt
+    and the state carried between chunks stay float32 under AMP."""
+    helper = LayerHelper('ssd_scan', name=name)
+    if num_heads % n_groups:
+        raise ValueError('ssd_scan: num_heads %d is not a multiple of '
+                         'n_groups %d' % (num_heads, n_groups))
+
+    def head_param(value):
+        from ..param_attr import ParamAttr
+        return helper.create_parameter(
+            attr=ParamAttr(), shape=[num_heads], dtype='float32',
+            default_initializer=Constant(value))
+
+    # dt_bias: softplus^-1(0.01), the geometric middle of Mamba-2's
+    # time-step range [0.001, 0.1]
+    inputs = {'X': x, 'Dt': dt, 'B': b, 'C': c,
+              'ALog': head_param(0.0), 'D': head_param(1.0),
+              'DtBias': head_param(-4.6002)}
+    out = helper.create_tmp_variable(x.dtype, shape=x.shape)
+    helper.append_op(type='ssd_scan', inputs=inputs, outputs={'Out': out},
+                     attrs={'num_heads': num_heads, 'head_dim': head_dim,
+                            'n_groups': n_groups, 'state_size': state_size,
+                            'chunk_size': chunk_size})
+    return out
+
+
+def mamba2_mixer(input, num_heads, head_dim, state_size, n_groups=1,
+                 conv_kernel=4, chunk_size=128, epsilon=1e-05, name=None):
+    """A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) on [B, T, D], from
+    the layers above: [z | xBC | dt] = x W_in; xBC = silu(causal conv);
+    y = ssd_scan(x', dt, B, C); y = group_rms_norm(y silu(z)), one norm
+    a group; out = y W_out. No bias but the conv's. ``num_heads`` and
+    ``n_groups`` are what this chip holds of them."""
+    inner, gn = num_heads * head_dim, n_groups * state_size
+    zxbcdt = fc(input, 2 * inner + 2 * gn + num_heads, num_flatten_dims=2,
+                bias_attr=False)
+    z, xbc, dt = split(zxbcdt, [inner, inner + 2 * gn, num_heads], dim=2)
+    xbc = causal_conv1d(xbc, conv_kernel, act='silu')
+    xs, b, c = split(xbc, [inner, gn, gn], dim=2)
+    y = ssd_scan(xs, dt, b, c, num_heads, head_dim, state_size,
+                 n_groups=n_groups, chunk_size=chunk_size)
+    from .ops import swish
+    y = rms_norm(y * swish(z), epsilon=epsilon, begin_norm_axis=2,
+                 group_size=inner // n_groups)
+    return fc(y, int(input.shape[-1]), num_flatten_dims=2, bias_attr=False)
+
+
+def router_scores(input, num_experts, param_attr=None, name=None):
+    """Router of a mixture of experts: sigmoid(x W) over ``num_experts``
+    outputs, in float32 whatever the stream's dtype (the operands take
+    the MXU in bf16 under AMP, the logits do not come back rounded: the
+    choice of experts is a top-k over them)."""
+    helper = LayerHelper('router_scores', param_attr=param_attr, name=name)
+    w = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1]), num_experts],
+        dtype='float32')
+    out = helper.create_tmp_variable(
+        'float32', shape=tuple(input.shape[:-1]) + (num_experts,))
+    helper.append_op(type='router_scores', inputs={'X': input, 'W': w},
+                     outputs={'Out': out})
+    return out
+
+
+def routed_experts(input, scores, hidden_size, num_experts, top_k,
+                   experts_held=None, routed_scaling_factor=1.0, name=None):
+    """The routed experts of a mixture, for the experts this chip
+    holds. ``scores`` [B, T, num_experts] float32 are routed over ALL
+    experts: the ``top_k`` of scores + bias choose (bias: the
+    ``e_score_correction_bias`` buffer [num_experts], zero, which no
+    gradient reaches), the scores weigh (normalised over the chosen,
+    times ``routed_scaling_factor``).
+    ``experts_held`` = (first, count), default all: stacked weights
+    W1 [count, D, hidden_size], W2 [count, hidden_size, D]; out = sum
+    over the chosen experts held of w_e W2_e relu(W1_e x)^2. No token is
+    dropped whatever the routing. Returns (out, tokens_per_expert
+    [count] int32). What the experts held elsewhere add is their
+    chips' to compute and an exchange's to sum."""
+    helper = LayerHelper('routed_experts', name=name)
+    from ..param_attr import ParamAttr
+    first, count = experts_held or (0, num_experts)
+    if first < 0 or count < 1 or first + count > num_experts:
+        raise ValueError('routed_experts: experts_held %r outside 0..%d'
+                         % ((first, count), num_experts))
+    D = int(input.shape[-1])
+    w1 = helper.create_parameter(
+        attr=ParamAttr(), shape=[count, D, hidden_size], dtype='float32')
+    w2 = helper.create_parameter(
+        attr=ParamAttr(), shape=[count, hidden_size, D], dtype='float32')
+    bias = helper.create_parameter(
+        attr=ParamAttr(trainable=False), shape=[num_experts],
+        dtype='float32', default_initializer=Constant(0.0))
+    bias.stop_gradient = True
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    tokens = helper.create_tmp_variable('int32', shape=(count,),
+                                        stop_gradient=True)
+    helper.append_op(
+        type='routed_experts',
+        inputs={'X': input, 'Scores': scores, 'Bias': bias, 'W1': w1,
+                'W2': w2},
+        outputs={'Out': out, 'TokensPerExpert': tokens},
+        attrs={'num_experts': num_experts, 'top_k': top_k,
+               'first_expert': first, 'held': count,
+               'routed_scaling_factor': float(routed_scaling_factor)})
+    return out, tokens
 
 
 def sequence_concat(input, name=None):

@@ -18,5 +18,6 @@ from . import detection_ops  # noqa
 from . import collective_ops  # noqa
 from . import zero_ops  # noqa
 from . import misc_ops  # noqa
+from . import hybrid_ops  # noqa
 
 from ..core.registry import registered_ops  # noqa

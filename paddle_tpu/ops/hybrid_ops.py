@@ -1,0 +1,363 @@
+"""Kernels of hybrid state-space / mixture-of-experts blocks: RMS
+normalisation, a causal depthwise conv1d, the chunked Mamba-2 scan,
+router scores and dropless routed experts over the experts held here.
+
+paddle_tpu-native additions (the reference has none of them); gradients
+come from the lowering's ``value_and_grad`` as for every op. What each
+keeps in float32 under AMP is stated once, in core/amp.py::act_bf16.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .. import observability as _obs
+from ..core.amp import mxu_operand
+from ..core.registry import register_kernel
+from .common import unwrap, f32
+from .math_ops import _prod
+
+
+# ---- RMS normalisation ------------------------------------------------------
+@register_kernel('rms_norm')
+def _rms_norm(ctx):
+    """y = x / sqrt(mean(x^2) + eps) * scale over the dims from
+    ``begin_norm_axis``; with ``group_size`` each run of that many
+    channels is normalised apart (a gated norm split over tensor-
+    parallel groups). Statistics in f32, output in the input's dtype, as
+    layer_norm."""
+    x_in = unwrap(ctx.input('X'))
+    begin = ctx.attr('begin_norm_axis', 1)
+    eps = ctx.attr('epsilon', 1e-5)
+    x = f32(x_in)
+    lead, d = x.shape[:begin], _prod(x.shape[begin:])
+    group = int(ctx.attr('group_size', 0) or 0) or d
+    xg = x.reshape(lead + (d // group, group))
+    y = xg * lax.rsqrt(jnp.mean(jnp.square(xg), axis=-1, keepdims=True)
+                       + eps)
+    y = y.reshape(lead + (d,)) * unwrap(ctx.input('Scale')).reshape((d,))
+    ctx.set_output('Y', y.reshape(x.shape).astype(x_in.dtype))
+
+
+# ---- causal depthwise conv1d ------------------------------------------------
+@register_kernel('causal_conv1d')
+def _causal_conv1d(ctx):
+    """y[t, c] = sum_k w[c, k] x[t - (K-1) + k, c] + b[c] on [B, T, C]
+    (zeros before t = 0), then ``act`` ('silu' or none). K shifted
+    multiply-adds that XLA fuses; f32 inside, the input's dtype out."""
+    x_in = unwrap(ctx.input('X'))
+    w = unwrap(ctx.input('Filter'))
+    x = f32(x_in)
+    T, K = x.shape[1], w.shape[1]
+    pad = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    y = sum(pad[:, k:k + T, :] * w[:, k] for k in range(K))
+    if ctx.has_input('Bias'):
+        y = y + unwrap(ctx.input('Bias'))
+    act = ctx.attr('act', None)
+    if act == 'silu':
+        y = y * jax.nn.sigmoid(y)
+    elif act:
+        raise ValueError('causal_conv1d: unknown act %r' % (act,))
+    ctx.set_output('Out', y.astype(x_in.dtype))
+
+
+# ---- the Mamba-2 scan, chunked (state-space duality) ------------------------
+def ssd_chunked(x, dt, a_log, b, c, chunk):
+    """The selective scan S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t,
+    y_t = S_t C_t of Mamba-2 (Dao & Gu, arXiv:2405.21060), computed in
+    chunks of ``chunk`` positions: inside a chunk one masked
+    [chunk, chunk] product a head, between chunks a carried state.
+
+    x [B, T, H, P]; dt [B, T, H] (after softplus); a_log [H] (A =
+    -exp(a_log)); b, c [B, T, G, N], head h reads group h // (H // G).
+    Returns y [B, T, H, P] in float32. dt, the decays, their cumulative
+    sums and the carried state are float32; only the operands of the
+    four products go through ``mxu_operand``. T need not be a multiple
+    of ``chunk``: the tail is padded with dt = 0, which neither decays
+    nor feeds the state."""
+    Bsz, T, H, P = x.shape
+    G, N = b.shape[2:]
+    r = H // G
+    Q = int(chunk)
+    pad = (-T) % Q
+    if pad:
+        def grow(t):
+            return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        x, dt, b, c = grow(x), grow(dt), grow(b), grow(c)
+    nc = (T + pad) // Q
+    dt = dt.astype(jnp.float32)
+    a = dt * -jnp.exp(a_log.astype(jnp.float32))          # log decay a step
+    # heads before positions: the [Q, Q] planes lie on sublanes x lanes
+    dtc = dt.reshape(Bsz, nc, Q, G, r).transpose(0, 1, 3, 4, 2)
+    cum = jnp.cumsum(a.reshape(Bsz, nc, Q, G, r).transpose(0, 1, 3, 4, 2),
+                     axis=-1)                             # [B, nc, G, r, Q]
+    xc = x.reshape(Bsz, nc, Q, G, r, P)
+    bc = mxu_operand(b.reshape(Bsz, nc, Q, G, N))
+    cc = mxu_operand(c.reshape(Bsz, nc, Q, G, N))
+
+    def dot(spec, u, v):
+        return jnp.einsum(spec, u, v, preferred_element_type=jnp.float32)
+
+    # inside a chunk: y_i += sum_{j<=i} (C_i.B_j) exp(cum_i - cum_j) dt_j x_j
+    keep = jnp.tril(jnp.ones((Q, Q), bool))
+    seg = cum[..., :, None] - cum[..., None, :]
+    decay = jnp.where(keep, jnp.exp(jnp.where(keep, seg, 0.0)), 0.0)
+    cb = dot('bcign,bcjgn->bcgij', cc, bc)                # [B, nc, G, Q, Q]
+    m = cb[:, :, :, None] * decay * dtc[..., None, :]     # [B, nc, G, r, Q, Q]
+    y = dot('bcgrij,bcjgrp->bcigrp', mxu_operand(m), mxu_operand(xc))
+    # what a chunk leaves behind: sum_j exp(cum_end - cum_j) dt_j x_j (x) B_j
+    left = jnp.exp(cum[..., -1:] - cum) * dtc             # [B, nc, G, r, Q]
+    xw = xc.astype(jnp.float32) * left.transpose(0, 1, 4, 2, 3)[..., None]
+    s_chunk = dot('bcjgrp,bcjgn->bcgrpn', mxu_operand(xw), bc)
+    # between chunks: the carried state, float32
+    through = jnp.exp(cum[..., -1])                       # [B, nc, G, r]
+
+    def carry(s, inp):
+        dec, add = inp
+        return dec[..., None, None] * s + add, s
+
+    _, entering = lax.scan(
+        carry, jnp.zeros((Bsz, G, r, P, N), jnp.float32),
+        (through.transpose(1, 0, 2, 3), s_chunk.transpose(1, 0, 2, 3, 4, 5)))
+    entering = entering.transpose(1, 0, 2, 3, 4, 5)       # [B, nc, G, r, P, N]
+    y_in = dot('bcign,bcgrpn->bcigrp', cc, mxu_operand(entering))
+    y = y + y_in * jnp.exp(cum).transpose(0, 1, 4, 2, 3)[..., None]
+    return y.reshape(Bsz, T + pad, H, P)[:, :T]
+
+
+@register_kernel('ssd_scan')
+def _ssd_scan(ctx):
+    """The mixer's scan: dt = softplus(Dt + DtBias), y = scan(x, dt, A, B,
+    C) + D x. X [B, T, H*P], Dt [B, T, H], B / C [B, T, G*N]; Out in X's
+    dtype. Each lowering counts once in ``ssd_lowerings_total{route=,
+    chunk=}`` (compiler/passes.py::ssd_counts)."""
+    x_in = unwrap(ctx.input('X'))
+    H, P = int(ctx.attr('num_heads')), int(ctx.attr('head_dim'))
+    G, N = int(ctx.attr('n_groups', 1)), int(ctx.attr('state_size'))
+    chunk = int(ctx.attr('chunk_size', 128))
+    Bsz, T = x_in.shape[:2]
+    x = x_in.reshape(Bsz, T, H, P)
+    dt = jax.nn.softplus(
+        unwrap(ctx.input('Dt')).astype(jnp.float32)
+        + unwrap(ctx.input('DtBias')).astype(jnp.float32))
+    b = unwrap(ctx.input('B')).reshape(Bsz, T, G, N)
+    c = unwrap(ctx.input('C')).reshape(Bsz, T, G, N)
+    _obs.default_registry().counter(
+        'ssd_lowerings_total',
+        help='ssd_scan op lowerings, by the route taken (xla: the '
+             'chunked state-space-duality form in jax.numpy) and the '
+             'chunk length',
+        route='xla', chunk=str(chunk)).inc()
+    y = ssd_chunked(x, dt, unwrap(ctx.input('ALog')), b, c, chunk)
+    y = y + unwrap(ctx.input('D')).astype(jnp.float32)[:, None] * f32(x)
+    ctx.set_output('Out', y.reshape(x_in.shape).astype(x_in.dtype))
+
+
+# ---- router scores ----------------------------------------------------------
+@register_kernel('router_scores')
+def _router_scores(ctx):
+    """sigmoid(x W) with float32 logits: the operands take one bf16 MXU
+    pass under AMP, accumulation and result stay float32 whatever the
+    stream's dtype, because the expert choice is a top-k over them."""
+    x = mxu_operand(f32(unwrap(ctx.input('X'))))
+    w = mxu_operand(unwrap(ctx.input('W')))
+    ctx.set_output('Out', jax.nn.sigmoid(
+        jnp.matmul(x, w, preferred_element_type=jnp.float32)))
+
+
+# ---- routed experts, dropless, over the experts held ------------------------
+def route_held(scores, bias, top_k, first, count, scale):
+    """Route [N, E] float32 scores over all E experts; for the ``count``
+    experts from ``first`` return ``chosen`` [count, N] (bool) and the
+    routing weight ``weight`` [count, N] (0 where not chosen): the top
+    ``top_k`` of scores + bias choose, the scores themselves weigh,
+    normalised over the chosen and scaled. The chosen experts become a
+    [N, E] mask by comparison (a gather of the chosen scores, and the
+    scatter that is its transpose, cost a millisecond each on the TPU);
+    a held expert's score is a static slice."""
+    E = scores.shape[1]
+    _, idx = lax.top_k(scores + bias, top_k)
+    mask = jnp.any(idx[:, :, None] == jnp.arange(E)[None, None, :], axis=1)
+    total = jnp.sum(jnp.where(mask, scores, 0.0), axis=1, keepdims=True)
+    held = mask[:, first:first + count]
+    weight = jnp.where(held, scores[:, first:first + count], 0.0) \
+        / (total + 1e-20) * scale
+    return held.T, weight.T
+
+
+def _move_rows(p, x, transpose=False):
+    """``p @ x`` (``p.T @ x``) for a 0/1 matrix ``p`` with at most one 1
+    a row: rows of ``x`` picked (or summed back) on the MXU, where a
+    gather or scatter would serialise. A bf16 ``x`` moves exactly in one
+    pass; a float32 one goes as two bf16 halves under AMP (16 bits of
+    mantissa, f32 accumulation) and at ``highest`` precision without."""
+    spec = 'rn,rl->nl' if transpose else 'rn,nl->rl'
+
+    def mm(v, **kw):
+        return jnp.einsum(spec, p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32, **kw)
+    if x.dtype == jnp.bfloat16:
+        return mm(x)
+    if mxu_operand(x).dtype == jnp.bfloat16:
+        hi = x.astype(jnp.bfloat16)
+        return mm(hi) + mm((x - hi.astype(x.dtype)).astype(jnp.bfloat16))
+    return mm(x, precision=lax.Precision.HIGHEST)
+
+
+def _exact(spec, a, b):
+    """A small float32 product whose result must be its operands' own
+    values (a 0/1 matrix against row numbers or weights)."""
+    return jnp.einsum(spec, a, b, precision=lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def _expert_chunk(u, w1, w2, weight, place, counts, chunk, c):
+    """The held experts' part for rows ``c * chunk .. (c + 1) * chunk``
+    of the expert-sorted list of (token, expert) pairs: ``place`` [held,
+    N] is a pair's row (-1: not chosen), ``weight`` [held, N] its
+    routing weight. A row's expert follows from the groups' ends; its
+    token is the one whose place in that expert's line is the row.
+    Pick the rows' tokens, two grouped products with relu^2 between,
+    weigh, sum back to [N, L] float32.
+
+    The rows past the routed pairs are zero rows given to the last
+    expert, so every row of a chunk lies in a group: ``ragged_dot``
+    leaves rows outside its groups uninitialised on the TPU (in the
+    transposed products too, from where they would reach a gradient),
+    and a chunk costs the same whatever the routing sends it."""
+    lo = c * chunk
+    rows = lo + jnp.arange(chunk, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    sizes = jnp.clip(ends, lo, lo + chunk) \
+        - jnp.clip(ends - counts, lo, lo + chunk)
+    sizes = sizes.at[-1].add(chunk - jnp.sum(sizes))
+    owner = jnp.sum(rows[:, None] >= ends[None, :], axis=1)
+    owner = (owner[:, None] == jnp.arange(counts.shape[0])[None, :]) \
+        .astype(jnp.float32)                               # [chunk, held]
+    pick = _exact('re,en->rn', owner, place.astype(jnp.float32)) \
+        == rows[:, None].astype(jnp.float32)               # [chunk, N]
+    row_w = jnp.sum(owner * _exact('rn,en->re', pick.astype(jnp.float32),
+                                   weight), axis=1)
+    x = mxu_operand(u)
+    xs = _move_rows(pick, x).astype(x.dtype)
+    h = lax.ragged_dot(xs, mxu_operand(w1), sizes,
+                       preferred_element_type=jnp.float32)
+    h = jnp.square(jnp.maximum(h, 0.0))
+    y = lax.ragged_dot(mxu_operand(h).astype(xs.dtype), mxu_operand(w2),
+                       sizes, preferred_element_type=jnp.float32)
+    return _move_rows(pick, y * row_w[:, None], transpose=True)
+
+
+def _chunks(counts, chunk):
+    """Chunks of ``chunk`` rows the routed pairs fill (a traced count)."""
+    return (jnp.sum(counts) + chunk - 1) // chunk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _held_experts(u, w1, w2, weight, place, counts, chunk):
+    """Sum of ``_expert_chunk`` over as many chunks as the routed pairs
+    fill: the first always; the others, which only a skewed routing
+    fills, in a loop whose trip count is read from ``counts``, behind a
+    ``cond`` so that a balanced step does not pay for the loop's
+    carried copies."""
+    return _held_experts_fwd(u, w1, w2, weight, place, counts, chunk)[0]
+
+
+def _held_experts_fwd(u, w1, w2, weight, place, counts, chunk):
+    out, vjp_first = jax.vjp(
+        lambda *t: _expert_chunk(*t, place, counts, chunk, 0),
+        u, w1, w2, weight)
+
+    n = _chunks(counts, chunk)
+
+    def rest(out):
+        return lax.fori_loop(
+            1, n, lambda c, acc: acc + _expert_chunk(
+                u, w1, w2, weight, place, counts, chunk, c), out)
+    out = lax.cond(n > 1, rest, lambda out: out, out)
+    return out, (vjp_first, u, w1, w2, weight, place, counts)
+
+
+def _held_experts_bwd(chunk, res, g):
+    # the first chunk's products were saved; a further chunk (a skewed
+    # routing) is computed again for its gradient, into the same sums
+    vjp_first, u, w1, w2, weight, place, counts = res
+
+    def more(c, grads):
+        _, vjp = jax.vjp(
+            lambda *t: _expert_chunk(*t, place, counts, chunk, c),
+            u, w1, w2, weight)
+        return tuple(a + b for a, b in zip(grads, vjp(g)))
+
+    def first():
+        return tuple(vjp_first(g))
+
+    n = _chunks(counts, chunk)
+    grads = lax.cond(n > 1, lambda: lax.fori_loop(1, n, more, first()),
+                     first)
+    return tuple(grads) + (None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+_ROW_QUANTUM = 256
+
+
+def expert_chunk_rows(tokens, top_k, held, experts):
+    """Rows of (token, held expert) pairs a chunk takes: twice the
+    balanced load, rounded up, and no more than any routing can send (a
+    token takes a held expert at most once)."""
+    fast = -(-(2 * tokens * top_k * held // experts + 1) // _ROW_QUANTUM) \
+        * _ROW_QUANTUM
+    return min(fast, tokens * min(top_k, held))
+
+
+@register_kernel('routed_experts')
+def _routed_experts(ctx):
+    """Out = sum over the experts e that are chosen AND held of
+    w_e W2_e relu(W1_e x)^2. X [B, T, L], Scores [B, T, E] float32 over
+    all E experts, Bias [E] (added for the choice only), W1 [held, L,
+    F], W2 [held, F, L] for experts ``first`` .. ``first + held``.
+
+    Dropless: the (token, held expert) pairs are laid out expert by
+    expert by counting (a token takes an expert at most once, so a
+    cumulative sum over [held, N] places every pair; nothing is sorted,
+    gathered or scattered: rows move through 0/1 matrices on the MXU),
+    multiplied by ``lax.ragged_dot`` over the groups, weighed and summed
+    back. The rows go in chunks of twice the balanced load N top_k held
+    / E: one under a balanced routing, as many more as a skewed one
+    fills (a loop with a run-time trip count, so XLA's static shapes
+    hold any routing). TokensPerExpert [held] is the second output.
+    What the experts held elsewhere would add is left out. Each
+    lowering counts once in ``moe_lowerings_total{experts=, held=,
+    top_k=, route=}`` (compiler/passes.py::moe_counts)."""
+    x_in = unwrap(ctx.input('X'))
+    E, K = int(ctx.attr('num_experts')), int(ctx.attr('top_k'))
+    first, held = int(ctx.attr('first_expert', 0)), int(ctx.attr('held'))
+    L = x_in.shape[-1]
+    u = x_in.reshape(-1, L)
+    N = u.shape[0]
+    scores = unwrap(ctx.input('Scores')).astype(jnp.float32).reshape(N, E)
+    bias = unwrap(ctx.input('Bias')).astype(jnp.float32) \
+        if ctx.has_input('Bias') else jnp.zeros((E,), jnp.float32)
+    chosen, weight = route_held(
+        scores, lax.stop_gradient(bias), K, first, held,
+        float(ctx.attr('routed_scaling_factor', 1.0)))
+    _obs.default_registry().counter(
+        'moe_lowerings_total',
+        help='routed_experts op lowerings, by the experts routed over, '
+             'the experts held here, the experts a token takes and the '
+             'grouped product (ragged_dot)',
+        experts=str(E), held=str(held), top_k=str(K),
+        route='ragged_dot').inc()
+    counts = jnp.sum(chosen, axis=1, dtype=jnp.int32)       # [held]
+    place = (jnp.cumsum(counts) - counts)[:, None] \
+        + jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1
+    out = _held_experts(
+        u, unwrap(ctx.input('W1')), unwrap(ctx.input('W2')), weight,
+        jnp.where(chosen, place, -1), counts,
+        expert_chunk_rows(N, K, held, E))
+    ctx.set_output('Out', out.reshape(x_in.shape).astype(x_in.dtype))
+    ctx.set_output('TokensPerExpert', counts)

@@ -445,8 +445,10 @@ def _listen_and_serv_marker(ctx):
 def _flash_attention_op(ctx):
     """paddle_tpu-native multi-head attention op backed by the Pallas
     flash kernel (ops/pallas_kernels.py) — engaged on TPU at long seq
-    lens, identical-math XLA reference elsewhere. Inputs Q/K/V:
-    [B, T, D]; attr num_heads splits D. This is the op behind
+    lens, identical-math XLA reference elsewhere. Inputs Q [B, T,
+    num_heads * dh], K/V [B, T, num_kv_heads * dh]; dh is attr head_dim,
+    or D / num_heads without it; num_kv_heads (default num_heads)
+    divides num_heads. This is the op behind
     layers.flash_attention, the fluid route to the kernels (the OPT
     cell of benchmark/chip builds its attention from it). The engaged
     kernels take Q, K, V and write Out in this very layout (a program
@@ -461,33 +463,43 @@ def _flash_attention_op(ctx):
     cast to bf16 on either route, the dots accumulate f32 and the
     softmax state stays f32 inside the kernels, and the output flows
     bf16 under act_bf16(). Each lowering counts once in
-    ``flash_attention_lowerings_total{route=, dtype=, diag=}``
+    ``flash_attention_lowerings_total{route=, dtype=, diag=, kv_heads=}``
     (compiler/passes.py::flash_counts)."""
     from .pallas_kernels import flash_attention, flash_diag, flash_plan
     from ..core.amp import mxu_compute
     heads = int(ctx.attr('num_heads', 1))
+    kv_heads = int(ctx.attr('num_kv_heads', 0) or heads)
+    head_dim = int(ctx.attr('head_dim', 0) or 0)
     causal = bool(ctx.attr('causal', True))
 
     def attend(q, k, v):
         B, T, D = q.shape
-        dh = D // heads
+        dh = head_dim or D // heads
         qh = q.reshape(B, T, heads, dh)
-        kh = k.reshape(B, T, heads, dh)
-        vh = v.reshape(B, T, heads, dh)
+        kh = k.reshape(B, T, kv_heads, dh)
+        vh = v.reshape(B, T, kv_heads, dh)
+        if kv_heads != heads:
+            # grouped queries: each KV head repeated for the query heads
+            # that share it, before either route; the repeat's transpose
+            # sums their dK, dV
+            kh = jnp.repeat(kh, heads // kv_heads, axis=2)
+            vh = jnp.repeat(vh, heads // kv_heads, axis=2)
         plan = flash_plan(qh, causal=causal)
         _obs.default_registry().counter(
             'flash_attention_lowerings_total',
             help='flash_attention op lowerings, by the route taken '
                  '(pallas kernels / xla reference), the operand dtype '
-                 'the attention ran in and the body the kernels give '
-                 'a tile on the diagonal (chunked<r> / whole / none)',
+                 'the attention ran in, the body the kernels give '
+                 'a tile on the diagonal (chunked<r> / whole / none) '
+                 'and the KV heads the query heads share',
             route='xla' if plan is None else 'pallas',
             dtype={'bfloat16': 'bf16', 'float32': 'f32'}.get(
                 qh.dtype.name, qh.dtype.name),
-            diag=flash_diag(plan, causal)).inc()
+            diag=flash_diag(plan, causal),
+            kv_heads=str(kv_heads)).inc()
         # NB: flash_attention applies the 1/sqrt(dh) logit scale itself
         out = flash_attention(qh, kh, vh, causal=causal)
-        return out.reshape(B, T, D)
+        return out.reshape(B, T, heads * dh)
 
     ctx.set_output('Out', mxu_compute(
         attend, unwrap(ctx.input('Q')), unwrap(ctx.input('K')),

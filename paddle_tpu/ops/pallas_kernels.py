@@ -688,7 +688,11 @@ def _pick_block(T, target):
 # 32Ki -> 1.00x (B4 H16 T512, dead even); 64Ki -> 1.10x (B8 T512) /
 # 1.19x (B4 T1024); 128Ki -> 1.62x; 256Ki -> 2.49x. Engage strictly
 # above the measured break-even: B*H*T >= 64Ki, with T >= 512 so blocks
-# stay MXU-sized.
+# stay MXU-sized. Those shapes were T <= 1024; the XLA route alone pays
+# the [T, T] scores, which grow with T at equal rows, so past T = 1024 a
+# row counts T / 1024 times. One point of that is measured: B1 H4 T4096
+# dh128 (16Ki rows; 4.7x, PERF.md section 6, PR 31). The shapes between
+# (32-64Ki rows at T 2048, 16-64Ki at T 4096) engage unmeasured.
 _FLASH_MIN_T = 512
 _FLASH_MIN_ROWS = 64 * 1024  # B*H*T break-even (measured, v5e)
 
@@ -740,7 +744,7 @@ def flash_plan(q, block_q=None, block_k=None, interpret=None, causal=True):
         block_q = (2048 if one_tile else 1024) if bf16 else 512
     if block_k is None:
         block_k = (2048 if one_tile else 1024) if bf16 else 1024
-    work = B * H * T
+    work = B * H * T * max(T, 1024) // 1024
     use_pallas = interpret or (
         _on_tpu() and T >= _FLASH_MIN_T and work >= _FLASH_MIN_ROWS)
     bq = _pick_block(T, block_q)

@@ -1,0 +1,462 @@
+"""The hybrid blocks (RMS norm, Mamba-2 mixer with a chunked scan,
+grouped-query attention, routed experts over the experts held) against
+the plain reference of benchmark/chip/models/nemotron_h.py, at tiny
+widths that keep the structure: 2 groups, 4 Mamba heads, 4 query heads on
+2 KV heads, 16 experts top 3, pattern ``ME*E``. CPU, float32.
+"""
+import importlib.util
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.ops import hybrid_ops
+
+# by path: ``models`` is also benchmark/fluid's module, which other
+# tests of this suite import by that name
+_spec = importlib.util.spec_from_file_location(
+    'chip_models_nemotron_h', os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        'benchmark', 'chip', 'models', 'nemotron_h.py'))
+nemotron_h = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(nemotron_h)
+
+T = 37                  # not a multiple of the chunk
+TRAFFIC = {'batch': 2, 'seq_len': T}
+
+
+def tiny_cfg(**over):
+    cfg = {
+        'hybrid_override_pattern': 'ME*E', 'num_hidden_layers': 4,
+        'hidden_size': 32, 'vocab_size': 64, 'layer_norm_epsilon': 1e-5,
+        'mamba_num_heads': 4, 'mamba_head_dim': 8, 'n_groups': 2,
+        'ssm_state_size': 16, 'conv_kernel': 4, 'chunk_size': 16,
+        'num_attention_heads': 4, 'num_key_value_heads': 2, 'head_dim': 8,
+        'router_num_experts': 16, 'n_routed_experts': 16,
+        'experts_first': 0, 'num_experts_per_tok': 3,
+        'moe_latent_size': 12, 'moe_intermediate_size': 20,
+        'moe_shared_expert_intermediate_size': 24,
+        'routed_scaling_factor': 2.5, 'norm_topk_prob': True,
+        'initializer_range': 0.2, 'time_step_min': 0.001,
+        'time_step_max': 0.1, 'time_step_floor': 1e-4,
+        'published': {'num_hidden_layers': 4},
+        'optimizer': {'learning_rate': 1e-2, 'beta1': 0.9, 'beta2': 0.95,
+                      'epsilon': 1e-8},
+    }
+    cfg.update(over)
+    return cfg
+
+
+def close(a, b, tol=2e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert a.shape == b.shape
+    scale = max(1e-6, float(np.max(np.abs(b))))
+    assert np.max(np.abs(a - b)) <= tol * scale, (
+        np.max(np.abs(a - b)) / scale)
+
+
+def run_branch(build_fn, x, weights, cfg):
+    """One branch of the program on a fed [B, T, D] input with
+    ``weights`` (the reference's leaves, creation order) put in its
+    parameters: the branch's output, its gradient in x and in every
+    trainable parameter under sum(out * g), and the extra fetches."""
+    d = nemotron_h.Dims(cfg)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        xin = fluid.layers.data(name='x', shape=list(x.shape[1:]),
+                                dtype='float32')
+        g = fluid.layers.data(name='g', shape=list(x.shape[1:]),
+                              dtype='float32')
+        xin.stop_gradient = False
+        out = build_fn(fluid.layers, xin, d)
+        extra = []
+        if isinstance(out, tuple):
+            out, extra = out[0], list(out[1:])
+        params = main.global_block().all_parameters()
+        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, g))
+        train = [p for p in params if p.trainable]
+        grads = fluid.gradients(loss, [xin] + train)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        assert [tuple(p.shape) for p in params] == \
+            [tuple(np.shape(w)) for w in weights]
+        for p, w in zip(params, weights):
+            scope.set_var(p.name, jnp.array(w, copy=True))
+        rng = np.random.RandomState(5)
+        gval = rng.randn(*x.shape).astype('float32')
+        got = exe.run(main, feed={'x': x, 'g': gval},
+                      fetch_list=[out] + grads + extra)
+    n = 1 + len(grads)
+    return got[0], got[1:n], gval, got[n:]
+
+
+def ref_branch(fn, x, gval, p):
+    """Reference output of ``fn(p, x)`` and its gradients in x and in p
+    under sum(out * g)."""
+    def loss(x, p):
+        out = fn(p, x)
+        return jnp.sum(out * gval), out
+    (_, out), (dx, dp) = jax.value_and_grad(loss, argnums=(0, 1),
+                                            has_aux=True)(jnp.asarray(x), p)
+    return out, dx, dp
+
+
+def block_params(cfg, kind, seed=3):
+    ref = nemotron_h.Reference(cfg)
+    pattern = cfg['hybrid_override_pattern']
+    i = pattern.index(kind)
+    full = ref.init(jax.random.PRNGKey(seed))
+    pre = 'l%d.' % i
+    names = [n for n, _, _ in ref.block_leaves(kind, pre)][1:]   # no norm
+    return ref, pre, names, {n: full[n] for n in names}
+
+
+def stream(seed=0, width=32):
+    return np.random.RandomState(seed).randn(2, T, width).astype('float32')
+
+
+# ---- each new op against the reference, forward and all gradients ----------
+@pytest.mark.parametrize('group', [None, 8])
+def test_rms_norm_matches_reference(group):
+    cfg = tiny_cfg()
+    ref = nemotron_h.Reference(cfg)
+    w = np.random.RandomState(1).rand(32).astype('float32') + 0.5
+    x = stream()
+
+    def build(layers, xin, d):
+        return layers.rms_norm(xin, epsilon=d.eps, begin_norm_axis=2,
+                               group_size=group)
+    out, grads, g, _ = run_branch(build, x, [w], cfg)
+    want, dx, dp = ref_branch(
+        lambda p, t: ref.rms_norm(t, p['w'], group=group), x, g,
+        {'w': jnp.asarray(w)})
+    close(out, want)
+    close(grads[0], dx)
+    close(grads[1], dp['w'])
+
+
+@pytest.mark.parametrize('t_len,chunk', [(37, 16), (64, 16), (9, 16)])
+def test_chunked_scan_equals_the_sequential_recurrence(t_len, chunk):
+    """Forward and every gradient, at T below, at and not a multiple of
+    the chunk."""
+    ref = nemotron_h.Reference(tiny_cfg())
+    rng = np.random.RandomState(2)
+    H, P, G, N = 4, 8, 2, 16
+    x = jnp.asarray(rng.randn(2, t_len, H, P), jnp.float32)
+    dt = jnp.asarray(rng.rand(2, t_len, H) * 0.5 + 0.01, jnp.float32)
+    a_log = jnp.asarray(np.log(np.arange(1, H + 1)), jnp.float32)
+    b = jnp.asarray(rng.randn(2, t_len, G, N), jnp.float32)
+    c = jnp.asarray(rng.randn(2, t_len, G, N), jnp.float32)
+    g = jnp.asarray(rng.randn(2, t_len, H, P), jnp.float32)
+
+    def chunked(x, dt, a_log, b, c):
+        return jnp.sum(hybrid_ops.ssd_chunked(x, dt, a_log, b, c, chunk) * g)
+
+    def sequential(x, dt, a_log, b, c):
+        return jnp.sum(ref.scan(x, dt, -jnp.exp(a_log), b, c) * g)
+
+    args = (x, dt, a_log, b, c)
+    close(hybrid_ops.ssd_chunked(*args, chunk),
+          ref.scan(x, dt, -jnp.exp(a_log), b, c), 1e-4)
+    for got, want in zip(jax.grad(chunked, argnums=range(5))(*args),
+                         jax.grad(sequential, argnums=range(5))(*args)):
+        close(got, want, 1e-4)
+
+
+def test_mamba_mixer_matches_reference():
+    cfg = tiny_cfg()
+    ref, pre, names, p = block_params(cfg, 'M')
+    x = stream()
+    out, grads, g, _ = run_branch(nemotron_h.mamba_branch, x,
+                                  [p[n] for n in names], cfg)
+    want, dx, dp = ref_branch(
+        lambda p, t: ref.mamba(p, t, pre, nemotron_h.Float32Dots()),
+        x, g, p)
+    close(out, want, 1e-4)
+    close(grads[0], dx, 1e-4)
+    for got, n in zip(grads[1:], names):
+        close(got, dp[n], 2e-4)
+
+
+def test_attention_with_fewer_kv_heads_matches_reference():
+    cfg = tiny_cfg()
+    ref, pre, names, p = block_params(cfg, '*')
+    x = stream()
+    out, grads, g, _ = run_branch(nemotron_h.attention_branch, x,
+                                  [p[n] for n in names], cfg)
+    want, dx, dp = ref_branch(
+        lambda p, t: ref.attention(p, t, pre, nemotron_h.Float32Dots()),
+        x, g, p)
+    close(out, want, 1e-4)
+    close(grads[0], dx, 1e-4)
+    for got, n in zip(grads[1:], names):
+        close(got, dp[n], 1e-4)
+
+
+def _routed_case(cfg, bias=None, seed=3):
+    ref, pre, names, p = block_params(cfg, 'E', seed)
+    names = [n for n in names if 'shared' not in n]
+    if bias is not None:
+        p[pre + 'e_score_correction_bias'] = jnp.asarray(bias, jnp.float32)
+    x = stream()
+    out, grads, g, extra = run_branch(nemotron_h.routed_branch, x,
+                                      [p[n] for n in names], cfg)
+    want, dx, dp = ref_branch(
+        lambda p, t: ref.routed(p, t, pre, nemotron_h.Float32Dots()),
+        x, g, p)
+    close(out, want, 1e-4)
+    close(grads[0], dx, 1e-4)
+    train = [n for n in names if 'correction' not in n]
+    for got, n in zip(grads[1:], train):
+        close(got, dp[n], 2e-4)
+    _, idx, _ = ref.routing(p, jnp.asarray(x), pre,
+                            nemotron_h.Float32Dots())
+    first, held = cfg.get('experts_first', 0), cfg['n_routed_experts']
+    want_tokens = [int(jnp.sum(idx == first + j)) for j in range(held)]
+    assert list(np.asarray(extra[0])) == want_tokens
+    return want_tokens
+
+
+@pytest.mark.parametrize('held', [(0, 16), (4, 8), (13, 3)])
+def test_routed_experts_match_reference(held):
+    """All experts, and two shares: routed over all 16, computed for the
+    experts held."""
+    tokens = _routed_case(tiny_cfg(experts_first=held[0],
+                                   n_routed_experts=held[1]))
+    assert sum(tokens) > 0
+
+
+def test_no_token_is_dropped_under_a_skewed_routing(monkeypatch):
+    """Every token is sent to experts 5 and 6 (and one other): each sees
+    all 2 T tokens, more pairs than one chunk of rows (twice the
+    balanced load) holds, and the result is still the reference's."""
+    monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', 8)
+    cfg = tiny_cfg(experts_first=4, n_routed_experts=4)
+    chunk = hybrid_ops.expert_chunk_rows(2 * T, 3, 4, 16)
+    # a balanced routing fits one chunk ...
+    assert sum(_routed_case(cfg)) <= chunk
+    # ... this one overflows into a second
+    bias = np.zeros(16, 'float32')
+    bias[5] = bias[6] = 100.0
+    tokens = _routed_case(cfg, bias=bias)
+    assert tokens[1] == tokens[2] == 2 * T
+    assert chunk < sum(tokens) <= 2 * chunk
+
+
+def test_shared_expert_matches_reference():
+    cfg = tiny_cfg()
+    ref, pre, names, p = block_params(cfg, 'E')
+    names = [n for n in names if 'shared' in n]
+    x = stream()
+    out, grads, g, _ = run_branch(nemotron_h.shared_branch, x,
+                                  [p[n] for n in names], cfg)
+    want, dx, dp = ref_branch(
+        lambda p, t: ref.shared(p, t, pre, nemotron_h.Float32Dots()),
+        x, g, p)
+    close(out, want, 1e-4)
+    close(grads[0], dx, 1e-4)
+    for got, n in zip(grads[1:], names):
+        close(got, dp[n], 1e-4)
+
+
+# ---- the shares add up to the uncut layer ----------------------------------
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Four chips hold 4 experts each: their routed parts (each through
+    its own copy of router and latent projections, which every chip
+    computes alike) plus the shared expert counted once equal the uncut
+    reference layer."""
+    cfg = tiny_cfg()
+    ref, pre, names, p = block_params(cfg, 'E')
+    x = stream()
+    dots = nemotron_h.Float32Dots()
+    want = ref.branch('E', p, jnp.asarray(x), pre, dots)
+    routed = [n for n in names if 'shared' not in n]
+    total = 0.0
+    for first in range(0, 16, 4):
+        share = dict(p)
+        share[pre + 'w1'] = p[pre + 'w1'][first:first + 4]
+        share[pre + 'w2'] = p[pre + 'w2'][first:first + 4]
+        part, _, _, _ = run_branch(
+            nemotron_h.routed_branch, x, [share[n] for n in routed],
+            tiny_cfg(experts_first=first, n_routed_experts=4))
+        total = total + part
+    once, _, _, _ = run_branch(
+        nemotron_h.shared_branch, x,
+        [p[n] for n in names if 'shared' in n], cfg)
+    close(total + once, want, 1e-4)
+
+
+def test_mamba_head_shares_add_up_to_the_uncut_layer():
+    """Two chips hold one group (2 heads, its B and C, its slice of the
+    gated norm) each; their out-projections' partial sums add up."""
+    cfg = tiny_cfg()
+    ref, pre, names, p = block_params(cfg, 'M')
+    d = nemotron_h.Dims(cfg)
+    x = stream()
+    want = ref.mamba(p, jnp.asarray(x), pre, nemotron_h.Float32Dots())
+    hp, gn = d.inner // d.G, d.N                 # a group's x and B widths
+    heads = d.H // d.G
+    total = 0.0
+    for g in range(d.G):
+        xs = np.arange(g * hp, (g + 1) * hp)
+        bs = d.inner + np.arange(g * gn, (g + 1) * gn)
+        cs = d.inner + d.G * d.N + np.arange(g * gn, (g + 1) * gn)
+        conv = np.concatenate([xs, bs, cs])
+        hs = np.arange(g * heads, (g + 1) * heads)
+        cols = np.concatenate([xs, d.inner + conv, d.inner + d.conv + hs])
+        share = [p[pre + 'in_proj'][:, cols], p[pre + 'conv.w'][conv],
+                 p[pre + 'conv.b'][conv], p[pre + 'A_log'][hs],
+                 p[pre + 'D'][hs], p[pre + 'dt_bias'][hs],
+                 p[pre + 'gate_norm'][xs], p[pre + 'out_proj'][xs]]
+        part, _, _, _ = run_branch(
+            nemotron_h.mamba_branch, x, share,
+            tiny_cfg(mamba_num_heads=heads, n_groups=1))
+        total = total + part
+    close(total, want, 1e-4)
+
+
+def test_attention_head_shares_add_up_to_the_uncut_layer():
+    """Two chips hold one KV head and its two query heads each."""
+    cfg = tiny_cfg()
+    ref, pre, names, p = block_params(cfg, '*')
+    d = nemotron_h.Dims(cfg)
+    x = stream()
+    want = ref.attention(p, jnp.asarray(x), pre, nemotron_h.Float32Dots())
+    per = d.Hq // d.Hkv
+    total = 0.0
+    for kv in range(d.Hkv):
+        qs = np.arange(kv * per * d.dh, (kv + 1) * per * d.dh)
+        ks = np.arange(kv * d.dh, (kv + 1) * d.dh)
+        share = [p[pre + 'q'][:, qs], p[pre + 'k'][:, ks],
+                 p[pre + 'v'][:, ks], p[pre + 'o'][qs]]
+        part, _, _, _ = run_branch(
+            nemotron_h.attention_branch, x, share,
+            tiny_cfg(num_attention_heads=per, num_key_value_heads=1))
+        total = total + part
+    close(total, want, 1e-4)
+
+
+# ---- the whole tiny model --------------------------------------------------
+@pytest.mark.parametrize('held', [(0, 16), (8, 8)])
+def test_tiny_model_loss_gradients_and_three_adam_steps(held):
+    cfg = tiny_cfg(experts_first=held[0], n_routed_experts=held[1])
+    ref = nemotron_h.Reference(cfg)
+    built = nemotron_h.build(cfg, TRAFFIC)
+    leaves = ref.leaves()
+    key = jax.random.PRNGKey(7)
+    init = ref.init(key)
+    batches = [{k: np.asarray(v) for k, v in nemotron_h.draw_batch(
+        cfg, TRAFFIC, jax.random.fold_in(key, 100 + i)).items()}
+        for i in range(3)]
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    names = built['param_names']
+    assert len(names) == len(leaves)
+    with fluid.scope_guard(scope):
+        exe.run(built['startup'])
+        for n, (leaf, shape, _) in zip(names, leaves):
+            assert tuple(np.shape(scope.raw(n))) == tuple(shape), leaf
+            scope.set_var(n, jnp.array(init[leaf], copy=True))
+        losses, grads = [], None
+        for i, b in enumerate(batches):
+            out = exe.run(built['main'], feed=b, fetch_list=[built['loss']])
+            losses.append(float(np.ravel(out[0])[0]))
+            if i == 0:
+                grads = {leaf: np.asarray(scope.raw(built['grad_state'](n)))
+                         * built['grad_scale']
+                         for n, (leaf, _, t) in zip(names, leaves) if t}
+        final = {leaf: np.asarray(scope.raw(n))
+                 for n, (leaf, _, _) in zip(names, leaves)}
+
+    params, state = dict(init), ref.new_opt_state(init)
+    for i, b in enumerate(batches):
+        loss, g = jax.value_and_grad(lambda p: ref.loss(p, b))(params)
+        assert abs(losses[i] - float(loss)) <= 2e-5 * abs(float(loss))
+        if i == 0:
+            assert set(grads) == set(ref.trainable())
+            for n in ref.trainable():
+                close(grads[n], g[n], 5e-4)
+        params, state = ref.update(params, g, state, jnp.float32(i + 1))
+    for n, _, t in leaves:
+        if t:
+            close(final[n] - np.asarray(init[n]),
+                  params[n] - init[n], 5e-3)
+        else:
+            np.testing.assert_array_equal(final[n], np.asarray(init[n]))
+
+
+# ---- shapes, counters, AMP -------------------------------------------------
+def test_shape_inference_covers_the_new_ops():
+    from paddle_tpu.analysis import infer
+    assert {'rms_norm', 'causal_conv1d', 'ssd_scan', 'router_scores',
+            'routed_experts', 'flash_attention'} \
+        <= set(infer.registered_shape_ops())
+    built = nemotron_h.build(tiny_cfg(), TRAFFIC)
+    env, diags, _ = infer.infer_program(built['main'])
+    assert [d for d in diags if d.severity == 'error'] == []
+    ops = {op.type: op for op in built['main'].global_block().ops}
+    tokens = env[ops['routed_experts'].outputs['TokensPerExpert'][0]]
+    assert tokens.shape == (16,) and tokens.dtype == 'int32'
+    scores = env[ops['router_scores'].outputs['Out'][0]]
+    assert scores.shape[-1] == 16 and scores.dtype == 'float32'
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = fluid.layers.data(name='q', shape=[T, 32], dtype='float32')
+        kv = fluid.layers.data(name='kv', shape=[T, 32], dtype='float32')
+        fluid.layers.flash_attention(q, kv, kv, num_heads=4,
+                                     num_kv_heads=2, head_dim=8)
+    _, diags, _ = infer.infer_program(main)
+    assert [d.code for d in diags if d.severity == 'error'] \
+        == ['rank-mismatch'] * 2
+
+
+def test_lowerings_are_counted():
+    from paddle_tpu.compiler.passes import (flash_counts, moe_counts,
+                                            ssd_counts)
+    built = nemotron_h.build(tiny_cfg(experts_first=8, n_routed_experts=8),
+                             TRAFFIC)
+    batch = {k: np.asarray(v) for k, v in nemotron_h.draw_batch(
+        tiny_cfg(), TRAFFIC, jax.random.PRNGKey(0)).items()}
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(built['startup'])
+        before = moe_counts(), ssd_counts(), flash_counts(by=('kv_heads',))
+        exe.lowered(built['main'], feed=batch, fetch_list=[built['loss']])
+        after = moe_counts(), ssd_counts(), flash_counts(by=('kv_heads',))
+    moved = [{k: n - was.get(k, 0) for k, n in now.items()
+              if n != was.get(k, 0)} for was, now in zip(before, after)]
+    assert moved == [{('16', '8', '3', 'ragged_dot'): 2},
+                     {('xla', '16'): 1}, {('2',): 1}]
+
+
+def test_amp_keeps_scores_and_the_carried_state_float32(amp):
+    """Under forced AMP the router's scores and the state carried
+    between chunks stay float32, and the products take bf16 operands."""
+    amp.set_amp(True)
+    built = nemotron_h.build(tiny_cfg(), TRAFFIC)
+    batch = {k: np.asarray(v) for k, v in nemotron_h.draw_batch(
+        tiny_cfg(), TRAFFIC, jax.random.PRNGKey(0)).items()}
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(built['startup'])
+        text = exe.lowered(built['main'], feed=batch,
+                           fetch_list=[built['loss']]).as_text()
+    import re
+    # the scan's carry: one while a mixer, forward, and its transpose
+    # [.., P, N] = [.., 8, 16]: the state a scan's loop carries
+    states = re.findall(r'tensor<[0-9x]*x8x16x(\w+)>', ' '.join(
+        re.findall(r'stablehlo\.while.*', text)))
+    assert states and set(states) == {'f32'}, states
+    scores = re.findall(r'chlo\.top_k.*: tensor<74x16x(\w+)>', text)
+    assert scores and set(scores) == {'f32'}
+    dots = re.findall(
+        r'stablehlo\.dot_general.*: \(tensor<[0-9x]*x(\w+)>, '
+        r'tensor<[0-9x]*x(\w+)>\) -> tensor<[0-9x]*x(\w+)>', text)
+    assert ('bf16', 'bf16', 'f32') in set(dots)

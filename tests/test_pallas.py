@@ -635,6 +635,88 @@ def test_flash_op_amp_matches_f32_reference(route, T, blocks, amp, engage):
         assert err < 3e-2, '%s (%s): %.3g' % (name, route, err)
 
 
+def _gqa_program(T, heads, kv_heads, dh):
+    """One flash_attention op with fewer KV heads than query heads on
+    fed q [B, T, heads*dh], k, v [B, T, kv_heads*dh], and the gradients
+    of sum(out * w) in q, k, v."""
+    import paddle_tpu.fluid as fluid
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q, w = [fluid.layers.data(name=n, shape=[T, heads * dh],
+                                  dtype='float32') for n in 'qw']
+        k, v = [fluid.layers.data(name=n, shape=[T, kv_heads * dh],
+                                  dtype='float32') for n in 'kv']
+        for x in (q, k, v):
+            x.stop_gradient = False
+        out = fluid.layers.flash_attention(
+            q, k, v, num_heads=heads, causal=True, num_kv_heads=kv_heads,
+            head_dim=dh)
+        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, w))
+        fetch = [out] + fluid.gradients(loss, [q, k, v])
+    return main, startup, fetch
+
+
+@pytest.mark.parametrize('route,T,heads,kv_heads,dh', [
+    ('pallas', 512, 4, 2, 64), ('pallas', 512, 2, 1, 128),
+    ('xla', 256, 4, 2, 64), ('xla', 512, 4, 1, 32)])
+def test_flash_op_with_fewer_kv_heads(route, T, heads, kv_heads, dh, amp,
+                                      engage):
+    """num_kv_heads < num_heads on both routes (the engaged one through
+    the interpreter): each KV head serves its run of query heads, and
+    its gradient is the sum over them. Against plain attention with the
+    KV heads repeated; a head size that is not D / num_heads is taken
+    from head_dim."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.compiler.passes import flash_counts
+    amp.set_amp(False)
+    rng = np.random.RandomState(4)
+    B = _FLASH_OP_B
+    feed = {'q': rng.randn(B, T, heads * dh), 'w': rng.randn(B, T, heads * dh),
+            'k': rng.randn(B, T, kv_heads * dh),
+            'v': rng.randn(B, T, kv_heads * dh)}
+    feed = {n: x.astype('float32') for n, x in feed.items()}
+    main, startup, fetch = _gqa_program(T, heads, kv_heads, dh)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        before = flash_counts(by=('route', 'kv_heads'))
+        got = exe.run(main, feed=feed, fetch_list=fetch)
+        after = flash_counts(by=('route', 'kv_heads'))
+    assert after.get((route, str(kv_heads)), 0) \
+        > before.get((route, str(kv_heads)), 0)
+
+    def loss(q, k, v):
+        rep = heads // kv_heads
+        kh = jnp.repeat(k.reshape(B, T, kv_heads, dh), rep, axis=2)
+        vh = jnp.repeat(v.reshape(B, T, kv_heads, dh), rep, axis=2)
+        o = pk.attention_reference(q.reshape(B, T, heads, dh), kh, vh,
+                                   causal=True).reshape(q.shape)
+        return jnp.sum(o * feed['w']), o
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(
+        *(jnp.asarray(feed[n]) for n in 'qkv'))
+    for name, a, b in zip(('out', 'dq', 'dk', 'dv'), got, (out,) + grads):
+        assert a.shape == b.shape, name
+        err = np.max(np.abs(a - np.asarray(b))) / np.max(np.abs(b))
+        assert err < 2e-5, '%s (%s): %.3g' % (name, route, err)
+
+
+def test_flash_plan_counts_a_long_row_for_its_scores(monkeypatch):
+    """The engagement floor is in rows at T <= 1024 and in scores past
+    it: B1 H4 T4096 (16Ki rows) has the scores of 64Ki rows at T 1024
+    and engages; the same rows at T 1024 do not; what engaged before
+    still does."""
+    monkeypatch.setattr(pk, '_on_tpu', lambda: True)
+    bf16 = jnp.bfloat16
+    assert pk.flash_plan(jnp.zeros((1, 4096, 4, 128), bf16)) == (1024, 1024)
+    assert pk.flash_plan(jnp.zeros((1, 2048, 4, 128), bf16)) is None
+    assert pk.flash_plan(jnp.zeros((4, 1024, 4, 128), bf16)) is None
+    assert pk.flash_plan(jnp.zeros((2, 2048, 32, 64), bf16)) == (2048, 2048)
+    assert pk.flash_plan(jnp.zeros((8, 512, 16, 64), bf16)) is not None
+    assert pk.flash_plan(jnp.zeros((4, 512, 16, 64), bf16)) is None
+
+
 @pytest.mark.parametrize('amp_on,route,T,heads', [
     (True, 'pallas', 512, 4), (True, 'xla', 256, 4),
     (False, 'pallas', 512, 4), (False, 'xla', 256, 4),
