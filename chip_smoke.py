@@ -20,7 +20,9 @@ process at a time; this parent never touches JAX.
    through the Executor, with the Mosaic custom calls counted in the
    lowered step; six Adam steps of the tiny hybrid stack (Mamba-2
    mixer, routed experts, grouped-query attention) under AMP, its
-   scan state and router scores float32 in the lowered step; and,
+   scan state and router scores float32 in the lowered step, and one
+   routed-experts layer wide enough for the Pallas grouped matmul
+   against the ``lax.ragged_dot`` route on the same operands; and,
    when four devices are visible, ResNet-50 through
    ``ParallelExecutor``.
 2. ``--phase cache`` — a second process compiles the same ResNet-50
@@ -513,7 +515,82 @@ def leg_hybrid(rehearse, steps=6):
           'the router chooses over float32 scores')
     mosaic = text.count('tpu_custom_call')
     say('  %d Mosaic custom call(s) in the lowered step' % mosaic)
-    return {'losses': losses, 'mosaic_calls': mosaic}
+    return {'losses': losses, 'mosaic_calls': mosaic,
+            'routed_layer': _routed_layer_on_both_routes(rehearse)}
+
+
+def _routed_layer_on_both_routes(rehearse):
+    """The tiny stack is too narrow for the Pallas grouped matmul to
+    engage (latent 12, experts 20 wide). One ``routed_experts`` layer at
+    the smallest widths that rule takes (128 -> 128, 256 tokens, 8 of
+    16 experts held, top 2, a 512-row chunk), under the backend's AMP:
+    the counter says 'pallas', the output and both weight gradients are
+    those of the ``lax.ragged_dot`` route on the same operands, and an
+    expert the bias keeps every token from gets exactly zero."""
+    import numpy as np
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.compiler.passes import moe_counts
+    from paddle_tpu.ops import pallas_kernels as pk
+    B, T, L, F, E, held, top_k, idle = 2, 128, 128, 128, 16, 8, 2, 3
+    rng = np.random.RandomState(0)
+    feed = {'x': rng.randn(B, T, L).astype('float32'),
+            'scores': rng.rand(B, T, E).astype('float32'),
+            'g': rng.randn(B, T, L).astype('float32')}
+    weights = [rng.randn(held, L, F).astype('float32') * 0.1,
+               rng.randn(held, F, L).astype('float32') * 0.1,
+               np.where(np.arange(E) == idle, -100.0, 0.0).astype('float32')]
+
+    def run():
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            x, scores, g = (fluid.layers.data(
+                name=n, shape=list(feed[n].shape[1:]), dtype='float32')
+                for n in ('x', 'scores', 'g'))
+            out, tokens = fluid.layers.routed_experts(
+                x, scores, hidden_size=F, num_experts=E, top_k=top_k,
+                experts_held=(0, held))
+            params = main.global_block().all_parameters()
+            loss = fluid.layers.reduce_sum(
+                fluid.layers.elementwise_mul(out, g))
+            grads = fluid.gradients(loss, params[:2])
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(_place(fluid, rehearse))
+            exe.run(startup)
+            for p, w in zip(params, weights):
+                scope.set_var(p.name, np.array(w))
+            before = moe_counts(by=('route',))
+            got = exe.run(main, feed=feed,
+                          fetch_list=[out, tokens] + grads)
+        routes = {k[0] for k, n in moe_counts(by=('route',)).items()
+                  if n != before.get(k, 0)}
+        return [np.asarray(a) for a in got], routes
+
+    (out, tokens, dw1, dw2), routes = run()
+    say('[hybrid/routed layer] %d x %d tokens, %d -> %d, route %s, '
+        'tokens an expert %s' % (B, T, L, F, sorted(routes), tokens.tolist()))
+    if not rehearse:
+        check(routes == {'pallas'},
+              'moe_lowerings_total says the Pallas grouped matmul engaged')
+    plan = pk.grouped_plan
+    pk.grouped_plan = lambda rows, w, interpret=None: None
+    try:
+        (ref_out, ref_tokens, ref_dw1, ref_dw2), routes = run()
+    finally:
+        pk.grouped_plan = plan
+    check(routes == {'ragged_dot'} and tokens.tolist() == ref_tokens.tolist()
+          and tokens[idle] == 0 and tokens.sum() > 0,
+          'the same routing through lax.ragged_dot, expert %d idle' % idle)
+    worst = {}
+    for name, a, b in (('out', out, ref_out), ('dW1', dw1, ref_dw1),
+                       ('dW2', dw2, ref_dw2)):
+        worst[name] = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+        check(np.isfinite(a).all() and worst[name] <= _TOL_KERNEL,
+              '%s within %.0e of the ragged_dot route (%.2e)'
+              % (name, _TOL_KERNEL, worst[name]))
+    check(not dw1[idle].any() and not dw2[idle].any(),
+          'the idle expert\'s weight gradients are exactly zero')
+    return worst
 
 
 # ---- leg 6: four chips, one process --------------------------------------

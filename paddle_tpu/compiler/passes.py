@@ -879,7 +879,11 @@ def moe_counts(by=('experts', 'held', 'top_k', 'route')):
     routed_experts op lowerings so far (ops/hybrid_ops.py; counted per
     trace, as the others are): the experts routed over, the experts
     held here, the experts a token takes (strings, as labels are) and
-    the grouped product ('ragged_dot')."""
+    the route of the grouped products: 'pallas' (the Pallas grouped
+    matmul of ops/pallas_kernels.py: a TPU backend, bf16 operands,
+    latent and expert widths multiples of 128) or 'ragged_dot'
+    (``lax.ragged_dot``: everything else, the CPU tests among it).
+    ops/pallas_kernels.py::grouped_plan is the rule."""
     return _label_counts('moe_lowerings_total', by)
 
 

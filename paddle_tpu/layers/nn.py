@@ -1552,7 +1552,14 @@ def routed_experts(input, scores, hidden_size, num_experts, top_k,
     over the chosen experts held of w_e W2_e relu(W1_e x)^2. No token is
     dropped whatever the routing. Returns (out, tokens_per_expert
     [count] int32). What the experts held elsewhere add is their
-    chips' to compute and an exchange's to sum."""
+    chips' to compute and an exchange's to sum.
+
+    The grouped products over the held experts run in the Pallas
+    grouped matmul (ops/pallas_kernels.py::grouped_matmul) on a TPU
+    backend when their operands are bf16 (AMP) and D and ``hidden_size``
+    are multiples of 128, and in ``lax.ragged_dot`` everywhere else;
+    nothing here chooses. ``compiler.passes.moe_counts()`` says which
+    route a lowering took."""
     helper = LayerHelper('routed_experts', name=name)
     from ..param_attr import ParamAttr
     first, count = experts_held or (0, num_experts)

@@ -218,14 +218,21 @@ def _expert_chunk(u, w1, w2, weight, place, counts, chunk, c):
     N] is a pair's row (-1: not chosen), ``weight`` [held, N] its
     routing weight. A row's expert follows from the groups' ends; its
     token is the one whose place in that expert's line is the row.
-    Pick the rows' tokens, two grouped products with relu^2 between,
-    weigh, sum back to [N, L] float32.
+    Pick the rows' tokens, two grouped products with relu^2 between
+    (pallas_kernels.grouped_matmul: the Pallas kernels or
+    ``lax.ragged_dot``, one layout for both), weigh, sum back to
+    [N, L] float32.
 
     The rows past the routed pairs are zero rows given to the last
-    expert, so every row of a chunk lies in a group: ``ragged_dot``
-    leaves rows outside its groups uninitialised on the TPU (in the
-    transposed products too, from where they would reach a gradient),
-    and a chunk costs the same whatever the routing sends it."""
+    expert, so every row of a chunk lies in a group. On either route
+    that is what keeps a gradient clean: ``ragged_dot`` leaves rows
+    outside its groups uninitialised on the TPU (in the transposed
+    products too), and the Pallas kernels write only the rows of the
+    groups they are told. A chunk costs the same whatever the routing
+    sends it: ``ragged_dot`` by its row count, the kernels by their
+    grid, which is the chunk's row tiles plus one visit a further
+    expert however the rows split."""
+    from .pallas_kernels import grouped_matmul
     lo = c * chunk
     rows = lo + jnp.arange(chunk, dtype=jnp.int32)
     ends = jnp.cumsum(counts)
@@ -241,11 +248,10 @@ def _expert_chunk(u, w1, w2, weight, place, counts, chunk, c):
                                    weight), axis=1)
     x = mxu_operand(u)
     xs = _move_rows(pick, x).astype(x.dtype)
-    h = lax.ragged_dot(xs, mxu_operand(w1), sizes,
-                       preferred_element_type=jnp.float32)
+    h = grouped_matmul(xs, mxu_operand(w1), sizes)
     h = jnp.square(jnp.maximum(h, 0.0))
-    y = lax.ragged_dot(mxu_operand(h).astype(xs.dtype), mxu_operand(w2),
-                       sizes, preferred_element_type=jnp.float32)
+    y = grouped_matmul(mxu_operand(h).astype(xs.dtype), mxu_operand(w2),
+                       sizes)
     return _move_rows(pick, y * row_w[:, None], transpose=True)
 
 
@@ -307,11 +313,15 @@ _ROW_QUANTUM = 256
 
 def expert_chunk_rows(tokens, top_k, held, experts):
     """Rows of (token, held expert) pairs a chunk takes: twice the
-    balanced load, rounded up, and no more than any routing can send (a
-    token takes a held expert at most once)."""
-    fast = -(-(2 * tokens * top_k * held // experts + 1) // _ROW_QUANTUM) \
-        * _ROW_QUANTUM
-    return min(fast, tokens * min(top_k, held))
+    balanced load and no more than any routing can send (a token takes
+    a held expert at most once), in whole row quanta either way. The
+    Pallas products need no row beyond the pairs (a row tile may
+    straddle experts), so a chunk holds as many pairs as it has rows in
+    any split."""
+    def quanta(n):
+        return -(-n // _ROW_QUANTUM) * _ROW_QUANTUM
+    return min(quanta(2 * tokens * top_k * held // experts + 1),
+               quanta(tokens * min(top_k, held)))
 
 
 @register_kernel('routed_experts')
@@ -325,14 +335,20 @@ def _routed_experts(ctx):
     expert by counting (a token takes an expert at most once, so a
     cumulative sum over [held, N] places every pair; nothing is sorted,
     gathered or scattered: rows move through 0/1 matrices on the MXU),
-    multiplied by ``lax.ragged_dot`` over the groups, weighed and summed
-    back. The rows go in chunks of twice the balanced load N top_k held
-    / E: one under a balanced routing, as many more as a skewed one
-    fills (a loop with a run-time trip count, so XLA's static shapes
-    hold any routing). TokensPerExpert [held] is the second output.
+    multiplied group by group, weighed and summed back. The grouped
+    products take one of two routes, chosen from what the op sees
+    (pallas_kernels.grouped_plan): on the TPU, with bf16 operands (AMP)
+    and L, F multiples of 128, the Pallas grouped matmul ('pallas');
+    anywhere else ``lax.ragged_dot`` ('ragged_dot': the CPU, float32
+    without AMP, odd widths). The rows go in chunks of twice the
+    balanced load N top_k held / E: one under a balanced routing, as
+    many more as a skewed one fills (a loop with a run-time trip count,
+    so XLA's static shapes hold any routing). TokensPerExpert [held] is
+    the second output.
     What the experts held elsewhere would add is left out. Each
     lowering counts once in ``moe_lowerings_total{experts=, held=,
     top_k=, route=}`` (compiler/passes.py::moe_counts)."""
+    from .pallas_kernels import grouped_plan
     x_in = unwrap(ctx.input('X'))
     E, K = int(ctx.attr('num_experts')), int(ctx.attr('top_k'))
     first, held = int(ctx.attr('first_expert', 0)), int(ctx.attr('held'))
@@ -345,19 +361,24 @@ def _routed_experts(ctx):
     chosen, weight = route_held(
         scores, lax.stop_gradient(bias), K, first, held,
         float(ctx.attr('routed_scaling_factor', 1.0)))
+    chunk = expert_chunk_rows(N, K, held, E)
+    w1 = unwrap(ctx.input('W1'))
+    plan = grouped_plan(
+        jax.ShapeDtypeStruct((chunk, L), mxu_operand(u).dtype),
+        jax.ShapeDtypeStruct(w1.shape, mxu_operand(w1).dtype))
     _obs.default_registry().counter(
         'moe_lowerings_total',
         help='routed_experts op lowerings, by the experts routed over, '
              'the experts held here, the experts a token takes and the '
-             'grouped product (ragged_dot)',
+             'grouped product (pallas: the Pallas grouped matmul; '
+             'ragged_dot: lax.ragged_dot)',
         experts=str(E), held=str(held), top_k=str(K),
-        route='ragged_dot').inc()
+        route='ragged_dot' if plan is None else 'pallas').inc()
     counts = jnp.sum(chosen, axis=1, dtype=jnp.int32)       # [held]
     place = (jnp.cumsum(counts) - counts)[:, None] \
         + jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1
     out = _held_experts(
-        u, unwrap(ctx.input('W1')), unwrap(ctx.input('W2')), weight,
-        jnp.where(chosen, place, -1), counts,
-        expert_chunk_rows(N, K, held, E))
+        u, w1, unwrap(ctx.input('W2')), weight,
+        jnp.where(chosen, place, -1), counts, chunk)
     ctx.set_output('Out', out.reshape(x_in.shape).astype(x_in.dtype))
     ctx.set_output('TokensPerExpert', counts)

@@ -1313,3 +1313,237 @@ def fused_lstm_cell(xg, r_prev, c_prev, w, interpret=None):
     if not use_pallas:
         return _lstm_cell_reference(xg, r_prev, c_prev, w)
     return _lstm_cell(xg, r_prev, c_prev, w, interpret)
+
+
+# ---- grouped matmul (routed experts) --------------------------------------------
+# rows [M, K] lie expert by expert; ``sizes`` [held] says how many rows
+# each expert owns and sums to M. The rows go in tiles of ``tm``; a tile
+# that straddles a group boundary is visited once for every expert with
+# rows in it, under that expert's row mask, so no row is padded and a
+# product's grid is M / tm + held - 1 visits whatever the split.
+
+def grouped_visits(sizes, tiles, tm):
+    """The visit table of a grouped product: int32 [tiles + held - 1]
+    arrays (tile, expert, lo, hi). Visit v multiplies rows lo..hi of
+    row tile ``tile`` by expert ``expert``. The visits partition the
+    rows at every tile start and every group start, in row order;
+    where the two coincide (or a group is empty) a visit has no row
+    (lo == hi), which is how an expert with no row still gets its
+    visit. Made by counting, as the layer's layout is: a sort of 31
+    numbers is a kernel of its own on the TPU."""
+    held = sizes.shape[0]
+    starts = jnp.cumsum(sizes) - sizes
+    point = jnp.concatenate(
+        [jnp.arange(tiles, dtype=jnp.int32) * tm, starts[1:]])
+    ident = jnp.concatenate(
+        [jnp.zeros((tiles,), jnp.int32), jnp.arange(1, held, dtype=jnp.int32)])
+    key = point * held + ident                   # a tile's start first
+    rank = jnp.sum(key[None, :] < key[:, None], axis=1)
+    v = jnp.arange(point.shape[0])[:, None]      # [v, point]
+    lo = jnp.sum(jnp.where(rank[None, :] == v, point[None, :], 0), axis=1)
+    hi = jnp.concatenate([lo[1:], jnp.full((1,), tiles * tm, jnp.int32)])
+    expert = jnp.max(jnp.where(rank[None, :] <= v, ident[None, :], 0),
+                     axis=1)
+    return jnp.minimum(lo // tm, tiles - 1), expert, lo, hi
+
+
+def _visit_rows(tile_ref, lo_ref, hi_ref, v, shape, tm):
+    """The rows of visit ``v`` as a mask of ``shape`` ([tm, lanes])."""
+    row = tile_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return jnp.logical_and(row >= lo_ref[v], row < hi_ref[v])
+
+
+def _gmm_kernel(tile_ref, expert_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref,
+                *, tm, dims):
+    # a tile's block stays in VMEM over its consecutive visits; each
+    # writes its own rows and the last leaves every row written
+    v = pl.program_id(1)
+    y = _dot(x_ref[...], w_ref[...], dims).astype(o_ref.dtype)
+    keep = _visit_rows(tile_ref, lo_ref, hi_ref, v, y.shape, tm)
+    o_ref[...] = jnp.where(keep, y, o_ref[...])
+
+
+def _tgmm_kernel(tile_ref, expert_ref, lo_ref, hi_ref, a_ref, b_ref, o_ref,
+                 acc_ref, *, tm):
+    # an expert's visits are consecutive: zero at its first, write at
+    # its last. The rows of other experts in the tile are zeroed in a
+    # (through float32: v5e's vector unit has no bf16), so whatever b
+    # holds there is multiplied by 0.
+    v, last = pl.program_id(1), pl.num_programs(1) - 1
+    e = expert_ref[v]
+
+    @pl.when(jnp.logical_or(v == 0, expert_ref[jnp.maximum(v - 1, 0)] != e))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    a = a_ref[...]
+    keep = _visit_rows(tile_ref, lo_ref, hi_ref, v, a.shape, tm)
+    a = jnp.where(keep, a.astype(jnp.float32), 0.0).astype(a.dtype)
+    acc_ref[...] += _dot(a, b_ref[...], (0, 0))
+
+    @pl.when(jnp.logical_or(
+        v == last, expert_ref[jnp.minimum(v + 1, last)] != e))
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+# both grids are (blocks of the dimension that is not contracted,
+# visits); whole weight blocks at the cell's widths need 14 MB (product)
+# and 23 MB (weight gradient) of VMEM
+_GROUPED_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=('parallel', 'arbitrary'),
+    vmem_limit_bytes=32 * 1024 * 1024)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    'tm', 'tn', 'transpose', 'out_dtype', 'interpret'))
+def _gmm_pallas_call(visits, x, w, *, tm, tn, transpose, out_dtype,
+                     interpret):
+    """x [M, K] against w [held, K, N] (``transpose``: [held, N, K], read
+    as it lies and contracted on its last dimension) -> [M, N]."""
+    M, K = x.shape
+    N = w.shape[1] if transpose else w.shape[2]
+    if transpose:
+        w_spec = pl.BlockSpec((None, tn, K), lambda n, v, t, e, lo, hi:
+                              (e[v], n, 0))
+    else:
+        w_spec = pl.BlockSpec((None, K, tn), lambda n, v, t, e, lo, hi:
+                              (e[v], 0, n))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm,
+                          dims=(1, 1) if transpose else (1, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(N // tn, visits[0].shape[0]),
+            in_specs=[
+                pl.BlockSpec((tm, K), lambda n, v, t, e, lo, hi: (t[v], 0)),
+                w_spec,
+            ],
+            out_specs=pl.BlockSpec((tm, tn), lambda n, v, t, e, lo, hi:
+                                   (t[v], n)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        name='_gmm_kernel',
+        compiler_params=_GROUPED_COMPILER_PARAMS,
+        interpret=interpret,
+    )(*visits, x, w)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    'held', 'tm', 'tn', 'out_dtype', 'interpret'))
+def _tgmm_pallas_call(visits, a, b, *, held, tm, tn, out_dtype, interpret):
+    """out[e] = a[rows of e].T @ b[rows of e]: a [M, K], b [M, N] ->
+    [held, K, N]; an expert without a row gets zeros."""
+    (M, K), N = a.shape, b.shape[1]
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(N // tn, visits[0].shape[0]),
+            in_specs=[
+                pl.BlockSpec((tm, K), lambda n, v, t, e, lo, hi: (t[v], 0)),
+                pl.BlockSpec((tm, tn), lambda n, v, t, e, lo, hi:
+                             (t[v], n)),
+            ],
+            out_specs=pl.BlockSpec((None, K, tn), lambda n, v, t, e, lo, hi:
+                                   (e[v], 0, n)),
+            scratch_shapes=[pltpu.VMEM((K, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((held, K, N), out_dtype),
+        name='_tgmm_kernel',
+        compiler_params=_GROUPED_COMPILER_PARAMS,
+        interpret=interpret,
+    )(*visits, a, b)
+
+
+# The tiles, from a sweep on one v5e chip at the cell's shape (3072 rows
+# in 8 groups, 1024 <-> 2688, bf16; device ms of the kernel alone,
+# PERF.md section 6, PR 32; lax.ragged_dot on the same operands: 0.595 /
+# 0.381 forward, 0.575 / 0.658 data gradients, 0.323 / 0.407 weight
+# gradients). Row tile 128 and 256 read alike (0.171 / 0.178 forward,
+# 0.184 / 0.187 weight gradient): 256-row tiles fill the MXU better and
+# recompute more rows of a straddled tile; 128 keeps the visits' waste
+# least. The other dimensions whole wherever a weight block stays
+# under _GROUPED_BLOCK_BYTES (doubled by the pipeline; a weight
+# gradient's float32 accumulator is twice a bf16 block): N in 896-wide
+# blocks read 0.194 forward against 0.171 whole, 384-wide 0.247; a
+# weight gradient 0.205 at [1024, 896] against 0.184 whole, 0.197-0.249
+# with K in 512-wide blocks too (it stays whole). The time is
+# the MXU's (one expert taking every row, an eighth of the weight
+# bytes, reads the same 0.171), at about two thirds of its rate.
+_GROUPED_ROW_TILE = 128
+_GROUPED_BLOCK_BYTES = 6 * 1024 * 1024
+
+
+def _grouped_block(n, other, dtype):
+    """The widest block of an ``n``-wide dimension, in whole 128-lane
+    tiles that divide ``n``, whose [other, block] weights stay under
+    _GROUPED_BLOCK_BYTES."""
+    room = _GROUPED_BLOCK_BYTES // (other * jnp.dtype(dtype).itemsize)
+    return _pick_div(n, max(room, 128), 128)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped(rows, w, visits, tm, interpret):
+    K, N = w.shape[1:]
+    return _gmm_pallas_call(
+        visits, rows, w, tm=tm, tn=_grouped_block(N, K, w.dtype),
+        transpose=False, out_dtype=jnp.float32, interpret=interpret)
+
+
+def _grouped_fwd(rows, w, visits, tm, interpret):
+    return _grouped(rows, w, visits, tm, interpret), (rows, w, visits)
+
+
+def _grouped_bwd(tm, interpret, res, g):
+    # both gradients take the cotangent in the rows' dtype and come out
+    # in their operand's, which is where jax's transposition of a
+    # bf16 x bf16 -> f32 product rounds them too
+    rows, w, visits = res
+    K, N = w.shape[1:]
+    g = g.astype(rows.dtype)
+    d_rows = _gmm_pallas_call(
+        visits, g, w, tm=tm, tn=_grouped_block(K, N, w.dtype),
+        transpose=True, out_dtype=rows.dtype, interpret=interpret)
+    d_w = _tgmm_pallas_call(
+        visits, rows, g, held=w.shape[0], tm=tm,
+        tn=_grouped_block(N, K, w.dtype), out_dtype=w.dtype,
+        interpret=interpret)
+    return d_rows, d_w, None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def grouped_plan(rows, w, interpret=None):
+    """THE engagement decision for rows [M, K] against w [held, K, N]:
+    ``(row tile, interpret)`` for the Pallas grouped matmul, or None
+    where ``lax.ragged_dot`` runs instead. The kernels take bf16
+    operands (AMP's ``mxu_operand`` on the chip) whose K and N are whole
+    128-lane tiles and whose rows are whole row tiles; the interpreter
+    (tests) takes float32 too. grouped_matmul routes by it and the
+    routed_experts op labels its lowering counter with it."""
+    M, K = rows.shape
+    N = w.shape[2]
+    bf16 = rows.dtype == w.dtype == jnp.bfloat16
+    if not (interpret or (_on_tpu() and bf16)):
+        return None
+    if K % 128 or N % 128 or M % _GROUPED_ROW_TILE:
+        return None
+    return _GROUPED_ROW_TILE, interpret or False
+
+
+def grouped_matmul(rows, w, sizes, interpret=None):
+    """rows[rows of e] @ w[e] for every expert e -> [M, N] float32, as
+    ``lax.ragged_dot(rows, w, sizes, preferred_element_type=float32)``.
+    rows [M, K] lie expert by expert, ``sizes`` [held] int32 must sum to
+    M (the caller gives the rows nobody routed to its last expert: the
+    kernels, like libtpu's, write only rows that lie in a group).
+    Differentiable in rows and w on either route."""
+    plan = grouped_plan(rows, w, interpret)
+    if plan is None:
+        return jax.lax.ragged_dot(rows, w, sizes,
+                                  preferred_element_type=jnp.float32)
+    tm, interpret = plan
+    visits = grouped_visits(sizes, rows.shape[0] // tm, tm)
+    return _grouped(rows, w, visits, tm, interpret)

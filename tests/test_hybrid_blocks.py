@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.ops import hybrid_ops
+from paddle_tpu.ops import pallas_kernels as pk
 
 # by path: ``models`` is also benchmark/fluid's module, which other
 # tests of this suite import by that name
@@ -223,21 +224,51 @@ def _routed_case(cfg, bias=None, seed=3):
     return want_tokens
 
 
+@pytest.fixture
+def route(request, monkeypatch):
+    """The grouped products' route: 'ragged_dot' as the CPU takes it,
+    or 'pallas' forced through the interpreter (the rule itself says
+    no on this backend). Returns what the configuration needs for the
+    rule's shape side to hold: latent and expert widths of whole
+    128-lane tiles, a chunk of whole row tiles."""
+    if request.param == 'ragged_dot':
+        return {}
+    plan = pk.grouped_plan
+    monkeypatch.setattr(pk, 'grouped_plan', lambda rows, w, interpret=None:
+                        plan(rows, w, True))
+    monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', pk._GROUPED_ROW_TILE)
+    return {'moe_latent_size': 128, 'moe_intermediate_size': 256}
+
+
+def _routes_taken(fn):
+    from paddle_tpu.compiler.passes import moe_counts
+    before = moe_counts(by=('route',))
+    out = fn()
+    return out, {k[0] for k, n in moe_counts(by=('route',)).items()
+                 if n != before.get(k, 0)}
+
+
+@pytest.mark.parametrize('route', ['ragged_dot', 'pallas'], indirect=True)
 @pytest.mark.parametrize('held', [(0, 16), (4, 8), (13, 3)])
-def test_routed_experts_match_reference(held):
+def test_routed_experts_match_reference(held, route, request):
     """All experts, and two shares: routed over all 16, computed for the
-    experts held."""
-    tokens = _routed_case(tiny_cfg(experts_first=held[0],
-                                   n_routed_experts=held[1]))
+    experts held; through ``lax.ragged_dot`` and through the Pallas
+    grouped matmul (interpreted)."""
+    tokens, taken = _routes_taken(lambda: _routed_case(tiny_cfg(
+        experts_first=held[0], n_routed_experts=held[1], **route)))
     assert sum(tokens) > 0
+    assert taken == {request.node.callspec.params['route']}
 
 
-def test_no_token_is_dropped_under_a_skewed_routing(monkeypatch):
+@pytest.mark.parametrize('route', ['ragged_dot', 'pallas'], indirect=True)
+def test_no_token_is_dropped_under_a_skewed_routing(route, monkeypatch):
     """Every token is sent to experts 5 and 6 (and one other): each sees
     all 2 T tokens, more pairs than one chunk of rows (twice the
-    balanced load) holds, and the result is still the reference's."""
-    monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', 8)
-    cfg = tiny_cfg(experts_first=4, n_routed_experts=4)
+    balanced load) holds, and the result is still the reference's, on
+    either route of the grouped products."""
+    if not route:
+        monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', 8)
+    cfg = tiny_cfg(experts_first=4, n_routed_experts=4, **route)
     chunk = hybrid_ops.expert_chunk_rows(2 * T, 3, 4, 16)
     # a balanced routing fits one chunk ...
     assert sum(_routed_case(cfg)) <= chunk
@@ -434,6 +465,50 @@ def test_lowerings_are_counted():
               if n != was.get(k, 0)} for was, now in zip(before, after)]
     assert moved == [{('16', '8', '3', 'ragged_dot'): 2},
                      {('xla', '16'): 1}, {('2',): 1}]
+
+
+@pytest.mark.parametrize('backend,amp_on,widths,route', [
+    ('tpu', True, (128, 256), 'pallas'),
+    ('tpu', False, (128, 256), 'ragged_dot'),     # float32 operands
+    ('tpu', True, (12, 20), 'ragged_dot'),        # not whole lane tiles
+    ('cpu', True, (128, 256), 'ragged_dot'),
+], ids=['chip-amp', 'chip-f32', 'chip-odd-widths', 'cpu-amp'])
+def test_moe_counter_names_the_route_on_each_side_of_the_rule(
+        backend, amp_on, widths, route, amp, monkeypatch):
+    """``moe_lowerings_total{route=}`` says which grouped product a
+    lowering took: 'pallas' on a TPU backend with bf16 operands (AMP)
+    and widths of whole 128-lane tiles, 'ragged_dot' on every other
+    side of that rule. Lowered, not run: the backend is only said to be
+    a TPU."""
+    from paddle_tpu.compiler.passes import moe_counts
+    amp.set_amp(amp_on)
+    monkeypatch.setattr(pk, '_on_tpu', lambda: backend == 'tpu')
+    monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', pk._GROUPED_ROW_TILE)
+    # what the rule engages is lowered for this backend's interpreter
+    kernels, ragged_dot, calls = pk._grouped, jax.lax.ragged_dot, []
+    monkeypatch.setattr(pk, '_grouped', lambda rows, w, visits, tm, _:
+                        calls.append('pallas')
+                        or kernels(rows, w, visits, tm, True))
+    monkeypatch.setattr(jax.lax, 'ragged_dot', lambda *a, **kw:
+                        calls.append('ragged_dot') or ragged_dot(*a, **kw))
+    cfg = tiny_cfg(experts_first=8, n_routed_experts=8,
+                   hybrid_override_pattern='E', num_hidden_layers=1,
+                   moe_latent_size=widths[0],
+                   moe_intermediate_size=widths[1])
+    cfg['published'] = {'num_hidden_layers': 1}
+    built = nemotron_h.build(cfg, TRAFFIC)
+    batch = {k: np.asarray(v) for k, v in nemotron_h.draw_batch(
+        cfg, TRAFFIC, jax.random.PRNGKey(0)).items()}
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(built['startup'])
+        before = moe_counts()
+        exe.lowered(built['main'], feed=batch, fetch_list=[built['loss']])
+        after = moe_counts()
+    moved = {k: n - before.get(k, 0) for k, n in after.items()
+             if n != before.get(k, 0)}
+    assert moved == {('16', '8', '3', route): 1}
+    assert calls and set(calls) == {route}
 
 
 def test_amp_keeps_scores_and_the_carried_state_float32(amp):

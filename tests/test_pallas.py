@@ -878,3 +878,163 @@ def test_flash_engaged_path_has_no_transpose(H, D):
         lambda q, k, v, g: jax.vjp(attend, q, k, v)[1](g), x, x, x, x)
     assert names.count('pallas_call') == 2      # forward, merged backward
     assert shapes == [(B, H, T)], shapes
+
+
+# ---- the grouped matmul of the routed experts ------------------------------
+_GROUPED_HELD = 4
+# rows an expert owns, of 512 in 128-row tiles
+_GROUPED_SPLITS = {
+    'balanced': [128, 128, 128, 128],
+    'one-takes-all': [0, 512, 0, 0],
+    'an-expert-without-a-row': [200, 0, 56, 256],
+    'ends-on-tile-boundaries': [256, 128, 0, 128],
+    'ends-off-tile-boundaries': [1, 130, 254, 127],
+    'three-experts-in-one-tile': [100, 10, 8, 394],
+}
+_GROUPED_ORDERS = {'wide': (128, 256), 'narrow': (256, 128)}
+
+
+def _grouped_operands(split, order, seed=0):
+    rng = np.random.RandomState(seed)
+    K, N = _GROUPED_ORDERS[order]
+    sizes = np.asarray(_GROUPED_SPLITS[split], np.int32)
+    M = int(sizes.sum())
+    return (rng.randn(M, K).astype('float32'),
+            rng.randn(_GROUPED_HELD, K, N).astype('float32'),
+            rng.randn(M, N).astype('float32'), sizes)
+
+
+def _grouped_dense(rows, w, g, sizes):
+    """The product, its data gradient and its weight gradient under
+    sum(out * g), one expert after the other."""
+    out, d_rows, d_w = [], [], []
+    lo = 0
+    for e, n in enumerate(sizes):
+        r, ge = rows[lo:lo + n], g[lo:lo + n]
+        out.append(r @ w[e])
+        d_rows.append(ge @ w[e].T)
+        d_w.append(r.T @ ge)
+        lo += n
+    return {'product': np.concatenate(out),
+            'data-gradient': np.concatenate(d_rows),
+            'weight-gradient': np.stack(d_w)}
+
+
+def _grouped_pallas(rows, w, g, sizes, interpret=True):
+    def loss(rows, w):
+        out = pk.grouped_matmul(rows, w, jnp.asarray(sizes),
+                                interpret=interpret)
+        return jnp.sum(out * g), out
+    (_, out), (d_rows, d_w) = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True))(jnp.asarray(rows),
+                                             jnp.asarray(w))
+    return {'product': np.asarray(out), 'data-gradient': np.asarray(d_rows),
+            'weight-gradient': np.asarray(d_w)}
+
+
+_GROUPED_RUNS = {}
+
+
+def _grouped_case(split, order, blocks):
+    """One run of the three kernels for the three uses' cases; with
+    ``blocks`` 'blocked' a weight block is one 128 x 128 tile, so the
+    dimension that is not contracted goes in two blocks."""
+    if (split, order, blocks) not in _GROUPED_RUNS:
+        operands = _grouped_operands(split, order)
+        with pytest.MonkeyPatch.context() as patch:
+            if blocks == 'blocked':
+                patch.setattr(pk, '_GROUPED_BLOCK_BYTES', 128 * 128 * 4)
+                K, N = _GROUPED_ORDERS[order]
+                assert pk._grouped_block(N, K, jnp.float32) < max(K, N)
+            _GROUPED_RUNS[split, order, blocks] = (
+                _grouped_pallas(*operands), _grouped_dense(*operands))
+    return _GROUPED_RUNS[split, order, blocks]
+
+
+@pytest.mark.parametrize('use', ['product', 'data-gradient',
+                                 'weight-gradient'])
+@pytest.mark.parametrize('blocks', ['whole', 'blocked'])
+@pytest.mark.parametrize('order', sorted(_GROUPED_ORDERS))
+@pytest.mark.parametrize('split', sorted(_GROUPED_SPLITS))
+def test_grouped_matmul_matches_a_dense_loop(split, order, blocks, use):
+    """The Pallas grouped matmul through the interpreter, float32,
+    against one dense product an expert: rows @ W[e], its gradient in
+    the rows (against W[e] transposed, read as it lies) and in W
+    (accumulated over an expert's row tiles)."""
+    got, want = _grouped_case(split, order, blocks)
+    assert got[use].dtype == np.float32
+    np.testing.assert_allclose(got[use], want[use], rtol=2e-5, atol=2e-4)
+    if use == 'weight-gradient':
+        for e, n in enumerate(_GROUPED_SPLITS[split]):
+            if n == 0:
+                assert not got[use][e].any()        # exactly zero
+
+
+def test_grouped_matmul_visits_cover_every_row_once():
+    """The visit table: tiles + held - 1 visits whatever the split, in
+    row order, an expert's visits consecutive and every expert visited;
+    the visits' rows partition the rows, each inside its tile and
+    inside its expert's group."""
+    tm, tiles = 128, 4
+    for split, sizes in sorted(_GROUPED_SPLITS.items()):
+        tile, expert, lo, hi = (np.asarray(t) for t in pk.grouped_visits(
+            jnp.asarray(sizes, jnp.int32), tiles, tm))
+        assert len(tile) == tiles + _GROUPED_HELD - 1, split
+        assert lo[0] == 0 and hi[-1] == tiles * tm
+        assert (lo[1:] == hi[:-1]).all() and (lo <= hi).all()
+        assert (np.diff(expert) >= 0).all() and (np.diff(tile) >= 0).all()
+        assert sorted(set(expert)) == list(range(_GROUPED_HELD))
+        ends = np.cumsum(sizes)
+        for t, e, a, b in zip(tile, expert, lo, hi):
+            assert 0 <= t < tiles
+            if a < b:
+                assert t * tm <= a and b <= (t + 1) * tm
+                assert ends[e] - sizes[e] <= a and b <= ends[e]
+
+
+def test_grouped_matmul_keeps_experts_apart_and_reads_nothing_unwritten():
+    """What libtpu's ragged-dot taught PR 31 (a gradient of 1.4e8 from
+    rows nobody wrote, every CPU test green): the interpreter fills
+    every buffer a kernel has not written with NaN, and the rows and
+    the cotangent of one expert are 1e30, which its neighbours in the
+    same row tile multiply by zero. No NaN comes out, the other experts'
+    rows and weight gradients are what they are without the poison, the
+    poisoned expert's own are finite where 1e30 times a weight is, and
+    the expert without a row gets exactly zero."""
+    from jax.experimental.pallas import tpu as pltpu
+    nan_filled = pltpu.InterpretParams(uninitialized_memory='nan')
+    rows, w, g, sizes = _grouped_operands('an-expert-without-a-row', 'wide')
+    clean = _grouped_pallas(rows, w, g, sizes, interpret=nan_filled)
+    lo, hi = sizes[0], sizes[0] + sizes[1] + sizes[2]   # expert 2's rows
+    assert hi - lo == sizes[2] and lo // 128 == 1 and hi == 256
+    rows[lo:hi], g[lo:hi] = 1e30, 1e30
+    got = _grouped_pallas(rows, w, g, sizes, interpret=nan_filled)
+    for name in ('product', 'data-gradient', 'weight-gradient'):
+        assert not np.isnan(clean[name]).any(), name
+        assert not np.isnan(got[name]).any(), name
+    others = np.r_[0:lo, hi:len(rows)]
+    for name in ('product', 'data-gradient'):
+        np.testing.assert_array_equal(got[name][others], clean[name][others])
+        assert np.isfinite(got[name]).all()
+    for e in (0, 3):
+        np.testing.assert_array_equal(got['weight-gradient'][e],
+                                      clean['weight-gradient'][e])
+    assert not got['weight-gradient'][1].any()
+    assert not clean['weight-gradient'][1].any()
+
+
+@pytest.mark.parametrize('dtype,K,N,rows,backend,interpret,plan', [
+    ('bfloat16', 1024, 2688, 3072, 'tpu', None, (128, False)),
+    ('float32', 1024, 2688, 3072, 'tpu', None, None),      # no AMP
+    ('bfloat16', 1024, 2688, 3072, 'cpu', None, None),
+    ('bfloat16', 12, 20, 3072, 'tpu', None, None),         # odd widths
+    ('bfloat16', 1024, 2688, 3000, 'tpu', None, None),     # a ragged tile
+    ('float32', 128, 256, 512, 'cpu', True, (128, True)),  # the tests
+], ids=['chip-amp', 'chip-f32', 'cpu', 'odd-widths', 'odd-rows',
+        'interpreter'])
+def test_grouped_plan_engages_by_backend_dtype_and_shape(
+        dtype, K, N, rows, backend, interpret, plan, monkeypatch):
+    monkeypatch.setattr(pk, '_on_tpu', lambda: backend == 'tpu')
+    got = pk.grouped_plan(jax.ShapeDtypeStruct((rows, K), dtype),
+                          jax.ShapeDtypeStruct((8, K, N), dtype), interpret)
+    assert got == plan
