@@ -22,9 +22,11 @@ process at a time; this parent never touches JAX.
    mixer, routed experts, grouped-query attention) under AMP, its
    scan state and router scores float32 in the lowered step, and one
    routed-experts layer wide enough for the Pallas grouped matmul
-   against the ``lax.ragged_dot`` route on the same operands; and,
-   when four devices are visible, ResNet-50 through
-   ``ParallelExecutor``.
+   against the ``lax.ragged_dot`` route on the same operands; six Adam
+   steps of a tiny window / full attention stack with rotary positions,
+   gated attention and gated experts under AMP, a windowed lowering and
+   the gated products on the Pallas route; and, when four devices are
+   visible, ResNet-50 through ``ParallelExecutor``.
 2. ``--phase cache`` — a second process compiles the same ResNet-50
    step and must get it from the persistent compile cache.
 
@@ -593,6 +595,89 @@ def _routed_layer_on_both_routes(rehearse):
     return worst
 
 
+# ---- leg 5c: window and full attention, rotary, gated experts, under AMP ---
+def leg_window_stack(rehearse, steps=6):
+    """A few Adam steps of a tiny stack as benchmark/chip/models/afmoe.py
+    builds it (layers S S F S after... the first is the dense one; 4
+    query heads on 2 KV heads at head size 64, a window of a quarter of
+    the sequence, rotary on the window layers, gated attention, 8 of
+    16 gated experts held, one shared) through the Executor under the
+    backend's own AMP: the loss falls; the windowed lowerings took the
+    Pallas kernels, which skip the tiles below the band, and the gated
+    experts' products the Pallas grouped matmul; the rotary angles are
+    float32 in the lowered step."""
+    import importlib.util
+    import re
+    import jax
+    import numpy as np
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.compiler.passes import (flash_counts, moe_counts,
+                                            window_flash_counts)
+    from paddle_tpu.core import amp
+    spec = importlib.util.spec_from_file_location(
+        'chip_models_afmoe', os.path.join(
+            _HERE, 'benchmark', 'chip', 'models', 'afmoe.py'))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+    T = 256 if rehearse else 4096
+    cfg = {
+        'layer_types': [model.SLIDING, model.SLIDING, model.FULL,
+                        model.SLIDING],
+        'num_hidden_layers': 4, 'num_dense_layers': 1, 'hidden_size': 128,
+        'vocab_size': 256, 'rms_norm_eps': 1e-5, 'num_attention_heads': 4,
+        'num_key_value_heads': 2, 'head_dim': 64, 'sliding_window': T // 4,
+        'rope_theta': 10000.0, 'intermediate_size': 256,
+        'moe_intermediate_size': 128, 'num_shared_experts': 1,
+        'router_num_experts': 16, 'num_experts': 8, 'experts_first': 4,
+        'num_experts_per_tok': 2, 'route_scale': 2.826, 'route_norm': True,
+        'score_func': 'sigmoid', 'hidden_act': 'silu', 'mup_enabled': True,
+        'tie_word_embeddings': False,
+        'optimizer': {'learning_rate': 3e-3, 'beta1': 0.9, 'beta2': 0.95,
+                      'epsilon': 1e-8}}
+    traffic = {'batch': 1, 'seq_len': T}
+    say('[window stack/executor] layers S S F S, 1 x %d tokens, window %d, '
+        'AMP %s' % (T, cfg['sliding_window'],
+                    'on' if amp.amp_enabled() else 'off'))
+    built = model.build(cfg, traffic)
+    feed = {k: np.asarray(v) for k, v in model.draw_batch(
+        cfg, traffic, jax.random.PRNGKey(0)).items()}
+
+    def counts():
+        return (flash_counts(by=('route', 'window')), window_flash_counts(),
+                moe_counts(by=('route', 'act')))
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(_place(fluid, rehearse))
+        exe.run(built['startup'])
+        before = counts()
+        text = exe.lowered(built['main'], feed, [built['loss']]).as_text()
+        after = counts()
+        losses = [float(np.ravel(exe.run(
+            built['main'], feed=feed, fetch_list=[built['loss']])[0])[0])
+            for _ in range(steps)]
+    moved = [{k: n - was.get(k, 0) for k, n in now.items()
+              if n != was.get(k, 0)} for was, now in zip(before, after)]
+    say('  losses: ' + ' '.join('%.4f' % v for v in losses))
+    say('  lowerings (route, window): %s; windowed on the kernels: %s; '
+        'experts (route, act): %s' % tuple(moved))
+    check(_all_finite(losses) and losses[-1] < losses[0],
+          'losses finite and falling over %d steps' % steps)
+    trig = re.findall(r'stablehlo\.(?:cosine|sine) .*tensor<[0-9x]*x(\w+)>',
+                      text)
+    check(trig and set(trig) == {'f32'},
+          'the rotary angles are float32 (%d cos / sin)' % len(trig))
+    if not rehearse:
+        window = str(cfg['sliding_window'])
+        check(moved[0] == {('pallas', window): 3, ('pallas', '0'): 1},
+              'three windowed lowerings and the full one took the kernels')
+        check(moved[1] == {(window,): 3},
+              'window_flash_counts() reads the three')
+        check(moved[2] == {('pallas', 'swiglu'): 3},
+              'the gated experts\' products took the Pallas grouped matmul')
+    mosaic = text.count('tpu_custom_call')
+    say('  %d Mosaic custom call(s) in the lowered step' % mosaic)
+    return {'losses': losses, 'mosaic_calls': mosaic}
+
+
 # ---- leg 6: four chips, one process --------------------------------------
 def leg_four_chips(cfg, one_chip_losses):
     import jax
@@ -718,6 +803,7 @@ def phase_main(rehearse):
     result['se_resnext'] = leg_model_step(
         rehearse, 'se_resnext', cfg['resnet_batch'], {}, None)
     result['hybrid'] = leg_hybrid(rehearse)
+    result['window_stack'] = leg_window_stack(rehearse)
     if rehearse:
         say('[resnet50/parallel_executor] not rehearsed')
     else:

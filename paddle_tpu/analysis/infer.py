@@ -142,6 +142,7 @@ _IDENTITY_SLOTS = {
     'rms_norm': ('X', ('Y',)),
     'causal_conv1d': ('X', ('Out',)),
     'ssd_scan': ('X', ('Out',)),
+    'rotary_embedding': ('X', ('Out',)),
     'assign': ('X', ('Out',)),
     'relu_grad': ('X', ('Out',)),
     'softmax_with_cross_entropy': ('Logits', ('Softmax',)),
@@ -175,7 +176,7 @@ def _cast(op, env, emit):
 
 
 @register_shape('softmax', 'dropout', 'batch_norm', 'layer_norm',
-                'rms_norm', 'causal_conv1d', 'ssd_scan',
+                'rms_norm', 'causal_conv1d', 'ssd_scan', 'rotary_embedding',
                 'assign', 'zero_reduce_scatter',
                 'softmax_with_cross_entropy')
 def _identity(op, env, emit):
@@ -560,7 +561,8 @@ def _router_scores(op, env, emit):
 @register_shape('routed_experts')
 def _routed_experts(op, env, emit):
     """Out mirrors X; Scores is num_experts wide; W1 [held, D, F] and
-    W2 [held, F, D] agree with X's width and with each other."""
+    W2 [held, F, D] agree with X's width and with each other, and a
+    gated expert's W3 with W1."""
     x = env(_first(op, 'X'))
     out = _out(op)
     if out is None or x is None:
@@ -576,6 +578,10 @@ def _routed_experts(op, env, emit):
         _last_dim_is(op, 'X', x, w1.shape[1], "W1's rows", emit)
         _last_dim_is(op, 'W1', w1, w2.shape[1], "W2's rows", emit)
         _last_dim_is(op, 'W2', w2, w1.shape[1], "W1's rows", emit)
+        w3 = env(_first(op, 'W3')) if op.inputs.get('W3') else None
+        if w3 is not None and w3.shape is not None and len(w3.shape) == 3:
+            # a gated expert's up projection is shaped as its gate
+            _last_dim_is(op, 'W3', w3, w1.shape[2], "W1's columns", emit)
     updates = {out: VarInfo(x.shape, x.dtype)}
     tokens = _out(op, 'TokensPerExpert')
     if tokens is not None:

@@ -870,12 +870,26 @@ def flash_counts(by=('route', 'dtype')):
     is the body the kernels give a tile on the causal diagonal
     (pallas_kernels.flash_diag: 'chunked<r>', 'whole', or 'none' for
     the xla route or no mask), 'kv_heads' the KV heads the query heads
-    share (num_heads where every query head has its own)."""
+    share (num_heads where every query head has its own), 'window' the
+    window the attention ran under ('0': none, or one that reaches
+    every key)."""
     return _label_counts('flash_attention_lowerings_total', by)
 
 
+def window_flash_counts():
+    """``{(window,): n}``: the flash_attention lowerings with a window
+    that took the Pallas route, whose kernels skip the tiles below the
+    band; a windowed lowering that fell to the XLA route (masked, not
+    skipped) is not in it."""
+    return {(w,): n for (route, w), n in
+            flash_counts(by=('route', 'window')).items()
+            if route == 'pallas' and w != '0'}
+
+
 def moe_counts(by=('experts', 'held', 'top_k', 'route')):
-    """``{(experts, held, top_k, route): n}``: the process's
+    """``{(experts, held, top_k, route): n}`` (``by`` may also name
+    'act', the expert's activation: 'relu2' or 'swiglu'): the
+    process's
     routed_experts op lowerings so far (ops/hybrid_ops.py; counted per
     trace, as the others are): the experts routed over, the experts
     held here, the experts a token takes (strings, as labels are) and
@@ -885,6 +899,14 @@ def moe_counts(by=('experts', 'held', 'top_k', 'route')):
     (``lax.ragged_dot``: everything else, the CPU tests among it).
     ops/pallas_kernels.py::grouped_plan is the rule."""
     return _label_counts('moe_lowerings_total', by)
+
+
+def rotary_counts(by=('dim', 'dtype')):
+    """``{(dim, dtype): n}``: the process's rotary_embedding op
+    lowerings so far (ops/hybrid_ops.py): the head size turned and the
+    dtype of the stream it turned ('bfloat16' under AMP; the angles,
+    cos and sin are float32 in all)."""
+    return _label_counts('rotary_lowerings_total', by)
 
 
 def ssd_counts(by=('route', 'chunk')):

@@ -94,8 +94,14 @@ def act_bf16():
     be decided by ties); ``ssd_scan``'s ``dt``, decays, cumulative
     sums and the state carried between chunks (only the operands of
     its four products go through ``mxu_operand``); ``routed_experts``'
-    routing weights, its activation and the sum over experts (the two
-    grouped products take ``mxu_operand`` inputs)."""
+    routing weights, its activation (for a gated expert the silu, the
+    gate's product with the up projection and both projections'
+    float32 outputs), the rows it gathers and scatters (float32 rows
+    picked, float32 rows summed back, so their transposes add in
+    float32 too) and the sum over experts (the grouped products, two or
+    three forward, take ``mxu_operand`` inputs); ``rotary_embedding``'s
+    angles, cos, sin and the rotation itself (a bf16 angle at position
+    8191 is off by whole turns; output in the input's dtype)."""
     mode = _STATE.get('act')
     if mode is None:
         env = os.environ.get('PADDLE_TPU_AMP_ACT', 'bf16').lower()

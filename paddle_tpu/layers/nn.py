@@ -33,7 +33,7 @@ __all__ = [
     'flash_attention',
     'sequence_concat',
     'rms_norm', 'causal_conv1d', 'ssd_scan', 'mamba2_mixer',
-    'router_scores', 'routed_experts',
+    'rotary_embedding', 'router_scores', 'routed_experts',
 ]
 
 
@@ -1398,28 +1398,39 @@ def spp(x, pyramid_height, pool_type='max', name=None):
 
 
 def flash_attention(q, k, v, num_heads=1, causal=True, num_kv_heads=None,
-                    head_dim=None, name=None):
+                    head_dim=None, window=None, name=None):
     """Multi-head scaled-dot-product attention on the Pallas flash
     kernel (paddle_tpu-native addition; the reference's composite is
     nets.scaled_dot_product_attention). q: [B, T, num_heads * dh], k and
     v: [B, T, num_kv_heads * dh]; ``dh`` is ``head_dim``, or q's width
     over ``num_heads`` without it; ``num_kv_heads`` (default
     ``num_heads``) query heads share a KV head in runs of num_heads /
-    num_kv_heads. Engages the blockwise Mosaic kernel on TPU at long
-    sequence lengths and the identical-math XLA reference elsewhere
-    (ops/pallas_kernels.py engagement policy)."""
+    num_kv_heads. ``window`` (causal only; None: none): a query attends
+    to the ``window`` keys up to and with its own position, j <= i and
+    i - j < window; a model's window layers and full layers are this
+    one op with and without it. Engages the blockwise Mosaic kernel on
+    TPU at long sequence lengths and the identical-math XLA reference
+    elsewhere (ops/pallas_kernels.py engagement policy); the engaged
+    kernels skip the tiles a window leaves dead as they skip those
+    above the diagonal, so a window layer costs its band
+    (``compiler.passes.flash_counts(by=('route', 'window'))`` says
+    which route a windowed lowering took)."""
     helper = LayerHelper('flash_attention', **locals())
     kv_heads = int(num_kv_heads or num_heads)
     if num_heads % kv_heads:
         raise ValueError('flash_attention: num_heads %d is not a multiple '
                          'of num_kv_heads %d' % (num_heads, kv_heads))
+    if window is not None and (window < 1 or not causal):
+        raise ValueError('flash_attention: window %r needs causal '
+                         'attention and at least one key' % (window,))
     out = helper.create_tmp_variable(dtype=q.dtype, shape=q.shape)
     helper.append_op(
         type='flash_attention',
         inputs={'Q': q, 'K': k, 'V': v},
         outputs={'Out': out},
         attrs={'num_heads': num_heads, 'causal': causal,
-               'num_kv_heads': kv_heads, 'head_dim': int(head_dim or 0)})
+               'num_kv_heads': kv_heads, 'head_dim': int(head_dim or 0),
+               'window': int(window or 0)})
     return out
 
 
@@ -1523,6 +1534,26 @@ def mamba2_mixer(input, num_heads, head_dim, state_size, n_groups=1,
     return fc(y, int(input.shape[-1]), num_flatten_dims=2, bias_attr=False)
 
 
+def rotary_embedding(input, head_dim, base=10000.0, name=None):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) of
+    [B, T, heads * head_dim] at positions 0..T-1: every head's
+    ``head_dim`` dimensions, paired half against half (dimension i with
+    i + head_dim / 2: the rotate_half form), turned by t *
+    base^(-2 i / head_dim). No parameter. The angles and the rotation
+    are float32 under AMP; the output keeps the input's dtype. Apply it
+    to q and k before ``flash_attention`` (paddle_tpu-native addition)."""
+    helper = LayerHelper('rotary_embedding', name=name)
+    width = int(input.shape[-1])
+    if head_dim % 2 or width % head_dim:
+        raise ValueError('rotary_embedding: head_dim %d must be even and '
+                         'divide the width %d' % (head_dim, width))
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op(type='rotary_embedding', inputs={'X': input},
+                     outputs={'Out': out},
+                     attrs={'head_dim': int(head_dim), 'base': float(base)})
+    return out
+
+
 def router_scores(input, num_experts, param_attr=None, name=None):
     """Router of a mixture of experts: sigmoid(x W) over ``num_experts``
     outputs, in float32 whatever the stream's dtype (the operands take
@@ -1540,7 +1571,8 @@ def router_scores(input, num_experts, param_attr=None, name=None):
 
 
 def routed_experts(input, scores, hidden_size, num_experts, top_k,
-                   experts_held=None, routed_scaling_factor=1.0, name=None):
+                   experts_held=None, routed_scaling_factor=1.0,
+                   act='relu2', name=None):
     """The routed experts of a mixture, for the experts this chip
     holds. ``scores`` [B, T, num_experts] float32 are routed over ALL
     experts: the ``top_k`` of scores + bias choose (bias: the
@@ -1549,7 +1581,12 @@ def routed_experts(input, scores, hidden_size, num_experts, top_k,
     times ``routed_scaling_factor``).
     ``experts_held`` = (first, count), default all: stacked weights
     W1 [count, D, hidden_size], W2 [count, hidden_size, D]; out = sum
-    over the chosen experts held of w_e W2_e relu(W1_e x)^2. No token is
+    over the chosen experts held of w_e W2_e relu(W1_e x)^2 (``act``
+    'relu2'), or of the gated expert w_e W2_e (silu(W1_e x) * (W3_e x))
+    (``act`` 'swiglu': a third stacked weight W3 [count, D, hidden_size],
+    created after W1; three grouped products forward, nine with the
+    gradients, through the same kernels). The activation, the gate's
+    product and the sum over experts are float32 under AMP. No token is
     dropped whatever the routing. Returns (out, tokens_per_expert
     [count] int32). What the experts held elsewhere add is their
     chips' to compute and an exchange's to sum.
@@ -1566,26 +1603,34 @@ def routed_experts(input, scores, hidden_size, num_experts, top_k,
     if first < 0 or count < 1 or first + count > num_experts:
         raise ValueError('routed_experts: experts_held %r outside 0..%d'
                          % ((first, count), num_experts))
+    if act not in ('relu2', 'swiglu'):
+        raise ValueError("routed_experts: act %r is neither 'relu2' nor "
+                         "'swiglu'" % (act,))
     D = int(input.shape[-1])
-    w1 = helper.create_parameter(
+    inputs = {'X': input, 'Scores': scores}
+    inputs['W1'] = helper.create_parameter(
         attr=ParamAttr(), shape=[count, D, hidden_size], dtype='float32')
-    w2 = helper.create_parameter(
+    if act == 'swiglu':
+        inputs['W3'] = helper.create_parameter(
+            attr=ParamAttr(), shape=[count, D, hidden_size],
+            dtype='float32')
+    inputs['W2'] = helper.create_parameter(
         attr=ParamAttr(), shape=[count, hidden_size, D], dtype='float32')
     bias = helper.create_parameter(
         attr=ParamAttr(trainable=False), shape=[num_experts],
         dtype='float32', default_initializer=Constant(0.0))
     bias.stop_gradient = True
+    inputs['Bias'] = bias
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
     tokens = helper.create_tmp_variable('int32', shape=(count,),
                                         stop_gradient=True)
     helper.append_op(
-        type='routed_experts',
-        inputs={'X': input, 'Scores': scores, 'Bias': bias, 'W1': w1,
-                'W2': w2},
+        type='routed_experts', inputs=inputs,
         outputs={'Out': out, 'TokensPerExpert': tokens},
         attrs={'num_experts': num_experts, 'top_k': top_k,
                'first_expert': first, 'held': count,
-               'routed_scaling_factor': float(routed_scaling_factor)})
+               'routed_scaling_factor': float(routed_scaling_factor),
+               'act': act})
     return out, tokens
 
 

@@ -166,6 +166,45 @@ def _router_scores(ctx):
         jnp.matmul(x, w, preferred_element_type=jnp.float32)))
 
 
+# ---- rotary positions ---------------------------------------------------------
+def rotary(x, head_dim, base):
+    """Rotary position embedding of x [B, T, H * head_dim] at positions
+    0..T-1, all ``head_dim`` dimensions of every head, the half-split
+    (rotate_half) pairing: dimension i < head_dim / 2 turns with
+    dimension i + head_dim / 2 by the angle t * base^(-2 i / head_dim).
+    Angles, cos and sin and the rotation are float32 whatever x is (a
+    bf16 angle at position 8191 is off by whole turns); returns
+    float32."""
+    B, T, D = x.shape
+    half = head_dim // 2
+    inv = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) * 2.0
+                          / head_dim))
+    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    xh = f32(x).reshape(B, T, D // head_dim, 2, half)
+    x1, x2 = xh[..., 0, :], xh[..., 1, :]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
+    return out.reshape(B, T, D)
+
+
+@register_kernel('rotary_embedding')
+def _rotary_embedding(ctx):
+    """Out = rotary(X) (above) in X's dtype; X [B, T, heads * head_dim].
+    No parameter. Each lowering counts once in
+    ``rotary_lowerings_total{dim=, dtype=}`` (compiler/passes.py::
+    rotary_counts): the head size turned and the stream's dtype."""
+    x_in = unwrap(ctx.input('X'))
+    head_dim = int(ctx.attr('head_dim'))
+    _obs.default_registry().counter(
+        'rotary_lowerings_total',
+        help='rotary_embedding op lowerings, by the head size turned and '
+             'the dtype of the stream (the angles are float32 in all)',
+        dim=str(head_dim), dtype=x_in.dtype.name).inc()
+    out = rotary(x_in, head_dim, float(ctx.attr('base', 10000.0)))
+    ctx.set_output('Out', out.astype(x_in.dtype))
+
+
 # ---- routed experts, dropless, over the experts held ------------------------
 def route_held(scores, bias, top_k, first, count, scale):
     """Route [N, E] float32 scores over all E experts; for the ``count``
@@ -186,40 +225,73 @@ def route_held(scores, bias, top_k, first, count, scale):
     return held.T, weight.T
 
 
-def _move_rows(p, x, transpose=False):
-    """``p @ x`` (``p.T @ x``) for a 0/1 matrix ``p`` with at most one 1
-    a row: rows of ``x`` picked (or summed back) on the MXU, where a
-    gather or scatter would serialise. A bf16 ``x`` moves exactly in one
-    pass; a float32 one goes as two bf16 halves under AMP (16 bits of
-    mantissa, f32 accumulation) and at ``highest`` precision without."""
-    spec = 'rn,rl->nl' if transpose else 'rn,nl->rl'
-
-    def mm(v, **kw):
-        return jnp.einsum(spec, p.astype(v.dtype), v,
-                          preferred_element_type=jnp.float32, **kw)
-    if x.dtype == jnp.bfloat16:
-        return mm(x)
-    if mxu_operand(x).dtype == jnp.bfloat16:
-        hi = x.astype(jnp.bfloat16)
-        return mm(hi) + mm((x - hi.astype(x.dtype)).astype(jnp.bfloat16))
-    return mm(x, precision=lax.Precision.HIGHEST)
+def _expert_hidden(xs, ws, sizes, act):
+    """A held expert's hidden rows, float32: relu(x W1)^2 ('relu2': ws =
+    (W1, W2)) or silu(x W_gate) * (x W_up) ('swiglu': ws = (W_gate,
+    W_up, W_down)); the activation and the gate's product are float32
+    on the float32 outputs of the grouped products."""
+    from .pallas_kernels import grouped_matmul
+    h = grouped_matmul(xs, mxu_operand(ws[0]), sizes)
+    if act == 'relu2':
+        return jnp.square(jnp.maximum(h, 0.0))
+    return h * jax.nn.sigmoid(h) * grouped_matmul(xs, mxu_operand(ws[1]),
+                                                  sizes)
 
 
-def _exact(spec, a, b):
-    """A small float32 product whose result must be its operands' own
-    values (a 0/1 matrix against row numbers or weights)."""
-    return jnp.einsum(spec, a, b, precision=lax.Precision.HIGHEST,
-                      preferred_element_type=jnp.float32)
+def pairs_in_row_order(place, chunk):
+    """The (expert, token) pairs, as flat indices e * N + n into [held,
+    N], in the order of their rows (``place``; -1: not chosen, sorted
+    behind every row), padded so that any chunk of ``chunk`` rows that
+    starts inside the routed pairs can be sliced out. One sort of
+    held * N keys an op (0.24 ms for 131 k on a v5e chip)."""
+    held, n = place.shape
+    flat = place.reshape(-1)
+    key = jnp.where(flat < 0, jnp.int32(held * n), flat)
+    _, order = lax.sort((key, jnp.arange(held * n, dtype=jnp.int32)),
+                        num_keys=1)
+    return jnp.pad(order, (0, chunk))
 
 
-def _expert_chunk(u, w1, w2, weight, place, counts, chunk, c):
+def _place_rows(u, weight, order, counts, rows):
+    """(the tokens of ``rows`` picked from u [N, L], their routing
+    weights, the function that sums rows back to their tokens), through
+    the pairs in row order: a row gather, a gather of scalars and a
+    scatter-add, each linear in rows. Float32 rows both ways, so that
+    the transposes (a scatter-add of the rows' gradients, a gather of
+    the output's) add in float32 too. A row past the routed pairs reads
+    a zero row and adds to nothing.
+
+    Why not a 0/1 [chunk, N] matrix on the MXU, which moves rows
+    without a gather: its cost is the product of rows and tokens.
+    Measured on one v5e chip (PERF.md section 6, PR 33; the op alone,
+    forward / forward and backward, ms): 16,640 rows of 8192 tokens,
+    2048 wide, gated experts 13.56 / 21.87 by the matrix (537 MB of
+    float32 comparisons to make it, a 268 MB operand, 2.95 to pick bf16
+    rows, 6.42 to sum float32 rows back in two bf16 halves) and 5.12 /
+    11.40 this way (the gather 0.23-0.34, the scatter-add 1.59); 3072
+    rows of 4096 tokens, 1024 wide 1.76 / 2.83 and 1.52 / 2.47: this
+    way is faster at both shapes the benchmark runs."""
+    n = u.shape[0]
+    ids = lax.dynamic_slice(order, (rows[0],), (rows.shape[0],))
+    live = rows < jnp.sum(counts)
+    tok = jnp.where(live, ids % n, n)
+    row_w = jnp.where(live, jnp.take(weight.reshape(-1),
+                                     jnp.where(live, ids, 0)), 0.0)
+    xs = jnp.take(f32(u), tok, axis=0, mode='fill', fill_value=0)
+
+    def back(y):
+        return jnp.zeros((n, y.shape[1]), jnp.float32).at[tok].add(
+            y, mode='drop')
+    return xs.astype(mxu_operand(u).dtype), row_w, back
+
+
+def _expert_chunk(u, ws, weight, order, counts, chunk, c, act):
     """The held experts' part for rows ``c * chunk .. (c + 1) * chunk``
-    of the expert-sorted list of (token, expert) pairs: ``place`` [held,
-    N] is a pair's row (-1: not chosen), ``weight`` [held, N] its
-    routing weight. A row's expert follows from the groups' ends; its
-    token is the one whose place in that expert's line is the row.
-    Pick the rows' tokens, two grouped products with relu^2 between
-    (pallas_kernels.grouped_matmul: the Pallas kernels or
+    of the expert-sorted list of (token, expert) pairs: ``weight``
+    [held, N] is a pair's routing weight, ``order`` the pairs in row
+    order (pairs_in_row_order). Pick the rows' tokens (_place_rows),
+    the grouped products with the activation between (_expert_hidden;
+    pallas_kernels.grouped_matmul: the Pallas kernels or
     ``lax.ragged_dot``, one layout for both), weigh, sum back to
     [N, L] float32.
 
@@ -231,7 +303,8 @@ def _expert_chunk(u, w1, w2, weight, place, counts, chunk, c):
     groups they are told. A chunk costs the same whatever the routing
     sends it: ``ragged_dot`` by its row count, the kernels by their
     grid, which is the chunk's row tiles plus one visit a further
-    expert however the rows split."""
+    expert however the rows split; and the placement moves a chunk's
+    rows, whoever they belong to."""
     from .pallas_kernels import grouped_matmul
     lo = c * chunk
     rows = lo + jnp.arange(chunk, dtype=jnp.int32)
@@ -239,20 +312,11 @@ def _expert_chunk(u, w1, w2, weight, place, counts, chunk, c):
     sizes = jnp.clip(ends, lo, lo + chunk) \
         - jnp.clip(ends - counts, lo, lo + chunk)
     sizes = sizes.at[-1].add(chunk - jnp.sum(sizes))
-    owner = jnp.sum(rows[:, None] >= ends[None, :], axis=1)
-    owner = (owner[:, None] == jnp.arange(counts.shape[0])[None, :]) \
-        .astype(jnp.float32)                               # [chunk, held]
-    pick = _exact('re,en->rn', owner, place.astype(jnp.float32)) \
-        == rows[:, None].astype(jnp.float32)               # [chunk, N]
-    row_w = jnp.sum(owner * _exact('rn,en->re', pick.astype(jnp.float32),
-                                   weight), axis=1)
-    x = mxu_operand(u)
-    xs = _move_rows(pick, x).astype(x.dtype)
-    h = grouped_matmul(xs, mxu_operand(w1), sizes)
-    h = jnp.square(jnp.maximum(h, 0.0))
-    y = grouped_matmul(mxu_operand(h).astype(xs.dtype), mxu_operand(w2),
+    xs, row_w, back = _place_rows(u, weight, order, counts, rows)
+    h = _expert_hidden(xs, ws, sizes, act)
+    y = grouped_matmul(mxu_operand(h).astype(xs.dtype), mxu_operand(ws[-1]),
                        sizes)
-    return _move_rows(pick, y * row_w[:, None], transpose=True)
+    return back(y * row_w[:, None])
 
 
 def _chunks(counts, chunk):
@@ -260,44 +324,44 @@ def _chunks(counts, chunk):
     return (jnp.sum(counts) + chunk - 1) // chunk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _held_experts(u, w1, w2, weight, place, counts, chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_experts(u, ws, weight, order, counts, chunk, act):
     """Sum of ``_expert_chunk`` over as many chunks as the routed pairs
     fill: the first always; the others, which only a skewed routing
     fills, in a loop whose trip count is read from ``counts``, behind a
     ``cond`` so that a balanced step does not pay for the loop's
-    carried copies."""
-    return _held_experts_fwd(u, w1, w2, weight, place, counts, chunk)[0]
+    carried copies. ``ws``: the held experts' stacked matrices."""
+    return _held_experts_fwd(u, ws, weight, order, counts, chunk, act)[0]
 
 
-def _held_experts_fwd(u, w1, w2, weight, place, counts, chunk):
-    out, vjp_first = jax.vjp(
-        lambda *t: _expert_chunk(*t, place, counts, chunk, 0),
-        u, w1, w2, weight)
+def _held_experts_fwd(u, ws, weight, order, counts, chunk, act):
+    def one(c):
+        return lambda *t: _expert_chunk(*t, order, counts, chunk, c, act)
+
+    out, vjp_first = jax.vjp(one(0), u, ws, weight)
 
     n = _chunks(counts, chunk)
 
     def rest(out):
         return lax.fori_loop(
-            1, n, lambda c, acc: acc + _expert_chunk(
-                u, w1, w2, weight, place, counts, chunk, c), out)
+            1, n, lambda c, acc: acc + one(c)(u, ws, weight), out)
     out = lax.cond(n > 1, rest, lambda out: out, out)
-    return out, (vjp_first, u, w1, w2, weight, place, counts)
+    return out, (vjp_first, u, ws, weight, order, counts)
 
 
-def _held_experts_bwd(chunk, res, g):
+def _held_experts_bwd(chunk, act, res, g):
     # the first chunk's products were saved; a further chunk (a skewed
     # routing) is computed again for its gradient, into the same sums
-    vjp_first, u, w1, w2, weight, place, counts = res
+    vjp_first, u, ws, weight, order, counts = res
 
     def more(c, grads):
         _, vjp = jax.vjp(
-            lambda *t: _expert_chunk(*t, place, counts, chunk, c),
-            u, w1, w2, weight)
-        return tuple(a + b for a, b in zip(grads, vjp(g)))
+            lambda *t: _expert_chunk(*t, order, counts, chunk, c, act),
+            u, ws, weight)
+        return jax.tree_util.tree_map(jnp.add, grads, vjp(g))
 
     def first():
-        return tuple(vjp_first(g))
+        return vjp_first(g)
 
     n = _chunks(counts, chunk)
     grads = lax.cond(n > 1, lambda: lax.fori_loop(1, n, more, first()),
@@ -327,15 +391,19 @@ def expert_chunk_rows(tokens, top_k, held, experts):
 @register_kernel('routed_experts')
 def _routed_experts(ctx):
     """Out = sum over the experts e that are chosen AND held of
-    w_e W2_e relu(W1_e x)^2. X [B, T, L], Scores [B, T, E] float32 over
-    all E experts, Bias [E] (added for the choice only), W1 [held, L,
-    F], W2 [held, F, L] for experts ``first`` .. ``first + held``.
+    w_e f_e(x): f_e = W2_e relu(W1_e x)^2 (attr ``act`` 'relu2') or the
+    gated W2_e (silu(W1_e x) * (W3_e x)) ('swiglu': W1 the gate, W3 the
+    up projection). X [B, T, L], Scores [B, T, E] float32 over all E
+    experts, Bias [E] (added for the choice only), W1 (and W3) [held,
+    L, F], W2 [held, F, L] for experts ``first`` .. ``first + held``.
 
     Dropless: the (token, held expert) pairs are laid out expert by
     expert by counting (a token takes an expert at most once, so a
-    cumulative sum over [held, N] places every pair; nothing is sorted,
-    gathered or scattered: rows move through 0/1 matrices on the MXU),
-    multiplied group by group, weighed and summed back. The grouped
+    cumulative sum over [held, N] places every pair; one sort of those
+    places puts the pairs in row order), their rows picked by a row
+    gather and summed back by a scatter-add, both linear in rows
+    (_place_rows), multiplied group by group and weighed between. The
+    grouped
     products take one of two routes, chosen from what the op sees
     (pallas_kernels.grouped_plan): on the TPU, with bf16 operands (AMP)
     and L, F multiples of 128, the Pallas grouped matmul ('pallas');
@@ -347,7 +415,7 @@ def _routed_experts(ctx):
     the second output.
     What the experts held elsewhere would add is left out. Each
     lowering counts once in ``moe_lowerings_total{experts=, held=,
-    top_k=, route=}`` (compiler/passes.py::moe_counts)."""
+    top_k=, route=, act=}`` (compiler/passes.py::moe_counts)."""
     from .pallas_kernels import grouped_plan
     x_in = unwrap(ctx.input('X'))
     E, K = int(ctx.attr('num_experts')), int(ctx.attr('top_k'))
@@ -362,23 +430,28 @@ def _routed_experts(ctx):
         scores, lax.stop_gradient(bias), K, first, held,
         float(ctx.attr('routed_scaling_factor', 1.0)))
     chunk = expert_chunk_rows(N, K, held, E)
+    act = ctx.attr('act', 'relu2') or 'relu2'
+    if act not in ('relu2', 'swiglu'):
+        raise ValueError('routed_experts: unknown act %r' % (act,))
     w1 = unwrap(ctx.input('W1'))
+    ws = (w1,) + ((unwrap(ctx.input('W3')),) if act == 'swiglu' else ()) \
+        + (unwrap(ctx.input('W2')),)
     plan = grouped_plan(
         jax.ShapeDtypeStruct((chunk, L), mxu_operand(u).dtype),
         jax.ShapeDtypeStruct(w1.shape, mxu_operand(w1).dtype))
     _obs.default_registry().counter(
         'moe_lowerings_total',
         help='routed_experts op lowerings, by the experts routed over, '
-             'the experts held here, the experts a token takes and the '
+             'the experts held here, the experts a token takes, the '
              'grouped product (pallas: the Pallas grouped matmul; '
-             'ragged_dot: lax.ragged_dot)',
+             'ragged_dot: lax.ragged_dot) and the expert\'s activation '
+             '(relu2 / swiglu)',
         experts=str(E), held=str(held), top_k=str(K),
-        route='ragged_dot' if plan is None else 'pallas').inc()
+        route='ragged_dot' if plan is None else 'pallas', act=act).inc()
     counts = jnp.sum(chosen, axis=1, dtype=jnp.int32)       # [held]
     place = (jnp.cumsum(counts) - counts)[:, None] \
         + jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1
-    out = _held_experts(
-        u, w1, unwrap(ctx.input('W2')), weight,
-        jnp.where(chosen, place, -1), counts, chunk)
+    order = pairs_in_row_order(jnp.where(chosen, place, -1), chunk)
+    out = _held_experts(u, ws, weight, order, counts, chunk, act)
     ctx.set_output('Out', out.reshape(x_in.shape).astype(x_in.dtype))
     ctx.set_output('TokensPerExpert', counts)

@@ -456,16 +456,21 @@ def _flash_attention_op(ctx):
     below is a reshape on both routes and nothing is transposed or
     copied between a projection and a kernel; a head shape the lane
     blocks cannot take (odd num_heads at head size 64, a head size
-    other than 64 or a multiple of 128) counts as route=xla.
+    other than 64 or a multiple of 128) counts as route=xla. Attr
+    ``window`` (0: none; causal only): a query keeps the ``window``
+    keys up to its own position on either route, and the kernels skip
+    the tiles below that band; a window that reaches every query's
+    first key (>= T) is no window.
 
     QK^T and PV are matmuls, so under AMP the op is on the MXU path
     like mul/matmul/conv2d (core/amp.py::mxu_compute): f32 q, k, v are
     cast to bf16 on either route, the dots accumulate f32 and the
     softmax state stays f32 inside the kernels, and the output flows
     bf16 under act_bf16(). Each lowering counts once in
-    ``flash_attention_lowerings_total{route=, dtype=, diag=, kv_heads=}``
-    (compiler/passes.py::flash_counts)."""
-    from .pallas_kernels import flash_attention, flash_diag, flash_plan
+    ``flash_attention_lowerings_total{route=, dtype=, diag=, kv_heads=,
+    window=}`` (compiler/passes.py::flash_counts)."""
+    from .pallas_kernels import (effective_window, flash_attention,
+                                 flash_diag, flash_plan)
     from ..core.amp import mxu_compute
     heads = int(ctx.attr('num_heads', 1))
     kv_heads = int(ctx.attr('num_kv_heads', 0) or heads)
@@ -484,21 +489,23 @@ def _flash_attention_op(ctx):
             # sums their dK, dV
             kh = jnp.repeat(kh, heads // kv_heads, axis=2)
             vh = jnp.repeat(vh, heads // kv_heads, axis=2)
-        plan = flash_plan(qh, causal=causal)
+        window = effective_window(ctx.attr('window', 0), T, causal)
+        plan = flash_plan(qh, causal=causal, window=window)
         _obs.default_registry().counter(
             'flash_attention_lowerings_total',
             help='flash_attention op lowerings, by the route taken '
                  '(pallas kernels / xla reference), the operand dtype '
                  'the attention ran in, the body the kernels give '
-                 'a tile on the diagonal (chunked<r> / whole / none) '
-                 'and the KV heads the query heads share',
+                 'a tile on the diagonal (chunked<r> / whole / none), '
+                 'the KV heads the query heads share and the window '
+                 '(0: none, or one that reaches every key)',
             route='xla' if plan is None else 'pallas',
             dtype={'bfloat16': 'bf16', 'float32': 'f32'}.get(
                 qh.dtype.name, qh.dtype.name),
-            diag=flash_diag(plan, causal),
-            kv_heads=str(kv_heads)).inc()
+            diag=flash_diag(plan, causal, window),
+            kv_heads=str(kv_heads), window=str(window or 0)).inc()
         # NB: flash_attention applies the 1/sqrt(dh) logit scale itself
-        out = flash_attention(qh, kh, vh, causal=causal)
+        out = flash_attention(qh, kh, vh, causal=causal, window=window)
         return out.reshape(B, T, heads * dh)
 
     ctx.set_output('Out', mxu_compute(

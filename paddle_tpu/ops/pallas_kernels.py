@@ -34,11 +34,14 @@ _NEG_INF = -1e30
 
 
 # ---- flash attention ------------------------------------------------------------
-def attention_reference_with_lse(q, k, v, causal=True, q_off=0, k_off=0):
+def attention_reference_with_lse(q, k, v, causal=True, q_off=0, k_off=0,
+                                 window=None):
     """Masked-softmax attention + per-row logsumexp, plain XLA.
     q,k,v: [B, T, H, D] -> (out [B, T, H, D], lse [B, H, T]). The lse
     output is what lets ring attention merge per-block partial results
-    exactly (see models/transformer.py::ring_attention)."""
+    exactly (see models/transformer.py::ring_attention). ``window``
+    (causal only): a query also drops the keys ``window`` or more
+    positions behind it."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum('bqhd,bkhd->bhqk', q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -46,6 +49,8 @@ def attention_reference_with_lse(q, k, v, causal=True, q_off=0, k_off=0):
         qpos = q_off + jnp.arange(q.shape[1])
         kpos = k_off + jnp.arange(k.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
+        if window:
+            mask = mask & (qpos[:, None] - kpos[None, :] < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)     # [B, H, Tq]
     p = jnp.exp(s - lse[..., None]).astype(q.dtype)
@@ -54,14 +59,15 @@ def attention_reference_with_lse(q, k, v, causal=True, q_off=0, k_off=0):
     return out, lse
 
 
-def attention_reference(q, k, v, causal=True, q_off=0, k_off=0):
+def attention_reference(q, k, v, causal=True, q_off=0, k_off=0, window=None):
     """Canonical masked-softmax attention, plain XLA. q,k,v: [B, T, H, D].
 
     Single source of truth for the math: the Pallas kernel's parity tests,
     flash_attention's off-TPU fallback, AND the transformer model's
     blockwise/ring path (which passes q_off/k_off for the global positions
     of local blocks) all call this."""
-    return attention_reference_with_lse(q, k, v, causal, q_off, k_off)[0]
+    return attention_reference_with_lse(q, k, v, causal, q_off, k_off,
+                                        window)[0]
 
 
 # exp2-based softmax (VERDICT r4 #4): fold log2(e) into the score
@@ -142,53 +148,109 @@ def _row_chunks(block_q, block_k):
     return block_q // math.gcd(block_q, 512)
 
 
-def _tile_parts(qi, kb, block_q, block_k, T, causal, part):
-    """Run ``part(rows, cols, diag)`` over what is live of tile (q
-    block qi, k block kb): the q rows ``rows`` (a pl.ds) against the
-    first ``cols`` columns of the k block, under the causal mask of
-    rows that start ``diag`` positions after the columns do
-    (_causal_keep; None: no mask). THE one place that tells the three
-    kinds of tile apart, for the forward and every backward kernel: a
-    tile wholly above the diagonal runs nothing (its DMA is skipped by
-    the index maps); one wholly at or below it runs whole and unmasked,
-    as every tile does without ``causal``; one that straddles it runs
-    each row chunk against the columns left of that chunk's end, a
-    shape of its own each (all the columns, masked, where the tile is
-    one chunk: _row_chunks). Where the ``T`` positions hold no tile
-    below the diagonal (one block of them, the OPT cell's) that body
-    is left out of the kernel: Mosaic unrolls what a kernel holds, run
-    or not, and every set-up lowers it."""
+def _window_chunked(block_q, block_k, window):
+    """Whether a window's lower edge falls on tile corners of square
+    blocks that go in row chunks: the tiles it cuts are then one kind,
+    kb = qi - window / block, of a static shape (the strict upper
+    triangle), as the diagonal's are."""
+    return block_q == block_k and window % block_q == 0 \
+        and _row_chunks(block_q, block_k) > 1
+
+
+def _tile_parts(qi, kb, block_q, block_k, T, causal, part, window=None):
+    """Run ``part(rows, cols, edges)`` over what is live of tile (q
+    block qi, k block kb): the q rows ``rows`` against the k rows
+    ``cols`` (each a pl.ds of its block), under the mask ``edges`` =
+    (diag, low) of _causal_keep ((None, None): no mask). THE one place that
+    tells the kinds of tile apart, for the forward and every backward
+    kernel: a tile wholly above the diagonal runs nothing (its DMA is
+    skipped by the index maps); one wholly at or below it runs whole
+    and unmasked, as every tile does without ``causal``; one that
+    straddles it runs each row chunk against the columns left of that
+    chunk's end, a shape of its own each (all the columns, masked,
+    where the tile is one chunk: _row_chunks). Where the ``T``
+    positions hold no tile below the diagonal (one block of them, the
+    OPT cell's) that body is left out of the kernel: Mosaic unrolls
+    what a kernel holds, run or not, and every set-up lowers it.
+
+    With a ``window`` (a query keeps the ``window`` keys up to its own)
+    the live tiles are a band: a tile wholly below the band runs
+    nothing either (skipped, not masked: the index maps hold the first
+    live block through those steps); the tiles the band's lower edge
+    cuts are a second kind of partly-masked tile. Where that edge falls
+    on tile corners (_window_chunked) such a tile is the strict upper
+    triangle and goes in the diagonal's row chunks, each against the
+    columns from its own start on; any other window (narrower than a
+    block, or not a multiple of it) runs every cut tile whole under
+    both edges, whose offsets are grid values."""
     def _full():
-        part(pl.ds(0, block_q), block_k, None)
+        part(pl.ds(0, block_q), pl.ds(0, block_k), (None, None))
 
     if not causal:
         _full()
         return
     live = kb * block_k <= (qi + 1) * block_q - 1
     full = (kb + 1) * block_k - 1 <= qi * block_q
-    if T - block_q >= block_k:      # a q block starts past a k block's end
-        pl.when(full)(_full)
+    r = _row_chunks(block_q, block_k)
+    c = block_q // r
 
-    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
-    def _diagonal():
-        r = _row_chunks(block_q, block_k)
-        c = block_q // r
-        if r == 1:
-            part(pl.ds(0, block_q), block_k, qi * block_q - kb * block_k)
-            return
+    def _diagonal_chunks():
         for j in range(r):
-            part(pl.ds(j * c, c), (j + 1) * c, j * c)
+            part(pl.ds(j * c, c), pl.ds(0, (j + 1) * c), (j * c, None))
+
+    if window is None:
+        if T - block_q >= block_k:  # a q block starts past a k block's end
+            pl.when(full)(_full)
+
+        @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
+        def _diagonal():
+            if r == 1:
+                part(pl.ds(0, block_q), pl.ds(0, block_k),
+                     (qi * block_q - kb * block_k, None))
+                return
+            _diagonal_chunks()
+        return
+
+    # inside the band: below the diagonal and above the lower edge
+    inside = jnp.logical_and(
+        full, kb * block_k >= (qi + 1) * block_q - window)
+    if T - block_q >= block_k and window >= block_q + block_k - 1:
+        pl.when(inside)(_full)
+    if _window_chunked(block_q, block_k, window):
+        pl.when(kb == qi)(_diagonal_chunks)
+
+        @pl.when(kb == qi - window // block_q)
+        def _lower_edge():
+            # row i of the tile keeps the columns past i
+            for j in range(r):
+                part(pl.ds(j * c, c), pl.ds(j * c, block_k - j * c),
+                     (None, 1))
+        return
+    live = jnp.logical_and(
+        live, (kb + 1) * block_k - 1 + window > qi * block_q)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(inside)))
+    def _edges():
+        diag = qi * block_q - kb * block_k
+        part(pl.ds(0, block_q), pl.ds(0, block_k),
+             (diag, diag - window + 1))
 
 
-def _causal_keep(shape, diag):
-    """Which scores [rows, cols] of q rows that start ``diag``
-    positions after the k columns do the causal mask keeps: row i
-    keeps column j <= i + diag. None: all of them (``diag`` None)."""
-    if diag is None:
+def _causal_keep(shape, diag, low=None):
+    """Which scores [rows, cols] the mask keeps, for q rows that start
+    ``diag`` positions after the k columns do: row i keeps column j <=
+    i + diag (the causal edge; None: no such edge) and column j >= i +
+    ``low`` (a window's lower edge, low = diag - window + 1; None: no
+    window). None: all of them."""
+    if diag is None and low is None:
         return None
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return row + diag >= col
+    if low is None:
+        return row + diag >= col
+    if diag is None:
+        return col >= row + low
+    return jnp.logical_and(row + diag >= col, col >= row + low)
 
 
 def _masked(s, keep):
@@ -207,7 +269,7 @@ def _dot(x, y, dims):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, block_q, block_k, T, causal, dh):
+                  acc_scr, *, block_q, block_k, T, causal, dh, window=None):
     """One (batch, head group, q-block, k-block) grid step on blocks
     [block, hp*dh] of [B, T, H*dh] arrays: the ``hp`` heads whose lanes
     fill the block (two at head size 64) are attended one after the
@@ -232,16 +294,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _part(rows, cols, diag):
+    def _part(rows, cols, edges):
         # the dots take their operands as they come (_dot); the
         # online-softmax state stays f32 (r4 perf: the f32 upcast
-        # halved MXU throughput on the AMP path)
+        # halved MXU throughput on the AMP path). A row a window's tile
+        # masks whole keeps m at the finite _NEG_INF, its p are 1s, and
+        # the first tile with a live key (the diagonal's at the latest)
+        # rescales them to nothing.
         q = q_ref[0, rows, :]                     # [rows, hp*dh]
-        k = k_ref[0, pl.ds(0, cols), :]           # [cols, hp*dh]
-        v = v_ref[0, pl.ds(0, cols), :]
+        k = k_ref[0, cols, :]                     # [cols, hp*dh]
+        v = v_ref[0, cols, :]
         scale = 1.0 / math.sqrt(dh) * _LOG2E  # scores live in log2 units
         heads = _head_lanes(q.shape, dh)
-        keep = _causal_keep((rows.size, cols), diag)
+        keep = _causal_keep((rows.size, cols.size), *edges)
         stat = (rows.size,) + m_scr.shape[2:]
         alphas, pvs = [], []
         for i in range(hp):
@@ -262,7 +327,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             acc_scr[rows, :] * _by_head(alphas, heads, q.shape)
             + _by_head(pvs, heads, q.shape))
 
-    _tile_parts(qi, kb, block_q, block_k, T, causal, _part)
+    _tile_parts(qi, kb, block_q, block_k, T, causal, _part, window)
 
     if causal:
         last_kb = jnp.minimum(n_kb - 1, ((qi + 1) * block_q - 1) // block_k)
@@ -281,24 +346,35 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             lse_ref[0, i] = m_scr[i, :, :1] / _LOG2E + jnp.log(ls[i])
 
 
-def _live_kb(causal, block_q, block_k, n_kb):
+def _live_kb(causal, block_q, block_k, n_kb, window=None):
     """(q-block i, step j) -> the k block that step reads. Causal: dead
     (fully-masked) steps re-reference the last live block, so Pallas
     skips their HBM DMA entirely (an index map that repeats the
-    previous indices is a no-op fetch)."""
+    previous indices is a no-op fetch); under a window the steps below
+    the band hold the first live block likewise."""
     if not causal:
         return lambda i, j: j
-    return lambda i, j: jnp.minimum(
-        j, jnp.minimum(n_kb - 1, ((i + 1) * block_q - 1) // block_k))
+
+    def first(i):
+        if window is None:
+            return 0
+        return jnp.maximum(i * block_q - window + 1, 0) // block_k
+    return lambda i, j: jnp.clip(
+        j, first(i),
+        jnp.minimum(n_kb - 1, ((i + 1) * block_q - 1) // block_k))
 
 
-def _live_qi(causal, block_q, block_k):
+def _live_qi(causal, block_q, block_k, window=None, n_qb=None):
     """(k-block j, step i) -> the q block that step of a kv-major sweep
     reads: steps before the diagonal re-reference the first live q
-    block (no-op DMA)."""
+    block (no-op DMA), steps past a window's band the last."""
     if not causal:
         return lambda j, i: i
-    return lambda j, i: jnp.maximum(i, (j * block_k) // block_q)
+    if window is None:
+        return lambda j, i: jnp.maximum(i, (j * block_k) // block_q)
+    return lambda j, i: jnp.clip(
+        i, (j * block_k) // block_q,
+        jnp.minimum(n_qb - 1, ((j + 1) * block_k + window - 2) // block_q))
 
 
 def _outer(x, y):
@@ -338,11 +414,12 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
 # kernel once, not once a layer. Two heads a program double a kernel's
 # trace, and the step is traced two or three times before its first
 # timed run.
-_RAW_STATICS = ('H', 'causal', 'block_q', 'block_k', 'interpret')
+_RAW_STATICS = ('H', 'causal', 'block_q', 'block_k', 'interpret', 'window')
 
 
 @functools.partial(jax.jit, static_argnames=_RAW_STATICS)
-def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret):
+def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret,
+                       window=None):
     """Raw Pallas forward on [B, T, H*dh] -> (out [B, T, H*dh],
     lse [B, H, T, 1])."""
     B, T, HD = q.shape
@@ -350,10 +427,10 @@ def _flash_pallas_call(q, k, v, *, H, causal, block_q, block_k, interpret):
     hp = _lane_heads(H, dh)
     lanes = hp * dh
     n_kb = T // block_k
-    kb_at = _live_kb(causal, block_q, block_k, n_kb)
+    kb_at = _live_kb(causal, block_q, block_k, n_kb, window)
     return pl.pallas_call(
-        functools.partial(_flash_kernel, block_q=block_q,
-                          block_k=block_k, T=T, causal=causal, dh=dh),
+        functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
+                          T=T, causal=causal, dh=dh, window=window),
         grid=(B, H // hp, T // block_q, n_kb),
         in_specs=[
             _wide(block_q, lanes, _outer),
@@ -399,7 +476,7 @@ def _bwd_p_ds(q, k, v, do, lse, delta, keep, dh):
 
 
 def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
-              put_dq=None, dk_scr=None, dv_scr=None):
+              put_dq=None, dk_scr=None, dv_scr=None, window=None):
     """One (q-block, k-block) tile of the backward for the ``hp`` heads
     of head group ``g``, one head after the other, over the tile's live
     parts (_tile_parts): dk and dv of a part are added to its columns'
@@ -413,15 +490,14 @@ def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, glse_ref = in_refs
     hp = lse_ref.shape[1]
 
-    def _part(rows, cols, diag):
-        kcols = pl.ds(0, cols)
+    def _part(rows, kcols, edges):
         q, do = q_ref[0, rows, :], do_ref[0, rows, :]
         k, v = k_ref[0, kcols, :], v_ref[0, kcols, :]
         do_o = do.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32)
         g_lse = glse_ref[0, rows, :]                  # [rows, H]
         head = jax.lax.broadcasted_iota(jnp.int32, g_lse.shape, 1)
         heads = _head_lanes(q.shape, dh)
-        keep = _causal_keep((rows.size, cols), diag)
+        keep = _causal_keep((rows.size, kcols.size), *edges)
         dqs = []
         for i in range(hp):
             q_i = _only_head(q, heads, i)
@@ -445,10 +521,10 @@ def _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
         if put_dq is not None:
             put_dq(rows, _by_head(dqs, heads, q.shape))
 
-    _tile_parts(qi, kb, block_q, block_k, T, causal, _part)
+    _tile_parts(qi, kb, block_q, block_k, T, causal, _part, window)
 
 
-def _flash_dq_kernel(*refs, block_q, block_k, T, causal, dh):
+def _flash_dq_kernel(*refs, block_q, block_k, T, causal, dh, window=None):
     """dq pass of the two-pass fallback: one (batch, head group,
     q-block, k-block) step; dq accumulates in VMEM. ``refs``: the seven
     inputs of _bwd_tile, dq_ref, dq_scr."""
@@ -465,14 +541,14 @@ def _flash_dq_kernel(*refs, block_q, block_k, T, causal, dh):
         dq_scr[rows, :] += dq
 
     _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
-              put_dq=_add_dq)
+              put_dq=_add_dq, window=window)
 
     @pl.when(kb == T // block_k - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, dh):
+def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, dh, window=None):
     """One (batch, head group, k-block, q-block) step of a kv-major
     sweep: q blocks stream innermost, dk/dv accumulate in VMEM. All
     math stays q-major so no in-kernel transposes are needed
@@ -504,7 +580,12 @@ def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, dh):
         if causal:
             # dead tiles still own a dqp slab slot — zero it so the XLA
             # sum sees defined content
-            @pl.when((qi + 1) * block_q - 1 < kb * block_k)
+            dead = (qi + 1) * block_q - 1 < kb * block_k
+            if window is not None:
+                dead = jnp.logical_or(
+                    dead, (kb + 1) * block_k - 1 + window <= qi * block_q)
+
+            @pl.when(dead)
             def _dead():
                 dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
 
@@ -514,7 +595,7 @@ def _flash_dkvdq_kernel(*refs, block_q, block_k, T, causal, dh):
             dqp_ref[0, 0, rows, :] = dq.astype(dqp_ref.dtype)
 
     _bwd_tile(in_refs, g, qi, kb, block_q, block_k, T, causal, dh,
-              put_dq, dk_scr, dv_scr)
+              put_dq, dk_scr, dv_scr, window)
 
     @pl.when(qi == T // block_q - 1)
     def _finalize():
@@ -548,7 +629,7 @@ def _slab_dtype(q_dtype):
 
 @functools.partial(jax.jit, static_argnames=_RAW_STATICS + ('merged',))
 def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
-                      block_k, interpret, merged):
+                      block_k, interpret, merged, window=None):
     """Blockwise backward on [B, T, H*dh] operands (lse [B, H, T, 1] as
     the forward wrote it): O(T) memory, never materialises the [T, T]
     score matrix (ADVICE r1: the old backward recomputed full attention
@@ -570,7 +651,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
     operands = (q, k, v, do, o, lse,
                 g_lse.astype(jnp.float32).transpose(0, 2, 1))
     kernel_args = dict(block_q=block_q, block_k=block_k, T=T,
-                       causal=causal, dh=dh)
+                       causal=causal, dh=dh, window=window)
 
     def in_specs(q_at, k_at):
         heads = pl.BlockSpec((1, block_q, H),
@@ -582,7 +663,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
 
     kv_major = dict(
         grid=(B, H // hp, n_kb, n_qb),
-        in_specs=in_specs(_live_qi(causal, block_q, block_k), _outer),
+        in_specs=in_specs(_live_qi(causal, block_q, block_k, window, n_qb),
+                          _outer),
         scratch_shapes=[pltpu.VMEM((block_k, lanes), jnp.float32),
                         pltpu.VMEM((block_k, lanes), jnp.float32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
@@ -607,7 +689,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **kernel_args),
         grid=(B, H // hp, n_qb, n_kb),
-        in_specs=in_specs(_outer, _live_kb(causal, block_q, block_k, n_kb)),
+        in_specs=in_specs(_outer, _live_kb(causal, block_q, block_k, n_kb,
+                                           window)),
         out_specs=_wide(block_q, lanes, _outer),
         out_shape=jax.ShapeDtypeStruct((B, T, HD), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, lanes), jnp.float32)],
@@ -623,30 +706,31 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, g_lse, *, H, causal, block_q,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, causal, block_q, block_k, interpret, window=None):
     """The engaged path on q, k, v [B, T, H, D] -> (out [B, T, H, D],
     lse [B, H, T]). The kernels read and write [B, T, H*D] — what a
     reshape of the projections' output is, and of the output
     projection's input — so nothing is transposed or copied on the way
     in or out, and the residuals are q, k, v and out themselves."""
     (out, lse), _ = _flash_lse_fwd(q, k, v, causal, block_q, block_k,
-                                   interpret)
+                                   interpret, window)
     return out, lse
 
 
-def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret,
+                   window=None):
     B, T, H, D = q.shape
     flat = (B, T, H * D)
     out, lse = _flash_pallas_call(
         q.reshape(flat), k.reshape(flat), v.reshape(flat), H=H,
         causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret)
+        interpret=interpret, window=window)
     out = out.reshape(q.shape)
     return (out, lse[..., 0]), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_lse_bwd(causal, block_q, block_k, interpret, window, res, g):
     # Blockwise Pallas backward: O(T) memory, recomputes p from the saved
     # logsumexp rather than materialising [T, T] (ADVICE r1). The lse
     # cotangent (nonzero when ring attention merges partial blocks)
@@ -662,7 +746,7 @@ def _flash_lse_bwd(causal, block_q, block_k, interpret, res, g):
         out.reshape(flat), lse, g_out.reshape(flat), g_lse, H=H,
         causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret,
-        merged=slab_bytes <= _MERGED_BWD_MAX_SLAB_BYTES)
+        merged=slab_bytes <= _MERGED_BWD_MAX_SLAB_BYTES, window=window)
     return tuple(x.reshape(q.shape) for x in grads)
 
 
@@ -698,8 +782,11 @@ _FLASH_MIN_ROWS = 64 * 1024  # B*H*T break-even (measured, v5e)
 
 
 def flash_attention(q, k, v, causal=True, block_q=None, block_k=None,
-                    interpret=None):
+                    interpret=None, window=None):
     """Blockwise attention. q,k,v: [B, T, H, D] -> [B, T, H, D].
+    ``window`` (causal only): a query attends to the ``window`` keys up
+    to its own position; the kernels skip the tiles below that band as
+    they skip the ones above the diagonal.
 
     Forward and backward both run as Pallas kernels on TPU (or under
     ``interpret=True``) on the arrays as they are, read as [B, T, H*D]:
@@ -713,10 +800,23 @@ def flash_attention(q, k, v, causal=True, block_q=None, block_k=None,
     identical-math XLA reference runs instead.
     """
     return flash_attention_with_lse(q, k, v, causal, block_q, block_k,
-                                    interpret)[0]
+                                    interpret, window)[0]
 
 
-def flash_plan(q, block_q=None, block_k=None, interpret=None, causal=True):
+def effective_window(window, T, causal=True):
+    """The window the kernels are given: None for none, and for one
+    that reaches past the first key of every query (window >= T), which
+    masks nothing: such a layer runs the causal kernels themselves."""
+    if not window or window >= T:
+        return None
+    if not causal:
+        raise ValueError('a window needs causal attention: the kernels '
+                         'keep the keys up to a query, not around it')
+    return int(window)
+
+
+def flash_plan(q, block_q=None, block_k=None, interpret=None, causal=True,
+               window=None):
     """THE engagement decision for q [B, T, H, D]: the (block_q, block_k)
     the Pallas kernels run with, or None where the XLA reference runs
     instead. flash_attention_with_lse routes by it; the flash_attention
@@ -739,7 +839,10 @@ def flash_plan(q, block_q=None, block_k=None, interpret=None, causal=True):
     # is not computed. f32 keeps 512/1024 as swept in r4 (no cell runs
     # f32, nobody has timed larger).
     bf16 = q.dtype == jnp.bfloat16
-    one_tile = causal and T <= 2048
+    # ... and no window: the tiles a window's edge cuts may have to run
+    # whole (_tile_parts), which a 2048x2048 tile cannot
+    one_tile = causal and T <= 2048 \
+        and effective_window(window, T, causal) is None
     if block_q is None:
         block_q = (2048 if one_tile else 1024) if bf16 else 512
     if block_k is None:
@@ -755,20 +858,25 @@ def flash_plan(q, block_q=None, block_k=None, interpret=None, causal=True):
     return bq, bk
 
 
-def flash_diag(plan, causal=True):
+def flash_diag(plan, causal=True, window=None):
     """How the kernels of a flash_plan compute the tiles that straddle
     the diagonal: 'chunked<r>' (r row chunks, each against its live
     columns), 'whole' (the whole tile under the mask), 'none' where no
     tile is masked (not causal, or no plan). Follows from the blocks
-    alone, as the kernels decide it (_row_chunks)."""
+    alone, as the kernels decide it (_row_chunks), and under a window
+    (an effective one) from where its lower edge falls
+    (_window_chunked): the tiles that edge cuts go as the diagonal's
+    do."""
     if plan is None or not causal:
         return 'none'
     r = _row_chunks(*plan)
+    if window is not None and not _window_chunked(*plan, window):
+        r = 1
     return 'whole' if r == 1 else 'chunked%d' % r
 
 
 def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
-                             block_k=None, interpret=None):
+                             block_k=None, interpret=None, window=None):
     """flash_attention that also returns per-row logsumexp [B, H, T].
 
     This is the ring-attention building block: each device computes its
@@ -779,11 +887,12 @@ def flash_attention_with_lse(q, k, v, causal=True, block_q=None,
     XLA reference (with lse) elsewhere."""
     if interpret is None:
         interpret = False
-    plan = flash_plan(q, block_q, block_k, interpret, causal)
+    window = effective_window(window, q.shape[1], causal)
+    plan = flash_plan(q, block_q, block_k, interpret, causal, window)
     if plan is None:
-        return attention_reference_with_lse(q, k, v, causal)
+        return attention_reference_with_lse(q, k, v, causal, window=window)
     bq, bk = plan
-    return _flash_lse(q, k, v, causal, bq, bk, interpret)
+    return _flash_lse(q, k, v, causal, bq, bk, interpret, window)
 
 
 # ---- fused LSTM cell ------------------------------------------------------------

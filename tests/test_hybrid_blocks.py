@@ -25,6 +25,11 @@ _spec = importlib.util.spec_from_file_location(
         'benchmark', 'chip', 'models', 'nemotron_h.py'))
 nemotron_h = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(nemotron_h)
+_spec = importlib.util.spec_from_file_location(
+    'chip_models_afmoe', os.path.join(
+        os.path.dirname(_spec.origin), 'afmoe.py'))
+afmoe = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(afmoe)
 
 T = 37                  # not a multiple of the chunk
 TRAFFIC = {'batch': 2, 'seq_len': T}
@@ -60,23 +65,23 @@ def close(a, b, tol=2e-5):
         np.max(np.abs(a - b)) / scale)
 
 
-def run_branch(build_fn, x, weights, cfg):
+def run_branch(build_fn, x, weights, cfg, dims=nemotron_h.Dims):
     """One branch of the program on a fed [B, T, D] input with
     ``weights`` (the reference's leaves, creation order) put in its
     parameters: the branch's output, its gradient in x and in every
     trainable parameter under sum(out * g), and the extra fetches."""
-    d = nemotron_h.Dims(cfg)
+    d = dims(cfg)
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         xin = fluid.layers.data(name='x', shape=list(x.shape[1:]),
                                 dtype='float32')
-        g = fluid.layers.data(name='g', shape=list(x.shape[1:]),
-                              dtype='float32')
         xin.stop_gradient = False
         out = build_fn(fluid.layers, xin, d)
         extra = []
         if isinstance(out, tuple):
             out, extra = out[0], list(out[1:])
+        g = fluid.layers.data(name='g', shape=list(out.shape[1:]),
+                              dtype='float32')
         params = main.global_block().all_parameters()
         loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, g))
         train = [p for p in params if p.trainable]
@@ -90,7 +95,8 @@ def run_branch(build_fn, x, weights, cfg):
         for p, w in zip(params, weights):
             scope.set_var(p.name, jnp.array(w, copy=True))
         rng = np.random.RandomState(5)
-        gval = rng.randn(*x.shape).astype('float32')
+        gval = rng.randn(*x.shape[:-1], int(out.shape[-1])) \
+            .astype('float32')
         got = exe.run(main, feed={'x': x, 'g': gval},
                       fetch_list=[out] + grads + extra)
     n = 1 + len(grads)
@@ -374,15 +380,18 @@ def test_attention_head_shares_add_up_to_the_uncut_layer():
 
 
 # ---- the whole tiny model --------------------------------------------------
-@pytest.mark.parametrize('held', [(0, 16), (8, 8)])
-def test_tiny_model_loss_gradients_and_three_adam_steps(held):
-    cfg = tiny_cfg(experts_first=held[0], n_routed_experts=held[1])
-    ref = nemotron_h.Reference(cfg)
-    built = nemotron_h.build(cfg, TRAFFIC)
+def _three_adam_steps(model, cfg, same_step):
+    """The tiny model through the Executor against ``model.Reference``:
+    each loss, every leaf of the first gradient as the optimizer got
+    it, and the parameters' change after three Adam steps, which
+    ``same_step(got, want)`` compares leaf by leaf; a buffer stays as
+    it was."""
+    ref = model.Reference(cfg)
+    built = model.build(cfg, TRAFFIC)
     leaves = ref.leaves()
     key = jax.random.PRNGKey(7)
     init = ref.init(key)
-    batches = [{k: np.asarray(v) for k, v in nemotron_h.draw_batch(
+    batches = [{k: np.asarray(v) for k, v in model.draw_batch(
         cfg, TRAFFIC, jax.random.fold_in(key, 100 + i)).items()}
         for i in range(3)]
     scope = fluid.Scope()
@@ -416,10 +425,17 @@ def test_tiny_model_loss_gradients_and_three_adam_steps(held):
         params, state = ref.update(params, g, state, jnp.float32(i + 1))
     for n, _, t in leaves:
         if t:
-            close(final[n] - np.asarray(init[n]),
-                  params[n] - init[n], 5e-3)
+            same_step(final[n] - np.asarray(init[n]),
+                      np.asarray(params[n] - init[n]))
         else:
             np.testing.assert_array_equal(final[n], np.asarray(init[n]))
+
+
+@pytest.mark.parametrize('held', [(0, 16), (8, 8)])
+def test_tiny_model_loss_gradients_and_three_adam_steps(held):
+    _three_adam_steps(
+        nemotron_h, tiny_cfg(experts_first=held[0], n_routed_experts=held[1]),
+        lambda got, want: close(got, want, 5e-3))
 
 
 # ---- shapes, counters, AMP -------------------------------------------------
@@ -535,3 +551,371 @@ def test_amp_keeps_scores_and_the_carried_state_float32(amp):
         r'stablehlo\.dot_general.*: \(tensor<[0-9x]*x(\w+)>, '
         r'tensor<[0-9x]*x(\w+)>\) -> tensor<[0-9x]*x(\w+)>', text)
     assert ('bf16', 'bf16', 'f32') in set(dots)
+
+
+# ============================================================================
+# A window / full attention stack with rotary positions, gated attention
+# and a mixture of gated experts (benchmark/chip/models/afmoe.py), at tiny
+# widths that keep the structure: layers S S F S after... the first dense,
+# 16 query heads on 2 KV heads, 16 experts top 3, a window of 16 of 37
+# positions.
+# ============================================================================
+def af_cfg(**over):
+    cfg = {
+        'layer_types': [afmoe.SLIDING, afmoe.SLIDING, afmoe.FULL,
+                        afmoe.SLIDING],
+        'num_hidden_layers': 4, 'num_dense_layers': 1,
+        'hidden_size': 32, 'vocab_size': 64, 'rms_norm_eps': 1e-5,
+        'num_attention_heads': 16, 'num_key_value_heads': 2, 'head_dim': 8,
+        'sliding_window': 16, 'rope_theta': 100.0,
+        'intermediate_size': 40, 'moe_intermediate_size': 20,
+        'num_shared_experts': 1, 'router_num_experts': 16,
+        'num_experts': 16, 'experts_first': 0, 'num_experts_per_tok': 3,
+        'route_scale': 2.826, 'route_norm': True, 'score_func': 'sigmoid',
+        'hidden_act': 'silu', 'mup_enabled': True,
+        'tie_word_embeddings': False, 'initializer_range': 0.2,
+        'embedding_std': 0.2,
+        'optimizer': {'learning_rate': 1e-2, 'beta1': 0.9, 'beta2': 0.95,
+                      'epsilon': 1e-8},
+    }
+    cfg.update(over)
+    return cfg
+
+
+def af_block(cfg, layer, seed=3):
+    """(reference, leaf prefix, the layer's leaves by name) at seeded
+    weights, the norms off 1 so that they show."""
+    ref = afmoe.Reference(cfg)
+    full = ref.init(jax.random.PRNGKey(seed))
+    pre = 'l%d.' % layer
+    p = {n: full[n] for n, _, _ in ref.block_leaves(layer, pre)}
+    for i, n in enumerate(sorted(p)):
+        if 'norm' in n:
+            p[n] = 1.0 + 0.3 * jax.random.normal(
+                jax.random.PRNGKey(100 + i), p[n].shape)
+    return ref, pre, p
+
+
+_ATT = ('q', 'k', 'v', 'gate', 'q_norm', 'k_norm', 'o')
+_ROUTED = ('router', 'e_gate', 'e_up', 'e_down', 'expert_bias')
+_SHARED = ('s_gate', 's_up', 's_down')
+
+
+def _af_check(build_fn, ref_fn, cfg, p, names, tol=1e-4):
+    x = stream(width=cfg['hidden_size'])
+    out, grads, g, extra = run_branch(build_fn, x, [p[n] for n in names],
+                                      cfg, afmoe.Dims)
+    want, dx, dp = ref_branch(ref_fn, x, g, p)
+    close(out, want, tol)
+    close(grads[0], dx, tol)
+    train = [n for n in names if 'expert_bias' not in n]
+    for got, n in zip(grads[1:], train):
+        close(got, dp[n], 2 * tol)
+    return extra
+
+
+# ---- rotary positions -------------------------------------------------------
+def _rotary_complex(x, head_dim, base):
+    """The complex-number form: the pair (x_i, x_{i + dh/2}) of a head is
+    the number x_i + i x_{i + dh/2}, turned by exp(i t base^(-2i/dh))."""
+    B, T_, D = x.shape
+    half = head_dim // 2
+    xh = x.reshape(B, T_, D // head_dim, 2, half)
+    z = xh[..., 0, :] + 1j * xh[..., 1, :]
+    inv = base ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    turn = jnp.exp(1j * jnp.arange(T_, dtype=jnp.float32)[:, None] * inv)
+    z = z * turn[None, :, None, :]
+    return jnp.stack([z.real, z.imag], axis=-2).reshape(B, T_, D)
+
+
+def test_rotary_matches_the_complex_form():
+    """Forward and the gradient in x; position 0 is left as it is."""
+    x = stream()
+    out, grads, g, _ = run_branch(
+        lambda layers, t, d: layers.rotary_embedding(t, 8, base=100.0),
+        x, [], af_cfg(), afmoe.Dims)
+    want, dx, _ = ref_branch(lambda p, t: _rotary_complex(t, 8, 100.0), x,
+                             g, {})
+    close(out, want, 2e-5)
+    close(grads[0], dx, 2e-5)
+    np.testing.assert_allclose(out[:, 0], x[:, 0], rtol=1e-6)
+    # the reference's rotate_half form says the same
+    ref = afmoe.Reference(af_cfg(head_dim=8))
+    close(ref.rotary(jnp.asarray(x).reshape(2, T, 4, 8)).reshape(x.shape),
+          want, 2e-5)
+
+
+def test_rotary_angles_stay_float32_under_amp(amp):
+    """Under forced AMP a bf16 stream is turned by float32 angles: cos
+    and sin are taken of float32 and the output returns to bf16."""
+    from paddle_tpu.compiler.passes import rotary_counts
+    amp.set_amp(True)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data(name='x', shape=[T, 32], dtype='float32')
+        y = fluid.layers.fc(x, 32, num_flatten_dims=2, bias_attr=False)
+        out = fluid.layers.rotary_embedding(y, 8)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        before = rotary_counts()
+        text = exe.lowered(main, feed={'x': stream()},
+                           fetch_list=[out]).as_text()
+        after = rotary_counts()
+    import re
+    trig = re.findall(r'stablehlo\.(?:cosine|sine) .*tensor<[0-9x]*x(\w+)>',
+                      text)
+    assert trig and set(trig) == {'f32'}, trig
+    assert after.get(('8', 'bfloat16'), 0) \
+        == before.get(('8', 'bfloat16'), 0) + 1
+    assert re.search(r'-> \(?tensor<2x37x32xbf16>', text)
+
+
+# ---- attention: window or full, rotary or none, gated ----------------------
+@pytest.mark.parametrize('layer', [1, 2], ids=['window-rotary', 'full'])
+def test_gated_attention_matches_reference(layer):
+    cfg = af_cfg()
+    ref, pre, p = af_block(cfg, layer)
+    kind = cfg['layer_types'][layer]
+    _af_check(lambda layers, t, d: afmoe.attention_branch(layers, t, d, kind),
+              lambda p, t: ref.attention(p, t, pre, afmoe.Float32Dots(),
+                                         kind),
+              cfg, p, [pre + n for n in _ATT])
+    # the window shows: the same weights without it give another output
+    other = afmoe.FULL if kind == afmoe.SLIDING else afmoe.SLIDING
+    x = jnp.asarray(stream())
+    dots = afmoe.Float32Dots()
+    gap = jnp.max(jnp.abs(ref.attention(p, x, pre, dots, kind)
+                          - ref.attention(p, x, pre, dots, other)))
+    assert float(gap) > 1e-3
+
+
+# ---- gated experts -----------------------------------------------------------
+def _af_routed_case(cfg, bias=None):
+    ref, pre, p = af_block(cfg, 1)
+    if bias is not None:
+        p[pre + 'expert_bias'] = jnp.asarray(bias, jnp.float32)
+    first, held = cfg['experts_first'], cfg['num_experts']
+    for n in ('e_gate', 'e_up', 'e_down'):
+        p[pre + n] = p[pre + n][:held] if p[pre + n].shape[0] != held \
+            else p[pre + n]
+    extra = _af_check(
+        afmoe.routed_branch,
+        lambda p, t: ref.routed(p, t, pre, afmoe.Float32Dots()),
+        cfg, p, [pre + n for n in _ROUTED])
+    _, idx, _ = ref.routing(
+        p, jnp.asarray(stream(width=cfg['hidden_size'])), pre,
+        afmoe.Float32Dots())
+    want = [int(jnp.sum(idx == first + j)) for j in range(held)]
+    assert list(np.asarray(extra[0])) == want
+    return want
+
+
+def _af_route_widths(route):
+    return {'hidden_size': 128, 'moe_intermediate_size': 256} if route \
+        else {}
+
+
+@pytest.mark.parametrize('route', ['ragged_dot', 'pallas'], indirect=True)
+@pytest.mark.parametrize('held', [(0, 16), (4, 8), (13, 3)])
+def test_gated_experts_match_reference(held, route, request):
+    """silu(x W_gate) * (x W_up) through W_down, all experts and two
+    shares, on both grouped routes (the Pallas one interpreted); the
+    counter names route and activation."""
+    from paddle_tpu.compiler.passes import moe_counts
+    by = ('route', 'act')
+    before = moe_counts(by)
+    tokens = _af_routed_case(af_cfg(
+        experts_first=held[0], num_experts=held[1],
+        **_af_route_widths(route)))
+    assert sum(tokens) > 0
+    moved = {k for k, n in moe_counts(by).items() if n != before.get(k, 0)}
+    assert moved == {(request.node.callspec.params['route'], 'swiglu')}
+
+
+@pytest.mark.parametrize('route', ['ragged_dot', 'pallas'], indirect=True)
+def test_gated_experts_drop_no_token_when_two_take_them_all(
+        route, monkeypatch):
+    """Two held experts take every token: more pairs than a chunk's rows
+    (twice the balanced load), and the result is still the reference's
+    on either route."""
+    if not route:
+        monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', 8)
+    cfg = af_cfg(experts_first=4, num_experts=4, **_af_route_widths(route))
+    chunk = hybrid_ops.expert_chunk_rows(2 * T, 3, 4, 16)
+    assert sum(_af_routed_case(cfg)) <= chunk
+    bias = np.zeros(16, 'float32')
+    bias[5] = bias[6] = 100.0
+    tokens = _af_routed_case(cfg, bias=bias)
+    assert tokens[1] == tokens[2] == 2 * T
+    assert chunk < sum(tokens) <= 2 * chunk
+
+
+def _move_rows(p, x, transpose=False):
+    """The placement PR 31-32 ran, kept here as what the linear one is
+    held to: ``p @ x`` (``p.T @ x``) for a 0/1 matrix with at most one 1
+    a row."""
+    spec = 'rn,rl->nl' if transpose else 'rn,nl->rl'
+    return jnp.einsum(spec, p.astype(x.dtype), x,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize('c', [0, 1])
+def test_rows_are_placed_as_the_pick_matrix_places_them(c):
+    """_place_rows against _move_rows on the same operands: the rows
+    picked, their weights, and what summing rows back gives, in the
+    first chunk and in one that a skewed routing overflows into; a row
+    past the routed pairs is a zero row with weight 0."""
+    rng = np.random.RandomState(0)
+    n, held, width, chunk = 74, 4, 24, 64
+    chosen = jnp.asarray(rng.rand(held, n) < (0.33 if c else 0.15))
+    counts = jnp.sum(chosen, axis=1, dtype=jnp.int32)
+    place = (jnp.cumsum(counts) - counts)[:, None] \
+        + jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1
+    place = jnp.where(chosen, place, -1)
+    total = int(jnp.sum(counts))
+    assert c * chunk < total and (c or total <= chunk)
+    u = jnp.asarray(rng.randn(n, width).astype('float32'))
+    weight = jnp.where(chosen, jnp.asarray(
+        rng.rand(held, n).astype('float32')), 0.0)
+    rows = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+    xs, row_w, back = hybrid_ops._place_rows(
+        u, weight, hybrid_ops.pairs_in_row_order(place, chunk), counts, rows)
+    # pick[r, n]: token n's pair lies in row r
+    pick = jnp.any(place[:, None, :] == rows[None, :, None], axis=0)
+    np.testing.assert_array_equal(np.asarray(xs),
+                                  np.asarray(_move_rows(pick, u)))
+    want_w = jnp.sum(jnp.where(
+        place[:, None, :] == rows[None, :, None], weight[:, None, :], 0.0),
+        axis=(0, 2))
+    np.testing.assert_array_equal(np.asarray(row_w), np.asarray(want_w))
+    y = jnp.asarray(rng.randn(chunk, 40).astype('float32'))
+    np.testing.assert_allclose(
+        np.asarray(back(y)), np.asarray(_move_rows(pick, y, transpose=True)),
+        rtol=1e-6, atol=1e-6)
+    dead = np.asarray(rows) >= total
+    assert dead.any() and not np.asarray(xs)[dead].any() \
+        and not np.asarray(row_w)[dead].any()
+
+
+# ---- the shares add up to the uncut layer -----------------------------------
+def test_afmoe_shares_add_up_to_the_uncut_layer():
+    """Eight chips share a layer: each holds 2 of the 16 query heads on 1
+    of the 2 KV heads (a KV head lies on four of them) with its slices
+    of W_q, W_g and W_o, and 2 of the 16 experts. Their attention parts
+    add up to the uncut attention (before the norm that follows the
+    exchange); their routed parts, with the shared expert counted once,
+    to the uncut MLP."""
+    cfg = af_cfg()
+    ref, pre, p = af_block(cfg, 1)
+    d = afmoe.Dims(cfg)
+    x = stream()
+    dots = afmoe.Float32Dots()
+    kind = cfg['layer_types'][1]
+    want = ref.attention(p, jnp.asarray(x), pre, dots, kind)
+    total = 0.0
+    for share in range(8):
+        qs = np.arange(share * 2 * d.dh, (share + 1) * 2 * d.dh)
+        kv = share // 4
+        ks = np.arange(kv * d.dh, (kv + 1) * d.dh)
+        w = {'q': p[pre + 'q'][:, qs], 'k': p[pre + 'k'][:, ks],
+             'v': p[pre + 'v'][:, ks], 'gate': p[pre + 'gate'][:, qs],
+             'q_norm': p[pre + 'q_norm'], 'k_norm': p[pre + 'k_norm'],
+             'o': p[pre + 'o'][qs]}
+        part, _, _, _ = run_branch(
+            lambda layers, t, dd: afmoe.attention_branch(layers, t, dd, kind),
+            x, [w[n] for n in _ATT],
+            af_cfg(num_attention_heads=2, num_key_value_heads=1),
+            afmoe.Dims)
+        total = total + part
+    close(total, want, 1e-4)
+
+    want = ref.mlp(1, p, jnp.asarray(x), pre, dots)
+    total = 0.0
+    for first in range(0, 16, 2):
+        w = dict(p)
+        for n in ('e_gate', 'e_up', 'e_down'):
+            w[pre + n] = p[pre + n][first:first + 2]
+        part, _, _, _ = run_branch(
+            afmoe.routed_branch, x, [w[pre + n] for n in _ROUTED],
+            af_cfg(experts_first=first, num_experts=2), afmoe.Dims)
+        total = total + part
+    once, _, _, _ = run_branch(
+        afmoe.shared_branch, x, [p[pre + n] for n in _SHARED], cfg,
+        afmoe.Dims)
+    close(total + once, want, 1e-4)
+
+
+# ---- the whole tiny model ----------------------------------------------------
+@pytest.mark.parametrize('held', [(0, 8), (8, 8)])
+def test_afmoe_tiny_model_loss_gradients_and_three_adam_steps(held):
+    def same_step(got, want):
+        # by the leaf's norm: Adam divides by sqrt(v), so where an
+        # element's gradient is next to nothing, round-off in it moves
+        # that element's step by a step
+        assert np.linalg.norm(got - want) <= 1e-2 * np.linalg.norm(want)
+    _three_adam_steps(
+        afmoe, af_cfg(experts_first=held[0], num_experts=held[1],
+                      num_attention_heads=4), same_step)
+
+
+# ---- shapes and counters -------------------------------------------------------
+def test_shape_inference_covers_the_window_stack():
+    from paddle_tpu.analysis import infer
+    assert 'rotary_embedding' in set(infer.registered_shape_ops())
+    built = afmoe.build(af_cfg(), TRAFFIC)
+    env, diags, _ = infer.infer_program(built['main'])
+    assert [d for d in diags if d.severity == 'error'] == []
+    block = built['main'].global_block()
+    rot = [op for op in block.ops if op.type == 'rotary_embedding']
+    assert len(rot) == 2 * 3                      # q and k, window layers
+    assert env[rot[0].outputs['Out'][0]].shape[-1] == 16 * 8
+    gated = [op for op in block.ops if op.type == 'routed_experts']
+    assert all(op.attrs['act'] == 'swiglu' and op.inputs.get('W3')
+               for op in gated)
+
+    # a gated expert's up projection must be shaped as its gate
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data(name='x', shape=[T, 32], dtype='float32')
+        scores = fluid.layers.router_scores(x, 16)
+        fluid.layers.routed_experts(x, scores, 20, 16, 3, act='swiglu')
+        op = main.global_block().ops[-1]
+        main.global_block().var(op.inputs['W3'][0]).shape = (16, 32, 24)
+        with pytest.raises(ValueError):
+            fluid.layers.routed_experts(x, scores, 20, 16, 3, act='gelu')
+        with pytest.raises(ValueError):
+            fluid.layers.rotary_embedding(x, 6)     # 6 does not divide 32
+    _, diags, _ = infer.infer_program(main)
+    assert [d.code for d in diags if d.severity == 'error'] \
+        == ['rank-mismatch']
+
+
+def test_window_stack_lowerings_are_counted():
+    """One trace of the tiny stack: four attention ops, three of them
+    under the window (the route is xla here; window_flash_counts() is
+    what took the kernels), three gated expert layers, six rotary
+    ops."""
+    from paddle_tpu.compiler.passes import (flash_counts, moe_counts,
+                                            rotary_counts,
+                                            window_flash_counts)
+    cfg = af_cfg(experts_first=8, num_experts=8)
+    built = afmoe.build(cfg, TRAFFIC)
+    batch = {k: np.asarray(v) for k, v in afmoe.draw_batch(
+        cfg, TRAFFIC, jax.random.PRNGKey(0)).items()}
+    exe = fluid.Executor(fluid.CPUPlace())
+
+    def counts():
+        return (flash_counts(by=('route', 'window')),
+                moe_counts(by=('held', 'act')),
+                rotary_counts(), window_flash_counts())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(built['startup'])
+        before = counts()
+        exe.lowered(built['main'], feed=batch, fetch_list=[built['loss']])
+        after = counts()
+    moved = [{k: n - was.get(k, 0) for k, n in now.items()
+              if n != was.get(k, 0)} for was, now in zip(before, after)]
+    assert moved == [{('xla', '16'): 3, ('xla', '0'): 1},
+                     {('8', 'swiglu'): 3},
+                     {('8', 'float32'): 6}, {}]

@@ -475,8 +475,8 @@ def engage(monkeypatch):
     monkeypatch.setattr(pk, '_FLASH_MIN_ROWS', 0)
     monkeypatch.setattr(
         pk, '_flash_lse',
-        lambda q, k, v, causal, bq, bk, interpret:
-            orig(q, k, v, causal, bq, bk, True))
+        lambda q, k, v, causal, bq, bk, interpret, window=None:
+            orig(q, k, v, causal, bq, bk, True, window))
 
 
 _FLASH_OP_B, _FLASH_OP_H, _FLASH_OP_DH = 2, 4, 64
@@ -493,8 +493,9 @@ def _plan_blocks(blocks):
             patch.setattr(
                 pk, 'flash_plan',
                 lambda q, block_q=None, block_k=None, interpret=None,
-                causal=True: orig(q, block_q or blocks[0],
-                                  block_k or blocks[1], interpret, causal))
+                causal=True, window=None: orig(
+                    q, block_q or blocks[0], block_k or blocks[1],
+                    interpret, causal, window))
         yield
 
 
@@ -635,7 +636,7 @@ def test_flash_op_amp_matches_f32_reference(route, T, blocks, amp, engage):
         assert err < 3e-2, '%s (%s): %.3g' % (name, route, err)
 
 
-def _gqa_program(T, heads, kv_heads, dh):
+def _gqa_program(T, heads, kv_heads, dh, window=None):
     """One flash_attention op with fewer KV heads than query heads on
     fed q [B, T, heads*dh], k, v [B, T, kv_heads*dh], and the gradients
     of sum(out * w) in q, k, v."""
@@ -650,7 +651,7 @@ def _gqa_program(T, heads, kv_heads, dh):
             x.stop_gradient = False
         out = fluid.layers.flash_attention(
             q, k, v, num_heads=heads, causal=True, num_kv_heads=kv_heads,
-            head_dim=dh)
+            head_dim=dh, window=window)
         loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, w))
         fetch = [out] + fluid.gradients(loss, [q, k, v])
     return main, startup, fetch
@@ -700,6 +701,197 @@ def test_flash_op_with_fewer_kv_heads(route, T, heads, kv_heads, dh, amp,
         assert a.shape == b.shape, name
         err = np.max(np.abs(a - np.asarray(b))) / np.max(np.abs(b))
         assert err < 2e-5, '%s (%s): %.3g' % (name, route, err)
+
+
+
+# ---- a window on the one attention op ---------------------------------------
+# (H, D, block_q, block_k, T, window): a window narrower than a block, a
+# block wide, and several (the tiles its lower edge cuts are whole under
+# both masks at these block sizes); unequal blocks either way round; T
+# that the asked-for block does not divide (_pick_block takes 128); then
+# square blocks of 384 rows whose cut tiles go in 3 row chunks: a window
+# a block wide (no tile between the diagonal's and the edge's), two
+# blocks (full tiles between, dead ones below), and one that is no
+# multiple of the block (cut tiles whole, offsets from the grid)
+_WINDOW_CASES = [(2, 64, 128, 128, 512, 100), (2, 64, 128, 128, 512, 128),
+                 (2, 64, 128, 128, 512, 300), (1, 128, 128, 256, 512, 200),
+                 (1, 128, 256, 128, 512, 300), (1, 128, 256, 256, 640, 256),
+                 (1, 128, 384, 384, 1536, 384), (2, 64, 384, 384, 1536, 768),
+                 (1, 128, 384, 384, 1536, 500)]
+_WINDOW_IDS = ['h%d-d%d-%dx%d-t%d-w%d' % c for c in _WINDOW_CASES]
+
+
+def _window_operands(H, D, T, B=1, seed=0):
+    rng = np.random.RandomState(seed)
+    return [jnp.asarray(rng.randn(*shape).astype('float32'))
+            for shape in [(B, T, H, D)] * 4 + [(B, H, T)]]
+
+
+def _out_lse_grads(fn, q, k, v, w, wl):
+    """(out, lse) of ``fn(q, k, v)`` and every gradient of sum(out * w)
+    + sum(lse * wl)."""
+    def loss(q, k, v):
+        out, lse = fn(q, k, v)
+        return jnp.sum(out * w) + jnp.sum(lse * wl), (out, lse)
+    (_, outs), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True)(q, k, v)
+    return outs + grads
+
+
+@pytest.mark.parametrize('two_pass', [False, True], indirect=True,
+                         ids=['merged', 'two-pass'])
+@pytest.mark.parametrize('H,D,bq,bk,T,window', _WINDOW_CASES,
+                         ids=_WINDOW_IDS)
+def test_windowed_flash_matches_the_band_masked_reference(
+        H, D, bq, bk, T, window, two_pass):
+    """The windowed forward and every gradient (through the lse output
+    too), merged and two-pass backward, through the interpreter against
+    plain attention under the causal and the band mask."""
+    q, k, v, w, wl = _window_operands(H, D, T)
+    want = _out_lse_grads(
+        lambda q, k, v: pk.attention_reference_with_lse(
+            q, k, v, True, window=window), q, k, v, w, wl)
+    got = _out_lse_grads(
+        lambda q, k, v: pk.flash_attention_with_lse(
+            q, k, v, True, bq, bk, True, window), q, k, v, w, wl)
+    for name, a, b in zip(('out', 'lse', 'dq', 'dk', 'dv'), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+    # and the band really is narrower than the triangle
+    full = pk.attention_reference(q, k, v, True)
+    assert float(jnp.max(jnp.abs(full - want[0]))) > 1e-2
+
+
+@pytest.mark.parametrize('window', [1152, 4096])
+def test_a_window_that_reaches_every_key_runs_the_causal_kernels(window):
+    """window >= T masks nothing: the op's kernels are the causal ones
+    themselves (no window reaches them), bit for bit, in the output and
+    in every gradient."""
+    H, D, T, b = 1, 128, 1152, 384
+    q, k, v, w, wl = _window_operands(H, D, T, seed=3)
+    assert pk.effective_window(window, T) is None
+    assert pk.effective_window(T - 1, T) == T - 1
+    causal = _out_lse_grads(lambda q, k, v: pk.flash_attention_with_lse(
+        q, k, v, True, b, b, True), q, k, v, w, wl)
+    windowed = _out_lse_grads(lambda q, k, v: pk.flash_attention_with_lse(
+        q, k, v, True, b, b, True, window), q, k, v, w, wl)
+    for a, c in zip(windowed, causal):
+        assert np.array_equal(np.asarray(a), np.asarray(c))
+    with pytest.raises(ValueError):
+        pk.flash_attention(q, k, v, causal=False, window=128,
+                           interpret=True)
+
+
+def _tile_has_a_kept_pair(qi, kb, bq, bk, window):
+    i = np.arange(qi * bq, (qi + 1) * bq)[:, None]
+    j = np.arange(kb * bk, (kb + 1) * bk)[None, :]
+    return bool(np.any((j <= i) & (i - j < window)))
+
+
+@pytest.mark.parametrize('bq,bk,T,window', [
+    (128, 128, 1024, 100), (128, 128, 1024, 128), (128, 128, 1024, 384),
+    (128, 256, 1024, 200), (256, 128, 1024, 300), (128, 128, 1024, 129),
+    (1024, 1024, 8192, 2048)])
+def test_live_tile_tables_follow_the_band(bq, bk, T, window):
+    """The index maps of the q-major and the kv-major sweeps under a
+    window: over a block's steps they name every tile that holds a kept
+    (query, key) pair and no tile that holds none (a dead step repeats
+    a live block, so nothing is fetched for it). At the cell's shape, 21
+    of the causal triangle's 36 tiles."""
+    n_qb, n_kb = T // bq, T // bk
+    kb_at = pk._live_kb(True, bq, bk, n_kb, window)
+    qi_at = pk._live_qi(True, bq, bk, window, n_qb)
+    live = {(qi, kb) for qi in range(n_qb) for kb in range(n_kb)
+            if _tile_has_a_kept_pair(qi, kb, bq, bk, window)}
+    q_major = {(qi, int(kb_at(qi, j))) for qi in range(n_qb)
+               for j in range(n_kb)}
+    kv_major = {(int(qi_at(kb, i)), kb) for kb in range(n_kb)
+                for i in range(n_qb)}
+    assert q_major == live
+    assert kv_major == live
+    causal = {(qi, kb) for qi in range(n_qb) for kb in range(n_kb)
+              if kb * bk <= (qi + 1) * bq - 1}
+    assert live < causal
+    if T == 8192:
+        assert (len(live), len(causal)) == (21, 36)
+
+
+@pytest.mark.parametrize('dtype,T,window,plan,diag', [
+    ('bfloat16', 8192, 2048, (1024, 1024), 'chunked2'),
+    ('bfloat16', 8192, 1536, (1024, 1024), 'whole'),
+    ('bfloat16', 2048, 1024, (1024, 1024), 'chunked2'),
+    ('bfloat16', 2048, 4096, (2048, 2048), 'chunked4'),
+    ('float32', 2048, 512, (512, 1024), 'whole')])
+def test_flash_plan_blocks_under_a_window(dtype, T, window, plan, diag):
+    """A window keeps flash_plan off the one 2048 x 2048 tile (a tile
+    its edge cuts may have to run whole, which that tile cannot), unless
+    it reaches every key; its cut tiles go in the diagonal's row chunks
+    where the edge falls on tile corners and whole otherwise."""
+    q = jnp.zeros((1, T, 4, 128), dtype)
+    assert pk.flash_plan(q, interpret=True, window=window) == plan
+    assert pk.flash_diag(plan, True, pk.effective_window(window, T)) == diag
+
+
+@pytest.mark.parametrize('route,T,window', [
+    ('pallas', 512, 200), ('pallas', 512, 4096), ('xla', 256, 100)])
+def test_flash_op_window_with_fewer_kv_heads(route, T, window, amp, engage):
+    """The op's ``window`` with num_kv_heads < num_heads on both routes
+    (the engaged one through the interpreter): output and every
+    gradient against plain attention under the band mask with the KV
+    heads repeated; the lowering counter carries the window, '0' for
+    one that reaches every key, and window_flash_counts() the windowed
+    lowerings that took the kernels."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.compiler.passes import flash_counts, window_flash_counts
+    amp.set_amp(False)
+    heads, kv_heads, dh, B = 4, 2, 64, _FLASH_OP_B
+    rng = np.random.RandomState(4)
+    feed = {'q': rng.randn(B, T, heads * dh), 'w': rng.randn(B, T, heads * dh),
+            'k': rng.randn(B, T, kv_heads * dh),
+            'v': rng.randn(B, T, kv_heads * dh)}
+    feed = {n: x.astype('float32') for n, x in feed.items()}
+    main, startup, fetch = _gqa_program(T, heads, kv_heads, dh, window)
+    exe = fluid.Executor(fluid.CPUPlace())
+    label = str(window if window < T else 0)
+    with fluid.scope_guard(fluid.Scope()), _plan_blocks((128, 128)):
+        exe.run(startup)
+        before = flash_counts(by=('route', 'window')), window_flash_counts()
+        got = exe.run(main, feed=feed, fetch_list=fetch)
+        after = flash_counts(by=('route', 'window')), window_flash_counts()
+    # counted once a trace, as the other labels are
+    assert after[0].get((route, label), 0) \
+        > before[0].get((route, label), 0)
+    engaged = route == 'pallas' and window < T
+    assert (after[1].get((label,), 0) > before[1].get((label,), 0)) \
+        == engaged
+    assert set(after[1]) - set(before[1]) <= {(label,)}
+
+    def loss(q, k, v):
+        rep = heads // kv_heads
+        kh = jnp.repeat(k.reshape(B, T, kv_heads, dh), rep, axis=2)
+        vh = jnp.repeat(v.reshape(B, T, kv_heads, dh), rep, axis=2)
+        o = pk.attention_reference(q.reshape(B, T, heads, dh), kh, vh,
+                                   causal=True, window=window) \
+            .reshape(q.shape)
+        return jnp.sum(o * feed['w']), o
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(
+        *(jnp.asarray(feed[n]) for n in 'qkv'))
+    for name, a, b in zip(('out', 'dq', 'dk', 'dv'), got, (out,) + grads):
+        err = np.max(np.abs(a - np.asarray(b))) / np.max(np.abs(b))
+        assert err < 2e-5, '%s (%s): %.3g' % (name, route, err)
+
+
+def test_flash_layer_refuses_a_window_without_causal():
+    import paddle_tpu.fluid as fluid
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        x = fluid.layers.data(name='x', shape=[64, 128], dtype='float32')
+        with pytest.raises(ValueError):
+            fluid.layers.flash_attention(x, x, x, num_heads=2, causal=False,
+                                         window=16)
+        with pytest.raises(ValueError):
+            fluid.layers.flash_attention(x, x, x, num_heads=2, window=0)
 
 
 def test_flash_plan_counts_a_long_row_for_its_scores(monkeypatch):
@@ -796,7 +988,7 @@ def test_flash_op_lowers_with_flash_plans_blocks(amp_on, T, causal, blocks,
     import paddle_tpu.fluid as fluid
     got = []
 
-    def kernels(q, k, v, causal, bq, bk, interpret):
+    def kernels(q, k, v, causal, bq, bk, interpret, window=None):
         got.append((q.dtype.name, bq, bk))
         return pk.attention_reference_with_lse(q, k, v, causal)
 
