@@ -295,23 +295,21 @@ def _expert_chunk(u, ws, weight, order, counts, chunk, c, act):
     ``lax.ragged_dot``, one layout for both), weigh, sum back to
     [N, L] float32.
 
-    The rows past the routed pairs are zero rows given to the last
-    expert, so every row of a chunk lies in a group. On either route
-    that is what keeps a gradient clean: ``ragged_dot`` leaves rows
-    outside its groups uninitialised on the TPU (in the transposed
-    products too), and the Pallas kernels write only the rows of the
-    groups they are told. A chunk costs the same whatever the routing
-    sends it: ``ragged_dot`` by its row count, the kernels by their
-    grid, which is the chunk's row tiles plus one visit a further
-    expert however the rows split; and the placement moves a chunk's
-    rows, whoever they belong to."""
+    The rows past the routed pairs are zero rows that belong to no
+    expert (``sizes`` sums to the pairs this chunk holds): grouped_matmul
+    gives them an exactly zero product and gradient on either route, and
+    on the Pallas route spends a grid step without an MXU pass on a row
+    tile of them, so a chunk's products cost what the routing sends it:
+    the first chunk of a balanced step about half its row tiles, the
+    last chunk of a skewed one what it holds. The placement and the
+    float32 work between the products still move a chunk's rows,
+    whoever they belong to."""
     from .pallas_kernels import grouped_matmul
     lo = c * chunk
     rows = lo + jnp.arange(chunk, dtype=jnp.int32)
     ends = jnp.cumsum(counts)
     sizes = jnp.clip(ends, lo, lo + chunk) \
         - jnp.clip(ends - counts, lo, lo + chunk)
-    sizes = sizes.at[-1].add(chunk - jnp.sum(sizes))
     xs, row_w, back = _place_rows(u, weight, order, counts, rows)
     h = _expert_hidden(xs, ws, sizes, act)
     y = grouped_matmul(mxu_operand(h).astype(xs.dtype), mxu_operand(ws[-1]),
@@ -411,8 +409,11 @@ def _routed_experts(ctx):
     without AMP, odd widths). The rows go in chunks of twice the
     balanced load N top_k held / E: one under a balanced routing, as
     many more as a skewed one fills (a loop with a run-time trip count,
-    so XLA's static shapes hold any routing). TokensPerExpert [held] is
-    the second output.
+    so XLA's static shapes hold any routing). A chunk's rows past the
+    routed pairs belong to no expert: the Pallas products pass over
+    their row tiles (pallas_kernels.live_row_tiles counts them from
+    TokensPerExpert), the row moves and the activation still sweep
+    them. TokensPerExpert [held] is the second output.
     What the experts held elsewhere would add is left out. Each
     lowering counts once in ``moe_lowerings_total{experts=, held=,
     top_k=, route=, act=}`` (compiler/passes.py::moe_counts)."""

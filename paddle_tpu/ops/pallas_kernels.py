@@ -1426,22 +1426,29 @@ def fused_lstm_cell(xg, r_prev, c_prev, w, interpret=None):
 
 # ---- grouped matmul (routed experts) --------------------------------------------
 # rows [M, K] lie expert by expert; ``sizes`` [held] says how many rows
-# each expert owns and sums to M. The rows go in tiles of ``tm``; a tile
-# that straddles a group boundary is visited once for every expert with
-# rows in it, under that expert's row mask, so no row is padded and a
-# product's grid is M / tm + held - 1 visits whatever the split.
+# each expert owns. They sum to the live rows, at most M: the rows past
+# them belong to no expert. The rows go in tiles of ``tm``; a tile that
+# straddles a group boundary is visited once for every expert with rows
+# in it, under that expert's row mask, so no row is padded and a
+# product's grid is M / tm + held - 1 visits whatever the split. A visit
+# whose rows all lie past the live ones is a grid step that fetches no
+# block and runs no MXU pass: it writes its tile of zeros (a product)
+# or nothing at all (a weight gradient).
 
 def grouped_visits(sizes, tiles, tm):
     """The visit table of a grouped product: int32 [tiles + held - 1]
-    arrays (tile, expert, lo, hi). Visit v multiplies rows lo..hi of
-    row tile ``tile`` by expert ``expert``. The visits partition the
-    rows at every tile start and every group start, in row order;
-    where the two coincide (or a group is empty) a visit has no row
-    (lo == hi), which is how an expert with no row still gets its
-    visit. Made by counting, as the layer's layout is: a sort of 31
-    numbers is a kernel of its own on the TPU."""
+    arrays (tile, expert, lo, hi) and ``live`` [1], the live rows
+    (sum(sizes)). Visit v holds rows lo..hi of row tile ``tile`` for
+    expert ``expert``: it multiplies those below ``live`` and zeroes
+    the others. The visits partition the rows at every tile start and
+    every group start, in row order; where the two coincide (or a group
+    is empty) a visit has no row (lo == hi), which is how an expert
+    with no row still gets its visit. Made by counting, as the layer's
+    layout is: a sort of 31 numbers is a kernel of its own on the
+    TPU."""
     held = sizes.shape[0]
-    starts = jnp.cumsum(sizes) - sizes
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
     point = jnp.concatenate(
         [jnp.arange(tiles, dtype=jnp.int32) * tm, starts[1:]])
     ident = jnp.concatenate(
@@ -1453,42 +1460,84 @@ def grouped_visits(sizes, tiles, tm):
     hi = jnp.concatenate([lo[1:], jnp.full((1,), tiles * tm, jnp.int32)])
     expert = jnp.max(jnp.where(rank[None, :] <= v, ident[None, :], 0),
                      axis=1)
-    return jnp.minimum(lo // tm, tiles - 1), expert, lo, hi
+    return (jnp.minimum(lo // tm, tiles - 1), expert, lo, hi,
+            jnp.minimum(ends[-1:], tiles * tm))
 
 
-def _visit_rows(tile_ref, lo_ref, hi_ref, v, shape, tm):
-    """The rows of visit ``v`` as a mask of ``shape`` ([tm, lanes])."""
-    row = tile_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    return jnp.logical_and(row >= lo_ref[v], row < hi_ref[v])
+def live_row_tiles(counts, rows, tm=None):
+    """(live, total) row tiles of the grouped products of one expert
+    layer in one step: ``counts`` the pairs each held expert was routed
+    (the op's TokensPerExpert), ``rows`` a chunk's rows
+    (hybrid_ops.expert_chunk_rows), ``tm`` the row tile. The pairs fill
+    as many chunks as they need, the first always run; a tile with a
+    routed row in it is live, the others cost a grid step each. Plain
+    host arithmetic, for tests and for reading a run."""
+    tm = tm or _GROUPED_ROW_TILE
+    pairs = sum(int(c) for c in counts)
+    chunks = max(1, -(-pairs // rows))
+    live = sum(-(-min(rows, pairs - c * rows) // tm) for c in range(chunks))
+    return live, chunks * (rows // tm)
 
 
-def _gmm_kernel(tile_ref, expert_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref,
-                *, tm, dims):
+def _src_tile(tile, live, tm):
+    """The row tile a visit reads: its own, or, past the live rows, the
+    last live row's, so that no block moves for a visit that reads
+    none."""
+    return jnp.minimum(tile, jnp.maximum(live - 1, 0) // tm)
+
+
+def _rows_between(tile, lo, hi, shape, tm):
+    """Rows lo..hi of row tile ``tile`` as a mask of ``shape`` ([tm,
+    lanes])."""
+    row = tile * tm + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return jnp.logical_and(row >= lo, row < hi)
+
+
+def _gmm_kernel(tile_ref, expert_ref, lo_ref, hi_ref, live_ref, x_ref, w_ref,
+                o_ref, *, tm, dims):
     # a tile's block stays in VMEM over its consecutive visits; each
-    # writes its own rows and the last leaves every row written
+    # writes its own rows and the last leaves every row written: the
+    # live ones (lo..mid) with their product, those past them (mid..hi)
+    # with zeros, whatever x holds there
     v = pl.program_id(1)
-    y = _dot(x_ref[...], w_ref[...], dims).astype(o_ref.dtype)
-    keep = _visit_rows(tile_ref, lo_ref, hi_ref, v, y.shape, tm)
-    o_ref[...] = jnp.where(keep, y, o_ref[...])
+    lo, hi = lo_ref[v], hi_ref[v]
+    mid = jnp.clip(live_ref[0], lo, hi)
+
+    @pl.when(lo < mid)
+    def _():
+        y = _dot(x_ref[...], w_ref[...], dims).astype(o_ref.dtype)
+        keep = _rows_between(tile_ref[v], lo, mid, y.shape, tm)
+        o_ref[...] = jnp.where(keep, y, o_ref[...])
+
+    @pl.when(mid < hi)
+    def _():
+        dead = _rows_between(tile_ref[v], mid, hi, o_ref.shape, tm)
+        o_ref[...] = jnp.where(dead, 0.0, o_ref[...])
 
 
-def _tgmm_kernel(tile_ref, expert_ref, lo_ref, hi_ref, a_ref, b_ref, o_ref,
-                 acc_ref, *, tm):
+def _tgmm_kernel(tile_ref, expert_ref, lo_ref, hi_ref, live_ref, a_ref, b_ref,
+                 o_ref, acc_ref, *, tm):
     # an expert's visits are consecutive: zero at its first, write at
-    # its last. The rows of other experts in the tile are zeroed in a
-    # (through float32: v5e's vector unit has no bf16), so whatever b
-    # holds there is multiplied by 0.
+    # its last, whether or not they hold a live row. The rows of other
+    # experts in the tile, and those past the live rows, are zeroed in a
+    # (through float32: v5e's vector unit has no bf16), so what b holds
+    # there is multiplied by 0 (_grouped hands b over with zeros past
+    # the live rows, where it may hold anything).
     v, last = pl.program_id(1), pl.num_programs(1) - 1
     e = expert_ref[v]
+    lo = lo_ref[v]
+    mid = jnp.clip(live_ref[0], lo, hi_ref[v])
 
     @pl.when(jnp.logical_or(v == 0, expert_ref[jnp.maximum(v - 1, 0)] != e))
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]
-    keep = _visit_rows(tile_ref, lo_ref, hi_ref, v, a.shape, tm)
-    a = jnp.where(keep, a.astype(jnp.float32), 0.0).astype(a.dtype)
-    acc_ref[...] += _dot(a, b_ref[...], (0, 0))
+    @pl.when(lo < mid)
+    def _():
+        a = a_ref[...]
+        keep = _rows_between(tile_ref[v], lo, mid, a.shape, tm)
+        a = jnp.where(keep, a.astype(jnp.float32), 0.0).astype(a.dtype)
+        acc_ref[...] += _dot(a, b_ref[...], (0, 0))
 
     @pl.when(jnp.logical_or(
         v == last, expert_ref[jnp.minimum(v + 1, last)] != e))
@@ -1513,22 +1562,23 @@ def _gmm_pallas_call(visits, x, w, *, tm, tn, transpose, out_dtype,
     M, K = x.shape
     N = w.shape[1] if transpose else w.shape[2]
     if transpose:
-        w_spec = pl.BlockSpec((None, tn, K), lambda n, v, t, e, lo, hi:
+        w_spec = pl.BlockSpec((None, tn, K), lambda n, v, t, e, lo, hi, live:
                               (e[v], n, 0))
     else:
-        w_spec = pl.BlockSpec((None, K, tn), lambda n, v, t, e, lo, hi:
+        w_spec = pl.BlockSpec((None, K, tn), lambda n, v, t, e, lo, hi, live:
                               (e[v], 0, n))
     return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm,
                           dims=(1, 1) if transpose else (1, 0)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(N // tn, visits[0].shape[0]),
             in_specs=[
-                pl.BlockSpec((tm, K), lambda n, v, t, e, lo, hi: (t[v], 0)),
+                pl.BlockSpec((tm, K), lambda n, v, t, e, lo, hi, live:
+                             (_src_tile(t[v], live[0], tm), 0)),
                 w_spec,
             ],
-            out_specs=pl.BlockSpec((tm, tn), lambda n, v, t, e, lo, hi:
+            out_specs=pl.BlockSpec((tm, tn), lambda n, v, t, e, lo, hi, live:
                                    (t[v], n)),
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
@@ -1547,14 +1597,16 @@ def _tgmm_pallas_call(visits, a, b, *, held, tm, tn, out_dtype, interpret):
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(N // tn, visits[0].shape[0]),
             in_specs=[
-                pl.BlockSpec((tm, K), lambda n, v, t, e, lo, hi: (t[v], 0)),
-                pl.BlockSpec((tm, tn), lambda n, v, t, e, lo, hi:
-                             (t[v], n)),
+                pl.BlockSpec((tm, K), lambda n, v, t, e, lo, hi, live:
+                             (_src_tile(t[v], live[0], tm), 0)),
+                pl.BlockSpec((tm, tn), lambda n, v, t, e, lo, hi, live:
+                             (_src_tile(t[v], live[0], tm), n)),
             ],
-            out_specs=pl.BlockSpec((None, K, tn), lambda n, v, t, e, lo, hi:
+            out_specs=pl.BlockSpec((None, K, tn),
+                                   lambda n, v, t, e, lo, hi, live:
                                    (e[v], 0, n)),
             scratch_shapes=[pltpu.VMEM((K, tn), jnp.float32)],
         ),
@@ -1584,6 +1636,11 @@ _GROUPED_ROW_TILE = 128
 _GROUPED_BLOCK_BYTES = 6 * 1024 * 1024
 
 
+def _rows_below(m, live):
+    """[m, 1] mask of the rows below ``live``."""
+    return jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0) < live
+
+
 def _grouped_block(n, other, dtype):
     """The widest block of an ``n``-wide dimension, in whole 128-lane
     tiles that divide ``n``, whose [other, block] weights stay under
@@ -1607,10 +1664,13 @@ def _grouped_fwd(rows, w, visits, tm, interpret):
 def _grouped_bwd(tm, interpret, res, g):
     # both gradients take the cotangent in the rows' dtype and come out
     # in their operand's, which is where jax's transposition of a
-    # bf16 x bf16 -> f32 product rounds them too
+    # bf16 x bf16 -> f32 product rounds them too. The cotangent's rows
+    # past the live ones go in as zeros (a select beside the cast: they
+    # may hold anything, and the weight gradient multiplies them by 0)
     rows, w, visits = res
     K, N = w.shape[1:]
-    g = g.astype(rows.dtype)
+    g = jnp.where(_rows_below(g.shape[0], visits[4]), g, 0.0) \
+        .astype(rows.dtype)
     d_rows = _gmm_pallas_call(
         visits, g, w, tm=tm, tn=_grouped_block(K, N, w.dtype),
         transpose=True, out_dtype=rows.dtype, interpret=interpret)
@@ -1644,15 +1704,27 @@ def grouped_plan(rows, w, interpret=None):
 
 def grouped_matmul(rows, w, sizes, interpret=None):
     """rows[rows of e] @ w[e] for every expert e -> [M, N] float32, as
-    ``lax.ragged_dot(rows, w, sizes, preferred_element_type=float32)``.
-    rows [M, K] lie expert by expert, ``sizes`` [held] int32 must sum to
-    M (the caller gives the rows nobody routed to its last expert: the
-    kernels, like libtpu's, write only rows that lie in a group).
-    Differentiable in rows and w on either route."""
+    ``lax.ragged_dot(rows, w, sizes, preferred_element_type=float32)``
+    where rows lie in a group. rows [M, K] lie expert by expert,
+    ``sizes`` [held] int32 sums to at most M: the rows past the groups
+    belong to no expert. Their product and their data gradient are
+    exactly zero and they add to no weight gradient, whatever they or
+    their cotangent hold. Differentiable in rows and w on either route.
+
+    The Pallas kernels spend a grid step on a row tile past the groups,
+    with no MXU pass and no block fetched (a product writes its zeros),
+    so a product costs what its live rows cost.
+    ``lax.ragged_dot`` leaves rows outside its groups uninitialised on
+    the TPU (in the transposed products too), so on that route the last
+    expert is handed them as zero rows and the outcome is masked."""
     plan = grouped_plan(rows, w, interpret)
     if plan is None:
-        return jax.lax.ragged_dot(rows, w, sizes,
-                                  preferred_element_type=jnp.float32)
+        M, pairs = rows.shape[0], jnp.sum(sizes)
+        live = _rows_below(M, pairs)
+        y = jax.lax.ragged_dot(
+            jnp.where(live, rows, jnp.zeros_like(rows)), w,
+            sizes.at[-1].add(M - pairs), preferred_element_type=jnp.float32)
+        return jnp.where(live, y, 0.0)
     tm, interpret = plan
     visits = grouped_visits(sizes, rows.shape[0] // tm, tm)
     return _grouped(rows, w, visits, tm, interpret)

@@ -246,6 +246,15 @@ def route(request, monkeypatch):
     return {'moe_latent_size': 128, 'moe_intermediate_size': 256}
 
 
+def _last_chunk_is_partly_filled(tokens, chunk):
+    """The second chunk of a skewed routing holds fewer pairs than rows:
+    the rows past them belong to no expert, and the grouped products
+    give them exact zeros."""
+    assert 0 < sum(tokens) - chunk < chunk
+    live, total = pk.live_row_tiles(tokens, chunk, hybrid_ops._ROW_QUANTUM)
+    assert live <= total == 2 * chunk // hybrid_ops._ROW_QUANTUM
+
+
 def _routes_taken(fn):
     from paddle_tpu.compiler.passes import moe_counts
     before = moe_counts(by=('route',))
@@ -284,6 +293,7 @@ def test_no_token_is_dropped_under_a_skewed_routing(route, monkeypatch):
     tokens = _routed_case(cfg, bias=bias)
     assert tokens[1] == tokens[2] == 2 * T
     assert chunk < sum(tokens) <= 2 * chunk
+    _last_chunk_is_partly_filled(tokens, chunk)
 
 
 def test_shared_expert_matches_reference():
@@ -749,6 +759,7 @@ def test_gated_experts_drop_no_token_when_two_take_them_all(
     tokens = _af_routed_case(cfg, bias=bias)
     assert tokens[1] == tokens[2] == 2 * T
     assert chunk < sum(tokens) <= 2 * chunk
+    _last_chunk_is_partly_filled(tokens, chunk)
 
 
 def _move_rows(p, x, transpose=False):

@@ -1169,9 +1169,10 @@ def test_grouped_matmul_visits_cover_every_row_once():
     inside its expert's group."""
     tm, tiles = 128, 4
     for split, sizes in sorted(_GROUPED_SPLITS.items()):
-        tile, expert, lo, hi = (np.asarray(t) for t in pk.grouped_visits(
-            jnp.asarray(sizes, jnp.int32), tiles, tm))
+        tile, expert, lo, hi, live = (np.asarray(t) for t in (
+            pk.grouped_visits(jnp.asarray(sizes, jnp.int32), tiles, tm)))
         assert len(tile) == tiles + _GROUPED_HELD - 1, split
+        assert live == [tiles * tm]
         assert lo[0] == 0 and hi[-1] == tiles * tm
         assert (lo[1:] == hi[:-1]).all() and (lo <= hi).all()
         assert (np.diff(expert) >= 0).all() and (np.diff(tile) >= 0).all()
@@ -1213,6 +1214,130 @@ def test_grouped_matmul_keeps_experts_apart_and_reads_nothing_unwritten():
                                       clean['weight-gradient'][e])
     assert not got['weight-gradient'][1].any()
     assert not clean['weight-gradient'][1].any()
+
+
+# rows an expert owns where the groups end before the 512 rows do: the
+# rows past them belong to no expert
+_GROUPED_SHORT_SPLITS = {
+    'no-row-routed': [0, 0, 0, 0],
+    'pairs-end-inside-a-tile': [100, 0, 60, 40],
+    'pairs-end-on-a-tile-edge': [128, 100, 0, 28],
+    'pairs-fill-the-chunk': [200, 0, 56, 256],
+}
+_GROUPED_SHORT_RUNS = {}
+
+
+def _grouped_short_case(split, poison, route):
+    """grouped_matmul's three results on 512 rows whose groups are
+    ``split``, the rows and the cotangent past the groups all ``poison``
+    (NaN / 1e30), through the interpreter that fills what no kernel
+    wrote with NaN or through ``lax.ragged_dot``; and the dense loop's
+    over the rows that lie in a group, zeros past them."""
+    if (split, poison, route) not in _GROUPED_SHORT_RUNS:
+        from jax.experimental.pallas import tpu as pltpu
+        rng = np.random.RandomState(1)
+        K, N = _GROUPED_ORDERS['wide']
+        sizes = np.asarray(_GROUPED_SHORT_SPLITS[split], np.int32)
+        live, M = int(sizes.sum()), 512
+        rows = rng.randn(M, K).astype('float32')
+        g = rng.randn(M, N).astype('float32')
+        w = rng.randn(_GROUPED_HELD, K, N).astype('float32')
+        want = _grouped_dense(rows[:live], w, g[:live], sizes)
+        for name, width in (('product', N), ('data-gradient', K)):
+            want[name] = np.concatenate(
+                [want[name].reshape(live, width),
+                 np.zeros((M - live, width), 'float32')])
+        rows[live:], g[live:] = poison, poison
+        interpret = None if route == 'ragged_dot' else \
+            pltpu.InterpretParams(uninitialized_memory='nan')
+        out, vjp = jax.vjp(
+            lambda rows, w: pk.grouped_matmul(
+                rows, w, jnp.asarray(sizes), interpret=interpret),
+            jnp.asarray(rows), jnp.asarray(w))
+        d_rows, d_w = vjp(jnp.asarray(g))
+        got = {'product': np.asarray(out), 'data-gradient': np.asarray(d_rows),
+               'weight-gradient': np.asarray(d_w)}
+        _GROUPED_SHORT_RUNS[split, poison, route] = got, want, live
+    return _GROUPED_SHORT_RUNS[split, poison, route]
+
+
+@pytest.mark.parametrize('use', ['product', 'data-gradient',
+                                 'weight-gradient'])
+@pytest.mark.parametrize('route', ['interpreter', 'ragged_dot'])
+@pytest.mark.parametrize('poison', [float('nan'), 1e30], ids=['nan', '1e30'])
+@pytest.mark.parametrize('split', sorted(_GROUPED_SHORT_SPLITS))
+def test_grouped_matmul_rows_past_the_groups_are_exact_zeros(
+        split, poison, route, use):
+    """sum(sizes) <= M: a row past the groups belongs to no expert. Its
+    product and its data gradient are exactly zero and it adds to no
+    weight gradient, whatever it and its cotangent hold and whatever
+    the kernels, which pass over its tile, left in memory there; the
+    rows in a group read what the dense loop reads."""
+    got, want, live = _grouped_short_case(split, poison, route)
+    assert not np.isnan(got[use]).any()
+    np.testing.assert_allclose(got[use], want[use], rtol=2e-5, atol=2e-4)
+    if use != 'weight-gradient':
+        assert not got[use][live:].any()             # exactly zero
+    else:
+        for e, n in enumerate(_GROUPED_SHORT_SPLITS[split]):
+            assert n or not got[use][e].any()
+
+
+@pytest.mark.parametrize('split', sorted(_GROUPED_SHORT_SPLITS)
+                         + sorted(_GROUPED_SPLITS))
+def test_grouped_matmul_skips_every_visit_without_a_live_row(split):
+    """The kernels run a visit's product on its rows below ``live``
+    only, where it has any. Every visit whose rows lie past the
+    groups, and every visit at which a tile start and a group start
+    coincide, has none; the visits that run hold every live row once,
+    in as many row tiles as live_row_tiles counts; the others' rows are
+    the rows past the groups, which a product zeroes; and a visit past
+    the groups reads the tile of the last live row, so no block moves
+    for it."""
+    tm, tiles = 128, 4
+    sizes = {**_GROUPED_SPLITS, **_GROUPED_SHORT_SPLITS}[split]
+    tile, expert, lo, hi, live = (np.asarray(t) for t in pk.grouped_visits(
+        jnp.asarray(sizes, jnp.int32), tiles, tm))
+    assert live.shape == (1,) and live[0] == sum(sizes)
+    live = int(live[0])
+    assert len(tile) == tiles + _GROUPED_HELD - 1
+    assert sorted(set(expert)) == list(range(_GROUPED_HELD))
+    assert (np.diff(expert) >= 0).all() and (np.diff(tile) >= 0).all()
+    assert lo[0] == 0 and hi[-1] == tiles * tm and (lo[1:] == hi[:-1]).all()
+    mid = np.clip(live, lo, hi)
+    runs = lo < mid                              # the kernels' condition
+    assert (~runs == ((lo >= live) | (lo == hi))).all()
+
+    def rows(starts, ends):
+        return np.concatenate([np.arange(a, b) for a, b in
+                               zip(starts, ends)] or [np.arange(0)])
+    np.testing.assert_array_equal(rows(lo[runs], mid[runs]), np.arange(live))
+    np.testing.assert_array_equal(rows(mid, hi), np.arange(live, tiles * tm))
+    ends = np.cumsum(sizes)
+    for t, e, a, b in zip(tile[runs], expert[runs], lo[runs], mid[runs]):
+        assert t * tm <= a and b <= (t + 1) * tm
+        assert ends[e] - sizes[e] <= a and b <= ends[e]
+    src = np.asarray(pk._src_tile(tile, live, tm))
+    assert (src[runs] == tile[runs]).all()
+    assert (src[lo >= live] == max(live - 1, 0) // tm).all()
+    assert (len(set(src[runs])), tiles) == pk.live_row_tiles(
+        sizes, tiles * tm, tm)
+
+
+@pytest.mark.parametrize('counts,rows,tm,want', [
+    ([512] * 8, 8448, 128, (32, 66)),            # the trinity cell, balanced
+    ([424, 627, 500, 512, 530, 498, 505, 505], 8448, 128, (33, 66)),
+    ([176] * 8, 3072, 128, (11, 24)),            # the nemotron cell, balanced
+    ([0] * 8, 3072, 128, (0, 24)),               # the first chunk always runs
+    ([384] * 8, 3072, 128, (24, 24)),            # pairs fill the chunk
+    ([1] + [0] * 7, 3072, 128, (1, 24)),
+    ([1000, 1000, 1000, 73], 3072, 128, (25, 48)),   # one row overflows
+    ([4096, 4096, 904, 904], 8448, None, (79, 132)),
+], ids=['trinity', 'trinity-uneven', 'nemotron', 'empty', 'full', 'one-row',
+        'one-row-over', 'second-chunk-partly-filled'])
+def test_live_row_tiles_against_a_hand_count(counts, rows, tm, want):
+    assert pk.live_row_tiles(counts, rows, tm) == want
+    assert pk.live_row_tiles(np.asarray(counts, np.int32), rows, tm) == want
 
 
 @pytest.mark.parametrize('dtype,K,N,rows,backend,interpret,plan', [
