@@ -120,24 +120,20 @@ def _models():
     return MODELS
 
 
-def _cache_events():
-    """Count JAX's persistent-compile-cache events from here on."""
-    import jax
-    seen = {'hits': 0, 'writes': 0, 'saved_s': 0.0}
-
-    def on_event(name, **_):
-        if name == '/jax/compilation_cache/cache_hits':
-            seen['hits'] += 1
-        elif name == '/jax/compilation_cache/cache_misses':
-            seen['writes'] += 1     # recorded when an entry is written
-
-    def on_duration(name, secs, **_):
-        if name == '/jax/compilation_cache/compile_time_saved_sec':
-            seen['saved_s'] += secs
-
-    jax.monitoring.register_event_listener(on_event)
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    return seen
+def _cache_counts():
+    """What JAX's persistent compile cache did in this process so far,
+    from the program's own listener (``observability.tracing``: the one
+    ``jax.monitoring`` registration of the tree): hits and entries
+    written from the registry, compile seconds saved from the log."""
+    from paddle_tpu import observability as obs
+    reg = obs.default_registry()
+    return {
+        'hits': reg.counter('jax_persistent_cache_total',
+                            result='hit').value,
+        'writes': reg.counter('jax_persistent_cache_total',
+                              result='miss').value,
+        'saved_s': sum(e.get('saved_s', 0.0)
+                       for e in obs.perf.compile_log())}
 
 
 def _build(fluid, name, **kwargs):
@@ -232,14 +228,14 @@ def leg_resnet_executor(cfg, rehearse):
 
 
 # ---- leg 2: the same through the benchmark harness -----------------------
-def leg_fluid_benchmark(cfg, rehearse, events):
+def leg_fluid_benchmark(cfg, rehearse):
     import paddle_tpu.fluid as fluid
     say('[resnet50/fluid_benchmark.py] --model resnet --batch_size %d '
         '--device %s' % (cfg['resnet_batch'],
                          'CPU' if rehearse else 'TPU'))
     _models()
     import fluid_benchmark
-    hits0 = events['hits']
+    hits0 = _cache_counts()['hits']
     with fluid.scope_guard(fluid.Scope()), fluid.unique_name.guard():
         rec = fluid_benchmark.main([
             '--model', 'resnet',
@@ -250,7 +246,7 @@ def leg_fluid_benchmark(cfg, rehearse, events):
     check(math.isfinite(rec['last_loss']), 'harness loss finite (%.4f)'
           % rec['last_loss'])
     say('  same program, second compile in this process: %d persistent-'
-        'cache hit(s)' % (events['hits'] - hits0))
+        'cache hit(s)' % (_cache_counts()['hits'] - hits0))
     return rec
 
 
@@ -775,13 +771,11 @@ def leg_four_chips(cfg, one_chip_losses):
 def phase_main(rehearse):
     device = require_backend(rehearse)
     cfg = sizes(rehearse)
-    events = _cache_events()
     from paddle_tpu.core.compile_cache import compile_cache_dir
     say('compile cache: %s' % compile_cache_dir())
     result = {'device': device}
     result['resnet'] = leg_resnet_executor(cfg, rehearse)
-    result['fluid_benchmark'] = leg_fluid_benchmark(cfg, rehearse,
-                                                    events)
+    result['fluid_benchmark'] = leg_fluid_benchmark(cfg, rehearse)
     result['flash'] = leg_flash(cfg)
     result['lstm_cell'] = leg_lstm_cell(cfg)
     result['fused_conv'] = leg_fused_conv(cfg)
@@ -809,7 +803,7 @@ def phase_main(rehearse):
     else:
         result['four_chips'] = leg_four_chips(
             cfg, result['resnet']['losses'])
-    result['cache_events'] = dict(events)
+    events = result['cache_events'] = _cache_counts()
     say('persistent compile cache in this process: %d hit(s), %d '
         'entr(ies) written' % (events['hits'], events['writes']))
     return result
@@ -820,7 +814,6 @@ def phase_cache():
     persistent compile cache the main phase filled."""
     device = require_backend(False)
     cfg = sizes(False)
-    events = _cache_events()
     import numpy as np
     import paddle_tpu.fluid as fluid
     from paddle_tpu.core.compile_cache import compile_cache_dir
@@ -834,6 +827,7 @@ def phase_cache():
         t0 = time.perf_counter()
         out, = exe.run(main, feed=feed, fetch_list=[loss])
         first = time.perf_counter() - t0
+    events = _cache_counts()
     say('  first step %.1f s, loss %.4f; cache hits %d, entries '
         'written %d, compile seconds saved %.1f'
         % (first, float(np.ravel(out)[0]), events['hits'],
@@ -842,7 +836,7 @@ def phase_cache():
           'the step came from the persistent cache (an entry whose '
           'first compile took seconds was read, not rebuilt)')
     return {'device': device, 'first_step_s': first,
-            'loss': float(np.ravel(out)[0]), 'events': dict(events)}
+            'loss': float(np.ravel(out)[0]), 'events': events}
 
 
 def run_phase(phase, rehearse=False):

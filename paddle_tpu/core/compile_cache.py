@@ -29,10 +29,15 @@ def compile_cache_dir():
 
 def configure_compile_cache():
     """Point JAX's persistent compilation cache at
-    :func:`compile_cache_dir`. Called once, when the package is
-    imported — before anything can compile. Returns the directory."""
+    :func:`compile_cache_dir`, and start listening to what jax says of
+    its traces, lowerings, compiles and cache loads
+    (``observability.tracing.listen_to_jax``). Called once, when the
+    package is imported — before anything can compile. Returns the
+    directory."""
+    from ..observability.tracing import listen_to_jax
     path = compile_cache_dir()
     if not os.environ.get(CACHE_ENV):
         import jax
         jax.config.update('jax_compilation_cache_dir', path)
+    listen_to_jax()
     return path

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from . import framework
 from . import observability as _obs
-from .observability import perf as _perf
+from .observability import perf as _perf, tracing as _tracing
 from .framework import Program, Variable, default_main_program
 from .core import places as _places
 from .core import lowering
@@ -37,10 +37,7 @@ __all__ = ['Executor', 'CacheInfo', 'global_scope', 'scope_guard',
 
 CacheInfo = collections.namedtuple('CacheInfo', ['hits', 'misses', 'size'])
 
-# What exe/prep hands to exe/launch and exe/commit: the compiled
-# callable with its arguments, and what the lookup found. ``compiled``
-# is True where this call will trace and compile (a miss that no AOT
-# entry served); ``checked`` where the callable is checkify-wrapped.
+
 def _dynamic_memoized(program):
     """:func:`_is_dynamic_program`, once per program fingerprint."""
     memo = program.__dict__.setdefault('_dynamic_memo', {})
@@ -57,9 +54,15 @@ class _Lowerable(object):
         self.abstract, self.sharded, self.runs = abstract, sharded, 1
 
 
+# What exe/prep hands to exe/launch and exe/commit: the compiled
+# callable with its arguments, and what the lookup found. ``lowered``
+# is ``(verify_s, lower_s)`` where this call will trace and compile (a
+# miss that no AOT entry served), else None; ``checked`` where the
+# callable is checkify-wrapped; ``seen`` the compile log's count as
+# exe/prep ends.
 _Step = collections.namedtuple('_Step', [
     'jitted', 'feed', 'state', 'fp', 'fetch_names', 'sharded', 'cache',
-    'compiled', 'checked', 'ledger'])
+    'lowered', 'checked', 'ledger', 'seen'])
 
 def _coldstart_store():
     """The active AOT cold-start store (SERVING.md "Self-driving
@@ -418,11 +421,10 @@ class Executor(object):
         self._m_misses = reg.counter(
             'executor_cache_misses_total',
             'compiled-program cache misses (each one is a trace+compile)')
-        self._m_hit_rate = reg.gauge(
-            'executor_cache_hit_rate',
-            'process-wide cache hits / lookups')
         self._m_run = reg.histogram(
-            'executor_run_seconds', 'Executor.run device-execution wall')
+            'executor_run_seconds',
+            'host wall of the jitted call (exe/launch): under '
+            'asynchronous dispatch the enqueue, not the device\'s time')
         self._m_compile = reg.histogram(
             'executor_compile_seconds',
             'lowering + first (compiling) execution wall per cache miss')
@@ -800,20 +802,25 @@ class Executor(object):
         the registry series, the compile and run events, and the new
         state in the scope."""
         self._m_run.observe(launch.dur_s)
-        h, m = self._m_hits.value, self._m_misses.value
-        self._m_hit_rate.set(h / (h + m) if h + m else 0.0)
-        if step.compiled:
+        if step.lowered is not None:
             # jax.jit compiles lazily at the first call, so the real
             # XLA compile wall is the whole miss: exe/prep's start to
             # the end of this first exe/launch (an AOT warm start never
             # compiled: its wall lives in coldstart_load_seconds / the
-            # 'coldstart' journal event)
-            compile_wall = launch.t0 + launch.dur_s - prep.t0
-            self._m_compile.observe(compile_wall)
-            _obs.emit('compile_end', fp=step.fp,
-                      dur_s=round(compile_wall, 6), **chain)
+            # 'coldstart' journal event). What jax said inside it
+            # splits it part by part (OBSERVABILITY.md, "The compile
+            # path").
+            miss = _tracing.log_miss(top, prep, launch, *step.lowered)
+            self._m_compile.observe(miss['wall_s'])
+            _obs.emit('compile_end', dur_s=round(miss['wall_s'], 6),
+                      **{k: round(v, 6) if isinstance(v, float) else v
+                         for k, v in miss.items()}, **chain)
             if step.ledger is not None:
-                _perf.seal(step.ledger, compile_wall, trace=top.context)
+                _perf.seal(step.ledger, miss['wall_s'], trace=top.context)
+        elif _tracing.COMPILE_LOG.count != step.seen \
+                and _tracing.retraced(launch):
+            # jax traced or compiled under a key this Executor holds
+            top.note(retraced=True)
         if _obs.journal_active():
             _obs.emit('exe_run', cache=step.cache, fp=step.fp,
                       dur_s=round(launch.dur_s, 6), **chain)
@@ -948,24 +955,28 @@ class Executor(object):
                         # entry was verified when first built.
                         jitted = self._cache[key] = loaded
                         aot_hit = True
+            lowered = None
             if entry is None and not aot_hit:
+                verify_s = 0.0
                 if not dynamic:
                     # static verify BEFORE any lowering: a mis-wired
                     # program raises typed ProgramInvalid naming the
                     # offending op instead of an XLA trace error
-                    with _obs.phase('exe/verify', top):
+                    with _obs.phase('exe/verify', top) as verify:
                         _analysis.verify_for_executor(
                             program,
                             feed_names=set(feed) | set(static_env),
                             fetch_names=fetch_names)
+                    verify_s = verify.dur_s
                 _obs.emit('compile_begin', fp=key[0])
-                with _obs.phase('exe/compile', top, fp=key[0]):
+                with _obs.phase('exe/compile', top, fp=key[0]) as lower:
                     jitted = self._lower_step(
                         program, feed, fetch_names, state_in_names,
                         state_out_names, static_env, scope,
                         dynamic=dynamic, profiling=profiling, guard=guard,
                         sharded=sharded, donate=aot_store is None,
                         feeds_s=feeds_s, state_s=state_s)
+                lowered = (verify_s, lower.dur_s)
                 self._cache[key] = jitted
             elif entry is not None:
                 self._cache_hits += 1
@@ -1042,9 +1053,9 @@ class Executor(object):
                     self._cache[key] = compiled
 
         return _Step(jitted, feed, state, key[0], fetch_names, sharded,
-                     'miss' if was_miss else 'hit',
-                     was_miss and not aot_hit,
-                     guard and not (profiling or dynamic), _ledger)
+                     'miss' if was_miss else 'hit', lowered,
+                     guard and not (profiling or dynamic), _ledger,
+                     _tracing.COMPILE_LOG.count)
 
     def _lower_step(self, program, feed, fetch_names, state_in_names,
                     state_out_names, static_env, scope, dynamic,
@@ -1255,15 +1266,16 @@ class Executor(object):
                 # feed shardings walk only the feed dict)
                 state_s = part.state_shardings(program, state_in_names)
                 stacked_s = part.stacked_feed_shardings(prepped[0])
+            lowered = None
             if entry is None:
                 self._cache_misses += 1
-                with _obs.phase('exe/verify', top):
+                with _obs.phase('exe/verify', top) as verify:
                     _analysis.verify_for_executor(
                         program,
                         feed_names=set(prepped[0]) | set(static_envs[0]),
                         fetch_names=fetch_names)
                 _obs.emit('compile_begin', fp=key[0], chain=k)
-                with _obs.phase('exe/compile', top, fp=key[0]):
+                with _obs.phase('exe/compile', top, fp=key[0]) as lower:
                     lower_prog = self._optimized_program(
                         program, fetch_names, scope=scope)
                     fn = lowering.lower_block_chained(
@@ -1288,6 +1300,7 @@ class Executor(object):
                             donate_argnums=(1,))
                     else:
                         jitted = part.partition(fn, donate_argnums=(1,))
+                lowered = (verify.dur_s, lower.dur_s)
                 self._cache[key] = jitted
             else:
                 self._cache_hits += 1
@@ -1363,8 +1376,8 @@ class Executor(object):
                     # declared in_shardings
                     stacked = part.reconcile(stacked, stacked_s)
         return _Step(jitted, stacked, state, key[0], fetch_names,
-                     part.active, 'miss' if was_miss else 'hit', was_miss,
-                     False, _ledger)
+                     part.active, 'miss' if was_miss else 'hit', lowered,
+                     False, _ledger, _tracing.COMPILE_LOG.count)
 
     def lowered(self, program, feed, fetch_list, scope=None):
         """The ``jax.stages.Lowered`` of the single-device step

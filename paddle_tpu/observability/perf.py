@@ -47,6 +47,7 @@ import weakref
 # contextmanager, so import the emit hook directly (not the submodule)
 from .journal import emit as _emit
 from . import metrics as _metrics
+from . import tracing as _tracing
 
 __all__ = [
     'PERF_ENV', 'UnknownDeviceKindError',
@@ -57,7 +58,7 @@ __all__ = [
     'peak_flops_for', 'hbm_gbps_for', 'mesh_signature',
     'shape_signature', 'memory_dict',
     'abstract_args', 'register_executor', 'scope_map', 'parse_scopes',
-    'split_scope', 'PHASES',
+    'split_scope', 'PHASES', 'compile_log',
 ]
 
 PERF_ENV = 'PTPU_PERF'              # '1' -> capture on for the process
@@ -665,6 +666,17 @@ def scope_map(min_runs=1, executors=None):
 
 
 # ---- shared offline helpers ----------------------------------------------
+def compile_log():
+    """What jax said of the compile path so far, oldest first (copies):
+    one entry ``{t, kind, dur_s, fun, phase, fp, thread}`` a ``trace``,
+    ``mlir`` or ``backend`` event (``cache`` and, on a hit,
+    ``retrieval_s`` / ``saved_s`` on a ``backend`` one), and the
+    Executor's own ``miss`` entry a cache miss (OBSERVABILITY.md, "The
+    compile path"). ``t`` is ``time.perf_counter()`` at the event's
+    end."""
+    return _tracing.COMPILE_LOG.entries()
+
+
 def memory_dict(comp):
     """Per-device byte accounting of an AOT-compiled executable —
     the shared ``memory_analysis()`` reader (ParallelExecutor

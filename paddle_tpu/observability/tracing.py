@@ -26,12 +26,15 @@ Overhead contract: with no journal installed every span API here
 returns the shared :data:`NULL_SPAN` after one module-global ``None``
 check — no allocation, no ids, no clock read. :class:`phase` (the
 Executor's phases) besides enters a profiler ``TraceAnnotation``, which
-is inert while no profiler session runs. With a journal installed, sampling
+is inert while no profiler session runs, and stores itself as the
+open phase, which is what a jax compile event is filed under (the
+compile log, at the end of this file). With a journal installed, sampling
 is decided once per root from ``PTPU_TRACE_SAMPLE`` (default 1.0) by
 hashing the trace id, so a rate of 0.25 keeps whole trees, never
 orphan fragments; unsampled trees still propagate one shared inert
 context so child processes agree with the root's decision.
 """
+import collections
 import os
 import random
 import threading
@@ -45,12 +48,21 @@ from .metrics import default_registry
 __all__ = ['TraceContext', 'Span', 'NULL_SPAN', 'start_span', 'span',
            'current_span', 'current_context', 'link', 'emit_span',
            'phase', 'sample_rate', 'parent_from_env', 'TRACE_PARENT_ENV',
-           'TRACE_SAMPLE_ENV']
+           'TRACE_SAMPLE_ENV', 'CompileLog', 'COMPILE_LOG', 'listen_to_jax',
+           'log_miss', 'retraced']
 
 TRACE_SAMPLE_ENV = 'PTPU_TRACE_SAMPLE'
 TRACE_PARENT_ENV = 'PTPU_TRACE_PARENT'
 
-_local = threading.local()
+
+class _Local(threading.local):
+    span = None         # the thread's active Span
+    phase = None        # the open root ``phase``
+    jax_open = 0        # jax compile events open on the thread
+    jax_cache = None    # what the open backend compile's cache said
+
+
+_local = _Local()
 
 
 # Id generation is on the per-span hot path (uuid4 costs ~5us; this is
@@ -207,28 +219,6 @@ def _sampled(trace_id):
     return int(trace_id[:8], 16) / float(0xffffffff) < r
 
 
-_SPANS = None
-_LINKS = None
-
-
-def _spans_counter():
-    # registry.reset() zeroes but never replaces metric objects, so a
-    # one-time intern is safe to cache on the span hot path
-    global _SPANS
-    if _SPANS is None:
-        _SPANS = default_registry().counter(
-            'tracing_spans_started_total', 'sampled spans begun')
-    return _SPANS
-
-
-def _links_counter():
-    global _LINKS
-    if _LINKS is None:
-        _LINKS = default_registry().counter(
-            'tracing_links_total', 'batch->request span links')
-    return _LINKS
-
-
 def start_span(name, parent=None, activate=True, **fields):
     """Begin a span and journal ``span_begin``.
 
@@ -254,7 +244,6 @@ def start_span(name, parent=None, activate=True, **fields):
         ctx = parent.child()
     sp = Span(name, ctx)
     if ctx.sampled:
-        _spans_counter().inc()
         # the flight recorder's live-span table is what lets a
         # postmortem bundle name the work still open at death
         _flight.note_span_begin(name, ctx)
@@ -292,32 +281,34 @@ def link(from_span, linked_ctx):
     ctx = from_span.context if isinstance(from_span, Span) else from_span
     if ctx is None or not ctx.sampled or not linked_ctx.sampled:
         return
-    _links_counter().inc()
     _emit('span_link', trace=ctx.trace_id, span=ctx.span_id,
           linked_trace=linked_ctx.trace_id,
           linked_span=linked_ctx.span_id)
 
 
-def emit_span(name, dur_s, parent=None, **fields):
+def emit_span(name, dur_s, parent=None, context=None, **fields):
     """Journal one already-measured span (``span_end`` only, no begin)
     — for retrofitting existing timings (queue waits, step durations)
-    without a second clock read. Returns the child context written, or
-    None when untraced."""
+    without a second clock read. ``context`` is the span's own identity
+    where it had to be handed out before the span's end (a child
+    ``phase`` whose jax compile spans are its children). Returns the
+    context written, or None when untraced."""
     if not _journal_active():
         return None
-    if isinstance(parent, Span):
-        parent = parent.context
-    if parent is None:
-        parent = current_context()
-    if parent is None:
-        tid = _new_id()
-        ctx = TraceContext(tid, _new_id(), None, True) \
-            if _sampled(tid) else _UNSAMPLED
-    else:
-        ctx = parent.child()
+    ctx = context
+    if ctx is None:
+        if isinstance(parent, Span):
+            parent = parent.context
+        if parent is None:
+            parent = current_context()
+        if parent is None:
+            tid = _new_id()
+            ctx = TraceContext(tid, _new_id(), None, True) \
+                if _sampled(tid) else _UNSAMPLED
+        else:
+            ctx = parent.child()
     if not ctx.sampled:
         return None
-    _spans_counter().inc()
     _emit('span_end', name=name, trace=ctx.trace_id,
           span=ctx.span_id, parent=ctx.parent_id,
           dur_s=round(dur_s, 6), **fields)
@@ -360,7 +351,7 @@ class phase(object):
     ``note()`` adds fields to the ``span_end`` record."""
 
     __slots__ = ('name', 'span', 't0', 'dur_s', '_ann', '_parent',
-                 '_fields', '_end')
+                 '_fields', '_end', '_prev', '_open', '_ctx')
 
     def __init__(self, name, parent=None, step_num=None, **fields):
         plain, step = _annotations()
@@ -370,27 +361,49 @@ class phase(object):
         self._ann = plain(name) if step_num is None \
             else step(name, step_num=step_num)
         self._parent, self._fields, self._end = parent, fields, None
+        self._open = self._ctx = None
 
     @property
     def context(self):
-        """The journal span's :class:`TraceContext`, None untraced."""
-        return self.span.context if self.span is not None else None
+        """The :class:`TraceContext` this phase's journal span is
+        written under, None untraced. A child's is made at the first
+        ask: its span is one ``span_end`` at exit, and a jax compile
+        inside it wants a parent before that."""
+        if self.span is not None:
+            return self.span.context
+        if self._ctx is None and self._parent is not None \
+                and self._parent.span is not None:
+            self._ctx = self._parent.span.context.child()
+        return self._ctx
 
     def note(self, **fields):
         self._end = dict(self._end or (), **fields)
 
     def __enter__(self):
         self._ann.__enter__()
-        if self._parent is None:
+        # the open phase is what a jax compile event on this thread is
+        # filed under (the compile log below): the thread's root, and
+        # on the root its innermost open child
+        parent = self._parent
+        if parent is None:
             pctx = current_context()
             if pctx is not None:
                 self.span = start_span(self.name, parent=pctx,
                                        activate=False, **self._fields)
+            self._prev = _local.phase
+            _local.phase = self
+        else:
+            self._prev = parent._open
+            parent._open = self
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_s = time.perf_counter() - self.t0
+        if self._parent is None:
+            _local.phase = self._prev
+        else:
+            self._parent._open = self._prev
         self._ann.__exit__(exc_type, exc, tb)
         end = self._end or {}
         if exc_type is not None:
@@ -400,8 +413,218 @@ class phase(object):
         elif self._parent is not None and self._parent.span is not None:
             end.update(self._fields)
             emit_span(self.name, self.dur_s, parent=self._parent.span,
-                      **end)
+                      context=self._ctx, **end)
         return False
+
+
+# ---- the compile path, told by jax itself -----------------------------------
+# jax records an event at every trace of a jitted function, every
+# jaxpr -> MLIR lowering and every backend compile (a scalar event at
+# the start, a duration event at the end, each with ``fun_name``), and
+# what its persistent cache did inside a backend compile. They fire at
+# a trace or a compile and never on the cached dispatch path, so
+# listening costs a steady step nothing.
+_JAX_KINDS = {
+    '/jax/core/compile/jaxpr_trace_duration': 'trace',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'mlir',
+    '/jax/core/compile/backend_compile_duration': 'backend',
+}
+_JAX_SPANS = {'trace': 'jax/trace', 'mlir': 'jax/mlir',
+              'backend': 'jax/xla_compile'}
+_CACHE_HIT = '/jax/compilation_cache/cache_hits'
+# jax records it where it writes an entry: a compile the cache did not
+# hold and found worth keeping
+_CACHE_MISS = '/jax/compilation_cache/cache_misses'
+_CACHE_SECONDS = {
+    '/jax/compilation_cache/cache_retrieval_time_sec': 'retrieval_s',
+    '/jax/compilation_cache/compile_time_saved_sec': 'saved_s',
+}
+
+
+class CompileLog(object):
+    """The process's jax compile events, oldest first, and the
+    Executor's own account of each miss (``kind`` ``miss``): a bounded
+    list, a running count of everything ever logged and a count of what
+    the bound dropped. ``count`` is what a steady step reads, once
+    before its jitted call and once after, to learn that nothing
+    compiled in between."""
+
+    CAP = 4096
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._entries = collections.deque(maxlen=self.CAP)
+        self.count = 0
+        self.dropped = 0
+
+    def add(self, entry):
+        with self._lock:
+            if len(self._entries) == self.CAP:
+                self.dropped += 1
+            self._entries.append(entry)
+            self.count += 1
+
+    def entries(self, since=None, thread=None):
+        """Copies, oldest first; those that ended at or after ``since``
+        (``time.perf_counter``) on ``thread`` where given."""
+        with self._lock:
+            found = list(self._entries)
+        return [dict(e) for e in found
+                if (since is None or e['t'] >= since)
+                and (thread is None or e['thread'] == thread)]
+
+
+COMPILE_LOG = CompileLog()
+_JAX_SERIES = {}
+
+
+def _jax_series(kind, owner):
+    series = _JAX_SERIES.get((kind, owner))
+    if series is None:
+        reg = default_registry()
+        series = _JAX_SERIES[(kind, owner)] = (
+            reg.counter('jax_compile_events_total',
+                        'jax trace / jaxpr->MLIR / backend-compile events, '
+                        'nested ones folded into the outermost; owner is '
+                        'executor inside an Executor phase',
+                        kind=kind, owner=owner),
+            reg.counter('jax_compile_seconds_total',
+                        'seconds inside those events',
+                        kind=kind, owner=owner))
+    return series
+
+
+def _cache_series(result):
+    return default_registry().counter(
+        'jax_persistent_cache_total',
+        'backend compiles jax\'s persistent cache served (hit) or '
+        'compiled and kept (miss)', result=result)
+
+
+def _on_jax_start(event, value, **kw):
+    if event in _JAX_KINDS:
+        _local.jax_open += 1
+        if _local.jax_open == 1 and _JAX_KINDS[event] == 'backend':
+            _local.jax_cache = {'cache': 'off'}
+
+
+def _on_jax_event(event, **kw):
+    note = _local.jax_cache
+    if note is not None and event in (_CACHE_HIT, _CACHE_MISS):
+        note['cache'] = 'hit' if event == _CACHE_HIT else 'miss'
+
+
+def _on_jax_seconds(event, secs, **kw):
+    kind = _JAX_KINDS.get(event)
+    if kind is None:
+        field = _CACHE_SECONDS.get(event)
+        if field is not None and _local.jax_cache is not None:
+            _local.jax_cache[field] = secs
+        return
+    # a listener installed inside an open event sees its end alone
+    _local.jax_open = max(_local.jax_open - 1, 0)
+    if _local.jax_open:
+        # begun while another was open on this thread (the jnp
+        # functions inside the step's trace, a lower_fun inside a
+        # lowering): its time is the outermost's already
+        return
+    ph = _local.phase
+    if ph is not None:
+        ph = ph._open or ph
+    entry = {'t': time.perf_counter(), 'kind': kind, 'dur_s': secs,
+             'fun': kw.get('fun_name'), 'phase': None, 'fp': None,
+             'thread': threading.get_ident()}
+    span_name = _JAX_SPANS[kind]
+    if kind == 'backend' and _local.jax_cache is not None:
+        entry.update(_local.jax_cache)
+        _local.jax_cache = None
+        if entry['cache'] != 'off':
+            _cache_series(entry['cache']).inc()
+        if entry['cache'] == 'hit':
+            span_name = 'jax/cache_load'
+    if ph is not None:
+        root = ph._parent or ph
+        entry['phase'] = ph.name
+        entry['fp'] = (root._end or {}).get('fp')
+    events, seconds = _jax_series(
+        kind, 'executor' if ph is not None else 'other')
+    events.inc()
+    seconds.inc(max(secs, 0.0))
+    COMPILE_LOG.add(entry)
+    if ph is not None and _journal_active():
+        ctx = ph.context
+        if ctx is not None:
+            emit_span(span_name, secs, parent=ctx, fun=entry['fun'],
+                      fp=entry['fp'])
+
+
+_LISTENING = []
+
+
+def listen_to_jax():
+    """Register the one ``jax.monitoring`` listener of the tree, once a
+    process (``core.compile_cache.configure_compile_cache`` calls it at
+    package import). Every jax compile event from then on is filed
+    under the Executor phase open on its thread, in the three sinks
+    that exist: the registry (``jax_compile_events_total``,
+    ``jax_compile_seconds_total``, ``jax_persistent_cache_total``), the
+    journal (``jax/trace``, ``jax/mlir``, ``jax/xla_compile`` or
+    ``jax/cache_load`` under the phase's span) and :data:`COMPILE_LOG`
+    (``observability.perf.compile_log()``)."""
+    if _LISTENING:
+        return
+    _LISTENING.append(True)
+    from jax import monitoring
+    monitoring.register_scalar_listener(_on_jax_start)
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_seconds)
+
+
+def log_miss(top, prep, launch, verify_s, lower_s):
+    """The Executor's account of one cache miss, from the jax events
+    that fell inside the call on this thread (``prep``'s start to
+    ``launch``'s end): one ``miss`` entry in :data:`COMPILE_LOG`, whose
+    parts are disjoint. ``verify_s`` / ``lower_s`` (``exe/verify``,
+    ``exe/compile``) and ``first_run_s`` (``exe/launch``) are those
+    phases less the jax events inside them; ``trace_s`` / ``mlir_s`` /
+    ``backend_s`` sum the events, wherever in the call they fell.
+    Returns the entry's fields but ``kind``, ``t`` and ``thread``."""
+    mine = COMPILE_LOG.entries(since=prep.t0,
+                               thread=threading.get_ident())
+    mine = [e for e in mine if e['kind'] in _JAX_SPANS]
+    inside = {'exe/verify': verify_s, 'exe/compile': lower_s,
+              'exe/launch': launch.dur_s}
+    total = dict.fromkeys(_JAX_SPANS, 0.0)
+    for e in mine:
+        total[e['kind']] += e['dur_s']
+        if e['phase'] in inside:
+            inside[e['phase']] -= e['dur_s']
+    backends = [e for e in mine if e['kind'] == 'backend']
+    caches = {e['cache'] for e in backends}
+    miss = {
+        'fp': (top._end or {}).get('fp'), 'phase': top.name,
+        'wall_s': launch.t0 + launch.dur_s - prep.t0,
+        'verify_s': max(inside['exe/verify'], 0.0),
+        'lower_s': max(inside['exe/compile'], 0.0),
+        'trace_s': total['trace'], 'mlir_s': total['mlir'],
+        'backend_s': total['backend'],
+        # jax and the phases read two clocks: a hair below zero is zero
+        'first_run_s': max(inside['exe/launch'], 0.0),
+        'cache': next((c for c in ('miss', 'hit') if c in caches), 'off'),
+        'retrieval_s': sum(e.get('retrieval_s', 0.0) for e in backends),
+        'modules': len(backends)}
+    COMPILE_LOG.add(dict(miss, kind='miss', t=launch.t0 + launch.dur_s,
+                         thread=threading.get_ident()))
+    return miss
+
+
+def retraced(launch):
+    """True where a jax trace or compile ended inside ``launch`` on
+    this thread: asked only when :data:`COMPILE_LOG`'s count moved over
+    a jitted call whose run was no miss — a retrace by jax under a key
+    the Executor holds."""
+    return any(e['phase'] == launch.name for e in COMPILE_LOG.entries(
+        since=launch.t0, thread=threading.get_ident()))
 
 
 def parent_from_env(environ=None):

@@ -11,9 +11,14 @@
 - ``perf.scope_map()`` maps the compiled step's instructions to the
   scopes the lowering left: ``forward`` / ``transpose(jvp(forward))``
   / ``optimizer``, then ``<op.type>:<output>``.
+- ``perf.compile_log()`` holds what jax said of every trace, lowering
+  and compile, filed under the phase it fell in, and the Executor's
+  account of each miss (OBSERVABILITY.md "The compile path").
 """
+import contextlib
 import glob
 import os
+import time
 
 import numpy as np
 import pytest
@@ -148,10 +153,12 @@ def test_journal_carries_the_same_names_under_a_parent_span(tmp_path):
     ends = [r for r in recs if r['ev'] == 'span_end']
     names = [r['name'] for r in ends]
     # children end before their root; the miss of the first (bare) run
-    # left no span, the hit of the second has neither verify nor compile
+    # left no span, the hit of the second has neither verify nor compile;
+    # the chunk's miss holds what jax said of its first launch
     assert names == ['exe/prep', 'exe/launch', 'exe/fetch', 'exe/commit',
                      'exe/run',
-                     'exe/verify', 'exe/compile', 'exe/prep', 'exe/launch',
+                     'exe/verify', 'exe/compile', 'exe/prep', 'jax/trace',
+                     'jax/mlir', 'jax/xla_compile', 'exe/launch',
                      'exe/fetch', 'exe/commit', 'exe/chain', 'test/step']
     by = {r['name']: r for r in ends[:5]}
     root = by['exe/run']
@@ -290,21 +297,32 @@ ENTRY %main.3 (x: f32[4]) -> (f32[4], f32[4]) {
     assert 'copy-done.2' not in scopes and 'x' not in scopes
 
 
+@contextlib.contextmanager
+def _persistent_cache(path):
+    """jax's persistent cache on, at ``path``, keeping every entry."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = ('jax_enable_compilation_cache', 'jax_compilation_cache_dir',
+             'jax_persistent_cache_min_compile_time_secs',
+             'jax_persistent_cache_min_entry_size_bytes')
+    keep = {n: getattr(jax.config, n) for n in names}
+    for n, v in zip(names, (True, str(path), 0, 0)):
+        jax.config.update(n, v)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        for n, v in keep.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
+
+
 def test_compiled_text_reads_past_a_cache_entry_with_older_scopes(tmp_path):
     """JAX leaves metadata out of the persistent cache's key, so the
     cache hands a program the executable an older version compiled,
     with that version's scopes. ``scope_map`` then compiles afresh, and
     writes nothing back."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
     import jax.numpy as jnp
-    names = ('jax_enable_compilation_cache', 'jax_compilation_cache_dir',
-             'jax_persistent_cache_min_compile_time_secs',
-             'jax_persistent_cache_min_entry_size_bytes')
-    keep = {n: getattr(jax.config, n) for n in names}
-    for n, v in zip(names, (True, str(tmp_path), 0, 0)):
-        jax.config.update(n, v)
-    cc.reset_cache()
-    try:
+    with _persistent_cache(tmp_path):
         def step(scope):
             def f(x):
                 with jax.named_scope(scope):
@@ -322,7 +340,148 @@ def test_compiled_text_reads_past_a_cache_entry_with_older_scopes(tmp_path):
         assert '/forward/' in text and 'zz_before' not in text
         assert sorted(os.listdir(str(tmp_path))) == entries
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
-    finally:
-        for n, v in keep.items():
-            jax.config.update(n, v)
-        cc.reset_cache()
+
+
+# ---- the compile path, told by jax -----------------------------------------
+JAX_KINDS = ('trace', 'mlir', 'backend')
+
+
+def _since(t0, kind=None):
+    return [e for e in perf.compile_log() if e['t'] >= t0
+            and (kind is None or e['kind'] == kind)]
+
+
+def test_a_miss_accounts_for_itself_part_by_part():
+    t0 = time.perf_counter()
+    exe, scope, main, loss, feeds = _tiny()
+    exe.run(main, feed=feeds[0], fetch_list=[loss], scope=scope)
+    startup, step = _since(t0, 'miss')       # one entry a miss
+    assert step['fp'] == main.fingerprint() != startup['fp']
+    assert step['phase'] == 'exe/run' and step['modules'] >= 1
+    for miss in (startup, step):
+        parts = [miss[k] for k in ('verify_s', 'lower_s', 'trace_s',
+                                   'mlir_s', 'backend_s', 'first_run_s')]
+        assert all(p >= 0.0 for p in parts)
+        assert 0.0 < sum(parts) <= miss['wall_s']
+        assert miss['cache'] == 'off' and miss['retrieval_s'] == 0.0
+    # the parts are what jax said inside the call
+    mine = [e for e in _since(t0) if e['fp'] == step['fp']
+            and e['kind'] in JAX_KINDS]
+    for kind in JAX_KINDS:
+        assert step[kind + '_s'] == pytest.approx(
+            sum(e['dur_s'] for e in mine if e['kind'] == kind))
+    assert step['modules'] == len([e for e in mine
+                                   if e['kind'] == 'backend'])
+
+
+def test_nested_traces_fold_into_the_steps_one_trace():
+    t0 = time.perf_counter()
+    exe, scope, main, loss, feeds = _tiny()
+    exe.run(main, feed=feeds[0], fetch_list=[loss], scope=scope)
+    fp = main.fingerprint()
+    # every jnp function inside the step's trace fires a trace event of
+    # its own; the log holds the outermost: one trace, one lowering and
+    # one compile of the step's module
+    for kind in JAX_KINDS:
+        e, = [e for e in _since(t0, kind)
+              if e['fp'] == fp and e['phase'] == 'exe/launch']
+        assert e['fun'] in ('fn', 'jit(fn)') and e['dur_s'] > 0
+        assert e['thread'] and ('cache' in e) == (kind == 'backend')
+
+
+def test_a_second_run_of_the_same_program_adds_no_entry():
+    exe, scope, main, loss, feeds = _tiny()
+    exe.run(main, feed=feeds[0], fetch_list=[loss], scope=scope)
+    t0 = time.perf_counter()
+    reg = obs.default_registry()
+    seen = reg.counter('jax_compile_events_total', kind='trace',
+                       owner='executor').value
+    for f in feeds[1:]:
+        exe.run(main, feed=f, fetch_list=[loss], scope=scope)
+    assert _since(t0) == []
+    assert reg.counter('jax_compile_events_total', kind='trace',
+                       owner='executor').value == seen
+
+
+def test_clear_caches_gives_a_retrace_under_a_held_key(tmp_path):
+    exe, scope, main, loss, feeds = _tiny()
+    exe.run(main, feed=feeds[0], fetch_list=[loss], scope=scope)
+    misses = exe.cache_info().misses
+    jax.clear_caches()
+    t0 = time.perf_counter()
+    p = str(tmp_path / 'j.jsonl')
+    with obs.journal(p), obs.span('test/step'):
+        exe.run(main, feed=feeds[1], fetch_list=[loss], scope=scope)
+        exe.run(main, feed=feeds[2], fetch_list=[loss], scope=scope)
+    # the Executor held the key: no miss of its own, no miss entry
+    assert exe.cache_info().misses == misses
+    assert _since(t0, 'miss') == []
+    for kind in ('trace', 'backend'):
+        e, = _since(t0, kind)
+        assert e['phase'] == 'exe/launch' and e['fp'] == main.fingerprint()
+    recs, _ = obs.read_journal(p)
+    first, second = [r for r in recs if r['ev'] == 'span_end'
+                     and r['name'] == 'exe/run']
+    assert first['retraced'] is True and 'retraced' not in second
+    assert [r['cache'] for r in recs if r['ev'] == 'exe_run'] \
+        == ['hit', 'hit']
+
+
+def test_a_compile_outside_any_phase_is_nobodys_of_ours():
+    import jax.numpy as jnp
+    reg = obs.default_registry()
+    series = [reg.counter('jax_compile_events_total', kind=k, owner='other')
+              for k in JAX_KINDS]
+    x = jnp.ones((8, 8))
+    seen = [c.value for c in series]
+    secs = reg.counter('jax_compile_seconds_total', kind='backend',
+                       owner='other').value
+    t0 = time.perf_counter()
+    jax.jit(lambda x: jnp.tanh(x) @ x)(x)
+    assert [e['kind'] for e in _since(t0)] == list(JAX_KINDS)
+    assert all(e['phase'] is None and e['fp'] is None for e in _since(t0))
+    assert [c.value for c in series] == [n + 1 for n in seen]
+    assert reg.counter('jax_compile_seconds_total', kind='backend',
+                       owner='other').value > secs
+
+
+def test_jax_spans_are_children_of_the_phase_they_fell_in(tmp_path):
+    exe, scope, main, loss, feeds = _tiny()
+    p = str(tmp_path / 'j.jsonl')
+    with obs.journal(p), obs.span('test/step'):
+        exe.run(main, feed=feeds[0], fetch_list=[loss], scope=scope)
+    recs, _ = obs.read_journal(p)
+    ends = {r['name']: r for r in recs if r['ev'] == 'span_end'}
+    launch, run = ends['exe/launch'], ends['exe/run']
+    assert launch['parent'] == run['span']
+    for name in ('jax/trace', 'jax/mlir', 'jax/xla_compile'):
+        assert ends[name]['parent'] == launch['span']
+        assert ends[name]['trace'] == run['trace']
+        assert ends[name]['fp'] == run['fp'] and ends[name]['fun']
+        assert ends[name]['dur_s'] <= launch['dur_s']
+    # the miss's own account rides on compile_end
+    end, = [r for r in recs if r['ev'] == 'compile_end']
+    assert end['fp'] == run['fp'] and end['modules'] >= 1
+    assert end['trace_s'] + end['mlir_s'] + end['backend_s'] \
+        + end['first_run_s'] <= end['dur_s'] + 1e-5
+
+
+def test_a_second_process_like_run_logs_the_cache_hit(tmp_path):
+    """With a persistent cache directory, ``jax.clear_caches()`` stands
+    in for a second process: the step's module is found, not compiled."""
+    reg = obs.default_registry()
+    hits = reg.counter('jax_persistent_cache_total', result='hit').value
+    with _persistent_cache(tmp_path):
+        exe, scope, main, loss, feeds = _tiny()
+        t0 = time.perf_counter()
+        exe.run(main, feed=feeds[0], fetch_list=[loss], scope=scope)
+        cold, = _since(t0, 'miss')
+        assert cold['cache'] == 'miss' and cold['retrieval_s'] == 0.0
+        jax.clear_caches()
+        t1 = time.perf_counter()
+        exe.run(main, feed=feeds[1], fetch_list=[loss], scope=scope)
+        warm, = _since(t1, 'backend')
+        assert warm['cache'] == 'hit' and warm['retrieval_s'] > 0
+        assert warm['phase'] == 'exe/launch' and 'saved_s' in warm
+        assert reg.counter('jax_persistent_cache_total',
+                           result='hit').value == hits + 1
