@@ -29,7 +29,7 @@ __all__ = ['DeadOpElimination', 'ConstantFolding', 'ElementwiseFusion',
            'DEFAULT_PASSES', 'INFERENCE_PASSES', 'RNG_OPS',
            'FUSED_ELEMENTWISE_OP', 'FUSED_CONV_OP',
            'conv_fuse_counts', 'flash_counts', 'amp_elementwise_counts',
-           'moe_counts', 'ssd_counts']
+           'moe_counts', 'ssd_counts', 'loss_counts']
 
 # Ops that consume the threaded PRNG key: removing one would shift the
 # RNG stream of every later stochastic op, silently changing numerics —
@@ -899,6 +899,15 @@ def moe_counts(by=('experts', 'held', 'top_k', 'route')):
     (``lax.ragged_dot``: everything else, the CPU tests among it).
     ops/pallas_kernels.py::grouped_plan is the rule."""
     return _label_counts('moe_lowerings_total', by)
+
+
+def loss_counts():
+    """``{(): n}``: the softmax_with_cross_entropy lowerings that took
+    the hard-label rule (ops/nn_ops.py::_lse_loss: the logits kept in
+    the dtype they came in, a row's float32 lse, the logits' gradient
+    in one pass), one a traced program; a soft-label lowering (a
+    float32 log_softmax the size of the logits) counts nothing."""
+    return _label_counts('loss_lowerings_total', ())
 
 
 def rotary_counts(by=('dim', 'dtype')):

@@ -260,18 +260,73 @@ def _cross_entropy(ctx):
     ctx.set_output('Y', loss)
 
 
+def _row_lse(x):
+    """A row's float32 ``max + log sum exp(x - max)`` over the last axis,
+    [..., 1]; ``x`` is read in the dtype it came in."""
+    return jax.nn.logsumexp(f32(x), axis=-1, keepdims=True)
+
+
+@jax.custom_vjp
+def _lse_loss(x, xf, idx):
+    """Hard-label softmax cross-entropy ``lse(x) - x[idx]``, [..., 1]
+    float32, of logits ``x`` [..., V] at integer labels ``idx`` [...];
+    ``xf`` is the caller's one widening ``f32(x)``. Nothing the size of
+    the logits is made or kept beside ``x`` itself (bf16 under AMP):
+    the label's logit is a gather from ``x``, the residuals are ``x``,
+    its rows' ``lse`` and ``idx``, and the backward is one elementwise
+    pass whose one-hot is a comparison. The cotangent goes to ``xf`` in
+    float32, so it meets whatever else read ``xf`` (the Softmax output)
+    before the widening's transpose rounds the sum to ``x``'s dtype,
+    once, where ordinary autodiff rounds it. Autodiff of
+    ``take_along_axis(log_softmax(f32(x)))`` scatters into float32 zeros
+    the size of the logits and reduces them again (PERF.md, PR 36)."""
+    return _lse_loss_fwd(x, xf, idx)[0]
+
+
+def _lse_loss_fwd(x, xf, idx):
+    lse = _row_lse(xf)
+    picked = jnp.take_along_axis(x, idx[..., None], axis=-1)
+    return lse - f32(picked), (x, lse, idx)
+
+
+def _lse_loss_bwd(res, g):
+    x, lse, idx = res
+    p = jnp.exp(f32(x) - lse)
+    hot = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) \
+        == idx[..., None]
+    return None, (p - hot.astype(p.dtype)) * g, None
+
+
+_lse_loss.defvjp(_lse_loss_fwd, _lse_loss_bwd)
+
+
 @register_kernel('softmax_with_cross_entropy')
 def _softmax_with_cross_entropy(ctx):
-    logits = f32(unwrap(ctx.input('Logits')))
+    """Hard labels take ``_lse_loss`` on the logits as they came; soft
+    labels the dense ``-sum(label * log_softmax)``. Softmax is
+    ``exp(x - lse)`` by ordinary autodiff either way, dropped by XLA
+    where nobody reads it. A lowering that takes ``_lse_loss`` counts
+    once in ``loss_lowerings_total``
+    (compiler/passes.py::loss_counts)."""
+    logits = unwrap(ctx.input('Logits'))
     label = unwrap(ctx.input('Label'))
-    logp = jax.nn.log_softmax(logits, axis=-1)
+    xf = f32(logits)        # widened once: its transpose is one rounding
+    logp = xf - _row_lse(xf)
     if ctx.attr('soft_label', False):
         loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
     else:
+        from .. import observability as _obs
+        _obs.default_registry().counter(
+            'loss_lowerings_total',
+            help='softmax_with_cross_entropy lowerings that took the '
+                 'hard-label rule: the logits kept as they came and a '
+                 'row\'s float32 lse').inc()
         idx = label.astype('int32')
         if idx.ndim == logits.ndim:
             idx = idx.reshape(idx.shape[:-1])
-        loss = -jnp.take_along_axis(logp, idx[..., None], axis=-1)
+        # a negative label counts from the end, as take_along_axis reads it
+        idx = jnp.where(idx < 0, idx + logits.shape[-1], idx)
+        loss = _lse_loss(logits, xf, idx)
     ctx.set_output('Softmax', jnp.exp(logp))
     ctx.set_output('Loss', loss)
 
