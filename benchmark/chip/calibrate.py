@@ -109,8 +109,8 @@ def main(argv=None):
             jstep, init, put = harness._reference_step_fn(
                 ref, dot, tuple(devs))
             with jax.default_device(devs[0]):
-                params = init(wkey0)
-                jstep.lower(params, ref.new_opt_state(params), put(bs[0]),
+                params, opt_state = init(wkey0)
+                jstep.lower(params, opt_state, put(bs[0]),
                             jnp.float32(1)).compile()
 
         jobs = [('program', bs0, None, devices)] + variants_of(bs0)

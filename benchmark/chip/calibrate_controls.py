@@ -11,7 +11,7 @@ it, over the same seeds (``--first-seed`` + 7919 i, as there). The next
 and deletes this file (ROADMAP, D18). Not part of a benchmark run.
 
     python3 benchmark/chip/calibrate_controls.py --workload <cell> \
-        --seeds 3 [--first-seed N] [--budget-s S]
+        --seeds 3 [--first-seed N] [--budget-s S] [--no-half]
 """
 import time
 T_START = time.perf_counter()
@@ -33,6 +33,8 @@ def main(argv=None):
     ap.add_argument('--budget-s', type=float, default=None,
                     help='start no further seed once this many seconds '
                          'have passed')
+    ap.add_argument('--no-half', action='store_true',
+                    help='read the control alone')
     ap.add_argument('--out', default=None)
     args = ap.parse_args(argv)
     if HERE not in sys.path:
@@ -71,8 +73,9 @@ def main(argv=None):
         want = harness.reference_steps(ref, wkey, bs, tuple(devices))
         harness.log('seed %d reference %.1f s' % (
             seed, time.perf_counter() - t))
-        for kind, vb, dot in (('control', bs, control),
-                              ('half', half, None)):
+        kinds = [('control', bs, control)] + (
+            [] if args.no_half else [('half', half, None)])
+        for kind, vb, dot in kinds:
             t = time.perf_counter()
             alt = harness.reference_steps(ref, wkey, vb, tuple(devices),
                                           dot=dot)

@@ -4,6 +4,8 @@ with the plain reference. Everything that belongs to one cell, one
 configuration, one traffic mix or one metric is data, found by the name
 ``BENCHMARK.json`` gives (``manifest.py``).
 """
+import collections
+import contextlib
 import functools
 import gc
 import importlib
@@ -243,10 +245,16 @@ def _reference_step_fn(ref, dot, devices):
         mesh = Mesh(np.array(devices), ('dp',))
         rep = NamedSharding(mesh, P())
         where = NamedSharding(mesh, P('dp'))
-        init = jax.jit(ref.init, out_shardings=rep)
     else:
-        where = devices[0]
-        init = jax.jit(ref.init)
+        rep = where = devices[0]
+    make = jax.jit(ref.init)
+
+    def init(key):
+        """Weights and optimizer state, committed where the step leaves
+        them: ``jit`` keys on whether an argument is committed, so the
+        step's later calls then find the program of its first."""
+        params = jax.device_put(make(key), rep)
+        return params, jax.device_put(ref.new_opt_state(params), rep)
 
     def put(batch):
         return {k: jax.device_put(np.asarray(v), where)
@@ -264,8 +272,7 @@ def reference_steps(ref, wkey, batches, devices, dot=None, n_steps=3):
     import jax.numpy as jnp
     jstep, init, put = _reference_step_fn(ref, dot, tuple(devices))
     with jax.default_device(devices[0]):
-        params = init(wkey)
-        opt_state = ref.new_opt_state(params)
+        params, opt_state = init(wkey)
         losses, grad_full = [], None
         for s in range(n_steps):
             loss, grads, params, opt_state = jstep(
@@ -345,46 +352,80 @@ def percentile(values, q):
     return v[int(rank) - 1]
 
 
-def run_window(sess, feeder, seconds, first_step, annotate=False,
+# a step's time over the window's median step: the bucket edges of
+# ``step_histogram``; 1.02 and up are the slow buckets
+HIST_EDGES = (0.98, 0.995, 1.005, 1.02, 1.05, 1.25)
+
+
+def step_histogram(gaps):
+    """The window's step times in fixed buckets around their median,
+    as one line: the median, the medians of the window's two halves (a
+    window slower in every step moves all three, a drift only the
+    second), the count in each bucket, and the first step of each slow
+    bucket (a load that grows shows late and stays)."""
+    gaps = np.asarray(gaps, np.float64)
+    med = float(np.median(gaps))
+    half = len(gaps) // 2
+    which = np.searchsorted(HIST_EDGES, gaps / med, side='right')
+    names = (['<%g' % HIST_EDGES[0]]
+             + ['%g-%g' % e for e in zip(HIST_EDGES, HIST_EDGES[1:])]
+             + ['>%g' % HIST_EDGES[-1]])
+    parts = []
+    for b, name in enumerate(names):
+        steps = np.flatnonzero(which == b)
+        part = '%s: %d' % (name, len(steps))
+        if len(steps) and b and HIST_EDGES[b - 1] >= 1.02:
+            part += ' from step %d' % steps[0]
+        parts.append(part)
+    return 'median step %.2f ms (halves %.2f / %.2f); by share of it %s' % (
+        1e3 * med, 1e3 * np.median(gaps[:max(half, 1)]),
+        1e3 * np.median(gaps[half:]), ', '.join(parts))
+
+
+def run_window(sess, feeder, seconds, first_step, ahead=0, annotate=False,
                max_steps=None):
-    """Steps until ``seconds`` have passed (or ``max_steps`` are done):
-    one dispatch, one fetch, each step. Returns completion times,
-    dispatch and fetch spans (host clock) and the losses."""
+    """Steps until ``seconds`` have passed (or ``max_steps`` are sent):
+    each step dispatched, then the loss of the step ``ahead`` steps
+    before it fetched, so that ``ahead`` steps stay queued on the device
+    while the host stands still. Once the time is up nothing more is
+    sent, and every step sent is waited for: all of them count, over
+    all of that time. A step is complete when its loss is on the host.
+    Returns completion times, dispatch and fetch spans (host clock, by
+    step) and the losses."""
     import jax
     spans = {'dispatch': [], 'fetch': []}
     done, losses = [], []
-    ann = jax.profiler.TraceAnnotation if annotate else None
+    queue = collections.deque()
+    ann = (jax.profiler.TraceAnnotation if annotate
+           else lambda name: contextlib.nullcontext())
+
+    def complete():
+        tb = time.perf_counter()
+        with ann('fetch'):
+            losses.append(sess.fetch(queue.popleft()))
+        tc = time.perf_counter()
+        spans['fetch'].append(tc - tb)
+        done.append(tc)
+
     i = first_step
     t0 = time.perf_counter()
     while True:
-        if ann:
-            with ann('feed'):
-                feed = feeder.feed(i)
-        else:
+        with ann('feed'):
             feed = feeder.feed(i)
         ta = time.perf_counter()
-        if ann:
-            with ann('dispatch'):
-                h = sess.dispatch(feed)
-        else:
-            h = sess.dispatch(feed)
-        tb = time.perf_counter()
-        if ann:
-            with ann('fetch'):
-                loss = sess.fetch(h)
-        else:
-            loss = sess.fetch(h)
-        tc = time.perf_counter()
-        spans['dispatch'].append(tb - ta)
-        spans['fetch'].append(tc - tb)
-        done.append(tc)
-        losses.append(loss)
+        with ann('dispatch'):
+            queue.append(sess.dispatch(feed))
+        spans['dispatch'].append(time.perf_counter() - ta)
         i += 1
+        if len(queue) > ahead:
+            complete()
         if max_steps is not None:
-            if len(done) >= max_steps:
+            if i - first_step >= max_steps:
                 break
-        elif tc - t0 >= seconds:
+        elif time.perf_counter() - t0 >= seconds:
             break
+    while queue:
+        complete()
     return {'t0': t0, 'done': done, 'spans': spans, 'losses': losses,
             'next_step': i}
 
@@ -442,7 +483,8 @@ def run_cell(man, workload, seed, seconds, trace, devices, out_dir,
 
     # -- the window -----------------------------------------------------------
     gc0 = [g['collections'] for g in gc.get_stats()]
-    win = run_window(sess, feeder, seconds, step)
+    ahead = traffic.get('ahead', 0)
+    win = run_window(sess, feeder, seconds, step, ahead)
     gc1 = [g['collections'] for g in gc.get_stats()]
     step = win['next_step']
     info1 = sess.cache_info()
@@ -452,12 +494,17 @@ def run_cell(man, workload, seed, seconds, trace, devices, out_dir,
     n_steps = len(win['done'])
     items = model.items_per_step(cfg, traffic)
     failed = sum(1 for v in win['losses'] if not np.isfinite(v))
-    # for whoever looks for the cause of a slow run: a stall shows here
-    log('window: %d steps in %.4f s; longest gaps (ms at step) %s; '
-        'collections by generation %s' % (
-            n_steps, window_s,
-            ', '.join('%.1f at %d' % (1e3 * gaps[j], j)
-                      for j in np.argsort(-gaps)[:3]),
+    # for whoever looks for the cause of a slow run: a stall, a drift or
+    # a window slower in every step shows here. In the gap before step
+    # j completes the host sent step j + ahead (none once time was up)
+    sp = win['spans']
+    sent = sp['dispatch'][ahead:] + [0.0] * ahead
+    log('window: %d steps in %.4f s, %d ahead; %s; longest gaps (ms at '
+        'step: dispatch + fetch) %s; collections by generation %s' % (
+            n_steps, window_s, ahead, step_histogram(gaps),
+            ', '.join('%.1f at %d: %.1f + %.1f' % (
+                1e3 * gaps[j], j, 1e3 * sent[j],
+                1e3 * sp['fetch'][j]) for j in np.argsort(-gaps)[:3]),
             [b - a for a, b in zip(gc0, gc1)]))
 
     ctx = {
@@ -482,7 +529,7 @@ def run_cell(man, workload, seed, seconds, trace, devices, out_dir,
         tdir = os.path.join(out_dir, 'trace')
         jax.profiler.start_trace(tdir)
         try:
-            tw = run_window(sess, feeder, 0, step, annotate=True,
+            tw = run_window(sess, feeder, 0, step, ahead, annotate=True,
                             max_steps=traffic.get('trace_steps', 12))
         finally:
             jax.profiler.stop_trace()

@@ -520,13 +520,18 @@ def ssd_work(cfg, traffic, chips):
 
 
 def expert_mm_work(cfg, traffic, chips):
-    """(operations, HBM bytes) of the grouped products over the held
-    experts, forward and backward, in one step under a balanced
-    routing: 6 operations a weight a routed (token, expert) pair; each
-    held expert's two matrices read in bf16 by the forward and by the
-    backward and their float32 gradient written once (8 bytes a
-    weight), and a pair's rows (latent in, hidden, latent out) moved
-    three times in bf16."""
+    """(operations, HBM bytes) of the six grouped products over the held
+    experts in the latent width, forward and backward, in one step under
+    a balanced routing: 6 operations a weight a routed (token, expert)
+    pair; each held expert's two matrices read in bf16 by the forward
+    and by the backward and their float32 gradient written once (8
+    bytes a weight), and a pair's rows (latent in, hidden, latent out)
+    moved three times in bf16. ``routed_experts_roofline.tok`` divides
+    this by the time of the whole ``routed_experts`` op, which also
+    runs the choice of experts (``top_k`` over the 512 scores), the
+    rows' placement (sort, gather, scatter-add), ``relu2`` and the
+    weighing: none of these is counted, as in ``afmoe.expert_mm_work``.
+    The latent projections and the router are ops of their own."""
     del chips
     d = Dims(cfg)
     n = d.count('E')

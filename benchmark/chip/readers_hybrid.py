@@ -7,36 +7,19 @@ the op or the counter gives them nothing to read: each returns None and
 the metric is left out of the line.
 """
 import readers_program
-import reduce_trace
 
 
 def _op_seconds(ctx, spec):
     """Device seconds a step of every operation lowered under a Fluid op
     of ``spec['op_type']`` (a fusion that holds several ops counts where
-    any of them is it), all phases, and of the operations whose
-    instruction name matches ``spec['unscoped']``: kernels the compiler
-    makes of the op after lowering, whose metadata it names anew (the
-    grouped products ``lax.ragged_dot`` becomes carry ``ragged-dot-*``
-    and no Fluid scope). None where nothing ran under the op, and where
-    the op ran but ``unscoped`` names nothing in the trace: the kernels
-    were renamed, and the time left would read as a faster op."""
+    any of them is it), all phases; None where nothing ran under the
+    op."""
     res = readers_program.by_scope(ctx)
     if res is None:
         return None
     secs = [s for (_, kind), s in res['type'].items()
             if kind and spec['op_type'] in kind.split('+')]
-    if not secs:
-        return None
-    total = sum(secs)
-    if spec.get('unscoped'):
-        lo, hi = ctx['trace_window']
-        found = reduce_trace.time_by_name(ctx['trace'], spec['unscoped'],
-                                          lo, hi)
-        named, count = max(found.values())
-        if not count:
-            return None
-        total += named / ctx['trace_steps']
-    return total
+    return sum(secs) if secs else None
 
 
 def scope_ms(ctx, spec):

@@ -142,14 +142,22 @@ def test_required_work_matches_hand_counts():
     ef, eb = nemotron_h.expert_mm_work(cfg, traffic, 1)
     assert ef == 5 * 6 * 1408 * 2 * 1024 * 2688
     assert eb > 5 * 8 * 8 * 2 * 1024 * 2688
+    # the products' least time is HBM's, 2.39 ms a step: what
+    # routed_experts_roofline.tok divides by the op's device time
+    assert ef / 197e12 < eb / 819e9
+    assert 2.38e-3 < eb / 819e9 < 2.40e-3
 
 
 def test_hybrid_readers_on_a_hand_made_trace(man):
-    """``moe_ms`` adds the grouped products' own kernels (named anew by
-    the compiler, no Fluid scope) to the time under the op's scopes;
-    the roofline divides the required work's least time by it; a
-    program without the op, the map or the counter reads nothing, and
-    neither does a trace in which those kernels go by another name."""
+    """The nemotron cell reads the routed op through the trinity cell's
+    two metrics: ``routed_experts_ms.tok`` is the device time under the
+    op's scopes, forward and backward, the grouped products' Pallas
+    kernels, the routing and the row moves included (no kernel is read
+    by name); ``routed_experts_roofline.tok`` divides the least time of
+    the six grouped products' required work over the latent width
+    (``expert_mm_work``) by it. ``ssd_ms.tok`` and its roofline read the
+    scan's scopes the same way; a program without the op, the map or
+    the counter reads nothing."""
     import readers_hybrid as rh
     from reduce_trace import Event
     from models import nemotron_h
@@ -157,12 +165,14 @@ def test_hybrid_readers_on_a_hand_made_trace(man):
     bwd = 'jit(fn)/transpose(jvp(forward))/'
     scopes = {'jit_fn|aa|0': {
         'fusion.1': pre + 'routed_experts:r.tmp_0/top_k',
+        '_gmm_kernel.7': pre + 'routed_experts:r.tmp_0/pallas_call',
         'fusion.2': bwd + 'routed_experts:r.tmp_0/mul',
+        '_tgmm_kernel.8': bwd + 'routed_experts:r.tmp_0/pallas_call',
         'fusion.3': pre + 'ssd_scan:s.tmp_0/dot_general',
         'fusion.4': bwd + 'mul:fc.tmp_0/dot_general'}}
-    dev = [Event('fusion.1', 0.0, 1.0), Event('ragged-dot-none.7', 1.0, 3.0),
+    dev = [Event('fusion.1', 0.0, 1.0), Event('_gmm_kernel.7', 1.0, 3.0),
            Event('fusion.2', 3.0, 4.0), Event('fusion.3', 4.0, 4.5),
-           Event('fusion.4', 4.5, 6.0), Event('ragged-dot-metadata', 6.0, 6.5)]
+           Event('fusion.4', 4.5, 6.0), Event('_tgmm_kernel.8', 6.0, 6.5)]
     cfg = man.config('nemotron3-super-120b-a12b')
     traffic = man.traffic('b1-s4096')
     ctx = {'trace': {'devices': {'a': dev}, 'host': []},
@@ -170,25 +180,29 @@ def test_hybrid_readers_on_a_hand_made_trace(man):
            'program_scopes': scopes, 'man': man, 'cfg': cfg,
            'traffic': traffic, 'chips': 1, 'model': nemotron_h,
            'device_kind': 'TPU v5 lite'}
-    moe = {'op_type': 'routed_experts', 'unscoped': '^ragged-dot'}
+
+    def spec(name):
+        with open(os.path.join(CHIP, 'layer_metrics', name + '.json')) as f:
+            return json.load(f)
+    moe = spec('routed_experts_ms.tok')
+    assert set(moe) == {'reader', 'op_type'}
     assert rh.scope_ms(ctx, moe) == pytest.approx(1e3 * 4.5 / 2)
-    assert rh.scope_ms(ctx, {'op_type': 'ssd_scan'}) \
+    assert rh.scope_ms(ctx, spec('ssd_ms.tok')) \
         == pytest.approx(1e3 * 0.5 / 2)
     assert rh.scope_ms(ctx, {'op_type': 'conv2d'}) is None
     flops, nbytes = nemotron_h.ssd_work(cfg, traffic, 1)
     least = max(flops / 197e12, nbytes / 819e9)
-    assert rh.scope_roofline(ctx, {'op_type': 'ssd_scan',
-                                   'work': 'ssd_work'}) \
+    assert rh.scope_roofline(ctx, spec('ssd_roofline.tok')) \
         == pytest.approx(100 * least / 0.25)
+    flops, nbytes = nemotron_h.expert_mm_work(cfg, traffic, 1)
+    least = max(flops / 197e12, nbytes / 819e9)
+    assert rh.scope_roofline(ctx, spec('routed_experts_roofline.tok')) \
+        == pytest.approx(100 * least / 2.25)
     assert rh.scope_ms({'trace': None}, moe) is None
-    # the op ran but its kernels carry another name: no reading, not a
-    # smaller one
-    renamed = {k: v for k, v in ctx.items() if k != 'by_scope'}
-    renamed['trace'] = {'devices': {'a': [
-        e for e in dev if not e.name.startswith('ragged-dot')]}, 'host': []}
-    assert rh.scope_ms(renamed, moe) is None
-    assert rh.scope_roofline(renamed, dict(moe, work='expert_mm_work')) \
+    none = dict(ctx, program_scopes={'jit_fn|aa|0': {
+        'fusion.4': bwd + 'mul:fc.tmp_0/dot_general'}})
+    none.pop('by_scope', None)
+    assert rh.scope_ms(none, moe) is None
+    assert rh.scope_roofline(none, spec('routed_experts_roofline.tok')) \
         is None
-    assert rh.scope_ms(renamed, {'op_type': 'routed_experts'}) \
-        == pytest.approx(1e3 * 2.0 / 2)
     assert rh.program_count(ctx, {'counts': 'no_such_counts'}) is None

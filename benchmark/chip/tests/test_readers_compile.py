@@ -121,14 +121,21 @@ def test_a_tiny_cell_reports_all_seven(cell, tmp_path):
     assert seen['xla_cache_misses'] == 0
 
 
-def test_every_new_metric_is_in_the_manifest_with_its_reader():
-    from conftest import ROOT
-    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
+@pytest.mark.parametrize('appended', [False, True],
+                         ids=['as_committed', 'one_appended'])
+def test_every_new_metric_is_in_the_manifest_with_its_reader(appended,
+                                                              tmp_path):
+    """PR 35's seven are found by name, wherever they stand: a later PR
+    appends its metrics after them (``appended``: one more, as such a
+    PR leaves the manifest)."""
+    from conftest import ROOT, appended_manifest
+    root = appended_manifest(tmp_path)[0] if appended else ROOT
+    with open(os.path.join(root, 'BENCHMARK.json')) as f:
         doc = json.load(f)
-    mine = [m for m in doc['per_layer'] if m['name'] in NAMES]
-    assert [m['name'] for m in mine] == list(NAMES)
-    assert doc['per_layer'][-7:] == mine
-    for m in mine:
+    by_name = {m['name']: m for m in doc['per_layer']}
+    for name in NAMES:
+        m = by_name[name]
         assert 'workloads' not in m and m['better'] == 'lower'
         assert m['moves'] == ('step_p90_ms' if m['name'] == 'jax_retraces'
                               else 'setup_s')
+        assert _spec(name)['reader'].startswith('readers_compile:')
