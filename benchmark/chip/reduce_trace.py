@@ -20,7 +20,10 @@ _SUMMARY_LINES = ('Steps', 'XLA Modules', 'XLA TraceMe', 'TC Overlay',
                   ASYNC_LINE)
 _COLLECTIVE = re.compile(
     r'^(all-reduce|all-gather|reduce-scatter|all-to-all|'
-    r'collective-permute|collective-broadcast)')
+    r'collective-permute|collective-broadcast|async-collective)')
+# the TPU compiler emits a reduce-scatter as a fusion named ``fusion.N``
+# that calls an ``all-reduce-scatter`` computation
+_CALLS_COLLECTIVE = re.compile(r'calls=%all-reduce-scatter')
 
 
 class Event(object):
@@ -144,8 +147,13 @@ def clip(intervals, lo, hi):
             if min(e, hi) > max(s, lo)]
 
 
-def is_collective(name):
-    return bool(_COLLECTIVE.match(name))
+def is_collective(event):
+    """A collective by its instruction's name, or a fusion that calls a
+    reduce-scatter. Collectives fused into a computing fusion (the
+    TPU's asynchronous collective fusions) run hidden inside it and
+    count as computing."""
+    return bool(_COLLECTIVE.match(event.name)
+                or _CALLS_COLLECTIVE.search(event.text))
 
 
 # ---- the reduction ---------------------------------------------------------
@@ -188,9 +196,9 @@ def exposed_collective(trace, lo, hi):
     for dev, evs in trace['devices'].items():
         flying = trace.get('in_flight', {}).get(dev, [])
         coll = clip(union((e.start, e.end) for e in list(evs) + flying
-                          if is_collective(e.name)), lo, hi)
+                          if is_collective(e)), lo, hi)
         comp = clip(union((e.start, e.end) for e in evs
-                          if not is_collective(e.name)), lo, hi)
+                          if not is_collective(e)), lo, hi)
         out[dev] = total(subtract(coll, comp))
     return out
 

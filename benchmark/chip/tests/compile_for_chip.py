@@ -101,6 +101,10 @@ def main():
            'tpu_custom_call': text.count('custom_call_target="tpu_custom_call"'),
            'all_reduce': text.count(' all-reduce('),
            'reduce_scatter': text.count(' reduce-scatter('),
+           # the TPU compiler's reduce-scatter: a fusion that calls an
+           # all-reduce-scatter computation (its all-reduce is counted
+           # above too)
+           'all_reduce_scatter': text.count('calls=%all-reduce-scatter'),
            'all_gather': text.count(' all-gather(')}
     out['live_bytes'] = (out['argument_bytes'] + out['output_bytes']
                          - out['alias_bytes'] + out['temp_bytes'])

@@ -59,7 +59,7 @@ def test_program_agrees_with_reference(man, cell, tmp_path):
 
 
 # ---- 2. the control --------------------------------------------------------
-@pytest.mark.parametrize('cell', CELLS[:2])
+@pytest.mark.parametrize('cell', CELLS)
 def test_low_precision_control_is_not_correct(man, cell):
     import jax
     import harness
@@ -68,7 +68,7 @@ def test_low_precision_control_is_not_correct(man, cell):
     limits = man.limits(cell)
     model = harness.model_module(cfg)
     ref = model.Reference(cfg)
-    devices = jax.devices()[:1]
+    devices = jax.devices()[:man.workload(cell)['chips']]
     failed_on = []
     for seed in (SEED, SEED + 1, SEED + 2):
         wkey = jax.random.fold_in(harness.key_of(seed), 0)
@@ -124,6 +124,8 @@ FAULTS = [('resnet50-b256-resident', unchanged_state),
           ('resnet50-b256-resident', half_batch),
           ('opt-1.3b-b2-s2048', unchanged_state),
           ('opt-1.3b-b2-s2048', half_batch),
+          ('resnet50-dp4-b1024-resident', unchanged_state),
+          ('resnet50-dp4-b1024-resident', half_batch),
           ('resnet50-dp4-b1024-resident', no_exchange)]
 
 

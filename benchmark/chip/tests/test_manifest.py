@@ -67,6 +67,8 @@ def test_names_units_and_lengths(bench):
 def test_every_moves_names_a_metric_its_cells_report(bench):
     doc, _, chip = bench
     cells = [w['name'] for w in doc['workloads']]
+    for m in doc['end_to_end'] + doc['per_layer']:
+        assert set(m.get('workloads', cells)) <= set(cells), m['name']
     e2e = {m['name']: m.get('workloads', cells) for m in doc['end_to_end']}
     assert 'setup_s' in e2e and e2e['setup_s'] == cells
     layers = set()

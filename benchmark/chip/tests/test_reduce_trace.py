@@ -63,6 +63,25 @@ def test_busy_idle_names_and_exposed_collectives():
         == {'dispatch': 1.0, 'fetch': 1.0}
 
 
+def test_the_tpus_fused_collectives_count_as_collectives():
+    """On the TPU a reduce-scatter is a fusion that calls an
+    ``all-reduce-scatter`` computation, and an asynchronous collective
+    fusion shows as a start and a done; a computing fusion with a
+    collective inside it stays computing (as read in the four-chip
+    cell's trace)."""
+    evs = [Event('%fusion.25 = bf16[512,1000]{0,1} fusion(bf16[2048,1000]'
+                 ' %x), kind=kCustom, calls=%all-reduce-scatter.24',
+                 0.0, 1.0),
+           Event('async-collective-start.3', 1.0, 1.5),
+           Event('%fusion.1966 = (bf16[64,3,7,7], f32[4,1,256]) fusion('
+                 'bf16[256,3,224,224] %p), kind=kOutput, '
+                 'calls=%fused_computation.2845', 1.5, 4.0),
+           Event('async-collective-done.3', 4.0, 4.25)]
+    assert [rt.is_collective(e) for e in evs] == [True, True, False, True]
+    tr = {'devices': {'/device:TPU:0': evs}, 'host': []}
+    assert rt.exposed_collective(tr, 0.0, 4.25) == {'/device:TPU:0': 1.75}
+
+
 @pytest.mark.skipif(not os.path.isfile(TINY),
                     reason='no recorded trace beside the test')
 def test_recorded_trace():
