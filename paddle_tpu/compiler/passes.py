@@ -897,8 +897,20 @@ def moe_counts(by=('experts', 'held', 'top_k', 'route')):
     matmul of ops/pallas_kernels.py: a TPU backend, bf16 operands,
     latent and expert widths multiples of 128) or 'ragged_dot'
     (``lax.ragged_dot``: everything else, the CPU tests among it).
-    ops/pallas_kernels.py::grouped_plan is the rule."""
+    ops/pallas_kernels.py::grouped_plan is the rule. 'row_sum' names
+    how a chunk's rows are summed back into their tokens: 'pallas' or
+    'xla' (moe_row_sum_counts)."""
     return _label_counts('moe_lowerings_total', by)
+
+
+def moe_row_sum_counts():
+    """``{('pallas',): n}``: the routed_experts lowerings whose rows are
+    summed back into their tokens (the forward's combine and the row
+    pick's transpose) by the Pallas kernel that adds only a chunk's live
+    rows (ops/pallas_kernels.py::row_sum_plan is the rule); a lowering
+    that kept XLA's scatter-add is not in it."""
+    return {k: n for k, n in moe_counts(by=('row_sum',)).items()
+            if k == ('pallas',)}
 
 
 def loss_counts():

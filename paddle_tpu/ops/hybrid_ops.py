@@ -256,10 +256,19 @@ def _place_rows(u, weight, order, counts, rows):
     """(the tokens of ``rows`` picked from u [N, L], their routing
     weights, the function that sums rows back to their tokens), through
     the pairs in row order: a row gather, a gather of scalars and a
-    scatter-add, each linear in rows. Float32 rows both ways, so that
-    the transposes (a scatter-add of the rows' gradients, a gather of
-    the output's) add in float32 too. A row past the routed pairs reads
-    a zero row and adds to nothing.
+    sum of rows into their tokens, each linear in rows. Float32 rows
+    both ways, so that the transposes (a sum of the rows' gradients
+    into their tokens, a gather of the output's) add in float32 too. A
+    row past the routed pairs reads a zero row and adds to nothing.
+
+    The sum back, in the forward and as the pick's transpose, takes one
+    of two routes (pallas_kernels.row_sum_plan is the rule): on the TPU,
+    with the width L in whole 128-lane tiles, the Pallas kernel that
+    adds only the chunk's live rows into a VMEM-resident column block
+    of the result, a token's rows in row (expert) order
+    (pallas_kernels.row_sum, row_pick: their gradients are the row
+    gather and the kernel); anywhere else (the CPU, odd widths) XLA's
+    scatter-add and autodiff's transposes of it and of the gather.
 
     Why not a 0/1 [chunk, N] matrix on the MXU, which moves rows
     without a gather: its cost is the product of rows and tokens.
@@ -268,20 +277,31 @@ def _place_rows(u, weight, order, counts, rows):
     2048 wide, gated experts 13.56 / 21.87 by the matrix (537 MB of
     float32 comparisons to make it, a 268 MB operand, 2.95 to pick bf16
     rows, 6.42 to sum float32 rows back in two bf16 halves) and 5.12 /
-    11.40 this way (the gather 0.23-0.34, the scatter-add 1.59); 3072
+    11.40 this way (the gather 0.23-0.34, XLA's scatter-add 1.59); 3072
     rows of 4096 tokens, 1024 wide 1.76 / 2.83 and 1.52 / 2.47: this
     way is faster at both shapes the benchmark runs."""
-    n = u.shape[0]
-    ids = lax.dynamic_slice(order, (rows[0],), (rows.shape[0],))
-    live = rows < jnp.sum(counts)
+    from .pallas_kernels import row_pick, row_sum, row_sum_plan
+    n, chunk = u.shape[0], rows.shape[0]
+    ids = lax.dynamic_slice(order, (rows[0],), (chunk,))
+    pairs = jnp.sum(counts)
+    live = rows < pairs
     tok = jnp.where(live, ids % n, n)
     row_w = jnp.where(live, jnp.take(weight.reshape(-1),
                                      jnp.where(live, ids, 0)), 0.0)
-    xs = jnp.take(f32(u), tok, axis=0, mode='fill', fill_value=0)
+    plan = row_sum_plan(jax.ShapeDtypeStruct((chunk, u.shape[1]),
+                                             jnp.float32), n)
+    if plan is None:
+        xs = jnp.take(f32(u), tok, axis=0, mode='fill', fill_value=0)
 
-    def back(y):
-        return jnp.zeros((n, y.shape[1]), jnp.float32).at[tok].add(
-            y, mode='drop')
+        def back(y):
+            return jnp.zeros((n, y.shape[1]), jnp.float32).at[tok].add(
+                y, mode='drop')
+    else:
+        n_live = jnp.clip(pairs - rows[0], 0, chunk)
+        xs = row_pick(f32(u), tok, n_live, n, plan)
+
+        def back(y):
+            return row_sum(y, tok, n_live, n, plan)
     return xs.astype(mxu_operand(u).dtype), row_w, back
 
 
@@ -349,7 +369,12 @@ def _held_experts_fwd(u, ws, weight, order, counts, chunk, act):
 
 def _held_experts_bwd(chunk, act, res, g):
     # the first chunk's products were saved; a further chunk (a skewed
-    # routing) is computed again for its gradient, into the same sums
+    # routing) is computed again for its gradient, into the same sums.
+    # The first chunk's gradient is taken once, before the cond (inside
+    # both branches each held its own temporaries: 0.46 GB of the
+    # trinity cell's step); the barrier keeps XLA from sinking the
+    # optimizer's work on the weight gradients into the branches, where
+    # it leaves its fusions (PERF.md section 6)
     vjp_first, u, ws, weight, order, counts = res
 
     def more(c, grads):
@@ -358,12 +383,10 @@ def _held_experts_bwd(chunk, act, res, g):
             u, ws, weight)
         return jax.tree_util.tree_map(jnp.add, grads, vjp(g))
 
-    def first():
-        return vjp_first(g)
-
     n = _chunks(counts, chunk)
-    grads = lax.cond(n > 1, lambda: lax.fori_loop(1, n, more, first()),
-                     first)
+    grads = lax.optimization_barrier(lax.cond(
+        n > 1, lambda grads: lax.fori_loop(1, n, more, grads),
+        lambda grads: grads, vjp_first(g)))
     return tuple(grads) + (None, None)
 
 
@@ -399,9 +422,12 @@ def _routed_experts(ctx):
     expert by counting (a token takes an expert at most once, so a
     cumulative sum over [held, N] places every pair; one sort of those
     places puts the pairs in row order), their rows picked by a row
-    gather and summed back by a scatter-add, both linear in rows
+    gather and summed back into their tokens, both linear in rows
     (_place_rows), multiplied group by group and weighed between. The
-    grouped
+    sum back (and the pick's transpose) takes the Pallas kernel that
+    adds only a chunk's live rows on the TPU where L is whole 128-lane
+    tiles, XLA's scatter-add anywhere else (pallas_kernels.row_sum_plan
+    is the rule). The grouped
     products take one of two routes, chosen from what the op sees
     (pallas_kernels.grouped_plan): on the TPU, with bf16 operands (AMP)
     and L, F multiples of 128, the Pallas grouped matmul ('pallas');
@@ -413,11 +439,13 @@ def _routed_experts(ctx):
     routed pairs belong to no expert: the Pallas products pass over
     their row tiles (pallas_kernels.live_row_tiles counts them from
     TokensPerExpert), the row moves and the activation still sweep
-    them. TokensPerExpert [held] is the second output.
+    them; the row sum reads none of them. TokensPerExpert [held] is the
+    second output.
     What the experts held elsewhere would add is left out. Each
     lowering counts once in ``moe_lowerings_total{experts=, held=,
-    top_k=, route=, act=}`` (compiler/passes.py::moe_counts)."""
-    from .pallas_kernels import grouped_plan
+    top_k=, route=, act=, row_sum=}`` (compiler/passes.py::moe_counts;
+    ``row_sum`` 'pallas' or 'xla': moe_row_sum_counts)."""
+    from .pallas_kernels import grouped_plan, row_sum_plan
     x_in = unwrap(ctx.input('X'))
     E, K = int(ctx.attr('num_experts')), int(ctx.attr('top_k'))
     first, held = int(ctx.attr('first_expert', 0)), int(ctx.attr('held'))
@@ -440,15 +468,18 @@ def _routed_experts(ctx):
     plan = grouped_plan(
         jax.ShapeDtypeStruct((chunk, L), mxu_operand(u).dtype),
         jax.ShapeDtypeStruct(w1.shape, mxu_operand(w1).dtype))
+    summed = row_sum_plan(jax.ShapeDtypeStruct((chunk, L), jnp.float32), N)
     _obs.default_registry().counter(
         'moe_lowerings_total',
         help='routed_experts op lowerings, by the experts routed over, '
              'the experts held here, the experts a token takes, the '
              'grouped product (pallas: the Pallas grouped matmul; '
-             'ragged_dot: lax.ragged_dot) and the expert\'s activation '
-             '(relu2 / swiglu)',
+             'ragged_dot: lax.ragged_dot), the expert\'s activation '
+             '(relu2 / swiglu) and the sum of rows back into their '
+             'tokens (pallas: the live-row kernel; xla: a scatter-add)',
         experts=str(E), held=str(held), top_k=str(K),
-        route='ragged_dot' if plan is None else 'pallas', act=act).inc()
+        route='ragged_dot' if plan is None else 'pallas', act=act,
+        row_sum='xla' if summed is None else 'pallas').inc()
     counts = jnp.sum(chosen, axis=1, dtype=jnp.int32)       # [held]
     place = (jnp.cumsum(counts) - counts)[:, None] \
         + jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1

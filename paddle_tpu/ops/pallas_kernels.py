@@ -1728,3 +1728,141 @@ def grouped_matmul(rows, w, sizes, interpret=None):
     tm, interpret = plan
     visits = grouped_visits(sizes, rows.shape[0] // tm, tm)
     return _grouped(rows, w, visits, tm, interpret)
+
+
+# ---- rows summed back into their tokens (routed experts) ------------------------
+# A chunk of the routed experts' rows [chunk, L] float32 lies expert by
+# expert, one (token, expert) pair a row; its first ``live`` rows are
+# routed pairs, the rows past them belong to nobody. The sum of the live
+# rows into their tokens [N, L] is the forward's combine and the
+# transpose of the row pick. XLA's scatter-add does it at about a
+# seventh of HBM's bandwidth (PERF.md section 6) and sweeps the
+# dead rows too; the kernel below keeps a column block of the result in
+# VMEM, adds the live rows into it one by one and fetches no row past
+# them.
+
+def _row_sum_kernel(tok_ref, live_ref, y_ref, o_ref, *, tr):
+    # the [N, tl] column block stays in VMEM over the row blocks: zeroed
+    # at the first, written back after the last. A row block adds its
+    # rows below ``live`` in row order (a token's rows in expert order);
+    # a block past them runs no row, and its index map holds the last
+    # live block, so nothing moves for it. Four rows an iteration: 8 %
+    # faster than two and 22 % faster than one on the chip (PERF.md
+    # section 6); eight gain under 2 % more
+    r = pl.program_id(1)
+    base = r * tr
+    rows = jnp.clip(live_ref[0] - base, 0, tr)
+
+    @pl.when(r == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def add(i, carry):
+        t = tok_ref[base + i]
+        o_ref[pl.ds(t, 1), :] += y_ref[pl.ds(i, 1), :]
+        return carry
+
+    def four(k, carry):
+        for q in range(4):
+            add(4 * k + q, carry)
+        return carry
+
+    jax.lax.fori_loop(0, rows // 4, four, 0)
+    jax.lax.fori_loop(rows // 4 * 4, rows, add, 0)
+
+
+@functools.partial(jax.jit, static_argnames=('n', 'tr', 'tl', 'interpret'))
+def _row_sum_call(tok, live, y, *, n, tr, tl, interpret):
+    """y [chunk, L] float32 summed by ``tok`` [chunk] into [n, L] over
+    the rows below ``live`` [1]."""
+    chunk, L = y.shape
+    return pl.pallas_call(
+        functools.partial(_row_sum_kernel, tr=tr),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(L // tl, chunk // tr),
+            in_specs=[pl.BlockSpec((tr, tl), lambda j, r, tok, live:
+                                   (_src_tile(r, live[0], tr), j))],
+            out_specs=pl.BlockSpec((n, tl), lambda j, r, tok, live: (0, j),
+                                   pipeline_mode=pl.Buffered(1)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, L), jnp.float32),
+        name='_row_sum_kernel',
+        compiler_params=_GROUPED_COMPILER_PARAMS,
+        interpret=interpret,
+    )(tok, live, y)
+
+
+# From a sweep on one v5e chip (PERF.md section 6; 8448 rows of
+# 2048 into 8192 tokens, 4096 live: XLA's scatter-add 0.955 ms): rows in
+# blocks of 128, 256 or 768 read alike (256 divides both cells'
+# chunks); the time is one loop iteration a live row and column block,
+# so the widest column block wins (one row an iteration: 256 lanes 0.46
+# ms, 512 lanes 0.29, 1024 lanes 0.23 but at 64 MB of VMEM); four rows
+# an iteration read 0.227 at 512 lanes. The [N, block] result, kept in
+# one buffer, stays under 24 MB of the grouped products' 32 MB limit.
+_ROW_SUM_BLOCK_ROWS = 256
+_ROW_SUM_BLOCK_BYTES = 24 * 1024 * 1024
+
+
+def row_sum_plan(y, n, interpret=None):
+    """THE engagement decision for summing rows y [chunk, L] into ``n``
+    tokens: ``(row block, column block, interpret)`` for the Pallas
+    kernel, or None where XLA's scatter-add runs. The kernel takes
+    float32 rows on a TPU backend (the interpreter, in tests, too),
+    ``L`` in whole 128-lane tiles and a chunk of whole row blocks; the
+    column block is the widest whose [n, block] result stays under
+    _ROW_SUM_BLOCK_BYTES of VMEM."""
+    chunk, L = y.shape
+    if not (interpret or _on_tpu()) or y.dtype != jnp.float32:
+        return None
+    room = _ROW_SUM_BLOCK_BYTES // (n * 4)
+    if L % 128 or chunk % _ROW_SUM_BLOCK_ROWS or room < 128:
+        return None
+    return _ROW_SUM_BLOCK_ROWS, _pick_div(L, room, 128), interpret or False
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def row_sum(y, tok, live, n, plan):
+    """out[t] = sum of y[r] over the rows r < ``live`` whose token
+    ``tok[r]`` is t, in row order, zero for a token no row names: y
+    [chunk, L] float32, tok [chunk] int32, live a scalar int32, ``plan``
+    row_sum_plan's. Its gradient in y is the row gather of the
+    cotangent, zero past ``live``."""
+    tr, tl, interpret = plan
+    return _row_sum_call(tok, jnp.reshape(live, (1,)).astype(jnp.int32), y,
+                         n=n, tr=tr, tl=tl, interpret=interpret)
+
+
+def _row_sum_fwd(y, tok, live, n, plan):
+    return row_sum(y, tok, live, n, plan), (tok, live)
+
+
+def _row_sum_bwd(n, plan, res, g):
+    tok, live = res
+    rows = jnp.take(g, jnp.clip(tok, 0, n - 1), axis=0)
+    return jnp.where(_rows_below(tok.shape[0], live), rows, 0.0), None, None
+
+
+row_sum.defvjp(_row_sum_fwd, _row_sum_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def row_pick(u, tok, live, n, plan):
+    """u [n, L] float32 picked by ``tok`` [chunk]: row r is u[tok[r]]
+    below ``live``, a zero row past it. Its gradient in u is row_sum of
+    the cotangent, which reads no row past ``live``."""
+    rows = jnp.take(u, jnp.clip(tok, 0, n - 1), axis=0)
+    return jnp.where(_rows_below(tok.shape[0], live), rows, 0.0)
+
+
+def _row_pick_fwd(u, tok, live, n, plan):
+    return row_pick(u, tok, live, n, plan), (tok, live)
+
+
+def _row_pick_bwd(n, plan, res, g):
+    tok, live = res
+    return row_sum(g, tok, live, n, plan), None, None
+
+
+row_pick.defvjp(_row_pick_fwd, _row_pick_bwd)

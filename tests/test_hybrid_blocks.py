@@ -234,14 +234,19 @@ def _routed_case(cfg, bias=None, seed=3):
 def route(request, monkeypatch):
     """The grouped products' route: 'ragged_dot' as the CPU takes it,
     or 'pallas' forced through the interpreter (the rule itself says
-    no on this backend). Returns what the configuration needs for the
-    rule's shape side to hold: latent and expert widths of whole
-    128-lane tiles, a chunk of whole row tiles."""
+    no on this backend), the rows then summed back into their tokens
+    by the Pallas kernel too (interpreted, in row blocks of a row
+    tile). Returns what the configuration needs for the rules' shape
+    side to hold: latent and expert widths of whole 128-lane tiles, a
+    chunk of whole row tiles."""
     if request.param == 'ragged_dot':
         return {}
-    plan = pk.grouped_plan
+    plan, summed = pk.grouped_plan, pk.row_sum_plan
     monkeypatch.setattr(pk, 'grouped_plan', lambda rows, w, interpret=None:
                         plan(rows, w, True))
+    monkeypatch.setattr(pk, 'row_sum_plan', lambda y, n, interpret=None:
+                        summed(y, n, True))
+    monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', pk._GROUPED_ROW_TILE)
     monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', pk._GROUPED_ROW_TILE)
     return {'moe_latent_size': 128, 'moe_intermediate_size': 256}
 
@@ -493,23 +498,31 @@ def test_lowerings_are_counted():
                      {('xla', '16'): 1}, {('2',): 1}]
 
 
-@pytest.mark.parametrize('backend,amp_on,widths,route', [
-    ('tpu', True, (128, 256), 'pallas'),
-    ('tpu', False, (128, 256), 'ragged_dot'),     # float32 operands
-    ('tpu', True, (12, 20), 'ragged_dot'),        # not whole lane tiles
-    ('cpu', True, (128, 256), 'ragged_dot'),
-], ids=['chip-amp', 'chip-f32', 'chip-odd-widths', 'cpu-amp'])
+@pytest.mark.parametrize('backend,amp_on,widths,route,row_sum', [
+    ('tpu', True, (128, 256), 'pallas', 'pallas'),
+    ('tpu', False, (128, 256), 'ragged_dot', 'pallas'),  # float32 operands
+    ('tpu', True, (12, 20), 'ragged_dot', 'xla'),   # not whole lane tiles
+    ('cpu', True, (128, 256), 'ragged_dot', 'xla'),
+    ('tpu', True, (128, 256), 'pallas', 'xla'),     # not whole row blocks
+], ids=['chip-amp', 'chip-f32', 'chip-odd-widths', 'cpu-amp',
+        'chip-odd-chunk'])
 def test_moe_counter_names_the_route_on_each_side_of_the_rule(
-        backend, amp_on, widths, route, amp, monkeypatch):
-    """``moe_lowerings_total{route=}`` says which grouped product a
-    lowering took: 'pallas' on a TPU backend with bf16 operands (AMP)
-    and widths of whole 128-lane tiles, 'ragged_dot' on every other
-    side of that rule. Lowered, not run: the backend is only said to be
-    a TPU."""
-    from paddle_tpu.compiler.passes import moe_counts
+        backend, amp_on, widths, route, row_sum, amp, request, monkeypatch):
+    """``moe_lowerings_total{route=, row_sum=}`` says which grouped
+    product a lowering took: 'pallas' on a TPU backend with bf16
+    operands (AMP) and widths of whole 128-lane tiles, 'ragged_dot' on
+    every other side of that rule; and how the rows were summed back
+    into their tokens: 'pallas' on a TPU backend where the width is
+    whole 128-lane tiles and the chunk whole row blocks (its rows are
+    float32 with AMP or without), 'xla' on every other side.
+    moe_row_sum_counts() reads the first. Lowered, not run: the backend
+    is only said to be a TPU."""
+    from paddle_tpu.compiler.passes import moe_counts, moe_row_sum_counts
     amp.set_amp(amp_on)
     monkeypatch.setattr(pk, '_on_tpu', lambda: backend == 'tpu')
     monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', pk._GROUPED_ROW_TILE)
+    if request.node.callspec.id == 'chip-odd-chunk':
+        monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', 512)
     # what the rule engages is lowered for this backend's interpreter
     kernels, ragged_dot, calls = pk._grouped, jax.lax.ragged_dot, []
     monkeypatch.setattr(pk, '_grouped', lambda rows, w, visits, tm, _:
@@ -517,6 +530,10 @@ def test_moe_counter_names_the_route_on_each_side_of_the_rule(
                         or kernels(rows, w, visits, tm, True))
     monkeypatch.setattr(jax.lax, 'ragged_dot', lambda *a, **kw:
                         calls.append('ragged_dot') or ragged_dot(*a, **kw))
+    summed, row_sum_call = [], pk._row_sum_call
+    monkeypatch.setattr(pk, '_row_sum_call', lambda *a, **kw:
+                        summed.append('pallas')
+                        or row_sum_call(*a, **{**kw, 'interpret': True}))
     cfg = tiny_cfg(experts_first=8, n_routed_experts=8,
                    hybrid_override_pattern='E', num_hidden_layers=1,
                    moe_latent_size=widths[0],
@@ -526,15 +543,19 @@ def test_moe_counter_names_the_route_on_each_side_of_the_rule(
     batch = {k: np.asarray(v) for k, v in nemotron_h.draw_batch(
         cfg, TRAFFIC, jax.random.PRNGKey(0)).items()}
     exe = fluid.Executor(fluid.CPUPlace())
+    by = ('experts', 'held', 'top_k', 'route', 'row_sum')
     with fluid.scope_guard(fluid.Scope()):
         exe.run(built['startup'])
-        before = moe_counts()
+        before = moe_counts(), moe_counts(by), moe_row_sum_counts()
         exe.lowered(built['main'], feed=batch, fetch_list=[built['loss']])
-        after = moe_counts()
-    moved = {k: n - before.get(k, 0) for k, n in after.items()
-             if n != before.get(k, 0)}
-    assert moved == {('16', '8', '3', route): 1}
+        after = moe_counts(), moe_counts(by), moe_row_sum_counts()
+    moved = [{k: n - was.get(k, 0) for k, n in now.items()
+              if n != was.get(k, 0)} for was, now in zip(before, after)]
+    assert moved == [{('16', '8', '3', route): 1},
+                     {('16', '8', '3', route, row_sum): 1},
+                     {('pallas',): 1} if row_sum == 'pallas' else {}]
     assert calls and set(calls) == {route}
+    assert bool(summed) == (row_sum == 'pallas')
 
 
 def test_amp_keeps_scores_and_the_carried_state_float32(amp):
@@ -763,50 +784,171 @@ def test_gated_experts_drop_no_token_when_two_take_them_all(
 
 
 def _move_rows(p, x, transpose=False):
-    """The placement PR 31-32 ran, kept here as what the linear one is
-    held to: ``p @ x`` (``p.T @ x``) for a 0/1 matrix with at most one 1
-    a row."""
+    """The placement by 0/1 matrices the op first ran, kept here as what
+    the linear one is held to: ``p @ x`` (``p.T @ x``) for a 0/1 matrix
+    with at most one 1 a row."""
     spec = 'rn,rl->nl' if transpose else 'rn,nl->rl'
     return jnp.einsum(spec, p.astype(x.dtype), x,
                       precision=jax.lax.Precision.HIGHEST)
 
 
-@pytest.mark.parametrize('c', [0, 1])
-def test_rows_are_placed_as_the_pick_matrix_places_them(c):
-    """_place_rows against _move_rows on the same operands: the rows
-    picked, their weights, and what summing rows back gives, in the
-    first chunk and in one that a skewed routing overflows into; a row
-    past the routed pairs is a zero row with weight 0."""
-    rng = np.random.RandomState(0)
-    n, held, width, chunk = 74, 4, 24, 64
-    chosen = jnp.asarray(rng.rand(held, n) < (0.33 if c else 0.15))
+@pytest.fixture
+def row_sum(request, monkeypatch):
+    """The route that sums a chunk's rows back into their tokens: 'xla'
+    (the scatter-add, as the CPU takes it) or 'pallas' forced through
+    the interpreter, which fills what the kernel has not written with
+    NaN, in row blocks of 16 rows (the rule itself says no on this
+    backend). Returns the route and the list of the kernel's calls."""
+    calls = []
+    if request.param == 'pallas':
+        from jax.experimental.pallas import tpu as pltpu
+        plan, kernel = pk.row_sum_plan, pk._row_sum_call
+        nan_filled = pltpu.InterpretParams(uninitialized_memory='nan')
+        monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', 16)
+        monkeypatch.setattr(pk, 'row_sum_plan', lambda y, n, interpret=None:
+                            plan(y, n, nan_filled))
+        monkeypatch.setattr(pk, '_row_sum_call', lambda *a, **kw:
+                            calls.append(kw) or kernel(*a, **kw))
+    return request.param, calls
+
+
+_PLACED = {'first': (0, 0.15), 'overflow': (1, 0.33),
+           'no-live-row': (1, 0.15), 'every-row-live': (0, 0.33)}
+
+
+def _placement(case, width=128, seed=0):
+    """Four held experts over 74 tokens in chunks of 64 rows: chunk ``c``
+    of a routing that chooses a pair with probability ``p``; the pairs'
+    places, rows, weights and tokens u [74, width]."""
+    c, p = _PLACED[case]
+    rng = np.random.RandomState(seed)
+    n, held, chunk = 74, 4, 64
+    chosen = jnp.asarray(rng.rand(held, n) < p)
     counts = jnp.sum(chosen, axis=1, dtype=jnp.int32)
     place = (jnp.cumsum(counts) - counts)[:, None] \
         + jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1
     place = jnp.where(chosen, place, -1)
-    total = int(jnp.sum(counts))
-    assert c * chunk < total and (c or total <= chunk)
     u = jnp.asarray(rng.randn(n, width).astype('float32'))
     weight = jnp.where(chosen, jnp.asarray(
         rng.rand(held, n).astype('float32')), 0.0)
     rows = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+    order = hybrid_ops.pairs_in_row_order(place, chunk)
+    live = int(np.clip(int(jnp.sum(counts)) - c * chunk, 0, chunk))
+    return dict(u=u, weight=weight, order=order, counts=counts, rows=rows,
+                place=place, live=live, chunk=chunk)
+
+
+@pytest.mark.parametrize('row_sum', ['xla', 'pallas'], indirect=True)
+@pytest.mark.parametrize('c', [0, 1])
+def test_rows_are_placed_as_the_pick_matrix_places_them(c, row_sum):
+    """_place_rows against _move_rows on the same operands: the rows
+    picked, their weights, and what summing rows back gives, in the
+    first chunk and in one that a skewed routing overflows into, on
+    either route of the sum; a row past the routed pairs is a zero row
+    with weight 0, and tokens several held experts name get all their
+    rows."""
+    route, calls = row_sum
+    k = _placement('overflow' if c else 'first')
+    place, rows, u, weight = k['place'], k['rows'], k['u'], k['weight']
+    total = int(jnp.sum(k['counts']))
+    assert c * k['chunk'] < total and (c or total <= k['chunk'])
     xs, row_w, back = hybrid_ops._place_rows(
-        u, weight, hybrid_ops.pairs_in_row_order(place, chunk), counts, rows)
+        u, weight, k['order'], k['counts'], rows)
     # pick[r, n]: token n's pair lies in row r
     pick = jnp.any(place[:, None, :] == rows[None, :, None], axis=0)
+    assert int(jnp.max(jnp.sum(pick, axis=0))) > 1     # shared tokens
     np.testing.assert_array_equal(np.asarray(xs),
                                   np.asarray(_move_rows(pick, u)))
     want_w = jnp.sum(jnp.where(
         place[:, None, :] == rows[None, :, None], weight[:, None, :], 0.0),
         axis=(0, 2))
     np.testing.assert_array_equal(np.asarray(row_w), np.asarray(want_w))
-    y = jnp.asarray(rng.randn(chunk, 40).astype('float32'))
+    y = jnp.asarray(np.random.RandomState(1).randn(
+        k['chunk'], u.shape[1]).astype('float32'))
     np.testing.assert_allclose(
         np.asarray(back(y)), np.asarray(_move_rows(pick, y, transpose=True)),
         rtol=1e-6, atol=1e-6)
     dead = np.asarray(rows) >= total
     assert dead.any() and not np.asarray(xs)[dead].any() \
         and not np.asarray(row_w)[dead].any()
+    assert len(calls) == (route == 'pallas')
+
+
+def _place_and_sum(k, u, y, xla=False):
+    """(rows picked, rows summed back) of _place_rows on ``u`` and ``y``;
+    with ``xla`` the XLA forms the CPU route runs, written out."""
+    xs, _, back = hybrid_ops._place_rows(u, k['weight'], k['order'],
+                                         k['counts'], k['rows'])
+    if not xla:
+        return xs, back(y)
+    n = u.shape[0]
+    ids = k['order'][k['rows'][0]:k['rows'][0] + k['chunk']]
+    tok = jnp.where(k['rows'] < jnp.sum(k['counts']), ids % n, n)
+    return (jnp.take(u, tok, axis=0, mode='fill', fill_value=0),
+            jnp.zeros_like(u).at[tok].add(y, mode='drop'))
+
+
+@pytest.mark.parametrize('row_sum', ['xla', 'pallas'], indirect=True)
+@pytest.mark.parametrize('case', sorted(_PLACED))
+def test_rows_past_the_live_ones_are_ignored_both_ways(case, row_sum):
+    """The rows past a chunk's live ones hold NaN, in the rows summed
+    back and in the cotangent of the rows picked: the sum, the gradient
+    of the sum in its rows and the gradient of the pick in the tokens
+    are what they are on clean rows, with exact zeros past the live
+    rows; with no live row (a chunk a balanced routing leaves empty) and
+    with every row live (a skewed one fills it) alike."""
+    route, calls = row_sum
+    k = _placement(case)
+    live, chunk, u = k['live'], k['chunk'], k['u']
+    assert live == {'no-live-row': 0, 'every-row-live': chunk}.get(
+        case, live) and (case in ('no-live-row', 'every-row-live')
+                         or 0 < live < chunk)
+    rng = np.random.RandomState(2)
+    y = rng.randn(chunk, u.shape[1]).astype('float32')
+    g_out = jnp.asarray(rng.randn(*u.shape).astype('float32'))
+    g_rows = rng.randn(chunk, u.shape[1]).astype('float32')
+    clean = [jnp.asarray(t) for t in (y, g_rows)]
+    y[live:], g_rows[live:] = np.nan, np.nan
+
+    def run(y, g_rows):
+        (xs, out), vjp = jax.vjp(lambda u, y: _place_and_sum(k, u, y), u, y)
+        d_u, _ = vjp((g_rows, jnp.zeros_like(out)))
+        _, d_y = vjp((jnp.zeros_like(xs), g_out))
+        return [np.asarray(t) for t in (out, d_y, d_u)]
+    got, want = run(jnp.asarray(y), jnp.asarray(g_rows)), run(*clean)
+    for name, a, b in zip(('sum', 'd_rows', 'd_tokens'), got, want):
+        assert np.isfinite(a).all(), name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    assert not got[1][live:].any()
+    if live == 0:
+        assert not got[0].any() and not got[2].any()
+    assert bool(calls) == (route == 'pallas')
+
+
+@pytest.mark.parametrize('case', sorted(_PLACED))
+def test_the_kernels_gradients_are_the_xla_forms_transposes(
+        case, monkeypatch):
+    """row_pick and row_sum (the Pallas route, interpreted) against
+    jax's own transposes of the XLA forms the CPU route runs (a gather
+    with zeros past the live rows, a scatter-add that drops them): the
+    same values forward, the same gradients in the tokens and in the
+    rows."""
+    from jax.experimental.pallas import tpu as pltpu
+    plan = pk.row_sum_plan
+    monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', 16)
+    monkeypatch.setattr(pk, 'row_sum_plan', lambda y, n, interpret=None:
+                        plan(y, n, pltpu.InterpretParams(
+                            uninitialized_memory='nan')))
+    k = _placement(case)
+    rng = np.random.RandomState(3)
+    y = jnp.asarray(rng.randn(k['chunk'], 128).astype('float32'))
+    cts = (jnp.asarray(rng.randn(k['chunk'], 128).astype('float32')),
+           jnp.asarray(rng.randn(74, 128).astype('float32')))
+    got = jax.vjp(lambda u, y: _place_and_sum(k, u, y), k['u'], y)
+    want = jax.vjp(lambda u, y: _place_and_sum(k, u, y, xla=True), k['u'], y)
+    for a, b in zip(got[0] + got[1](cts), want[0] + want[1](cts)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
 
 
 # ---- the shares add up to the uncut layer -----------------------------------

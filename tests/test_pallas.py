@@ -1355,3 +1355,56 @@ def test_grouped_plan_engages_by_backend_dtype_and_shape(
     got = pk.grouped_plan(jax.ShapeDtypeStruct((rows, K), dtype),
                           jax.ShapeDtypeStruct((8, K, N), dtype), interpret)
     assert got == plan
+
+
+# ---- rows summed back into their tokens (routed experts) -------------------
+@pytest.mark.parametrize('dtype,L,chunk,n,backend,interpret,plan', [
+    ('float32', 2048, 8448, 8192, 'tpu', None, (256, 512, False)),
+    ('float32', 1024, 3072, 4096, 'tpu', None, (256, 1024, False)),
+    ('float32', 2048, 8448, 8192, 'cpu', None, None),
+    ('bfloat16', 2048, 8448, 8192, 'tpu', None, None),
+    ('float32', 24, 256, 74, 'tpu', None, None),
+    ('float32', 2048, 8320, 8192, 'tpu', None, None),
+    ('float32', 128, 256, 50000, 'tpu', None, None),
+    ('float32', 128, 256, 74, 'cpu', True, (256, 128, True)),
+], ids=['trinity', 'nemotron', 'cpu', 'bf16-rows', 'odd-width',
+        'odd-chunk', 'too-many-tokens', 'interpreter'])
+def test_row_sum_plan_engages_by_backend_dtype_and_shape(
+        dtype, L, chunk, n, backend, interpret, plan, monkeypatch):
+    """The kernel takes float32 rows on a TPU backend, a width of whole
+    128-lane tiles and a chunk of whole row blocks; its column block is
+    the widest whose [n, block] result stays under the VMEM budget (512
+    of 2048 lanes at 8192 tokens, all 1024 at 4096), and with none of
+    128 lanes under it XLA's scatter-add runs."""
+    monkeypatch.setattr(pk, '_on_tpu', lambda: backend == 'tpu')
+    got = pk.row_sum_plan(jax.ShapeDtypeStruct((chunk, L), dtype), n,
+                          interpret)
+    assert got == plan
+
+
+@pytest.mark.parametrize('live', [0, 1, 15, 16, 17, 63, 64])
+def test_row_sum_adds_the_live_rows_in_row_order(live, monkeypatch):
+    """The kernel through the interpreter that fills what it has not
+    written with NaN, in row blocks of 16: the rows below ``live`` added
+    into their tokens one by one in row order, so a float32 loop in the
+    same order reads the same bits, also where one token takes two rows
+    in a row and where several held experts name one token; a token no
+    row names reads zero, and the rows past ``live`` (NaN, tokens out of
+    range) are never read."""
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', 16)
+    rng = np.random.RandomState(live)
+    chunk, L, n = 64, 256, 40
+    tok = rng.randint(0, n, chunk).astype(np.int32)
+    tok[1::7] = tok[0::7][:len(tok[1::7])]         # a token twice in a row
+    y = rng.randn(chunk, L).astype(np.float32)
+    want = np.zeros((n, L), np.float32)
+    for r in range(live):
+        want[tok[r]] += y[r]
+    tok[live:], y[live:] = 10 ** 6, np.nan
+    plan = pk.row_sum_plan(jax.ShapeDtypeStruct((chunk, L), jnp.float32), n,
+                           pltpu.InterpretParams(uninitialized_memory='nan'))
+    got = np.asarray(pk.row_sum(jnp.asarray(y), jnp.asarray(tok),
+                                jnp.int32(live), n, plan))
+    np.testing.assert_array_equal(got, want)
+    assert not got[np.setdiff1d(np.arange(n), tok[:live])].any()
