@@ -899,7 +899,9 @@ def moe_counts(by=('experts', 'held', 'top_k', 'route')):
     (``lax.ragged_dot``: everything else, the CPU tests among it).
     ops/pallas_kernels.py::grouped_plan is the rule. 'row_sum' names
     how a chunk's rows are summed back into their tokens: 'pallas' or
-    'xla' (moe_row_sum_counts)."""
+    'xla' (moe_row_sum_counts); 'row_gather' how that sum's transpose
+    gathers the rows' gradients back, the same way
+    (moe_row_gather_counts)."""
     return _label_counts('moe_lowerings_total', by)
 
 
@@ -910,6 +912,17 @@ def moe_row_sum_counts():
     rows (ops/pallas_kernels.py::row_sum_plan is the rule); a lowering
     that kept XLA's scatter-add is not in it."""
     return {k: n for k, n in moe_counts(by=('row_sum',)).items()
+            if k == ('pallas',)}
+
+
+def moe_row_gather_counts():
+    """``{('pallas',): n}``: the routed_experts lowerings whose sum back
+    is transposed (the rows' gradients gathered from the output's) by
+    the Pallas kernel that copies only a chunk's live rows
+    (ops/pallas_kernels.py::row_gather_plan is the rule); a lowering
+    that kept XLA's ``jnp.take`` is not in it. The forward's row pick
+    is ``jnp.take`` on every route."""
+    return {k: n for k, n in moe_counts(by=('row_gather',)).items()
             if k == ('pallas',)}
 
 

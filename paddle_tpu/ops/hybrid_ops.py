@@ -266,9 +266,16 @@ def _place_rows(u, weight, order, counts, rows):
     with the width L in whole 128-lane tiles, the Pallas kernel that
     adds only the chunk's live rows into a VMEM-resident column block
     of the result, a token's rows in row (expert) order
-    (pallas_kernels.row_sum, row_pick: their gradients are the row
-    gather and the kernel); anywhere else (the CPU, odd widths) XLA's
-    scatter-add and autodiff's transposes of it and of the gather.
+    (pallas_kernels.row_sum, row_pick); anywhere else (the CPU, odd
+    widths) XLA's scatter-add and autodiff's transposes of it and of
+    the gather. On the kernel's route the sum's transpose, a gather of
+    float32 rows, takes the Pallas kernel that copies only the live
+    rows from a VMEM-resident column block of the tokens where L is at
+    least 2048 lanes (pallas_kernels.row_gather_plan), ``jnp.take``
+    where it is narrower; the pick stays ``jnp.take``, which XLA turns
+    into a gather of the bf16 copy of u the products read (rounding
+    commutes with the pick), faster than the kernel at both shapes the
+    benchmark runs (PERF.md section 6, PR 42).
 
     Why not a 0/1 [chunk, N] matrix on the MXU, which moves rows
     without a gather: its cost is the product of rows and tokens.
@@ -280,7 +287,8 @@ def _place_rows(u, weight, order, counts, rows):
     11.40 this way (the gather 0.23-0.34, XLA's scatter-add 1.59); 3072
     rows of 4096 tokens, 1024 wide 1.76 / 2.83 and 1.52 / 2.47: this
     way is faster at both shapes the benchmark runs."""
-    from .pallas_kernels import row_pick, row_sum, row_sum_plan
+    from .pallas_kernels import (row_gather_plan, row_pick, row_sum,
+                                 row_sum_plan)
     n, chunk = u.shape[0], rows.shape[0]
     ids = lax.dynamic_slice(order, (rows[0],), (chunk,))
     pairs = jnp.sum(counts)
@@ -288,8 +296,8 @@ def _place_rows(u, weight, order, counts, rows):
     tok = jnp.where(live, ids % n, n)
     row_w = jnp.where(live, jnp.take(weight.reshape(-1),
                                      jnp.where(live, ids, 0)), 0.0)
-    plan = row_sum_plan(jax.ShapeDtypeStruct((chunk, u.shape[1]),
-                                             jnp.float32), n)
+    f32_rows = jax.ShapeDtypeStruct((chunk, u.shape[1]), jnp.float32)
+    plan = row_sum_plan(f32_rows, n)
     if plan is None:
         xs = jnp.take(f32(u), tok, axis=0, mode='fill', fill_value=0)
 
@@ -297,11 +305,12 @@ def _place_rows(u, weight, order, counts, rows):
             return jnp.zeros((n, y.shape[1]), jnp.float32).at[tok].add(
                 y, mode='drop')
     else:
+        gather = row_gather_plan(f32_rows, n)
         n_live = jnp.clip(pairs - rows[0], 0, chunk)
-        xs = row_pick(f32(u), tok, n_live, n, plan)
+        xs = row_pick(f32(u), tok, n_live, n, plan, gather)
 
         def back(y):
-            return row_sum(y, tok, n_live, n, plan)
+            return row_sum(y, tok, n_live, n, plan, gather)
     return xs.astype(mxu_operand(u).dtype), row_w, back
 
 
@@ -427,7 +436,9 @@ def _routed_experts(ctx):
     sum back (and the pick's transpose) takes the Pallas kernel that
     adds only a chunk's live rows on the TPU where L is whole 128-lane
     tiles, XLA's scatter-add anywhere else (pallas_kernels.row_sum_plan
-    is the rule). The grouped
+    is the rule); on that route the sum's transpose, a row gather,
+    takes the kernel that copies only the live rows where L is at least
+    2048 lanes (pallas_kernels.row_gather_plan). The grouped
     products take one of two routes, chosen from what the op sees
     (pallas_kernels.grouped_plan): on the TPU, with bf16 operands (AMP)
     and L, F multiples of 128, the Pallas grouped matmul ('pallas');
@@ -438,14 +449,16 @@ def _routed_experts(ctx):
     so XLA's static shapes hold any routing). A chunk's rows past the
     routed pairs belong to no expert: the Pallas products pass over
     their row tiles (pallas_kernels.live_row_tiles counts them from
-    TokensPerExpert), the row moves and the activation still sweep
-    them; the row sum reads none of them. TokensPerExpert [held] is the
-    second output.
+    TokensPerExpert), the row pick and the activation still sweep
+    them; the kernels that sum rows back and gather their gradients
+    read none of them. TokensPerExpert [held] is the second output.
     What the experts held elsewhere would add is left out. Each
     lowering counts once in ``moe_lowerings_total{experts=, held=,
-    top_k=, route=, act=, row_sum=}`` (compiler/passes.py::moe_counts;
-    ``row_sum`` 'pallas' or 'xla': moe_row_sum_counts)."""
-    from .pallas_kernels import grouped_plan, row_sum_plan
+    top_k=, route=, act=, row_sum=, row_gather=}``
+    (compiler/passes.py::moe_counts; ``row_sum`` 'pallas' or 'xla':
+    moe_row_sum_counts; ``row_gather`` the same for the sum's transpose,
+    pallas_kernels.row_gather_plan the rule: moe_row_gather_counts)."""
+    from .pallas_kernels import grouped_plan, row_gather_plan, row_sum_plan
     x_in = unwrap(ctx.input('X'))
     E, K = int(ctx.attr('num_experts')), int(ctx.attr('top_k'))
     first, held = int(ctx.attr('first_expert', 0)), int(ctx.attr('held'))
@@ -469,17 +482,22 @@ def _routed_experts(ctx):
         jax.ShapeDtypeStruct((chunk, L), mxu_operand(u).dtype),
         jax.ShapeDtypeStruct(w1.shape, mxu_operand(w1).dtype))
     summed = row_sum_plan(jax.ShapeDtypeStruct((chunk, L), jnp.float32), N)
+    gathered = row_gather_plan(jax.ShapeDtypeStruct((chunk, L), jnp.float32),
+                               N)
     _obs.default_registry().counter(
         'moe_lowerings_total',
         help='routed_experts op lowerings, by the experts routed over, '
              'the experts held here, the experts a token takes, the '
              'grouped product (pallas: the Pallas grouped matmul; '
              'ragged_dot: lax.ragged_dot), the expert\'s activation '
-             '(relu2 / swiglu) and the sum of rows back into their '
-             'tokens (pallas: the live-row kernel; xla: a scatter-add)',
+             '(relu2 / swiglu), the sum of rows back into their '
+             'tokens (pallas: the live-row kernel; xla: a scatter-add) '
+             'and the gather of rows that transposes that sum (pallas: '
+             'the live-row kernel; xla: jnp.take)',
         experts=str(E), held=str(held), top_k=str(K),
         route='ragged_dot' if plan is None else 'pallas', act=act,
-        row_sum='xla' if summed is None else 'pallas').inc()
+        row_sum='xla' if summed is None else 'pallas',
+        row_gather='xla' if gathered is None else 'pallas').inc()
     counts = jnp.sum(chosen, axis=1, dtype=jnp.int32)       # [held]
     place = (jnp.cumsum(counts) - counts)[:, None] \
         + jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1

@@ -1730,16 +1730,19 @@ def grouped_matmul(rows, w, sizes, interpret=None):
     return _grouped(rows, w, visits, tm, interpret)
 
 
-# ---- rows summed back into their tokens (routed experts) ------------------------
-# A chunk of the routed experts' rows [chunk, L] float32 lies expert by
-# expert, one (token, expert) pair a row; its first ``live`` rows are
-# routed pairs, the rows past them belong to nobody. The sum of the live
+# ---- rows summed into their tokens and picked from them (routed experts) --
+# A chunk of the routed experts' rows [chunk, L] lies expert by expert,
+# one (token, expert) pair a row; its first ``live`` rows are routed
+# pairs, the rows past them belong to nobody. The sum of the live
 # rows into their tokens [N, L] is the forward's combine and the
-# transpose of the row pick. XLA's scatter-add does it at about a
-# seventh of HBM's bandwidth (PERF.md section 6) and sweeps the
-# dead rows too; the kernel below keeps a column block of the result in
-# VMEM, adds the live rows into it one by one and fetches no row past
-# them.
+# transpose of the row pick; the gather of the live rows from their
+# tokens is the pick and the transpose of the combine. XLA's
+# scatter-add runs at about a seventh of HBM's bandwidth, and its
+# float32 row gather and mask at about half the kernel's rate at 2048
+# lanes, both over the dead rows too (PERF.md section 6); the kernels
+# below keep a column block of the [N, L] side in VMEM and move the
+# live rows into or out of it one by one, touching no row past them.
+# The pick itself stays XLA's gather (row_pick).
 
 def _row_sum_kernel(tok_ref, live_ref, y_ref, o_ref, *, tr):
     # the [N, tl] column block stays in VMEM over the row blocks: zeroed
@@ -1812,7 +1815,9 @@ def row_sum_plan(y, n, interpret=None):
     float32 rows on a TPU backend (the interpreter, in tests, too),
     ``L`` in whole 128-lane tiles and a chunk of whole row blocks; the
     column block is the widest whose [n, block] result stays under
-    _ROW_SUM_BLOCK_BYTES of VMEM."""
+    _ROW_SUM_BLOCK_BYTES of VMEM. The row gather's kernel holds an
+    [n, block] column block of the tokens the same way and takes this
+    rule where its rows are wide enough (row_gather_plan)."""
     chunk, L = y.shape
     if not (interpret or _on_tpu()) or y.dtype != jnp.float32:
         return None
@@ -1822,47 +1827,135 @@ def row_sum_plan(y, n, interpret=None):
     return _ROW_SUM_BLOCK_ROWS, _pick_div(L, room, 128), interpret or False
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def row_sum(y, tok, live, n, plan):
+def _row_gather_kernel(tok_ref, live_ref, src_ref, o_ref, *, tr):
+    # the [N, tl] column block of the tokens stays in VMEM over the row
+    # blocks (its index map moves with the column block alone). A row
+    # block copies its rows below ``live`` from their tokens' rows, four
+    # an iteration (a dynamic sublane read and write of a float32 row),
+    # then zeroes its rows from ``live`` on: a block past the live rows
+    # copies nothing and writes its zeros
+    r = pl.program_id(1)
+    base = r * tr
+    rows = jnp.clip(live_ref[0] - base, 0, tr)
+
+    def copy(i, carry):
+        t = tok_ref[base + i]
+        o_ref[pl.ds(i, 1), :] = src_ref[pl.ds(t, 1), :]
+        return carry
+
+    def four(k, carry):
+        for q in range(4):
+            copy(4 * k + q, carry)
+        return carry
+
+    jax.lax.fori_loop(0, rows // 4, four, 0)
+    jax.lax.fori_loop(rows // 4 * 4, rows, copy, 0)
+    kept = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0) < rows
+    o_ref[...] = jnp.where(kept, o_ref[...], 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=('tr', 'tl', 'interpret'))
+def _row_gather_call(tok, live, src, *, tr, tl, interpret):
+    """src [n, L] float32 picked by ``tok`` [chunk] into [chunk, L], zero
+    rows from ``live`` [1] on."""
+    n, L = src.shape
+    chunk = tok.shape[0]
+    return pl.pallas_call(
+        functools.partial(_row_gather_kernel, tr=tr),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(L // tl, chunk // tr),
+            in_specs=[pl.BlockSpec((n, tl), lambda j, r, tok, live: (0, j),
+                                   pipeline_mode=pl.Buffered(1))],
+            out_specs=pl.BlockSpec((tr, tl), lambda j, r, tok, live: (r, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((chunk, L), jnp.float32),
+        name='_row_gather_kernel',
+        compiler_params=_GROUPED_COMPILER_PARAMS,
+        interpret=interpret,
+    )(tok, live, src)
+
+
+# From a sweep on one v5e chip (PERF.md section 6, PR 42; device ms a
+# call, float32 rows at the balanced load): 8448 rows of 2048 from 8192
+# tokens, jnp.take and the live mask 0.399, the kernel 0.224; 3072 rows
+# of 1024 from 4096 tokens, 0.035 and 0.042. The kernel reads every token
+# row of its column blocks; XLA's gather and mask pass cost about twice
+# the kernel's bytes at 2048 lanes and run near HBM's bandwidth at 1024.
+_ROW_GATHER_MIN_LANES = 2048
+
+
+def row_gather_plan(rows, n, interpret=None):
+    """THE engagement decision for gathering float32 rows [chunk, L] from
+    ``n`` float32 token rows (the transpose of the sum back):
+    row_sum_plan's ``(row block, column block, interpret)`` for the
+    Pallas kernel, whose [n, block] column block of the tokens it holds
+    in VMEM as row_sum's holds its result, where ``L`` is at least
+    _ROW_GATHER_MIN_LANES; None where XLA's ``jnp.take`` runs (narrower
+    rows, where it is the faster, and everything row_sum_plan
+    declines)."""
+    if rows.shape[1] < _ROW_GATHER_MIN_LANES:
+        return None
+    return row_sum_plan(rows, n, interpret)
+
+
+def row_gather(src, tok, live, n, plan):
+    """rows[r] = src[tok[r]] for r < ``live``, a zero row past it: src
+    [n, L] float32, tok [chunk] int32, live a scalar int32; by the
+    kernel, which reads no row past ``live``, where ``plan``
+    (row_gather_plan's) is given, by ``jnp.take`` of every row where it
+    is None."""
+    if plan is None:
+        rows = jnp.take(src, jnp.clip(tok, 0, n - 1), axis=0)
+        return jnp.where(_rows_below(tok.shape[0], live), rows, 0.0)
+    tr, tl, interpret = plan
+    return _row_gather_call(tok, jnp.reshape(live, (1,)).astype(jnp.int32),
+                            src, tr=tr, tl=tl, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def row_sum(y, tok, live, n, plan, gather):
     """out[t] = sum of y[r] over the rows r < ``live`` whose token
     ``tok[r]`` is t, in row order, zero for a token no row names: y
     [chunk, L] float32, tok [chunk] int32, live a scalar int32, ``plan``
     row_sum_plan's. Its gradient in y is the row gather of the
-    cotangent, zero past ``live``."""
+    cotangent, zero past ``live`` (row_gather by ``gather``,
+    row_gather_plan's or None)."""
     tr, tl, interpret = plan
     return _row_sum_call(tok, jnp.reshape(live, (1,)).astype(jnp.int32), y,
                          n=n, tr=tr, tl=tl, interpret=interpret)
 
 
-def _row_sum_fwd(y, tok, live, n, plan):
-    return row_sum(y, tok, live, n, plan), (tok, live)
+def _row_sum_fwd(y, tok, live, n, plan, gather):
+    return row_sum(y, tok, live, n, plan, gather), (tok, live)
 
 
-def _row_sum_bwd(n, plan, res, g):
+def _row_sum_bwd(n, plan, gather, res, g):
     tok, live = res
-    rows = jnp.take(g, jnp.clip(tok, 0, n - 1), axis=0)
-    return jnp.where(_rows_below(tok.shape[0], live), rows, 0.0), None, None
+    return row_gather(g, tok, live, n, gather), None, None
 
 
 row_sum.defvjp(_row_sum_fwd, _row_sum_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def row_pick(u, tok, live, n, plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def row_pick(u, tok, live, n, plan, gather):
     """u [n, L] float32 picked by ``tok`` [chunk]: row r is u[tok[r]]
-    below ``live``, a zero row past it. Its gradient in u is row_sum of
-    the cotangent, which reads no row past ``live``."""
-    rows = jnp.take(u, jnp.clip(tok, 0, n - 1), axis=0)
-    return jnp.where(_rows_below(tok.shape[0], live), rows, 0.0)
+    below ``live``, a zero row past it, by ``jnp.take`` (rounded to the
+    products' bf16 after it, XLA gathers from the bf16 copy of u: 0.100
+    ms at the trinity shape, where the kernel read 0.178; PERF.md
+    section 6, PR 42). Its gradient in u is row_sum of the cotangent,
+    which reads no row past ``live``."""
+    return row_gather(u, tok, live, n, None)
 
 
-def _row_pick_fwd(u, tok, live, n, plan):
-    return row_pick(u, tok, live, n, plan), (tok, live)
+def _row_pick_fwd(u, tok, live, n, plan, gather):
+    return row_pick(u, tok, live, n, plan, gather), (tok, live)
 
 
-def _row_pick_bwd(n, plan, res, g):
+def _row_pick_bwd(n, plan, gather, res, g):
     tok, live = res
-    return row_sum(g, tok, live, n, plan), None, None
+    return row_sum(g, tok, live, n, plan, gather), None, None
 
 
 row_pick.defvjp(_row_pick_fwd, _row_pick_bwd)

@@ -498,31 +498,39 @@ def test_lowerings_are_counted():
                      {('xla', '16'): 1}, {('2',): 1}]
 
 
-@pytest.mark.parametrize('backend,amp_on,widths,route,row_sum', [
-    ('tpu', True, (128, 256), 'pallas', 'pallas'),
-    ('tpu', False, (128, 256), 'ragged_dot', 'pallas'),  # float32 operands
-    ('tpu', True, (12, 20), 'ragged_dot', 'xla'),   # not whole lane tiles
-    ('cpu', True, (128, 256), 'ragged_dot', 'xla'),
-    ('tpu', True, (128, 256), 'pallas', 'xla'),     # not whole row blocks
+@pytest.mark.parametrize('backend,amp_on,widths,route,row_sum,row_gather', [
+    ('tpu', True, (128, 256), 'pallas', 'pallas', 'xla'),
+    ('tpu', False, (128, 256), 'ragged_dot', 'pallas', 'xla'),  # float32
+    ('tpu', True, (12, 20), 'ragged_dot', 'xla', 'xla'),  # not lane tiles
+    ('cpu', True, (128, 256), 'ragged_dot', 'xla', 'xla'),
+    ('tpu', True, (128, 256), 'pallas', 'xla', 'xla'),  # not row blocks
+    ('tpu', True, (128, 256), 'pallas', 'pallas', 'pallas'),  # wide rows
 ], ids=['chip-amp', 'chip-f32', 'chip-odd-widths', 'cpu-amp',
-        'chip-odd-chunk'])
+        'chip-odd-chunk', 'chip-amp-wide'])
 def test_moe_counter_names_the_route_on_each_side_of_the_rule(
-        backend, amp_on, widths, route, row_sum, amp, request, monkeypatch):
-    """``moe_lowerings_total{route=, row_sum=}`` says which grouped
-    product a lowering took: 'pallas' on a TPU backend with bf16
-    operands (AMP) and widths of whole 128-lane tiles, 'ragged_dot' on
-    every other side of that rule; and how the rows were summed back
+        backend, amp_on, widths, route, row_sum, row_gather, amp, request,
+        monkeypatch):
+    """``moe_lowerings_total{route=, row_sum=, row_gather=}`` says which
+    grouped product a lowering took: 'pallas' on a TPU backend with
+    bf16 operands (AMP) and widths of whole 128-lane tiles, 'ragged_dot'
+    on every other side of that rule; how the rows were summed back
     into their tokens: 'pallas' on a TPU backend where the width is
     whole 128-lane tiles and the chunk whole row blocks (its rows are
-    float32 with AMP or without), 'xla' on every other side.
-    moe_row_sum_counts() reads the first. Lowered, not run: the backend
-    is only said to be a TPU."""
-    from paddle_tpu.compiler.passes import moe_counts, moe_row_sum_counts
+    float32 with AMP or without), 'xla' on every other side; and how the
+    gradients of the rows were gathered from their tokens: by the same
+    rule where the rows are at least _ROW_GATHER_MIN_LANES wide (here
+    set to the tiny layer's 128 in 'chip-amp-wide'), else 'xla'.
+    moe_row_sum_counts() and moe_row_gather_counts() read the 'pallas'
+    ones. Lowered, not run: the backend is only said to be a TPU."""
+    from paddle_tpu.compiler.passes import (
+        moe_counts, moe_row_gather_counts, moe_row_sum_counts)
     amp.set_amp(amp_on)
     monkeypatch.setattr(pk, '_on_tpu', lambda: backend == 'tpu')
     monkeypatch.setattr(hybrid_ops, '_ROW_QUANTUM', pk._GROUPED_ROW_TILE)
     if request.node.callspec.id == 'chip-odd-chunk':
         monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', 512)
+    if request.node.callspec.id == 'chip-amp-wide':
+        monkeypatch.setattr(pk, '_ROW_GATHER_MIN_LANES', 128)
     # what the rule engages is lowered for this backend's interpreter
     kernels, ragged_dot, calls = pk._grouped, jax.lax.ragged_dot, []
     monkeypatch.setattr(pk, '_grouped', lambda rows, w, visits, tm, _:
@@ -534,6 +542,10 @@ def test_moe_counter_names_the_route_on_each_side_of_the_rule(
     monkeypatch.setattr(pk, '_row_sum_call', lambda *a, **kw:
                         summed.append('pallas')
                         or row_sum_call(*a, **{**kw, 'interpret': True}))
+    gathered, row_gather_call = [], pk._row_gather_call
+    monkeypatch.setattr(pk, '_row_gather_call', lambda *a, **kw:
+                        gathered.append('pallas')
+                        or row_gather_call(*a, **{**kw, 'interpret': True}))
     cfg = tiny_cfg(experts_first=8, n_routed_experts=8,
                    hybrid_override_pattern='E', num_hidden_layers=1,
                    moe_latent_size=widths[0],
@@ -543,19 +555,25 @@ def test_moe_counter_names_the_route_on_each_side_of_the_rule(
     batch = {k: np.asarray(v) for k, v in nemotron_h.draw_batch(
         cfg, TRAFFIC, jax.random.PRNGKey(0)).items()}
     exe = fluid.Executor(fluid.CPUPlace())
-    by = ('experts', 'held', 'top_k', 'route', 'row_sum')
+    by = ('experts', 'held', 'top_k', 'route', 'row_sum', 'row_gather')
+
+    def counts():
+        return (moe_counts(), moe_counts(by), moe_row_sum_counts(),
+                moe_row_gather_counts())
     with fluid.scope_guard(fluid.Scope()):
         exe.run(built['startup'])
-        before = moe_counts(), moe_counts(by), moe_row_sum_counts()
+        before = counts()
         exe.lowered(built['main'], feed=batch, fetch_list=[built['loss']])
-        after = moe_counts(), moe_counts(by), moe_row_sum_counts()
+        after = counts()
     moved = [{k: n - was.get(k, 0) for k, n in now.items()
               if n != was.get(k, 0)} for was, now in zip(before, after)]
     assert moved == [{('16', '8', '3', route): 1},
-                     {('16', '8', '3', route, row_sum): 1},
-                     {('pallas',): 1} if row_sum == 'pallas' else {}]
+                     {('16', '8', '3', route, row_sum, row_gather): 1},
+                     {('pallas',): 1} if row_sum == 'pallas' else {},
+                     {('pallas',): 1} if row_gather == 'pallas' else {}]
     assert calls and set(calls) == {route}
     assert bool(summed) == (row_sum == 'pallas')
+    assert bool(gathered) == (row_gather == 'pallas')
 
 
 def test_amp_keeps_scores_and_the_carried_state_float32(amp):
@@ -794,22 +812,34 @@ def _move_rows(p, x, transpose=False):
 
 @pytest.fixture
 def row_sum(request, monkeypatch):
-    """The route that sums a chunk's rows back into their tokens: 'xla'
-    (the scatter-add, as the CPU takes it) or 'pallas' forced through
-    the interpreter, which fills what the kernel has not written with
-    NaN, in row blocks of 16 rows (the rule itself says no on this
-    backend). Returns the route and the list of the kernel's calls."""
+    """The route that moves a chunk's rows between it and their tokens:
+    'xla' (the scatter-add and ``jnp.take``, as the CPU takes them),
+    'pallas' (the kernels that sum the live rows back and gather their
+    gradients) or 'pallas-sum' (the sum's kernel; the gather declines,
+    the rows being narrower than 2048 lanes, and ``jnp.take`` runs, as
+    in the nemotron cell), the kernels forced through the interpreter,
+    which fills what a kernel has not written with NaN, in row blocks of
+    16 rows (the rules themselves say no on this backend). Returns the
+    route and the list of the kernels' calls, 'sum' or 'gather' each."""
     calls = []
-    if request.param == 'pallas':
+    if request.param != 'xla':
         from jax.experimental.pallas import tpu as pltpu
-        plan, kernel = pk.row_sum_plan, pk._row_sum_call
+        plan, summed, gathered = (pk.row_sum_plan, pk._row_sum_call,
+                                  pk._row_gather_call)
         nan_filled = pltpu.InterpretParams(uninitialized_memory='nan')
         monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', 16)
         monkeypatch.setattr(pk, 'row_sum_plan', lambda y, n, interpret=None:
                             plan(y, n, nan_filled))
         monkeypatch.setattr(pk, '_row_sum_call', lambda *a, **kw:
-                            calls.append(kw) or kernel(*a, **kw))
+                            calls.append('sum') or summed(*a, **kw))
+        monkeypatch.setattr(pk, '_row_gather_call', lambda *a, **kw:
+                            calls.append('gather') or gathered(*a, **kw))
+        if request.param == 'pallas':
+            monkeypatch.setattr(pk, '_ROW_GATHER_MIN_LANES', 128)
     return request.param, calls
+
+
+_KERNELS = {'xla': set(), 'pallas': {'sum', 'gather'}, 'pallas-sum': {'sum'}}
 
 
 _PLACED = {'first': (0, 0.15), 'overflow': (1, 0.33),
@@ -888,7 +918,8 @@ def _place_and_sum(k, u, y, xla=False):
             jnp.zeros_like(u).at[tok].add(y, mode='drop'))
 
 
-@pytest.mark.parametrize('row_sum', ['xla', 'pallas'], indirect=True)
+@pytest.mark.parametrize('row_sum', ['xla', 'pallas', 'pallas-sum'],
+                         indirect=True)
 @pytest.mark.parametrize('case', sorted(_PLACED))
 def test_rows_past_the_live_ones_are_ignored_both_ways(case, row_sum):
     """The rows past a chunk's live ones hold NaN, in the rows summed
@@ -922,7 +953,7 @@ def test_rows_past_the_live_ones_are_ignored_both_ways(case, row_sum):
     assert not got[1][live:].any()
     if live == 0:
         assert not got[0].any() and not got[2].any()
-    assert bool(calls) == (route == 'pallas')
+    assert set(calls) == _KERNELS[route]
 
 
 @pytest.mark.parametrize('case', sorted(_PLACED))

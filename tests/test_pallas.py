@@ -1357,28 +1357,43 @@ def test_grouped_plan_engages_by_backend_dtype_and_shape(
     assert got == plan
 
 
-# ---- rows summed back into their tokens (routed experts) -------------------
-@pytest.mark.parametrize('dtype,L,chunk,n,backend,interpret,plan', [
-    ('float32', 2048, 8448, 8192, 'tpu', None, (256, 512, False)),
-    ('float32', 1024, 3072, 4096, 'tpu', None, (256, 1024, False)),
-    ('float32', 2048, 8448, 8192, 'cpu', None, None),
-    ('bfloat16', 2048, 8448, 8192, 'tpu', None, None),
-    ('float32', 24, 256, 74, 'tpu', None, None),
-    ('float32', 2048, 8320, 8192, 'tpu', None, None),
-    ('float32', 128, 256, 50000, 'tpu', None, None),
-    ('float32', 128, 256, 74, 'cpu', True, (256, 128, True)),
+# ---- rows summed into their tokens and picked from them (routed experts) ---
+@pytest.mark.parametrize('rule,dtype,L,chunk,n,backend,interpret,plan', [
+    ('sum', 'float32', 2048, 8448, 8192, 'tpu', None, (256, 512, False)),
+    ('sum', 'float32', 1024, 3072, 4096, 'tpu', None, (256, 1024, False)),
+    ('sum', 'float32', 2048, 8448, 8192, 'cpu', None, None),
+    ('sum', 'bfloat16', 2048, 8448, 8192, 'tpu', None, None),
+    ('sum', 'float32', 24, 256, 74, 'tpu', None, None),
+    ('sum', 'float32', 2048, 8320, 8192, 'tpu', None, None),
+    ('sum', 'float32', 128, 256, 50000, 'tpu', None, None),
+    ('sum', 'float32', 128, 256, 74, 'cpu', True, (256, 128, True)),
+    ('gather', 'float32', 2048, 8448, 8192, 'tpu', None, (256, 512, False)),
+    ('gather', 'float32', 1024, 3072, 4096, 'tpu', None, None),
+    ('gather', 'float32', 2048, 8448, 8192, 'cpu', None, None),
+    ('gather', 'bfloat16', 2048, 8448, 8192, 'tpu', None, None),
+    ('gather', 'float32', 2048, 8320, 8192, 'tpu', None, None),
+    ('gather', 'float32', 2048, 256, 50000, 'tpu', None, None),
+    ('gather', 'float32', 2048, 256, 74, 'cpu', True, (256, 2048, True)),
+    ('gather', 'float32', 128, 256, 74, 'cpu', True, None),
 ], ids=['trinity', 'nemotron', 'cpu', 'bf16-rows', 'odd-width',
-        'odd-chunk', 'too-many-tokens', 'interpreter'])
+        'odd-chunk', 'too-many-tokens', 'interpreter', 'gather-trinity',
+        'gather-nemotron-narrow', 'gather-cpu', 'gather-bf16-rows',
+        'gather-odd-chunk', 'gather-too-many-tokens', 'gather-interpreter',
+        'gather-interpreter-narrow'])
 def test_row_sum_plan_engages_by_backend_dtype_and_shape(
-        dtype, L, chunk, n, backend, interpret, plan, monkeypatch):
-    """The kernel takes float32 rows on a TPU backend, a width of whole
-    128-lane tiles and a chunk of whole row blocks; its column block is
-    the widest whose [n, block] result stays under the VMEM budget (512
-    of 2048 lanes at 8192 tokens, all 1024 at 4096), and with none of
-    128 lanes under it XLA's scatter-add runs."""
+        rule, dtype, L, chunk, n, backend, interpret, plan, monkeypatch):
+    """The sum's kernel takes float32 rows on a TPU backend, a width of
+    whole 128-lane tiles and a chunk of whole row blocks; its column
+    block is the widest whose [n, block] result stays under the VMEM
+    budget (512 of 2048 lanes at 8192 tokens, all 1024 at 4096), and
+    with none of 128 lanes under it XLA's scatter-add runs. The
+    gather's kernel (row_gather_plan) takes the same rule and blocks,
+    its [n, block] column block of the tokens held as the sum's result
+    is, where the rows are at least 2048 lanes wide: at nemotron's 1024
+    (and in the interpreter alike) ``jnp.take`` runs."""
     monkeypatch.setattr(pk, '_on_tpu', lambda: backend == 'tpu')
-    got = pk.row_sum_plan(jax.ShapeDtypeStruct((chunk, L), dtype), n,
-                          interpret)
+    rule = pk.row_sum_plan if rule == 'sum' else pk.row_gather_plan
+    got = rule(jax.ShapeDtypeStruct((chunk, L), dtype), n, interpret)
     assert got == plan
 
 
@@ -1405,6 +1420,39 @@ def test_row_sum_adds_the_live_rows_in_row_order(live, monkeypatch):
     plan = pk.row_sum_plan(jax.ShapeDtypeStruct((chunk, L), jnp.float32), n,
                            pltpu.InterpretParams(uninitialized_memory='nan'))
     got = np.asarray(pk.row_sum(jnp.asarray(y), jnp.asarray(tok),
-                                jnp.int32(live), n, plan))
+                                jnp.int32(live), n, plan, None))
     np.testing.assert_array_equal(got, want)
     assert not got[np.setdiff1d(np.arange(n), tok[:live])].any()
+
+
+@pytest.mark.parametrize('live', [0, 1, 15, 16, 17, 63, 64])
+def test_row_gather_copies_the_live_rows_from_their_tokens(
+        live, monkeypatch):
+    """The kernel through the interpreter that fills what it has not
+    written with NaN, in row blocks of 16: below ``live`` row r is
+    src[tok[r]] and past it a zero row, bit for bit what ``jnp.take``
+    and the live mask give, with no live row at all, mid-block, at a
+    block's edge and over the whole chunk, where one token takes two
+    rows in a row and where several held experts name one token. The
+    rows past ``live`` (tokens out of range) and the tokens no live row
+    names (NaN) are never copied."""
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(pk, '_ROW_SUM_BLOCK_ROWS', 16)
+    monkeypatch.setattr(pk, '_ROW_GATHER_MIN_LANES', 128)
+    rng = np.random.RandomState(live)
+    chunk, L, n = 64, 256, 40
+    tok = rng.randint(0, n, chunk).astype(np.int32)
+    tok[1::7] = tok[0::7][:len(tok[1::7])]         # a token twice in a row
+    src = rng.randn(n, L).astype(np.float32)
+    src[np.setdiff1d(np.arange(n), tok[:live])] = np.nan
+    want = np.where(np.arange(chunk)[:, None] < live, src[tok], 0.0)
+    tok[live:] = 10 ** 6
+    src, tok = jnp.asarray(src), jnp.asarray(tok)
+    plan = pk.row_gather_plan(jax.ShapeDtypeStruct((chunk, L), jnp.float32),
+                              n, pltpu.InterpretParams(
+                                  uninitialized_memory='nan'))
+    got = pk.row_gather(src, tok, jnp.int32(live), n, plan)
+    xla = pk.row_gather(src, tok, jnp.int32(live), n, None)
+    assert plan is not None and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(xla))
